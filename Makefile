@@ -5,7 +5,7 @@
 
 PYTEST = python -m pytest -q
 
-.PHONY: test test-fast test-slow test-all test-onchip bench bench-comm \
+.PHONY: test test-fast test-slow test-all chip-smoke bench bench-comm \
         bench-comm-smoke native telemetry-smoke prof-smoke transport-smoke \
         stripe-smoke tracerec-smoke async-smoke ffi-smoke fused-smoke \
         probe-smoke placement-smoke synth-smoke hier-smoke sharded-smoke \
@@ -26,16 +26,17 @@ test-fast:
 	$(PYTEST) tests/ -m "not slow"
 
 # Slow tier: multi-process bfrun launches, example e2e runs, heavy model
-# grids, on-chip kernel checks (TPU tests self-skip without a chip).
+# grids.
 test-slow:
 	$(PYTEST) tests/ -m "slow"
 
 test-all:
 	$(PYTEST) tests/
 
-# On-chip subset only (flash/mosaic kernels compiled for the real TPU).
-test-onchip:
-	$(PYTEST) tests/ -m "slow" -k "on_tpu"
+# The chip check: trainer, gossip, compiled flash kernels and the 2048-wide
+# LM on every TPU chip of this host; exits non-zero without a TPU.
+chip-smoke:
+	python chip_smoke.py
 
 bench:
 	python bench.py

@@ -543,9 +543,8 @@ def transport_main(args) -> int:
     ffi_detail = None
     ffi_value = None
     if not smoke and native_ok:
-        from bluefog_tpu import _compat
         from bluefog_tpu import native as _native
-        if _native.has_win_xla() and _compat.jax_ffi() is not None \
+        if _native.has_win_xla() \
                 and os.environ.get("BLUEFOG_TPU_WIN_XLA") != "0":
             os.environ.setdefault("JAX_PLATFORMS", "cpu")
             flags = os.environ.get("XLA_FLAGS", "")
@@ -1899,14 +1898,11 @@ def ffi_main(args) -> int:
         os.environ["XLA_FLAGS"] = (
             flags + " --xla_force_host_platform_device_count=8")
 
-    from bluefog_tpu import _compat, native
+    from bluefog_tpu import native
 
-    if not (native.available() and native.has_win_xla()
-            and _compat.jax_ffi() is not None):
+    if not (native.available() and native.has_win_xla()):
         reason = ("native core lacks bf_xla symbols"
                   if native.available() else "native core not built")
-        if _compat.jax_ffi() is None:
-            reason = "jax has no ffi module"
         print(json.dumps({
             "metric": "win_put_ffi_dispatch_speedup", "value": None,
             "unit": "x", "status": "skipped",

@@ -14,7 +14,6 @@ Public surface mirrors ``import bluefog.torch as bf`` (reference
 >>> y = bf.neighbor_allreduce(x)
 """
 
-from bluefog_tpu import _compat  # noqa: F401  — jax version shims first
 from bluefog_tpu import topology  # noqa: F401
 from bluefog_tpu import topology as topology_util  # parity alias  # noqa: F401
 
@@ -40,6 +39,7 @@ from bluefog_tpu.basics import (  # noqa: F401
     owned_ranks,
     mesh,
     hierarchical_mesh,
+    rank_map,
     set_topology,
     set_machine_topology,
     placement_info,

@@ -203,6 +203,7 @@ def init(topology_fn=None, is_weighted: bool = False, *,
         np.asarray(devs).reshape(n // local_size, local_size),
         (MACHINE_AXIS, LOCAL_AXIS))
     _ctx.initialized = True
+    _configure_compile_cache()
     topo = topology_fn() if topology_fn is not None \
         else topology_util.ExponentialGraph(n)
     set_topology(topo, is_weighted=is_weighted)
@@ -215,20 +216,28 @@ def init(topology_fn=None, is_weighted: bool = False, *,
     telemetry.maybe_start_endpoint()
 
 
-def _local_device_kwargs(env) -> dict:
-    """Device ownership for multi-slot hosts (``bfrun -H host:slots``).
+def _configure_compile_cache() -> None:
+    """Give XLA's persistent compile cache a home that can be placed from
+    outside and survives the process.
 
-    With several processes on one host, each slot must claim a disjoint
-    device — the reference maps one GPU per mpirun slot
-    (``run/run.py:180-203`` ``-map-by slot``); here slot ``i`` owns local
-    device ``i`` via ``jax.distributed.initialize(local_device_ids=[i])``.
-    The virtual CPU mode (``BFTPU_LOCAL_DEVICES``) is exempt: there each
-    process forges its own private host-platform devices.
-    """
-    local_size = int(env.get("BFTPU_LOCAL_SIZE", "1"))
-    if local_size > 1 and "BFTPU_LOCAL_DEVICES" not in env:
-        return {"local_device_ids": [int(env.get("BFTPU_LOCAL_ID", "0"))]}
-    return {}
+    With ``JAX_COMPILATION_CACHE_DIR`` set, jax already reads it and nothing
+    is set here.  Otherwise the cache goes to ``<checkout>/.jax_cache`` (the
+    parent of this package's directory) — a fixed path, because the path is
+    part of the cache key and a directory that moves never hits.  CPU meshes
+    (tests, virtual-mesh smokes) set nothing: their compiles are cheap, and
+    the checkout should not grow under a test run."""
+    import os as _os
+    if (_os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or _mesh_platform() == "cpu"):
+        return
+    jax.config.update("jax_compilation_cache_dir", _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jax_cache"))
+
+
+# What jax.distributed.initialize() raises when its cluster auto-detection
+# (TPU pod metadata, SLURM, Open MPI, ...) recognised no environment.
+_NO_CLUSTER_ENV = "coordinator_address should be defined"
 
 
 def init_distributed(topology_fn=None, is_weighted: bool = False) -> None:
@@ -238,8 +247,12 @@ def init_distributed(topology_fn=None, is_weighted: bool = False) -> None:
     Reads the ``BFTPU_COORDINATOR`` / ``BFTPU_NUM_PROCESSES`` /
     ``BFTPU_PROCESS_ID`` env set by ``bfrun`` (``python -m bluefog_tpu.run``);
     with none set, defers to ``jax.distributed.initialize()`` auto-detection
-    (TPU pod metadata).  Replaces the reference's ``MPI_Init`` + bfrun/mpirun
-    contract (``run/run.py:180-203``).
+    (TPU pod metadata) and stays a one-process world only when that finds no
+    cluster environment — any other failure propagates.  Which local devices
+    a process owns is decided before jax loads, by the environment ``bfrun``
+    prepares (one TPU chip per slot, or a private virtual CPU mesh).
+    Replaces the reference's ``MPI_Init`` + bfrun/mpirun contract
+    (``run/run.py:180-203``).
     """
     import os as _os
     coord = _os.environ.get("BFTPU_COORDINATOR")
@@ -248,12 +261,15 @@ def init_distributed(topology_fn=None, is_weighted: bool = False) -> None:
             coordinator_address=coord,
             num_processes=int(_os.environ["BFTPU_NUM_PROCESSES"]),
             process_id=int(_os.environ["BFTPU_PROCESS_ID"]),
-            **_local_device_kwargs(_os.environ))
-    elif jax.process_count() == 1:
+            # Everything is explicit: skip the cluster probes, which on a
+            # TPU VM query a metadata server that may not exist.
+            cluster_detection_method="deactivate")
+    elif not jax.distributed.is_initialized():
         try:
             jax.distributed.initialize()
-        except Exception:  # single-process fallback (no pod metadata)
-            pass
+        except ValueError as e:
+            if _NO_CLUSTER_ENV not in str(e):
+                raise
     init(topology_fn, is_weighted)
     if jax.process_count() > 1:
         # Placement probe (reference mpi_controller.cc:71-96): feeds
@@ -418,6 +434,43 @@ def mesh() -> Mesh:
 def hierarchical_mesh() -> Mesh:
     """The 2-D (machine, local) mesh backing hierarchical ops."""
     return _require_init().hier_mesh
+
+
+def rank_map(fn):
+    """Run ``fn`` once per rank, each on that rank's own device.
+
+    The rank-major counterpart of ``jax.vmap``: every argument and result of
+    the returned callable is a pytree of rank-major arrays (leading dim ==
+    ``bf.size()``), and ``fn`` sees rank ``i``'s rows without that axis —
+    ``bf.rank_map(jax.grad(loss))(params, batch)`` is the per-rank gradient,
+    ``bf.rank_map(lambda: model.init(key, x))()`` builds a rank-major tree
+    with each row born on its own device.  It compiles to one
+    ``jit(shard_map)`` over :func:`mesh` with the rank axis in and out, so
+    the program holds no cross-device collective unless ``fn`` runs one over
+    the rank axis itself.  (``jit(vmap(fn))`` leaves the split to the SPMD
+    partitioner, which all-gathers rank-sharded conv operands.)  Every value
+    is per-rank by construction, so there is no replication for
+    ``check_vma`` to track, and it is off: ``fn`` is ordinary model code (a
+    ``lax.scan`` with a constant initial carry does not type-check under
+    it).  The result also has ``.lower(*args)``, like a jitted function."""
+    def run(*args):
+        out = fn(*jax.tree.map(lambda x: x[0], args))
+        return jax.tree.map(lambda x: x[None], out)
+
+    compiled = {}  # per mesh: set_topology may re-place ranks onto devices
+
+    def program():
+        mesh = _require_init().mesh
+        if mesh not in compiled:
+            compiled[mesh] = jax.jit(jax.shard_map(
+                run, mesh=mesh, in_specs=P(RANK_AXIS),
+                out_specs=P(RANK_AXIS), check_vma=False))
+        return compiled[mesh]
+
+    def mapped(*args):
+        return program()(*args)
+    mapped.lower = lambda *args: program().lower(*args)
+    return mapped
 
 
 # ---------------------------------------------------------------------------

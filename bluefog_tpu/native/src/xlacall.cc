@@ -15,7 +15,7 @@
 //                            pointer (CPU backend: device memory IS host
 //                            memory) and calls in over ctypes;
 //   * bf_xla_win_put       — the XLA FFI handler (registered through
-//                            jax.ffi / jax.extend.ffi): the same put
+//                            jax.ffi): the same put
 //                            lowered INTO a compiled program, so an
 //                            optimizer step can issue its puts while XLA
 //                            is still executing the rest of the program.

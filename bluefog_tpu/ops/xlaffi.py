@@ -17,13 +17,13 @@ Two dispatch routes share the one native executor:
   → one ctypes call into ``bf_xla_plan_run`` — microseconds of host work
   per put, independent of row size;
 * **in-program** (``bf_xla_win_put``): the same plan lowered to an XLA
-  FFI custom call (registered through ``jax.ffi`` /
-  ``jax.extend.ffi``), so a compiled step can issue its puts while XLA
-  is still executing the rest of the program — :func:`xla_put_program`.
+  FFI custom call (registered through ``jax.ffi``), so a compiled step
+  can issue its puts while XLA is still executing the rest of the
+  program — :func:`xla_put_program`.
 
-Arming (``BLUEFOG_TPU_WIN_XLA``, default on): requires the jax FFI
-module (``_compat.jax_ffi``), a current native core carrying the
-``bf_xla_*`` symbols, and host-addressable device buffers (CPU backend).
+Arming (``BLUEFOG_TPU_WIN_XLA``, default on): requires a current
+native core carrying the ``bf_xla_*`` symbols and host-addressable
+device buffers (CPU backend).
 Anything missing auto-disarms with ONE logged warning and the PR-9 path
 — kept fully intact — serves every put (``=0`` pins it unconditionally:
 the bitwise equivalence oracle, same contract PR 9 used for
@@ -91,10 +91,6 @@ def _evaluate() -> Tuple[bool, Optional[str]]:
     cfg = config.get()
     if not cfg.win_xla:
         return False, "BLUEFOG_TPU_WIN_XLA=0"
-    from bluefog_tpu import _compat
-    if _compat.jax_ffi() is None:
-        return False, ("this jax release has no jax.ffi / jax.extend.ffi "
-                       "module")
     if not native.has_win_xla():
         return False, ("native core lacks the bf_xla_plan symbols "
                        "(stale or old .so — run `make -C "
@@ -526,10 +522,7 @@ def _ensure_registered() -> bool:
         return True
     if not native.has_xla_handler():
         return False
-    from bluefog_tpu import _compat
-    mod = _compat.jax_ffi()
-    if mod is None:
-        return False
+    from jax import ffi as mod
     lib = native.lib()
     with _lock:
         if _registered[0]:
@@ -590,9 +583,8 @@ def xla_probe_program(probe_id: int):
     still works)."""
     if not has_probe():
         return None
-    from bluefog_tpu import _compat
     import jax
-    mod = _compat.jax_ffi()
+    from jax import ffi as mod
 
     def run(x):
         call = mod.ffi_call(
@@ -609,14 +601,12 @@ def xla_put_program(plan_id: int, tx: int):
     i32[1]`` status whose XLA custom call executes the SAME native plan
     mid-program — embed it in a jitted step so the transport enqueue
     overlaps the rest of the program's execution.  None when the FFI
-    handler or jax FFI module is unavailable (the eager pointer dispatch
-    still works)."""
+    handler is unavailable (the eager pointer dispatch still works)."""
     if not _ensure_registered():
         return None
-    from bluefog_tpu import _compat
     import jax
     import jax.numpy as jnp
-    mod = _compat.jax_ffi()
+    from jax import ffi as mod
     call = mod.ffi_call("bf_xla_win_put",
                         jax.ShapeDtypeStruct((1,), jnp.int32),
                         has_side_effect=True)
@@ -637,10 +627,9 @@ def xla_put_program_pass(plan_id: int, tx: int):
     the handler (or the pass variant of it) is unavailable."""
     if not has_passthrough():
         return None
-    from bluefog_tpu import _compat
     import jax
     import jax.numpy as jnp
-    mod = _compat.jax_ffi()
+    from jax import ffi as mod
 
     def run(x):
         call = mod.ffi_call(

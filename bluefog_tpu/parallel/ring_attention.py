@@ -22,11 +22,11 @@ index.  Partials merge by logsumexp weighting, and the lse cotangent flows
 back through the kernel's VJP, keeping the whole op differentiable.
 
 All inputs/outputs are per-device blocks ``(B, S_local, H, D)`` — call inside
-``shard_map`` with the sequence axis sharded over ``axis_name``.  On CPU
-(Pallas interpreter) pass ``check_vma=False`` to that ``shard_map``:
-in-kernel constants are not vma-tracked under the interpreter.  Compiled
+``shard_map`` with the sequence axis sharded over ``axis_name``.  Compiled
 Mosaic kernels on TPU work under the default ``check_vma=True`` (the kernels
-declare their varying axes via ``vma``).
+declare the axes their inputs vary over); on CPU (Pallas interpreter) pass
+``check_vma=False`` to that ``shard_map``: the interpreter's in-kernel
+constants are not vma-tracked.
 """
 
 from __future__ import annotations
@@ -45,9 +45,7 @@ _NEG = -1e30  # finite "minus infinity": logaddexp/exp stay NaN-free
 
 
 def _pvary(x, axis_name):
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axis_name, to="varying")
-    return lax.pvary(x, (axis_name,))
+    return lax.pcast(x, axis_name, to="varying")
 
 
 def _merge(o, lse, o_h, lse_h):
@@ -75,8 +73,7 @@ def ring_attention(q, k, v, *, axis_name: str, causal: bool = True):
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def flash(q, k_blk, v_blk, hop_causal):
-        o, lse = flash_attention_lse(q, k_blk, v_blk, causal=hop_causal,
-                                     vma=frozenset({axis_name}))
+        o, lse = flash_attention_lse(q, k_blk, v_blk, causal=hop_causal)
         return o.astype(jnp.float32), lse
 
     def hop_partial(q, k_blk, v_blk, src):

@@ -26,22 +26,20 @@ def ulysses_attention(q, k, v, *, axis_name: str, causal: bool = True,
     """All-to-all head-parallel attention over ``axis_name``.
 
     ``inner_attention(q, k, v, causal=...)`` runs on the gathered-sequence /
-    sharded-head layout.  Default: the compiled flash kernel on TPU (the
-    gathered sequence is exactly where O(S) memory matters), dense
-    ``local_attention`` elsewhere (the Pallas interpreter would dominate
-    CPU-mesh test time).
+    sharded-head layout.  Default: the compiled flash kernel when the
+    devices in use are TPUs (the gathered sequence is exactly where O(S)
+    memory matters), dense ``local_attention`` elsewhere (the Pallas
+    interpreter would dominate CPU-mesh test time).
     """
     n = lax.axis_size(axis_name)
     H = q.shape[2]
     assert H % n == 0, f"num_heads {H} must be divisible by axis size {n}"
     inner = inner_attention
     if inner is None:
-        import jax
-        if jax.default_backend() == "tpu":
-            from bluefog_tpu.ops.flash_attention import flash_attention
-            inner = partial(flash_attention, vma=frozenset({axis_name}))
-        else:
-            inner = local_attention
+        from bluefog_tpu.ops.flash_attention import (flash_attention,
+                                                     platform_in_use)
+        inner = (flash_attention if platform_in_use(q) == "tpu"
+                 else local_attention)
 
     def scatter_heads(x):  # (B, S/n, H, D) -> (B, S, H/n, D)
         return lax.all_to_all(x, axis_name, split_axis=2, concat_axis=1,

@@ -16,7 +16,9 @@ Modes
     python -m bluefog_tpu.run -np 8 -H tpu-host-0:4,tpu-host-1:4 python train.py
   launches ``slots`` processes per host via ssh (slot-major rank order, like
   mpirun ``-map-by slot``) with the coordinator on the first host.  A bare
-  hostname means one slot.
+  hostname means one slot.  On a TPU host each slot gets one chip
+  (:func:`tpu_slot_env`); one process driving all of a host's chips
+  (``bf.init()``, no launcher) is the main path.
 * TPU pod slices: run the same command on every host (GKE/xmanager style);
   ``bf.init_distributed()`` with no env auto-detects the TPU pod coordinator.
 """
@@ -34,7 +36,8 @@ import threading
 import time
 import uuid
 
-__all__ = ["main", "build_parser", "parse_hosts", "virtual_mesh_env"]
+__all__ = ["main", "build_parser", "parse_hosts", "virtual_mesh_env",
+           "tpu_slot_env"]
 
 
 def virtual_mesh_env(env: dict, num_devices: int) -> dict:
@@ -47,6 +50,44 @@ def virtual_mesh_env(env: dict, num_devices: int) -> dict:
     env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count="
                         f"{num_devices}")
     env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
+# The chips of one TPU host as a libtpu process grid, by slot count (TPU
+# hosts hold 1, 4 or 8 chips; a single slot keeps the whole host).
+_TPU_PROCESS_BOUNDS = {2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+_TPU_PROCESS_PORT = 8476  # libtpu's conventional first process port
+_TPU_SLOT_KEYS = ("TPU_VISIBLE_CHIPS", "TPU_CHIPS_PER_PROCESS_BOUNDS",
+                  "TPU_PROCESS_BOUNDS", "TPU_PROCESS_ADDRESSES",
+                  "TPU_PROCESS_PORT", "CLOUD_TPU_TASK_ID")
+
+
+def tpu_slot_env(env: dict, local_rank: int, local_size: int) -> dict:
+    """Mutate ``env`` so that, on a TPU host, slot ``local_rank`` of
+    ``local_size`` opens chip ``local_rank`` and nothing else.
+
+    libtpu decides which chips a process owns from its environment, before
+    jax loads (``jax.distributed.initialize(local_device_ids=...)`` only
+    steers GPUs), and without these variables every slot opens every chip
+    of the host and all but one fail.  The host's slots form one libtpu
+    process grid on loopback ports.  The host-level spellings of the same
+    settings, which a TPU VM image may export, are dropped so that the two
+    cannot disagree.  Inert on hosts without TPUs; slot counts that are no
+    TPU host's chip count are left alone."""
+    bounds = _TPU_PROCESS_BOUNDS.get(local_size)
+    if bounds is None:
+        return env
+    for legacy in ("TPU_CHIPS_PER_HOST_BOUNDS", "TPU_HOST_BOUNDS",
+                   "TPU_WORKER_HOSTNAMES", "TPU_WORKER_ID",
+                   "TPU_VISIBLE_DEVICES"):
+        env.pop(legacy, None)
+    env["TPU_VISIBLE_CHIPS"] = str(local_rank)
+    env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+    env["TPU_PROCESS_BOUNDS"] = bounds
+    env["TPU_PROCESS_ADDRESSES"] = ",".join(
+        f"localhost:{_TPU_PROCESS_PORT + i}" for i in range(local_size))
+    env["TPU_PROCESS_PORT"] = str(_TPU_PROCESS_PORT + local_rank)
+    env["CLOUD_TPU_TASK_ID"] = str(local_rank)
     return env
 
 
@@ -192,7 +233,8 @@ def remote_run_cmd(env: dict, cmd: list) -> str:
     new env var cannot reach one launcher's remote ranks and not the
     other's."""
     exports = " ".join(f"{k}={shlex.quote(v)}" for k, v in env.items()
-                       if k.startswith(_ENV_EXPORT_PREFIXES)
+                       if (k.startswith(_ENV_EXPORT_PREFIXES)
+                           or k in _TPU_SLOT_KEYS)
                        and k not in _ENV_NEVER_INLINE)
     return (f"cd {shlex.quote(os.getcwd())} && {exports} "
             + " ".join(shlex.quote(c) for c in cmd))
@@ -350,6 +392,8 @@ def _child_env(args, coord: str, rank: int, local_rank: int = 0,
             virtual_mesh_env(env, n)
         else:
             virtual_mesh_env(env, args.devices_per_proc)
+    else:
+        tpu_slot_env(env, local_rank, local_size)
     if elastic:
         env.setdefault("BLUEFOG_TPU_ELASTIC_JOIN", "1")
         env.setdefault("BLUEFOG_TPU_CHURN", "1")

@@ -181,12 +181,11 @@ def restore_host(path: str, *, step: Optional[int] = None) -> Any:
     fits the result to the live geometry afterwards."""
     import orbax.checkpoint as ocp
 
-    from bluefog_tpu import _compat
     path = os.path.abspath(path)
     if step is not None:
         path = os.path.join(path, f"step_{step:010d}")
     ckpt = _checkpointer()
-    meta = _compat.checkpoint_tree_metadata(ckpt, path)
+    meta = ckpt.metadata(path).item_metadata.tree
     restore_args = jax.tree.map(
         lambda m: ocp.RestoreArgs(restore_type=np.ndarray), meta)
     return ckpt.restore(path,
@@ -197,11 +196,10 @@ def leaf_shapes(path: str, *, step: Optional[int] = None) -> list:
     """Shapes of the saved leaves in tree-leaf order, WITHOUT reading data
     (orbax metadata only) — lets a restarting run detect that a checkpoint
     was written by a different world geometry before attempting restore."""
-    from bluefog_tpu import _compat
     path = os.path.abspath(path)
     if step is not None:
         path = os.path.join(path, f"step_{step:010d}")
-    meta = _compat.checkpoint_tree_metadata(_checkpointer(), path)
+    meta = _checkpointer().metadata(path).item_metadata.tree
     return [tuple(m.shape) for m in jax.tree.leaves(meta)]
 
 

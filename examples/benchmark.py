@@ -100,10 +100,29 @@ def build_parser():
     ap.add_argument("--mfu", action="store_true",
                     help="transformer model: also report model FLOPs "
                          "utilization from the measured tok/s")
-    ap.add_argument("--peak-tflops", type=float, default=197.0,
+    ap.add_argument("--peak-tflops", type=float, default=None,
                     help="accelerator peak (bf16) TFLOP/s for --mfu "
-                         "(default: TPU v5e)")
+                         "(default: looked up by device kind)")
     return ap
+
+
+# Peak dense bf16 TFLOP/s per chip by ``jax.devices()[0].device_kind``
+# (Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16).
+PEAK_BF16_TFLOPS = {"TPU v5 lite": 197.0}
+
+
+def peak_tflops(args) -> float:
+    """``--peak-tflops``, or the table's row for the device in use; a device
+    kind that is not in the table is an error, not a default."""
+    if args.peak_tflops is not None:
+        return args.peak_tflops
+    import jax
+    kind = jax.devices()[0].device_kind
+    if kind not in PEAK_BF16_TFLOPS:
+        raise SystemExit(
+            f"--mfu: no peak TFLOP/s on record for device kind {kind!r} "
+            f"(known: {sorted(PEAK_BF16_TFLOPS)}); pass --peak-tflops")
+    return PEAK_BF16_TFLOPS[kind]
 
 
 def transformer_train_flops_per_token(args, params_total: int) -> float:
@@ -137,25 +156,28 @@ def measure(args, devices=None, quiet=False):
         from bluefog_tpu.ops.flash_attention import flash_attention_impl
         attn = flash_attention_impl()
 
+    def images(*shape, dtype=jnp.bfloat16):
+        """A rank-major synthetic batch, each row made on its rank's device."""
+        return bf.rank_map(
+            lambda: jnp.zeros((args.batch_size,) + shape, dtype))()
+
     if args.model.startswith(("resnet", "vgg")):
         name = args.model.replace("resnet", "ResNet").replace("vgg", "VGG")
         model = getattr(models, name)(num_classes=1000, dtype=jnp.bfloat16)
-        data = jnp.zeros((n, args.batch_size, args.image_size,
-                          args.image_size, 3), jnp.bfloat16)
-        labels = jnp.zeros((n, args.batch_size), jnp.int32)
+        data = images(args.image_size, args.image_size, 3)
+        labels = images(dtype=jnp.int32)
         has_bn = args.model.startswith("resnet")  # classic VGG has no BN
     elif args.model == "lenet":
         model = models.LeNet5()
-        data = jnp.zeros((n, args.batch_size, 28, 28, 1))
-        labels = jnp.zeros((n, args.batch_size), jnp.int32)
+        data = images(28, 28, 1, dtype=jnp.float32)
+        labels = images(dtype=jnp.int32)
         has_bn = False
     elif args.model == "vit":
         model = models.ViT(num_classes=1000, image_size=args.image_size,
                            dtype=jnp.bfloat16, remat=args.remat,
                            remat_policy=args.remat_policy, attn_impl=attn)
-        data = jnp.zeros((n, args.batch_size, args.image_size,
-                          args.image_size, 3), jnp.bfloat16)
-        labels = jnp.zeros((n, args.batch_size), jnp.int32)
+        data = images(args.image_size, args.image_size, 3)
+        labels = images(dtype=jnp.int32)
         has_bn = False
     else:
         cfg = models.TransformerConfig(
@@ -168,19 +190,20 @@ def measure(args, devices=None, quiet=False):
             pos_encoding="rope" if args.rope else "learned",
             mlp="swiglu" if args.swiglu else "gelu")
         model = models.TransformerLM(cfg, attn_impl=attn)
-        data = jnp.zeros((n, args.batch_size, args.seq_len), jnp.int32)
+        data = images(args.seq_len, dtype=jnp.int32)
         labels = None
         has_bn = False
 
-    sample = data[0][:2]
-    variables = model.init(jax.random.PRNGKey(0), sample)
+    # Rank-major from birth: every rank initialises its own copy on its own
+    # device (n copies of a billion-parameter model do not fit device 0).
+    sample_shape, sample_dtype = (2,) + data.shape[2:], data.dtype
+    variables = bf.rank_map(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros(sample_shape, sample_dtype)))()
     # Stashed for --mfu reporting in main() (measure()'s return shape is
     # pinned by callers).
     args._params_total = sum(
-        int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(
+        int(np.prod(p.shape[1:])) for p in jax.tree_util.tree_leaves(
             variables["params"] if "params" in variables else variables))
-    rank_major = lambda t: jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), t)
 
     comm = {"neighbor_allreduce": CommunicationType.neighbor_allreduce,
             "allreduce": CommunicationType.allreduce,
@@ -213,8 +236,7 @@ def measure(args, devices=None, quiet=False):
                   compression=args.compression, donate=True)
 
     if has_bn:
-        params = rank_major(variables["params"])
-        bstats = rank_major(variables["batch_stats"])
+        params, bstats = variables["params"], variables["batch_stats"]
 
         def loss_fn(p, bs, x, y):
             logits, new = model.apply({"params": p, "batch_stats": bs},
@@ -222,7 +244,7 @@ def measure(args, devices=None, quiet=False):
             return optax.softmax_cross_entropy_with_integer_labels(
                 logits, y).mean(), new["batch_stats"]
 
-        vgrad = jax.jit(jax.vmap(jax.value_and_grad(loss_fn, has_aux=True)))
+        vgrad = bf.rank_map(jax.value_and_grad(loss_fn, has_aux=True))
 
         def one_batch(params, bstats, state, batch):
             x, y = batch
@@ -230,8 +252,7 @@ def measure(args, devices=None, quiet=False):
             params, state = opt.step(params, grads, state)
             return params, bstats, state
     else:
-        params = rank_major(variables["params"] if "params" in variables
-                            else variables)
+        params = variables["params"] if "params" in variables else variables
         if args.model == "transformer" and args.chunked_loss:
             from bluefog_tpu.ops.chunked_loss import \
                 chunked_softmax_cross_entropy
@@ -257,7 +278,7 @@ def measure(args, devices=None, quiet=False):
                 return optax.softmax_cross_entropy_with_integer_labels(
                     logits, y).mean()
 
-        vgrad = jax.jit(jax.vmap(jax.grad(loss_fn)))
+        vgrad = bf.rank_map(jax.grad(loss_fn))
         bstats = None
 
         def one_batch(params, bstats, state, batch):
@@ -324,6 +345,9 @@ def main():
     args = build_parser().parse_args()
     import jax
 
+    report_mfu = (args.mfu and args.model == "transformer"
+                  and not args.num_experts)
+    peak = peak_tflops(args) if report_mfu else None  # fail before measuring
     mean, ci, n = measure(args)
     unit = "tokens" if args.model == "transformer" else "img"
     if args.model == "transformer":
@@ -341,11 +365,10 @@ def main():
                   "per token); skipping the MFU report")
         else:
             fpt = transformer_train_flops_per_token(args, args._params_total)
-            mfu = mean / n * fpt / (args.peak_tflops * 1e12)
+            mfu = mean / n * fpt / (peak * 1e12)
             print(f"params: {args._params_total/1e9:.3f}B  "
                   f"train FLOPs/token: {fpt/1e9:.2f}G  "
-                  f"MFU: {100*mfu:.1f}% of {args.peak_tflops:.0f} "
-                  "TFLOP/s/chip")
+                  f"MFU: {100*mfu:.1f}% of {peak:.0f} TFLOP/s/chip")
 
     if args.efficiency and n > 1:
         mean1, _, _ = measure(args, devices=jax.devices()[:1], quiet=True)

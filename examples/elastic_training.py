@@ -59,9 +59,8 @@ def main():
                                    static_shards=True)
 
     model = MLP(features=(64,), num_classes=1)  # 1 output: regression head
-    p0 = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 16)))
-    params = jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), p0)
+    params = bf.rank_map(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 16))))()
     if args.optimizer == "push_sum":
         # Push-sum needs a topology whose out-degrees drive the
         # column-stochastic split; a directed ring keeps it simple.
@@ -74,7 +73,7 @@ def main():
     def loss_fn(p, x, y):
         return jnp.mean((model.apply(p, x) - y) ** 2)
 
-    grad_all = jax.jit(jax.vmap(jax.grad(loss_fn)))
+    grad_all = bf.rank_map(jax.grad(loss_fn))
     steps_per_epoch = loader.steps_per_epoch
 
     # Data order is derived from the step, so resume replays the same

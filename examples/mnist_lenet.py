@@ -55,9 +55,8 @@ def main():
     xt, yt = synthetic_mnist(n, 256, seed=123)  # held-out
 
     model = LeNet5()
-    params0 = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 28, 28, 1)))
-    params = jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), params0)
+    params = bf.rank_map(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 28, 28, 1))))()
 
     base = (optax.adam(args.lr) if args.base_optimizer == "adam"
             else optax.sgd(args.lr, momentum=0.9))
@@ -76,7 +75,7 @@ def main():
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, y).mean()
 
-    grad_all = jax.jit(jax.vmap(jax.grad(loss_fn)))
+    grad_all = bf.rank_map(jax.grad(loss_fn))
 
     @jax.jit
     def accuracy(params, x, y):

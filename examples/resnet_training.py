@@ -129,14 +129,13 @@ def main():
     x_val = x_val.reshape(-1, *x_val.shape[2:])
     y_val = y_val.reshape(-1)
 
-    variables = model.init(jax.random.PRNGKey(args.seed),
-                           jnp.asarray(x_train[0][:2]))
-    rank_major = lambda t: jax.tree.map(
-        lambda a: jnp.broadcast_to(a[None], (n,) + a.shape), t)
-    params = rank_major(variables["params"])
-    bstats = rank_major(variables["batch_stats"]) if has_bn else None
-    # Reference: bf.broadcast_parameters(model.state_dict(), root_rank=0)
-    params = bf.broadcast_parameters(params, root_rank=0)
+    # Rank-major from birth: every rank initialises the same seed on its
+    # own device (the reference broadcasts rank 0's state_dict instead).
+    sample = x_train[0][:2]
+    variables = bf.rank_map(lambda: model.init(
+        jax.random.PRNGKey(args.seed), jnp.asarray(sample)))()
+    params = variables["params"]
+    bstats = variables["batch_stats"] if has_bn else None
 
     batches_per_epoch = args.samples_per_rank // args.batch_size
     if batches_per_epoch < 1:
@@ -172,7 +171,7 @@ def main():
             loss = optax.softmax_cross_entropy_with_integer_labels(
                 logits, yb).mean()
             return loss, new["batch_stats"]
-        vgrad = jax.jit(jax.vmap(jax.value_and_grad(loss_fn, has_aux=True)))
+        vgrad = bf.rank_map(jax.value_and_grad(loss_fn, has_aux=True))
 
         @jax.jit
         def infer(p, bs, xb):
@@ -183,7 +182,7 @@ def main():
             logits = model.apply({"params": p}, xb)
             return optax.softmax_cross_entropy_with_integer_labels(
                 logits, yb).mean(), jnp.zeros(())
-        vgrad = jax.jit(jax.vmap(jax.value_and_grad(loss_fn, has_aux=True)))
+        vgrad = bf.rank_map(jax.value_and_grad(loss_fn, has_aux=True))
 
         @jax.jit
         def infer(p, _, xb):
@@ -220,8 +219,8 @@ def main():
         running = 0.0
         for b in range(batches_per_epoch):
             idx = order[b * args.batch_size:(b + 1) * args.batch_size]
-            xb = jnp.asarray(x_train[:, idx])
-            yb = jnp.asarray(y_train[:, idx])
+            # host arrays: each rank's rows go straight to its own device
+            xb, yb = x_train[:, idx], y_train[:, idx]
             if has_bn:
                 (loss, bstats), grads = vgrad(params, bstats, xb, yb)
             else:

@@ -141,10 +141,6 @@ def multiproc_child(args):
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     import jax
-    if os.environ.get("BFTPU_LOCAL_DEVICES"):
-        # Virtual-mesh mode: site hooks may pin another platform via
-        # jax.config, which overrides the JAX_PLATFORMS env bfrun sets.
-        jax.config.update("jax_platforms", "cpu")
 
     import bluefog_tpu as bf
     from bluefog_tpu import topology as topo

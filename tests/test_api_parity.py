@@ -104,7 +104,7 @@ def test_beyond_reference_surface_pinned():
         # window-state checkpointing
         "win_state_dict", "win_load_state_dict",
         # distributed bootstrap + mesh access
-        "init_distributed", "mesh", "hierarchical_mesh",
+        "init_distributed", "mesh", "hierarchical_mesh", "rank_map",
     ]:
         assert hasattr(bf, name), f"bf.{name} missing"
     from bluefog_tpu import parallel, models

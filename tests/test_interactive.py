@@ -83,9 +83,8 @@ def test_shutdown_unpauses_stall_watchdog():
 
 @pytest.mark.slow
 def test_ibfrun_command_mode_virtual_mesh(tmp_path):
-    """ibfrun -np 4 <cmd> prepares the virtual mesh for cmd — including the
-    platform pin, which the injected sitecustomize must supply (site hooks
-    that pin jax_platforms via jax.config beat plain env vars)."""
+    """ibfrun -np 4 <cmd> prepares the virtual mesh for cmd (device count
+    and platform, through the child's environment)."""
     script = tmp_path / "probe.py"
     script.write_text(
         "import bluefog_tpu as bf\n"

@@ -23,7 +23,8 @@ def qkv():
 def seq_sharded(fn, devices):
     # check_vma=False: the ring path calls Pallas kernels which on CPU run
     # under the interpreter, where in-kernel constants are not vma-tracked
-    # (compiled Mosaic kernels on TPU work under check_vma=True).
+    # (compiled Mosaic kernels on TPU work under check_vma=True:
+    # chip_smoke.py's ring leg).
     mesh = Mesh(np.asarray(devices), ("sp",))
     return jax.jit(jax.shard_map(
         fn, mesh=mesh,
@@ -102,50 +103,6 @@ def test_transformer_with_ring_attention(devices):
         out_specs=P(None, "sp"), check_vma=False))(tokens, positions)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
-
-
-@pytest.mark.slow
-def test_ring_flash_compiled_on_tpu_default_vma():
-    """Compiled Mosaic path: ring_attention (flash inner kernel) inside a
-    shard_map with the DEFAULT check_vma=True — exercises the vma threading
-    through the kernels' out_shapes.  Clean subprocess (the suite pins CPU);
-    skipped when no TPU is attached."""
-    import os
-    import subprocess
-    import sys
-    from conftest import tpu_subprocess_env
-    env = tpu_subprocess_env()  # skip on outage/no-TPU, FAIL on broken env
-    probe = """
-import jax, jax.numpy as jnp, numpy as np, sys
-if jax.default_backend() != "tpu":
-    print("NO-TPU"); sys.exit(0)
-from jax.sharding import Mesh, PartitionSpec as P
-from bluefog_tpu.parallel.ring_attention import ring_attention
-from bluefog_tpu.models import local_attention
-# ALL visible chips: on a pod this compiles the true multi-hop ring (switch over
-# Pallas branches, ppermute, vma threading); this sandbox has one chip, where
-# only the diagonal hop executes — still the compiled-under-check_vma path.
-ndev = len(jax.devices())
-B, S, H, D = 1, 256 * ndev, 4, 64  # per-device chunk stays 256 rows
-rng = np.random.RandomState(0)
-q, k, v = (jnp.asarray(rng.randn(B, S, H, D), jnp.bfloat16) for _ in range(3))
-mesh = Mesh(np.asarray(jax.devices()), ("sp",))
-f = jax.jit(jax.shard_map(
-    lambda a, b, c: ring_attention(a, b, c, axis_name="sp", causal=True),
-    mesh=mesh, in_specs=(P(None, "sp"),) * 3, out_specs=P(None, "sp")))
-out = f(q, k, v)
-ref = local_attention(q.astype(jnp.float32), k.astype(jnp.float32),
-                      v.astype(jnp.float32), causal=True)
-err = float(jnp.abs(out.astype(jnp.float32) - ref).max())
-assert err < 0.05, err
-print("RING-VMA-OK", err)
-"""
-    out = subprocess.run([sys.executable, "-c", probe], env=env,
-                         capture_output=True, text=True, timeout=560)
-    assert out.returncode == 0, f"stdout={out.stdout}\nstderr={out.stderr}"
-    if "NO-TPU" in out.stdout:
-        pytest.skip("no TPU attached")
-    assert "RING-VMA-OK" in out.stdout, out.stdout
 
 
 def test_tensor_parallel_sharded_forward_matches(devices):

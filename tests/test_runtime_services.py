@@ -335,18 +335,31 @@ def test_bfrun_host_slots_local(tmp_path):
     assert all(l["BFTPU_NUM_PROCESSES"] == "3" for l in lines)
 
 
-def test_local_device_ownership_kwargs():
-    """Co-hosted slots each claim one local device (reference -map-by slot:
-    one GPU per slot); the virtual CPU mode is exempt."""
-    from bluefog_tpu.basics import _local_device_kwargs
-    env = {"BFTPU_LOCAL_SIZE": "4", "BFTPU_LOCAL_ID": "2"}
-    assert _local_device_kwargs(env) == {"local_device_ids": [2]}
-    # single slot per host: the process owns all local devices (default)
-    assert _local_device_kwargs({"BFTPU_LOCAL_SIZE": "1"}) == {}
-    assert _local_device_kwargs({}) == {}
-    # CPU testing mode forges private per-process devices
-    env["BFTPU_LOCAL_DEVICES"] = "2"
-    assert _local_device_kwargs(env) == {}
+def test_tpu_slot_env_one_chip_per_slot():
+    """Co-hosted slots each get one TPU chip through libtpu's process
+    variables (reference -map-by slot: one GPU per slot); a single slot
+    keeps the whole host, and the virtual CPU mode is exempt."""
+    from bluefog_tpu.run.run import _child_env, build_parser, tpu_slot_env
+    legacy = {"TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1", "TPU_WORKER_ID": "0"}
+    envs = [tpu_slot_env(dict(legacy), i, 4) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "2,2,1"
+        ports = [a.rsplit(":", 1)[1]
+                 for a in e["TPU_PROCESS_ADDRESSES"].split(",")]
+        assert e["TPU_PROCESS_PORT"] in ports and len(ports) == 4
+        assert not set(legacy) & set(e)
+    # single slot per host: the process owns all local chips (default)
+    assert tpu_slot_env(dict(legacy), 0, 1) == legacy
+    # wired into the launcher; CPU testing mode forges private devices
+    args = build_parser().parse_args(["-np", "4", "true"])
+    assert _child_env(args, "h:1", 2, 2, 4)["TPU_VISIBLE_CHIPS"] == "2"
+    args = build_parser().parse_args(
+        ["-np", "4", "--devices-per-proc", "2", "true"])
+    assert "TPU_VISIBLE_CHIPS" not in _child_env(args, "h:1", 2, 2, 4)
 
 
 def test_packaging_metadata():

@@ -387,38 +387,8 @@ def test_host_path_reports_staging_copies(xla_env):
 
 
 # ---------------------------------------------------------------------------
-# Auto-disarm (jax stub without jax.ffi) and arming diagnostics
+# Arming diagnostics
 # ---------------------------------------------------------------------------
-
-def test_auto_disarm_without_jax_ffi(xla_env, monkeypatch, caplog):
-    """On a jax without jax.ffi/jax.extend.ffi the path disarms with one
-    warning, keep_device_ok refuses device arrays, and a put still works
-    through the fallback."""
-    from bluefog_tpu import _compat
-    xla_env(BLUEFOG_TPU_WIN_XLA=1)
-    monkeypatch.setattr(_compat, "jax_ffi", lambda: None)
-    xlaffi._reset_for_tests()
-    assert not xlaffi.armed()
-    assert "no jax.ffi" in (xlaffi.disarm_reason() or "")
-    # The one-shot warning fired (the bluefog logger does not propagate
-    # to caplog, so assert on the module's one-shot latch instead).
-    assert xlaffi._warned
-    config.reload()
-    assert not xlaffi.armed()
-    assert xlaffi._warned
-    # Puts fall back to the host path and still work (single-process).
-    bf.init(lambda: topo.RingGraph(8))
-    x = np.random.RandomState(1).randn(8, 3).astype(np.float32)
-    assert bf.win_create(x, "dz", zero_init=True)
-    try:
-        win = W._store.get("dz")
-        assert not xlaffi.keep_device_ok(jnp.asarray(x), win)
-        assert bf.win_put(jnp.asarray(x), "dz")
-        ver = bf.get_win_version("dz")
-        assert any(v > 0 for v in ver.values())
-    finally:
-        bf.win_free("dz")
-
 
 def test_disarm_reason_on_knob_off(xla_env):
     xla_env(BLUEFOG_TPU_WIN_XLA=0)
