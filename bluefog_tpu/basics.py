@@ -453,24 +453,42 @@ def rank_map(fn):
     ``check_vma`` to track, and it is off: ``fn`` is ordinary model code (a
     ``lax.scan`` with a constant initial carry does not type-check under
     it).  The result also has ``.lower(*args)``, like a jitted function."""
+    from bluefog_tpu.utils import telemetry
+    from bluefog_tpu.utils.timeline import op_span
+
     def run(*args):
         out = fn(*jax.tree.map(lambda x: x[0], args))
         return jax.tree.map(lambda x: x[None], out)
+    _name_program(run, f"rank_map_{getattr(fn, '__name__', 'fn')}")
 
     compiled = {}  # per mesh: set_topology may re-place ranks onto devices
 
     def program():
         mesh = _require_init().mesh
         if mesh not in compiled:
-            compiled[mesh] = jax.jit(jax.shard_map(
-                run, mesh=mesh, in_specs=P(RANK_AXIS),
-                out_specs=P(RANK_AXIS), check_vma=False))
+            with op_span("rank_map", "build"):
+                telemetry.inc("bf_step_program_builds_total",
+                              program="rank_map")
+                compiled[mesh] = jax.jit(jax.shard_map(
+                    run, mesh=mesh, in_specs=P(RANK_AXIS),
+                    out_specs=P(RANK_AXIS), check_vma=False))
         return compiled[mesh]
 
     def mapped(*args):
-        return program()(*args)
+        call = program()
+        with op_span("rank_map", "launch"):
+            return call(*args)
     mapped.lower = lambda *args: program().lower(*args)
     return mapped
+
+
+def _name_program(run, name: str):
+    """Call the XLA module that ``jax.jit`` makes of ``run``
+    ``jit_bf_<name>``: the name a profiler trace shows for every execution
+    of the program on the device (``docs/timeline.md``).  The name is part
+    of the compile cache's key and of nothing the program computes."""
+    run.__name__ = run.__qualname__ = f"bf_{name}"
+    return run
 
 
 # ---------------------------------------------------------------------------
@@ -961,9 +979,11 @@ def _throttle(out):
         if len(dq) > _max_inflight():
             old = dq.popleft()
             from bluefog_tpu.utils import telemetry
+            from bluefog_tpu.utils.timeline import op_span
             telemetry.inc("bf_throttle_waits_total")
             try:
-                jax.block_until_ready(old)
+                with op_span("throttle", "wait"):
+                    jax.block_until_ready(old)
             except Exception:  # noqa: BLE001 — see below
                 # The error also lives on the caller's copy of the value
                 # and surfaces there — but a fire-and-forget dispatch whose
@@ -1049,7 +1069,7 @@ def _dispatch_flat(key, fn, x, *extra) -> jnp.ndarray:
             return fn(b[0], *e)[None]
         n_extra = len(extra)
         return jax.jit(jax.shard_map(
-            run, mesh=ctx.mesh,
+            _name_program(run, str(key[0])), mesh=ctx.mesh,
             in_specs=(P(RANK_AXIS),) + (P(),) * n_extra,
             out_specs=P(RANK_AXIS)))
     from bluefog_tpu.utils import telemetry
@@ -1070,7 +1090,7 @@ def _dispatch_hier(key, fn, x, *extra) -> jnp.ndarray:
             return fn(b[0], *e)[None]
         n_extra = len(extra)
         return jax.jit(jax.shard_map(
-            run, mesh=ctx.hier_mesh,
+            _name_program(run, str(key[0])), mesh=ctx.hier_mesh,
             in_specs=(P((MACHINE_AXIS, LOCAL_AXIS)),) + (P(),) * n_extra,
             out_specs=P((MACHINE_AXIS, LOCAL_AXIS))))
     from bluefog_tpu.utils import telemetry
@@ -1340,7 +1360,8 @@ def allgather_v(tensors, name: Optional[str] = None) -> jnp.ndarray:
             parts = [g[i, :lengths[i]] for i in range(n)]  # static slices
             return jnp.concatenate(parts, axis=0)[None]
         return jax.jit(jax.shard_map(
-            run, mesh=ctx.mesh, in_specs=(P(RANK_AXIS),),
+            _name_program(run, "allgather_v"), mesh=ctx.mesh,
+            in_specs=(P(RANK_AXIS),),
             out_specs=P(RANK_AXIS)))
     from bluefog_tpu.utils.timeline import op_span
     _record_dispatch(("allgather_v",), None, padded)

@@ -22,12 +22,15 @@ Batches are **rank-major**: leading dim ``bf.size()``, row ``r`` is rank
 
 from __future__ import annotations
 
+import itertools
 import queue
 import threading
 from typing import Callable, Iterable, Iterator, Optional, Sequence
 
 import jax
 import numpy as np
+
+from bluefog_tpu.utils.timeline import op_span
 
 __all__ = ["DistributedSampler", "ShardedLoader", "prefetch_to_device"]
 
@@ -137,10 +140,15 @@ def prefetch_to_device(it: Iterable, *, size: int = 2,
                 continue
         return False
 
+    # bf.data.place (this thread's host-to-device copy) and bf.data.wait
+    # (the training thread, blocked on the queue) carry the batch's
+    # sequence number: the two spans of one batch sit on different threads.
     def producer():
         try:
-            for batch in it:
-                if not offer(place(batch)):
+            for seq, batch in enumerate(it):
+                with op_span("data", "place", batch=seq):
+                    batch = place(batch)
+                if not offer(batch):
                     return
         except Exception as e:  # surface in the consumer, not the thread
             offer(e)
@@ -152,8 +160,9 @@ def prefetch_to_device(it: Iterable, *, size: int = 2,
 
     def consumer():
         try:
-            while True:
-                item = q.get()
+            for seq in itertools.count():
+                with op_span("data", "wait", batch=seq):
+                    item = q.get()
                 if item is _END:
                     return
                 if isinstance(item, Exception):

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from bluefog_tpu.utils import timeline
+
 __all__ = ["chunked_softmax_cross_entropy"]
 
 
@@ -31,6 +33,14 @@ def chunked_softmax_cross_entropy(hidden, lm_head, targets, *,
     projection (pass ``params["lm_head"]["kernel"]``); ``targets``: (B, S)
     int labels.  ``chunk`` rows of logits exist at a time (per batch row).
     """
+    # One device scope over forward, remat recompute and transpose alike
+    # (their metadata reads checkpoint/.../bf.loss.chunked and
+    # transpose(jvp(bf.loss.chunked))): a trace reader matches the substring.
+    with timeline.device_scope("bf.loss.chunked"):
+        return _chunked_loss(hidden, lm_head, targets, chunk)
+
+
+def _chunked_loss(hidden, lm_head, targets, chunk: int):
     B, S, E = hidden.shape
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")

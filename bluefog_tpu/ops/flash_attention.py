@@ -126,7 +126,7 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None):
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k)
     o, lse = pl.pallas_call(
-        kernel,
+        kernel, name="bf_flash_fwd",
         grid=(bh, S // block_q, S // block_k),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -289,7 +289,7 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
-        grid=(bh, n_qb, n_kb),
+        name="bf_flash_dq", grid=(bh, n_qb, n_kb),
         in_specs=[q_at(own), k_at(red_dq), k_at(red_dq), q_at(own),
                   r_at(own), r_at(own)],
         out_specs=q_at(own),
@@ -301,7 +301,7 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
-        grid=(bh, n_kb, n_qb),
+        name="bf_flash_dkv", grid=(bh, n_kb, n_qb),
         in_specs=[q_at(red_kv), k_at(own), k_at(own), q_at(red_kv),
                   r_at(red_kv), r_at(red_kv)],
         out_specs=[k_at(own), k_at(own)],

@@ -37,6 +37,7 @@ from jax import lax
 
 from bluefog_tpu.ops import collective as C
 from bluefog_tpu.ops.schedule import DynamicSchedule, StaticSchedule
+from bluefog_tpu.utils import timeline
 
 __all__ = [
     "CommunicationType",
@@ -266,13 +267,21 @@ def _fused_apply(fn, tree, fusion_buckets: Optional[int]):
     if not leaves:
         return tree
     groups = _bucket_groups(leaves, fusion_buckets)
+
+    def through_buffer(bucket):
+        # Three device scopes (docs/timeline.md): a trace books every
+        # operation of the step program to the one its metadata names.
+        with timeline.device_scope("bf.optim.fuse"):
+            flat, unravel = ravel_pytree(bucket)
+        with timeline.device_scope("bf.optim.combine"):
+            flat = fn(flat)
+        with timeline.device_scope("bf.optim.unfuse"):
+            return unravel(flat)
     if len(groups) == 1:
-        flat, unravel = ravel_pytree(tree)
-        return unravel(fn(flat))
+        return through_buffer(tree)
     out = list(leaves)
     for grp in groups:
-        flat, unravel = ravel_pytree([leaves[i] for i in grp])
-        for i, leaf in zip(grp, unravel(fn(flat))):
+        for i, leaf in zip(grp, through_buffer([leaves[i] for i in grp])):
             out[i] = leaf
     return jax.tree_util.tree_unflatten(treedef, out)
 
@@ -313,8 +322,9 @@ def _tree_combine(params, combine, step, weights, steps_per_comm: int,
                 return _fused_apply(
                     lambda flat: combine(flat, step=step, weights=weights),
                     p, fusion_buckets)
-            return jax.tree.map(
-                lambda x: combine(x, step=step, weights=weights), p)
+            with timeline.device_scope("bf.optim.combine"):
+                return jax.tree.map(
+                    lambda x: combine(x, step=step, weights=weights), p)
         if steps_per_comm == 1:
             return comm_all(params)
         # lax.cond keeps one compiled program; both branches cheap to trace.
@@ -365,8 +375,9 @@ def awc_step(base: optax.GradientTransformation, combine: Combiner,
     combined = _tree_combine(params, combine, state.step, weights,
                              steps_per_comm, fuse, fusion_buckets,
                              shard_plan, shard_combine)
-    updates, base_state = base.update(grads, state.base, combined)
-    new_params = optax.apply_updates(combined, updates)
+    with timeline.device_scope("bf.optim.update"):
+        updates, base_state = base.update(grads, state.base, combined)
+        new_params = optax.apply_updates(combined, updates)
     return new_params, DistOptState(base_state, state.step + 1)
 
 
@@ -384,8 +395,9 @@ def atc_step(base: optax.GradientTransformation, combine: Combiner,
     bucket i's combine can hit the wire as soon as ITS leaves' updates are
     applied, overlapping the remaining buckets' optimizer math.
     """
-    updates, base_state = base.update(grads, state.base, params)
-    half = optax.apply_updates(params, updates)
+    with timeline.device_scope("bf.optim.update"):
+        updates, base_state = base.update(grads, state.base, params)
+        half = optax.apply_updates(params, updates)
     new_params = _tree_combine(half, combine, state.step, weights,
                                steps_per_comm, fuse, fusion_buckets,
                                shard_plan, shard_combine)
@@ -539,10 +551,12 @@ def gradient_allreduce_step(base: optax.GradientTransformation,
         if fuse and uniform_dtype:
             return _fused_apply(one, g, fusion_buckets)
         return jax.tree.map(one, g)
+    def update(avg):
+        with timeline.device_scope("bf.optim.update"):
+            updates, base_state = base.update(avg, state.base, params)
+            return optax.apply_updates(params, updates), base_state
     if steps_per_comm == 1:
-        avg = comm(grads)
-        updates, base_state = base.update(avg, state.base, params)
-        new_params = optax.apply_updates(params, updates)
+        new_params, base_state = update(comm(grads))
         return new_params, DistOptState(base_state, state.step + 1)
 
     acc = state.acc if state.acc is not None else \
@@ -550,10 +564,7 @@ def gradient_allreduce_step(base: optax.GradientTransformation,
     acc = jax.tree.map(lambda a, g: a + g, acc, grads)
 
     def communicate(_):
-        avg = comm(acc)
-        updates, base_state = base.update(avg, state.base, params)
-        return (optax.apply_updates(params, updates), base_state,
-                jax.tree.map(jnp.zeros_like, acc))
+        return update(comm(acc)) + (jax.tree.map(jnp.zeros_like, acc),)
 
     def silent(_):
         return params, state.base, acc

@@ -44,9 +44,12 @@ from jax.sharding import PartitionSpec as P
 from bluefog_tpu import basics
 from bluefog_tpu import topology as topology_util
 from bluefog_tpu.basics import LOCAL_AXIS, MACHINE_AXIS, RANK_AXIS
+from bluefog_tpu.ops import collective as C
 from bluefog_tpu.ops import schedule as S
 from bluefog_tpu.optim import functional as F
 from bluefog_tpu.optim.functional import CommunicationType, DistOptState
+from bluefog_tpu.utils import telemetry
+from bluefog_tpu.utils.timeline import op_span
 
 __all__ = [
     "CommunicationType",
@@ -314,7 +317,21 @@ class DistributedOptimizer:
             kw = {"weights": maybe_w[0]} if maybe_w else {}
             new_p, new_s = inner(p, g, s, **kw)
             return jax.tree.map(lambda x: x[None], (new_p, new_s))
+        basics._name_program(run, "optim_step")
 
+        # What one call of this program puts on the wire, for step()'s
+        # bf_comm_*_total{op="optimizer_step"}: the schedule it was built
+        # over, the share of steps that communicate and the payload's size
+        # against the tree's.  Computed here, once per built program.
+        from bluefog_tpu.utils import config
+        wire = sched if sched is not None else dyn
+        traffic = {
+            "sched_stats": (None if wire is None
+                            else C.schedule_wire_stats(wire)),
+            "calls": 1.0 / self.num_steps_per_communication,
+            "factor": (0.0 if getattr(combine, "is_identity", False) else
+                       config.compression_byte_factor(self.compression)),
+            "nbytes": None}   # of the combined tree: the first step fills it
         n_w = 1 if with_weights else 0
         # Donate grads + state only: XLA aliases the grads buffer (same
         # tree shape) into new_params, which is the whole params-sized
@@ -324,7 +341,7 @@ class DistributedOptimizer:
             run, mesh=mesh,
             in_specs=(spec, spec, spec) + (P(),) * n_w,
             out_specs=(spec, spec)),
-            donate_argnums=(1, 2) if self.donate else ())
+            donate_argnums=(1, 2) if self.donate else ()), traffic
 
     def _hier_gossip_bundle(self, ctx) -> dict:
         """Compiled two-level bundle for the ``hierarchical_gossip``
@@ -354,14 +371,22 @@ class DistributedOptimizer:
                 "outer_every": ht.outer_every, "outer_compression": comp,
                 "outer_frac": frac}
 
-    def _step_callable(self, with_weights: bool, plan=None):
+    def _step_program(self, with_weights: bool, plan=None):
+        """``(jitted step, its traffic)``, built on first use for each
+        topology version, weight-override arity and shard plan."""
         ctx = basics._require_init()
         key = (ctx.topology_version, ctx.machine_topology_version,
                with_weights,
                None if plan is None else plan.signature)
         if key not in self._jitted:
-            self._jitted[key] = self._build_step(with_weights, plan)
+            with op_span("optim", "build", key=str(key)):
+                telemetry.inc("bf_step_program_builds_total",
+                              program="optim_step")
+                self._jitted[key] = self._build_step(with_weights, plan)
         return self._jitted[key]
+
+    def _step_callable(self, with_weights: bool, plan=None):
+        return self._step_program(with_weights, plan)[0]
 
     # -- public surface -----------------------------------------------------
     def init(self, params) -> DistOptState:
@@ -379,30 +404,41 @@ class DistributedOptimizer:
             return jax.tree.map(lambda x: x[None], st)
         placed = jax.tree.map(basics._place, params)
         return jax.jit(jax.shard_map(
-            run, mesh=mesh, in_specs=(spec,), out_specs=spec))(placed)
+            basics._name_program(run, "optim_init"), mesh=mesh,
+            in_specs=(spec,), out_specs=spec))(placed)
 
-    def step(self, params, grads, state: DistOptState, *,
-             self_weight: Optional[float] = None,
-             src_weights=None, dst_weights=None):
-        """One optimizer step; returns ``(new_params, new_state)``.
-
-        Weight kwargs override the schedule's weights for this step only
-        (traced — no recompilation when they change every iteration).
-        """
-        import time as _time
-
-        from bluefog_tpu.utils import profiler, telemetry
-        t0 = telemetry.start_timer()
-        w = basics._weight_override_matrix(self_weight, src_weights, dst_weights)
+    def _dispatch(self, params, grads, state, w):
+        """Place the trees, launch the step program and book what it puts
+        on the wire; returns ``(new_params, new_state)`` without waiting
+        for the device."""
         plan = self._shard_plan(params)
-        placed = jax.tree.map(basics._place, (params, grads))
-        params, grads = placed
-        fn = self._step_callable(with_weights=w is not None, plan=plan)
-        if w is None:
-            out = basics._throttle(fn(params, grads, state))
-        else:
-            out = basics._throttle(
-                fn(params, grads, state, jnp.asarray(w, jnp.float32)))
+        leaves, treedef = jax.tree_util.tree_flatten((params, grads))
+        with op_span("optim", "place", leaves=len(leaves)):
+            params, grads = jax.tree_util.tree_unflatten(
+                treedef, [basics._place(x) for x in leaves])
+        fn, traffic = self._step_program(with_weights=w is not None,
+                                         plan=plan)
+        extra = () if w is None else (jnp.asarray(w, jnp.float32),)
+        with op_span("optim", "launch", step=self._steps_seen):
+            out = fn(params, grads, state, *extra)
+        # The in-flight window holds the new parameters: the next step
+        # donates the state this one returned (donate=True), never these.
+        basics._throttle(out[0])
+        if telemetry.enabled():
+            # The same counters every eager op feeds at dispatch
+            # (bf_comm_*_total), from what the built program was compiled
+            # over: silent steps of num_steps_per_communication count as
+            # the share of a call they are, the identity combine as zero
+            # bytes, sharded leaves in their own per-level series below.
+            if traffic["nbytes"] is None:
+                traffic["nbytes"] = float(
+                    plan.rep_bytes if plan is not None and plan.any_sharded
+                    else sum(x.nbytes for x in
+                             jax.tree_util.tree_leaves(params)))
+            telemetry.record_comm_traffic(
+                "optimizer_step", traffic["nbytes"] * traffic["factor"],
+                size=basics.size(), sched_stats=traffic["sched_stats"],
+                calls=traffic["calls"])
         hier_meta = getattr(self, "_hier_meta", None)
         if hier_meta is not None:
             # Per-level wire accounting of the fused two-level step (the
@@ -439,11 +475,31 @@ class DistributedOptimizer:
                 SH.record_level_bytes(
                     plan, rep_ici_edges=rep_ici, rep_dcn_edges=rep_dcn,
                     grp_edges=grp_edges, compression=self.compression)
-        self._steps_seen += 1
-        # DISPATCH wall time (async — device work keeps running); the
-        # synced profile below measures true step latency.
-        telemetry.observe_since(t0, "bf_optimizer_step_seconds",
-                                family="collective")
+        return out
+
+    def step(self, params, grads, state: DistOptState, *,
+             self_weight: Optional[float] = None,
+             src_weights=None, dst_weights=None):
+        """One optimizer step; returns ``(new_params, new_state)``.
+
+        Weight kwargs override the schedule's weights for this step only
+        (traced — no recompilation when they change every iteration).
+        """
+        import time as _time
+
+        from bluefog_tpu.utils import profiler
+        t0 = telemetry.start_timer()
+        # The host's share of the step, on the profiler's clock; its self
+        # time (plan lookup, accounting) is its length less place + launch.
+        with op_span("optim", "step", step=self._steps_seen):
+            out = self._dispatch(params, grads, state,
+                                 basics._weight_override_matrix(
+                                     self_weight, src_weights, dst_weights))
+            self._steps_seen += 1
+            # DISPATCH wall time (async — device work keeps running); the
+            # synced profile below measures true step latency.
+            telemetry.observe_since(t0, "bf_optimizer_step_seconds",
+                                    family="collective")
         pe = profiler.profile_period(self.profile_every)
         if pe and self._steps_seen % pe == 0 and t0 is not None:
             # Synced sample: the step is one fused XLA program, so phase
@@ -462,9 +518,7 @@ class DistributedOptimizer:
                 outer.attribute("host-sync", now - t_sync)
                 outer.request_straggler()
             else:
-                profiler.record_synced_step(
-                    now - t0, phases={"optimizer-update": t_sync - t0,
-                                      "host-sync": now - t_sync})
+                profiler.record_synced_step(now - t0)
         # costs_communication: this sampler adds a combine + host sync,
         # so it only runs when the consensus period was explicitly set.
         k = telemetry.consensus_every(costs_communication=True)
@@ -482,7 +536,6 @@ def _sample_consensus_distance(params) -> None:
     exact in multi-process runs) and costs one extra combine of the
     parameters every K steps; mean/max over ranks land in
     ``bf_consensus_distance`` / ``bf_consensus_distance_max``."""
-    from bluefog_tpu.utils import telemetry
     n = basics.size()
     leaves = [jnp.reshape(jnp.asarray(x), (n, -1)).astype(jnp.float32)
               for x in jax.tree_util.tree_leaves(params)]

@@ -74,14 +74,19 @@ FUSED_PHASE = "fused-step"
 def _classify_span(op_name: str, span_phase: str) -> str:
     """Map a ``timeline.op_span`` (op, phase) pair to a profiler phase.
 
-    UPDATE spans are optimizer math; the ``synchronize`` COMMUNICATE span
-    is a host-side block on device completion (host-sync); every other
-    ENQUEUE/COMMUNICATE span is communication work (dispatching a
-    collective, a window edge transfer, a transport apply)."""
-    if span_phase == "UPDATE":
+    UPDATE spans and ``opt.step()``'s own host phases (``bf.optim.*``)
+    are the optimizer; the ``synchronize`` COMMUNICATE span, a throttle
+    wait and a wait for the next batch are host-side blocks (host-sync);
+    launching the gradient program (``bf.rank_map.*``) is the step's own
+    compute; every other ENQUEUE/COMMUNICATE span is communication work
+    (dispatching a collective, a window edge transfer, a transport
+    apply)."""
+    if span_phase == "UPDATE" or op_name == "optim":
         return "optimizer-update"
-    if op_name == "synchronize":
+    if op_name in ("synchronize", "throttle", "data"):
         return "host-sync"
+    if op_name == "rank_map":
+        return "grad-compute"
     return "gossip-communicate"
 
 
@@ -149,10 +154,12 @@ def _on_op_span(op_name: str, span_phase: str, seconds: float) -> None:
     p = _active
     if p is None:
         return
-    if op_name.startswith("win_apply."):
+    if op_name.startswith("win_apply.") or \
+            (op_name, span_phase) == ("data", "place"):
         # Drain-thread spans are PEER-driven (inbound gossip landing while
         # we happen to be profiling) — not this step's own work; billing
         # them to the active step would misattribute a neighbor's traffic.
+        # The prefetch thread's placement runs beside the step, not in it.
         return
     p.attribute(_classify_span(op_name, span_phase), seconds)
 
@@ -364,18 +371,17 @@ def _record_straggler(times: np.ndarray) -> None:
 
 
 def record_synced_step(total_seconds: float,
-                       phases: Optional[Dict[str, float]] = None,
                        *, straggler: bool = True) -> None:
     """Record one fully-synced step measured by a caller (the optimizer
-    families' ``profile_every`` hook): step + phase histograms and — by
-    default — a straggler gather.  The caller must have block_until_ready'd
+    families' ``profile_every`` hook): the step histogram and — by
+    default — a straggler gather.  No phases: the step is one compiled
+    program, and what the host can time around it is dispatch, which the
+    ``bf.optim.*`` spans carry.  The caller must have block_until_ready'd
     the step so ``total_seconds`` is true wall time, and in multi-process
     runs must call this on every process together (collective gather)."""
     if not telemetry.enabled():
         return
     telemetry.observe("bf_step_seconds", total_seconds)
-    for ph, dt in (phases or {}).items():
-        telemetry.observe("bf_step_phase_seconds", dt, phase=ph)
     if straggler:
         times = _gather_step_seconds(total_seconds)
         if times is not None:

@@ -7,10 +7,14 @@ through the same env-var contract and additionally forwarded to
 ``jax.profiler.TraceAnnotation`` so they show up inside TPU profiler traces
 alongside XLA ops — something the reference cannot do.
 
-Device-side op timelines come for free from ``jax.profiler.trace()``; this
-module covers the *host-side* named-activity API
+One span mechanism, two users: the *host-side* named-activity API
 (``bf.timeline_start_activity/timeline_end_activity/timeline_context``,
-reference ``basics.py:415-495``).
+reference ``basics.py:415-495``) and the framework's own spans
+(:func:`op_span`: ``bf.optim.step``, ``bf.rank_map.launch``, ...; the table
+is in ``docs/timeline.md``).  Both open a span through :func:`_begin`, which
+writes it on the profiler's clock, so inside ``jax.profiler.trace()`` the
+host spans line up with the device's program and op events, whose names
+(:func:`device_scope`, the ``jit_bf_*`` programs) are stable too.
 """
 
 from __future__ import annotations
@@ -39,6 +43,8 @@ __all__ = [
     "probe_span",
     "thread_name",
     "set_op_span_hook",
+    "op_span",
+    "device_scope",
     "CLOCK_ANCHOR_NAME",
 ]
 
@@ -220,14 +226,54 @@ def flush() -> None:
             time.sleep(0.01)
 
 
+def _begin(annotation: str, name: str, cat: str, args: dict):
+    """Open one host span wherever someone listens and return what
+    :func:`_end` closes: on the profiler's clock (a ``TraceAnnotation``
+    costs well under a microsecond while no ``jax.profiler`` trace runs),
+    and in the chrome-JSON file if a timeline is open.  ``args`` ride the
+    annotation (``step=3`` joins a launch to its device execution) and
+    the Python writer's event."""
+    ann = jax.profiler.TraceAnnotation(annotation, **args)
+    ann.__enter__()
+    if _writer is not None:
+        _emit_edge("B", name, cat, args)
+    return ann
+
+
+def _end(ann, name: str, cat: str) -> None:
+    if _writer is not None:
+        _emit_edge("E", name, cat, None)
+    ann.__exit__(None, None, None)
+
+
+def _emit_edge(ph: str, name: str, cat: str, args) -> None:
+    w = _writer
+    if w is None:   # closed by another thread since the caller looked
+        return
+    ev = {"name": name, "cat": cat, "ph": ph,
+          "ts": time.monotonic_ns() // 1000, "pid": os.getpid(),
+          "tid": threading.get_ident()}
+    if args:
+        ev["args"] = args
+    w.emit(ev)
+
+
+def device_scope(name: str):
+    """Name the device operations traced inside the ``with``: the name
+    lands in their ``op_name`` metadata (``jit(bf_optim_step)/.../
+    bf.optim.update/mul``) and nowhere else, so the compiled program is
+    the same with and without it.  A trace reader books a device event to
+    the scope its instruction's metadata names."""
+    return jax.named_scope(name)
+
+
 def timeline_start_activity(tensor_name: str, activity_name: str = "USER") -> bool:
     """Open a named activity span (parity: ``basics.py:415-451``)."""
     _maybe_autostart()
     if _writer is None:
         return False
     key = f"{tensor_name}:{activity_name}"
-    ann = jax.profiler.TraceAnnotation(key)
-    ann.__enter__()
+    ann = _begin(key, activity_name, tensor_name, {})
     with _lock:
         prior = _active.pop(key, None)
         _active[key] = ann
@@ -235,9 +281,6 @@ def timeline_start_activity(tensor_name: str, activity_name: str = "USER") -> bo
         # A same-key span was still open (retry loop / double start): close it
         # so the profiler's thread-local annotation stack stays balanced.
         prior.__exit__(None, None, None)
-    _writer.emit({"name": activity_name, "cat": tensor_name, "ph": "B",
-                  "ts": time.monotonic_ns() // 1000, "pid": os.getpid(),
-                  "tid": threading.get_ident()})
     return True
 
 
@@ -248,10 +291,9 @@ def timeline_end_activity(tensor_name: str, activity_name: str = "USER") -> bool
     with _lock:
         ann = _active.pop(key, None)
     if ann is not None:
-        ann.__exit__(None, None, None)
-    _writer.emit({"name": activity_name, "cat": tensor_name, "ph": "E",
-                  "ts": time.monotonic_ns() // 1000, "pid": os.getpid(),
-                  "tid": threading.get_ident()})
+        _end(ann, activity_name, tensor_name)
+    else:
+        _emit_edge("E", activity_name, tensor_name, None)
     return True
 
 
@@ -327,37 +369,41 @@ def set_op_span_hook(hook) -> None:
     _span_hook = hook
 
 
-@contextmanager
-def op_span(op_name: str, phase: str):
-    """Framework-internal op-phase span (ENQUEUE/COMMUNICATE/UPDATE...):
-    the automatic analogue of the reference's per-phase ActivityStart/End
-    hooks (``mpi_controller.cc:540-561``).  Near-zero cost when tracing is
-    off and no profiler is active (two module-global checks, no autostart
-    probe)."""
-    hook = _span_hook
-    if hook is None and _writer is None \
-            and not os.environ.get("BLUEFOG_TIMELINE"):
-        yield
-        return
-    _maybe_autostart()
-    w = _writer
-    if w is None and hook is None:
-        yield
-        return
-    counted = hook is not None
-    if counted:
-        _span_depth.d = getattr(_span_depth, "d", 0) + 1
-        t0 = time.perf_counter()
-    base = {"name": phase, "cat": op_name, "pid": os.getpid(),
-            "tid": threading.get_ident()}
-    if w is not None:
-        w.emit({**base, "ph": "B", "ts": time.monotonic_ns() // 1000})
-    try:
-        yield
-    finally:
-        if w is not None:
-            w.emit({**base, "ph": "E", "ts": time.monotonic_ns() // 1000})
-        if counted:
+class op_span:
+    """Framework-internal span ``bf.<op_name>.<phase>``: the eager ops'
+    ENQUEUE/COMMUNICATE/UPDATE phases (the automatic analogue of the
+    reference's per-phase ActivityStart/End hooks,
+    ``mpi_controller.cc:540-561``) and the training step's own host phases
+    (``bf.optim.step`` > ``bf.optim.place`` / ``bf.optim.launch``,
+    ``bf.rank_map.launch``, ``bf.data.wait``, ...).  One ``with`` feeds all
+    three listeners: a running ``jax.profiler`` trace, the chrome-JSON
+    timeline, and the ``StepProfiler`` hook.  ``args`` (``step=``,
+    ``batch=``) are the identifiers that join a span to its counterpart on
+    another thread or on the device.  A class and not a generator: with
+    nobody listening a span costs one idle annotation and three
+    module-global checks (no autostart probe, no registry mutation)."""
+
+    __slots__ = ("_op", "_phase", "_args", "_ann", "_t0")
+
+    def __init__(self, op_name: str, phase: str, **args):
+        self._op, self._phase, self._args = op_name, phase, args
+
+    def __enter__(self):
+        if _writer is None and os.environ.get("BLUEFOG_TIMELINE"):
+            _maybe_autostart()
+        self._t0 = None
+        if _span_hook is not None:
+            _span_depth.d = getattr(_span_depth, "d", 0) + 1
+            self._t0 = time.perf_counter()
+        self._ann = _begin(f"bf.{self._op}.{self._phase}", self._phase,
+                           self._op, self._args)
+        return self
+
+    def __exit__(self, *exc):
+        _end(self._ann, self._phase, self._op)
+        if self._t0 is not None:
             _span_depth.d -= 1
             if _span_depth.d == 0 and _span_hook is not None:
-                _span_hook(op_name, phase, time.perf_counter() - t0)
+                _span_hook(self._op, self._phase,
+                           time.perf_counter() - self._t0)
+        return False

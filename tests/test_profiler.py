@@ -252,6 +252,11 @@ def test_classify_span_mapping():
         == "host-sync"
     assert profiler._classify_span("win_update.w", "UPDATE") \
         == "optimizer-update"
+    # the training step's own host phases (bf.optim.*, bf.rank_map.*, ...)
+    assert profiler._classify_span("optim", "step") == "optimizer-update"
+    assert profiler._classify_span("rank_map", "launch") == "grad-compute"
+    assert profiler._classify_span("throttle", "wait") == "host-sync"
+    assert profiler._classify_span("data", "wait") == "host-sync"
 
 
 # ---------------------------------------------------------------------------
@@ -509,6 +514,18 @@ def test_disabled_observe_and_profile_mutate_nothing(monkeypatch):
         assert profiler.last_straggler_report() is None
         from bluefog_tpu.utils import timeline
         assert timeline._span_hook is None  # hook never installed
+        # ... and a framework span with no jax.profiler trace, no timeline
+        # file and no hook leaves no trace of itself anywhere
+        monkeypatch.delenv("BLUEFOG_TIMELINE", raising=False)
+        before = (dict(timeline._active), timeline._writer,
+                  getattr(timeline._span_depth, "d", 0))
+        with timeline.op_span("optim", "step", step=3):
+            with timeline.op_span("optim", "launch", step=3):
+                pass
+        assert (dict(timeline._active), timeline._writer,
+                getattr(timeline._span_depth, "d", 0)) == before
+        assert before[1] is None and not timeline.timeline_enabled()
+        assert telemetry.snapshot() == {}
     finally:
         monkeypatch.delenv("BLUEFOG_TPU_TELEMETRY")
         config.reload()
