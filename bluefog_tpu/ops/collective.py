@@ -322,19 +322,25 @@ def neighbor_allreduce_matrix(x: jnp.ndarray, w: jnp.ndarray,
     return _tree_sum(terms)
 
 
-def dynamic_neighbor_allreduce(x: jnp.ndarray, step: jnp.ndarray,
-                               sched: DynamicSchedule,
-                               axis_name: str) -> jnp.ndarray:
+def dynamic_neighbor_allreduce(x, step: jnp.ndarray, sched: DynamicSchedule,
+                               axis_name: str):
     """Neighbor averaging whose topology changes every step.
 
     ``step`` is a traced scalar; the phase is chosen by ``lax.switch`` over the
     schedule's period, so the op compiles once and never renegotiates — this
     replaces the reference's per-step send/recv-list plumbing
     (``mpi_controller.cc:418-454``) and its stop-the-world topology handshake.
+
+    ``x`` is an array or a pytree of arrays (the optimizer's parts: large
+    leaves and packed buffers).  The phase is chosen ONCE for the whole tree
+    and each branch averages every leaf: the scheduler moves no operation
+    across a ``conditional``, so only inside one branch can one leaf's scale
+    and add run under another leaf's permute.
     """
     idx = _axis_index(axis_name)
-    branches = [partial(_apply_rounds, sched=ph, axis_name=axis_name, idx=idx)
-                for ph in sched.phases]
+    branches = [partial(jax.tree.map, partial(
+        _apply_rounds, sched=ph, axis_name=axis_name, idx=idx))
+        for ph in sched.phases]
     return lax.switch(step % sched.period, branches, x)
 
 

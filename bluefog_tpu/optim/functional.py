@@ -85,7 +85,9 @@ def make_combiner(
     The returned callable has signature ``combine(x, step, weights)`` where
     ``step`` is the traced step counter (used by dynamic schedules) and
     ``weights`` is an optional traced (n, n) matrix overriding the static
-    schedule's weights (None => baked-in weights).
+    schedule's weights (None => baked-in weights).  A callable marked
+    ``takes_parts`` (dynamic neighbor averaging) also takes a list of
+    arrays for ``x`` and chooses its phase once for all of them.
     """
     def _no_weights(weights, what):
         if weights is not None:
@@ -114,11 +116,15 @@ def make_combiner(
                 # Weight override on a dynamic topology: same phase switching,
                 # weights looked up from the traced matrix per active edge.
                 branches = [
-                    partial(lambda ph, args: C.neighbor_allreduce_matrix(
-                        args[0], args[1], ph, axis_name), ph)
+                    partial(lambda ph, args: jax.tree.map(
+                        lambda p: C.neighbor_allreduce_matrix(
+                            p, args[1], ph, axis_name), args[0]), ph)
                     for ph in dyn_sched.phases]
                 return lax.switch(step % dyn_sched.period, branches,
                                   (x, weights))
+            # x may be the list of all parts of a step's exchange
+            # (_fused_apply): one lax.switch then serves them all.
+            _dyn.takes_parts = True
             # Lets compress_combiner run the aligned rotating-block sparse
             # exchange under the same lax.switch of phases
             # (compression="sparse:<frac>" on dynamic topologies).
@@ -253,37 +259,77 @@ def _bucket_groups(leaves, fusion_buckets: Optional[int]):
     return groups
 
 
+# A leaf of at least this many bytes is exchanged as it is; smaller leaves
+# are packed.  Packing a leaf costs two passes over it (into the buffer and
+# out again) and makes its exchange wait for the whole buffer; sending it
+# alone costs one more collective to issue, a few us.  At the 46.7 GB/s one
+# v5e link gave the exchange (PERF.md, PR 23) 1 MiB is 22 us on the wire, so
+# from there on the issue cost is under a fifth of the transfer.
+_DIRECT_LEAF_BYTES = 1 << 20
+
+
+def _leaf_bytes(leaf) -> int:
+    return int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+
+
+def _split_direct(leaves):
+    """Flatten-order indices of the leaves exchanged as they are, and of
+    those that are packed (:data:`_DIRECT_LEAF_BYTES`)."""
+    big = [_leaf_bytes(l) >= _DIRECT_LEAF_BYTES for l in leaves]
+    return ([i for i, b in enumerate(big) if b],
+            [i for i, b in enumerate(big) if not b])
+
+
 def _fused_apply(fn, tree, fusion_buckets: Optional[int]):
-    """Apply ``fn`` (flat-array -> flat-array) to a pytree through fusion
-    buckets: each bucket of leaves ravels into one flat buffer, so a model
-    with hundreds of parameters issues one collective set per bucket
-    instead of one per parameter.  With multiple buckets the per-bucket
-    programs are INDEPENDENT subgraphs — bucket i+1's producer math carries
-    no data dependency on bucket i's collective, so XLA's latency-hiding
-    scheduler overlaps wire time with compute (the single-buffer ravel
-    serializes ALL producers before the first ppermute can start)."""
+    """Apply ``fn`` (list of arrays -> list of arrays, elementwise in shape
+    and dtype) to a pytree's leaves: the large ones as they are, the small
+    ones through fusion buckets.
+
+    A leaf of :data:`_DIRECT_LEAF_BYTES` or more reaches ``fn`` in its own
+    shape and dtype: no copy into a buffer and none out of it, and its
+    exchange depends on nothing but the leaf, so the scheduler can run one
+    leaf's scale and add under another's permute.  (On the v5e the one flat
+    buffer of a 2 GB tree cost three passes over it, 34 ms of a 244 ms
+    step, and 8 GB of scratch: PERF.md, PR 23.)  The remaining leaves ravel
+    into one flat buffer per bucket (``_bucket_groups``), so a model with
+    hundreds of small parameters issues one collective set per bucket
+    instead of one per parameter; a tree with no large leaf lowers to the
+    program it lowered to before there was a direct path.  All parts, the
+    large leaves and then the buffers, reach ``fn`` in ONE call: a combiner
+    that takes a list (``takes_parts``) chooses its phase once for all."""
     from jax.flatten_util import ravel_pytree
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
         return tree
-    groups = _bucket_groups(leaves, fusion_buckets)
-
-    def through_buffer(bucket):
-        # Three device scopes (docs/timeline.md): a trace books every
-        # operation of the step program to the one its metadata names.
-        with timeline.device_scope("bf.optim.fuse"):
-            flat, unravel = ravel_pytree(bucket)
-        with timeline.device_scope("bf.optim.combine"):
-            flat = fn(flat)
-        with timeline.device_scope("bf.optim.unfuse"):
-            return unravel(flat)
-    if len(groups) == 1:
-        return through_buffer(tree)
+    direct, packed = _split_direct(leaves)
+    groups = [[packed[j] for j in grp] for grp in _bucket_groups(
+        [leaves[i] for i in packed], fusion_buckets)] if packed else []
+    # Three device scopes (docs/timeline.md): a trace books every operation
+    # of the step program to the one its metadata names.
+    with timeline.device_scope("bf.optim.fuse"):
+        raveled = [ravel_pytree([leaves[i] for i in grp]) for grp in groups]
+    with timeline.device_scope("bf.optim.combine"):
+        parts = fn([leaves[i] for i in direct] + [flat for flat, _ in raveled])
     out = list(leaves)
-    for grp in groups:
-        for i, leaf in zip(grp, through_buffer([leaves[i] for i in grp])):
-            out[i] = leaf
+    for i, part in zip(direct, parts):
+        out[i] = part
+    with timeline.device_scope("bf.optim.unfuse"):
+        for grp, (_, unravel), flat in zip(groups, raveled,
+                                           parts[len(direct):]):
+            for i, leaf in zip(grp, unravel(flat)):
+                out[i] = leaf
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _combine_parts(combine, step, weights):
+    """``fn`` of :func:`_fused_apply` for a combiner: a combiner marked
+    ``takes_parts`` gets the list whole (one phase switch a step), every
+    other one is applied part by part, which without a switch is the same
+    program."""
+    if getattr(combine, "takes_parts", False):
+        return lambda parts: combine(parts, step=step, weights=weights)
+    return lambda parts: [combine(x, step=step, weights=weights)
+                          for x in parts]
 
 
 def _tree_combine(params, combine, step, weights, steps_per_comm: int,
@@ -292,16 +338,18 @@ def _tree_combine(params, combine, step, weights, steps_per_comm: int,
     """Apply ``combine`` to a pytree, skipping steps where
     ``step % steps_per_comm != 0`` (local aggregation).
 
-    ``fuse=True`` ravels the tree into fusion-bucket buffers (default: one)
-    so a model with hundreds of parameters issues one ppermute set per
-    round per bucket instead of one per parameter — the TPU-native
+    ``fuse=True`` hands the leaves of 1 MiB and more to ``combine`` as they
+    are and ravels the smaller ones into fusion-bucket buffers (default:
+    one), so a model with hundreds of small parameters issues one ppermute
+    set per round per bucket instead of one per parameter — the TPU-native
     replacement for the reference's FusionBufferManager + fused-response
-    machinery (``tensor_queue.h:70-92``, ``operations.cc:918-1001``), with
-    zero copy-in/copy-out phases because XLA fuses the concatenation into
-    the collective's producers/consumers.  ``fusion_buckets > 1`` (or the
-    ``BLUEFOG_TPU_FUSION_BUCKET_MB`` cap) splits the buffer so per-bucket
-    communication pipelines against the other buckets' optimizer math —
-    see :func:`_fused_apply`.
+    machinery (``tensor_queue.h:70-92``, ``operations.cc:918-1001``).  The
+    copies into and out of a buffer are real passes over it on the device
+    (19 ms a step for a 2 GB buffer on the v5e: PERF.md, PR 23), which is
+    why only leaves too small to pay for a collective of their own are
+    packed.  ``fusion_buckets > 1`` (or the ``BLUEFOG_TPU_FUSION_BUCKET_MB``
+    cap) splits what is packed — see :func:`_fused_apply`.  ``fuse=False``
+    combines every leaf alone.
 
     With an active ``shard_plan`` (a plan whose mask marks some leaves
     sharded) the tree is split by the mask: replicated leaves ride the
@@ -319,9 +367,8 @@ def _tree_combine(params, combine, step, weights, steps_per_comm: int,
 
         def comm_all(p):
             if fuse:
-                return _fused_apply(
-                    lambda flat: combine(flat, step=step, weights=weights),
-                    p, fusion_buckets)
+                return _fused_apply(_combine_parts(combine, step, weights),
+                                    p, fusion_buckets)
             with timeline.device_scope("bf.optim.combine"):
                 return jax.tree.map(
                     lambda x: combine(x, step=step, weights=weights), p)
@@ -341,7 +388,7 @@ def _tree_combine(params, combine, step, weights, steps_per_comm: int,
             rep = [leaves[i] for i in rep_idx]
             if fuse:
                 rep_out = _fused_apply(
-                    lambda flat: combine(flat, step=step, weights=weights),
+                    _combine_parts(combine, step, weights),
                     rep, fusion_buckets)
             else:
                 rep_out = [combine(x, step=step, weights=weights)
@@ -368,9 +415,8 @@ def awc_step(base: optax.GradientTransformation, combine: Combiner,
     Matches ``_DistributedReduceOptimizer`` (reference
     ``torch/optimizers.py:297-483``): the forward hook launches communication
     of ``x_t`` while backward computes ``g_t``; ``step()`` waits and applies
-    the local update to the *combined* parameters.  With ``fusion_buckets``
-    the base update of bucket i overlaps the combine of bucket i+1 (each
-    bucket's update depends only on its own combine).
+    the local update to the *combined* parameters.  Each part's update
+    depends only on its own combine (:func:`_fused_apply`).
     """
     combined = _tree_combine(params, combine, state.step, weights,
                              steps_per_comm, fuse, fusion_buckets,
@@ -391,9 +437,8 @@ def atc_step(base: optax.GradientTransformation, combine: Combiner,
     Matches ``_DistributedAdaptThenCombineOptimizer`` (reference
     ``torch/optimizers.py:485-842``) — which re-implements sgd/adam/rmsprop/
     adagrad/adadelta by hand to fuse the update into the backward hook; here
-    any optax transformation slots in unchanged.  With ``fusion_buckets``
-    bucket i's combine can hit the wire as soon as ITS leaves' updates are
-    applied, overlapping the remaining buckets' optimizer math.
+    any optax transformation slots in unchanged.  A part's combine depends
+    only on its own leaves' updates (:func:`_fused_apply`).
     """
     with timeline.device_scope("bf.optim.update"):
         updates, base_state = base.update(grads, state.base, params)
@@ -409,10 +454,17 @@ def compress_combiner(combine: Combiner, compression: str,
                       steps_per_comm: int = 1) -> Combiner:
     """Wrap a combiner so its payload crosses the wire compressed.
 
+    The wrapped combiner takes one array, so ``_fused_apply`` applies it
+    part by part: to each large leaf and to each packed buffer.
+
     ``"bf16"`` casts to bfloat16 before the collective and back after —
     half the ICI/DCN bytes per round, the role of the reference family's
     fp16 compression (Horovod-style; BlueFog inherits the float16 wire
     type, ``common/half.h``).  ``"none"`` returns the combiner unchanged.
+
+    ``"sparse:<frac>"`` ships a step-rotating block of ``ceil(frac *
+    size)`` entries of each part; every part is swept in full in
+    ``ceil(1/frac)`` communication rounds (comment below).
 
     ``residual=True`` (parameter-consensus orders) adds back the local
     quantization residual ``x - q(x)`` after combining — difference
@@ -433,7 +485,10 @@ def compress_combiner(combine: Combiner, compression: str,
                 "stay unmixed forever); use compression='sparse:<frac>' — "
                 "a step-rotating aligned block that sweeps every "
                 "coordinate and reaches EXACT consensus")
-        # "sparse:<frac>": ship only ceil(frac*size) entries per round —
+        # "sparse:<frac>": ship only ceil(frac*size) entries per round of
+        # each part it is handed (a large leaf or a packed buffer:
+        # _fused_apply; the block rotates within the part, not within the
+        # whole tree) —
         # (k,) values + (k,) int32 indices per edge instead of the dense
         # payload (C.sparse_neighbor_allreduce).  The index block ROTATES
         # with the step and is IDENTICAL on every rank, so each round is
@@ -549,7 +604,8 @@ def gradient_allreduce_step(base: optax.GradientTransformation,
 
     def comm(g):
         if fuse and uniform_dtype:
-            return _fused_apply(one, g, fusion_buckets)
+            return _fused_apply(lambda parts: [one(x) for x in parts], g,
+                                fusion_buckets)
         return jax.tree.map(one, g)
     def update(avg):
         with timeline.device_scope("bf.optim.update"):
@@ -587,10 +643,10 @@ def step_fn(order: str, base: optax.GradientTransformation,
             shard_plan=None, shard_combine=None) -> Callable:
     """Bind an execution order to a ``(params, grads, state[, weights])`` fn.
 
-    ``fusion_buckets`` splits the fused communication buffer into that many
-    byte-balanced buckets (None: one bucket, or the
-    ``BLUEFOG_TPU_FUSION_BUCKET_MB`` size cap when set) so per-bucket
-    collectives pipeline against the other buckets' optimizer math.
+    ``fusion_buckets`` splits the buffer of the packed leaves (those under
+    1 MiB: :func:`_fused_apply`) into that many byte-balanced buckets
+    (None: one bucket, or the ``BLUEFOG_TPU_FUSION_BUCKET_MB`` size cap
+    when set).
 
     ``residual`` controls difference compression under ``compression='bf16'``.
     A global-consensus allreduce must keep replicas bit-identical, so the

@@ -77,14 +77,13 @@ class DistributedOptimizer:
     use_dynamic_topology : cycle the one-peer phase table of the active
         topology (or ``phases`` if given) by step index.
     phases : explicit list of ``topology.DynamicPhase`` for dynamic mode.
-    fusion_buckets : split the fused communication buffer into this many
-        byte-balanced buckets so each bucket's collectives overlap the
-        other buckets' optimizer math (AWC: update(i) || combine(i+1);
-        ATC: combine(i) || update(i+1)).  ``None``: one bucket — unless
-        ``BLUEFOG_TPU_FUSION_BUCKET_MB`` caps bucket size instead.  Only
-        meaningful with ``fusion=True``; tune when the model is large
-        enough that parameter communication and step math are comparable
-        (see docs/performance.md).
+    fusion_buckets : with ``fusion=True`` a leaf of 1 MiB or more is
+        exchanged as it is and the smaller leaves are packed into one
+        flat buffer; this splits that buffer into this many byte-balanced
+        buckets.  ``None``: one bucket — unless
+        ``BLUEFOG_TPU_FUSION_BUCKET_MB`` caps bucket size instead.  It
+        governs the small leaves only (kilobytes in a transformer, the
+        batch-norm and bias vectors of a ResNet): see docs/performance.md.
     donate : donate the grads and state buffers to the jitted step so XLA
         aliases them into the outputs (grads, same tree shape as params,
         becomes the new params buffer) — peak memory drops by roughly one
@@ -147,8 +146,8 @@ class DistributedOptimizer:
         self.phases = phases
         if fusion_buckets is not None and int(fusion_buckets) < 1:
             raise ValueError(f"fusion_buckets must be >= 1, got {fusion_buckets}")
-        # Fused communication buffers (reference FusionBufferManager);
-        # fusion_buckets > 1 pipelines per-bucket comm against step math.
+        # Fused communication buffers for the small leaves (reference
+        # FusionBufferManager); large leaves are exchanged as they are.
         self.fusion = fusion
         self.fusion_buckets = (None if fusion_buckets is None
                                else int(fusion_buckets))
@@ -407,6 +406,29 @@ class DistributedOptimizer:
             basics._name_program(run, "optim_init"), mesh=mesh,
             in_specs=(spec,), out_specs=spec))(placed)
 
+    def _record_exchange_paths(self, params, plan, factor) -> None:
+        """``bf_optim_exchange_leaves/bytes{path}``: how much of one rank's
+        exchanged tree the program built last hands to the combiner as it
+        is (``direct``) and how much through a fusion buffer (``packed``).
+        Set on the first step of a built program, from the rule
+        ``functional._fused_apply`` traced it with."""
+        leaves = [jax.ShapeDtypeStruct(x.shape[1:], x.dtype)
+                  for x in jax.tree_util.tree_leaves(params)]
+        if plan is not None and plan.any_sharded:
+            leaves = [l for l, m in zip(leaves, plan.mask) if not m]
+        if not factor:      # the identity combine exchanges nothing
+            leaves = []
+        # gradient_allreduce leaves a mixed-dtype tree unpacked
+        packs = self.fusion and (self.order != "gradient_allreduce"
+                                 or len({l.dtype for l in leaves}) <= 1)
+        direct, packed = (F._split_direct(leaves) if packs
+                          else (range(len(leaves)), ()))
+        for path, idx in (("direct", direct), ("packed", packed)):
+            telemetry.set_gauge("bf_optim_exchange_leaves", len(idx),
+                                path=path)
+            telemetry.set_gauge("bf_optim_exchange_bytes", sum(
+                F._leaf_bytes(leaves[i]) for i in idx), path=path)
+
     def _dispatch(self, params, grads, state, w):
         """Place the trees, launch the step program and book what it puts
         on the wire; returns ``(new_params, new_state)`` without waiting
@@ -435,6 +457,7 @@ class DistributedOptimizer:
                     plan.rep_bytes if plan is not None and plan.any_sharded
                     else sum(x.nbytes for x in
                              jax.tree_util.tree_leaves(params)))
+                self._record_exchange_paths(params, plan, traffic["factor"])
             telemetry.record_comm_traffic(
                 "optimizer_step", traffic["nbytes"] * traffic["factor"],
                 size=basics.size(), sched_stats=traffic["sched_stats"],

@@ -183,6 +183,189 @@ def test_bucketed_fusion_matches_single_buffer(order, comm):
                                    rtol=1e-6, atol=1e-6)
 
 
+# --- large leaves direct, small leaves packed (functional._fused_apply) ------
+
+# Per-rank bytes of _split_problem's leaves: 256, 12, 16, 192, 8.  With the
+# threshold patched to 128 bytes "a" and "d" go direct and the rest is packed.
+_SMALL_THRESHOLD = 128
+
+
+def _split_problem(seed=5):
+    rng = np.random.RandomState(seed)
+    shapes = {"a": (8, 8), "b": (3,), "c": (2, 2), "d": (6, 8), "e": (2,)}
+    params = {k: jnp.asarray(rng.randn(N, *v), jnp.float32)
+              for k, v in shapes.items()}
+    grads = {k: jnp.asarray(rng.randn(N, *v), jnp.float32)
+             for k, v in shapes.items()}
+    return params, grads
+
+
+def _set_threshold(monkeypatch, nbytes):
+    from bluefog_tpu.optim import functional
+    monkeypatch.setattr(functional, "_DIRECT_LEAF_BYTES", nbytes)
+
+
+def _run_split(order, dynamic, kw, steps=4):
+    comm = (CommunicationType.allreduce if order == "gradient_allreduce"
+            else CommunicationType.neighbor_allreduce)
+    params, grads = _split_problem()
+    opt = bf.optim.DistributedOptimizer(
+        optax.sgd(0.05, momentum=0.9), comm, order=order,
+        use_dynamic_topology=dynamic, **kw)
+    state = opt.init(params)
+    for _ in range(steps):
+        # donate=True consumes the gradients it is handed
+        params, state = opt.step(params, jax.tree.map(jnp.copy, grads),
+                                 state)
+    return {k: np.asarray(v) for k, v in params.items()}
+
+
+@pytest.mark.parametrize("order,dynamic,kw", [
+    ("awc", False, {}),
+    ("awc", True, {}),
+    ("atc", False, {}),
+    ("atc", True, {}),
+    ("gradient_allreduce", False, {}),
+    ("atc", True, {"fusion_buckets": 1}),
+    ("atc", True, {"fusion_buckets": 3}),
+    ("awc", False, {"fusion_buckets": 3}),
+    ("awc", True, {"num_steps_per_communication": 2}),
+    ("gradient_allreduce", False, {"num_steps_per_communication": 2}),
+    ("atc", True, {"compression": "bf16"}),
+    ("awc", False, {"compression": "bf16"}),
+    ("atc", True, {"donate": True}),
+], ids=lambda v: v if isinstance(v, str) else
+    ("dynamic" if v is True else "static" if v is False else
+     "-".join(f"{k}={x}" for k, x in v.items()) or "default"))
+def test_direct_leaves_match_unfused(monkeypatch, order, dynamic, kw):
+    """A tree with leaves on both sides of the threshold: large leaves
+    direct and small ones packed give bit for bit the parameters of every
+    leaf alone (``fusion=False``) and of every leaf packed: the same
+    multiply, permute and add on every element."""
+    bf.init(lambda: topo.ExponentialTwoGraph(N))
+    _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    mixed = _run_split(order, dynamic, kw)
+    unfused = _run_split(order, dynamic, {**kw, "fusion": False})
+    _set_threshold(monkeypatch, 1 << 40)
+    all_packed = _run_split(order, dynamic, kw)
+    # One case is held to float32 rounding, as at the parent (fusion against
+    # fusion=False there: 31 elements of 119 off by an ulp after four
+    # steps): XLA's CPU backend contracts accumulate, average and update
+    # into other fused multiply-adds when the leaves sit in a buffer.
+    tol = (1e-6 if order == "gradient_allreduce"
+           and "num_steps_per_communication" in kw else 0.0)
+    for k in mixed:
+        for other in (unfused, all_packed):
+            np.testing.assert_allclose(mixed[k], other[k], rtol=tol,
+                                       atol=tol, err_msg=k)
+
+
+def test_direct_threshold_is_one_mebibyte_inclusive():
+    """A leaf of exactly the threshold goes direct, one byte under is
+    packed; the threshold is 1 MiB."""
+    from bluefog_tpu.optim import functional as F
+    assert F._DIRECT_LEAF_BYTES == 1 << 20
+    leaves = [jax.ShapeDtypeStruct((512, 512), jnp.float32),    # 1 MiB
+              jax.ShapeDtypeStruct(((1 << 20) - 1,), jnp.uint8),
+              jax.ShapeDtypeStruct((512, 1024), jnp.bfloat16),  # 1 MiB
+              jax.ShapeDtypeStruct((), jnp.float32)]
+    assert F._split_direct(leaves) == ([0, 2], [1, 3])
+
+
+def _lowered_step(opt, params, grads):
+    return opt._step_callable(with_weights=False).lower(
+        params, grads, opt.init(params)).as_text()
+
+
+@pytest.mark.parametrize("large", [1, 2, 5])
+def test_dynamic_exchange_is_one_switch(monkeypatch, large):
+    """However many large leaves the tree has, the dynamic step program
+    chooses its phase once: one ``case`` holds every part's permute."""
+    bf.init(lambda: topo.ExponentialTwoGraph(N))
+    _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    rng = np.random.RandomState(0)
+    params = {f"w{i}": jnp.asarray(rng.randn(N, 8, 8), jnp.float32)
+              for i in range(large)}
+    params["bias"] = jnp.asarray(rng.randn(N, 3), jnp.float32)
+    opt = bf.optim.DistributedAdaptThenCombineOptimizer(
+        optax.sgd(0.05), use_dynamic_topology=True)
+    text = _lowered_step(opt, params, params)
+    assert text.count("stablehlo.case") == 1
+    phases = 3                              # one-peer Exp2 over 8 ranks
+    assert text.count("stablehlo.collective_permute") == phases * (large + 1)
+
+
+@pytest.mark.parametrize("dynamic", [False, True], ids=["static", "dynamic"])
+def test_tree_without_large_leaf_lowers_as_all_packed(monkeypatch, dynamic):
+    """A tree with no leaf of 1 MiB lowers to the program it lowers to with
+    the threshold above every leaf: one concatenate, one permute set."""
+    bf.init(lambda: topo.ExponentialTwoGraph(N))
+    params, grads = _split_problem()
+
+    def counts():
+        opt = bf.optim.DistributedAdaptThenCombineOptimizer(
+            optax.sgd(0.05), use_dynamic_topology=dynamic)
+        text = _lowered_step(opt, params, grads)
+        return (text.count("stablehlo.concatenate"),
+                text.count("stablehlo.collective_permute"))
+    default = counts()
+    _set_threshold(monkeypatch, 1 << 40)
+    assert counts() == default
+    assert default[0] == 1
+    _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    assert counts()[1] == 3 * default[1]    # "a", "d" and one buffer
+
+
+@pytest.mark.parametrize("dynamic", [False, True], ids=["static", "dynamic"])
+def test_sparse_compression_with_direct_leaf_reaches_consensus(
+        monkeypatch, dynamic):
+    """``sparse:<frac>`` rotates its block within each part: a tree with a
+    direct leaf and a packed buffer still mixes every coordinate."""
+    bf.init(lambda: topo.ExponentialGraph(N))
+    _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    rng = np.random.RandomState(6)
+    # Parts of 64 and 8 elements: four blocks each, which shares no factor
+    # with the three phases of the dynamic schedule (a block count that
+    # does would tie each block to one phase, whatever the packing).
+    params = {"w": jnp.asarray(rng.randn(N, 8, 8), jnp.float32),
+              "b": jnp.asarray(rng.randn(N, 3), jnp.float32),
+              "c": jnp.asarray(rng.randn(N, 5), jnp.float32)}
+    zeros = jax.tree.map(jnp.zeros_like, params)
+    opt = bf.optim.DistributedNeighborAllreduceOptimizer(
+        optax.sgd(0.05), compression="sparse:0.25",
+        use_dynamic_topology=dynamic)
+    state = opt.init(params)
+    mean = {k: np.asarray(v).mean(axis=0) for k, v in params.items()}
+    for _ in range(240):
+        params, state = opt.step(params, zeros, state)
+    for k, v in params.items():
+        v = np.asarray(v)
+        assert np.abs(v - v.mean(axis=0, keepdims=True)).max() < 1e-3, k
+        np.testing.assert_allclose(v.mean(axis=0), mean[k], atol=1e-4)
+
+
+@pytest.mark.parametrize("kw,want", [
+    ({}, {"direct": (2, 448), "packed": (3, 36)}),
+    ({"fusion": False}, {"direct": (5, 484), "packed": (0, 0)}),
+    ({"communication_type": CommunicationType.empty},
+     {"direct": (0, 0), "packed": (0, 0)}),
+], ids=["fused", "unfused", "identity"])
+def test_exchange_path_gauges(monkeypatch, kw, want):
+    """``bf_optim_exchange_leaves/bytes{path}`` read the leaves and the
+    per-rank bytes of the tree the step program was built for."""
+    from bluefog_tpu.utils import telemetry
+    bf.init(lambda: topo.ExponentialTwoGraph(N))
+    telemetry.reset()
+    _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    params, grads = _split_problem()
+    opt = bf.optim.DistributedOptimizer(optax.sgd(0.05), **kw)
+    opt.step(params, grads, opt.init(params))
+    snap = telemetry.snapshot()
+    for path, (leaves, nbytes) in want.items():
+        assert snap[f'bf_optim_exchange_leaves{{path="{path}"}}'] == leaves
+        assert snap[f'bf_optim_exchange_bytes{{path="{path}"}}'] == nbytes
+
+
 def test_bucket_mb_env_cap_matches_single_buffer(monkeypatch):
     """BLUEFOG_TPU_FUSION_BUCKET_MB caps bucket size instead of fixing a
     count; a tiny cap (every leaf its own bucket) must still match the
