@@ -21,8 +21,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bluefog_tpu.utils import timeline
+
 __all__ = ["TransformerLM", "TransformerConfig", "local_attention",
-           "init_cache", "generate"]
+           "init_cache", "generate", "DroplessMoe", "moe_stats"]
 
 
 def local_attention(q, k, v, *, causal: bool = True):
@@ -45,7 +47,10 @@ class TransformerConfig:
                  causal=True, num_experts=0,
                  expert_capacity_factor=2.0, router_group_size=4096,
                  num_kv_heads=None, pos_encoding="learned",
-                 rope_theta=10000.0, mlp="gelu"):
+                 rope_theta=10000.0, mlp="gelu", num_experts_per_tok=1,
+                 expert_dim=None, norm_topk_prob=False, qk_norm=False,
+                 rms_norm_eps=1e-6, router_aux_loss_coef=0.01,
+                 router_z_loss_coef=0.001):
         self.vocab_size = vocab_size
         self.num_layers = num_layers
         self.num_heads = num_heads
@@ -72,11 +77,35 @@ class TransformerConfig:
         self.rope_theta = rope_theta
         if mlp not in ("gelu", "swiglu"):
             raise ValueError(f"mlp {mlp!r} not in ('gelu', 'swiglu')")
-        if mlp == "swiglu" and num_experts:
+        if num_experts_per_tok < 1 or (
+                num_experts and num_experts_per_tok > num_experts):
             raise ValueError(
-                "mlp='swiglu' with num_experts > 0 is contradictory: MoE "
-                "blocks replace the MLP with GELU experts")
+                f"num_experts_per_tok ({num_experts_per_tok}) must lie in "
+                f"1..num_experts ({num_experts})")
+        if num_experts_per_tok > 1 and not (num_experts
+                                            and mlp == "swiglu"):
+            raise ValueError(
+                "num_experts_per_tok > 1 without mlp='swiglu' and "
+                "num_experts > 0 is contradictory: only the dropless "
+                "SwiGLU experts route top-k; GELU experts are top-1 Switch")
         self.mlp = mlp
+        # With num_experts > 0 the MLP of every block is a mixture of
+        # experts, and ``mlp`` says which: "swiglu" = DroplessMoe (top-k of
+        # SwiGLU experts, no capacity, nothing dropped: OLMoE, Moonlight),
+        # "gelu" = SwitchMlp (top-1, static capacity).  ``expert_dim`` is
+        # the width of ONE expert (None = mlp_ratio * embed_dim);
+        # ``norm_topk_prob`` renormalises the k chosen probabilities.
+        self.num_experts_per_tok = num_experts_per_tok
+        self.expert_dim = expert_dim
+        self.norm_topk_prob = norm_topk_prob
+        # What a training loss adds per DroplessMoe layer (``moe_stats``):
+        # coef * load-balancing loss and coef * router z-loss (OLMoE's).
+        self.router_aux_loss_coef = router_aux_loss_coef
+        self.router_z_loss_coef = router_z_loss_coef
+        # RMSNorm over the WHOLE projected q and k (all heads together)
+        # before the head split and the rotary embedding (OLMoE, OLMo 2).
+        self.qk_norm = qk_norm
+        self.rms_norm_eps = rms_norm_eps
         self.embed_dim = embed_dim
         self.mlp_ratio = mlp_ratio
         self.max_seq_len = max_seq_len
@@ -110,8 +139,8 @@ class TransformerConfig:
         # causal=False gives BIDIRECTIONAL attention (encoder mode — the
         # ViT uses it); the KV-cache decode path requires causal=True.
         self.causal = causal
-        # num_experts > 0 replaces each block's MLP with a switch-routed
-        # mixture of experts (top-1, static capacity).  Expert weights are
+        # num_experts > 0 replaces each block's MLP with a mixture of
+        # experts (``mlp`` says which, above).  Expert weights are
         # stacked (E, ...) so ``parallel.tp_param_specs``-style expert
         # sharding (P("ep")) runs them expert-parallel under GSPMD.
         self.num_experts = num_experts
@@ -182,6 +211,69 @@ class SwitchMlp(nn.Module):
         return y.reshape(G * g, d)[:T].reshape(B, S, d)
 
 
+class DroplessMoe(nn.Module):
+    """Top-k routed mixture of SwiGLU experts with no capacity: every token
+    reaches its ``cfg.num_experts_per_tok`` most probable experts whatever
+    the load (``parallel.moe.dropless_moe``: sorted assignments, grouped
+    matmuls over ragged groups, cost linear in ``T * k``).
+
+    Parameters: ``router/kernel`` (d, E) and the three stacked leaves
+    ``gate``, ``up`` (E, d, f) and ``down`` (E, f, d), ``f =
+    cfg.expert_dim``.  Router matmul and softmax run in float32.  Sown into
+    ``intermediates`` (read them with ``moe_stats``): ``moe_load`` (E,)
+    int32 assignment counts, ``moe_balance_loss`` and ``moe_z_loss``."""
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, x):
+        from bluefog_tpu.parallel.moe import dropless_moe
+        cfg = self.cfg
+        B, S, d = x.shape
+        E = cfg.num_experts
+        f = cfg.expert_dim or cfg.mlp_ratio * d
+        # batch_axis keeps fan_in per expert (= d / f), not E*d.
+        init = nn.initializers.lecun_normal(batch_axis=(0,))
+        gate = self.param("gate", init, (E, d, f))
+        up = self.param("up", init, (E, d, f))
+        down = self.param("down", init, (E, f, d))
+        with timeline.device_scope("bf.moe"):
+            xt = x.reshape(B * S, d)
+            with timeline.device_scope("bf.moe.route"):
+                # float32 for real: the default precision of a float32
+                # matmul on the TPU is one bfloat16 pass, and a top-k
+                # choice flips on the rounding
+                logits = nn.Dense(E, use_bias=False, dtype=jnp.float32,
+                                  precision=jax.lax.Precision.HIGHEST,
+                                  name="router")(xt.astype(jnp.float32))
+            y, plan = dropless_moe(
+                xt.astype(cfg.dtype), logits, gate, up, down,
+                k=cfg.num_experts_per_tok, renormalize=cfg.norm_topk_prob)
+        self.sow("intermediates", "moe_load", plan.load)
+        self.sow("intermediates", "moe_balance_loss", plan.balance_loss)
+        self.sow("intermediates", "moe_z_loss", plan.z_loss)
+        return y.reshape(B, S, d)
+
+
+def moe_stats(intermediates) -> dict:
+    """What the ``DroplessMoe`` layers of one forward pass sowed (apply with
+    ``mutable=["intermediates"]``): ``load`` (layers, E) int32 assignment
+    counts, and ``balance_loss`` and ``z_loss`` as means over the layers.
+    A training loss adds ``cfg.router_aux_loss_coef * balance_loss +
+    cfg.router_z_loss_coef * z_loss``; ``load`` goes to
+    ``parallel.moe.observe_load`` once fetched."""
+    found = {"moe_load": [], "moe_balance_loss": [], "moe_z_loss": []}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates):
+        for key in found:
+            if any(getattr(p, "key", None) == key for p in path):
+                found[key].append(leaf)
+    if not found["moe_load"]:
+        raise ValueError("moe_stats: no DroplessMoe layer sowed anything; "
+                         "apply the model with mutable=['intermediates']")
+    return {"load": jnp.stack(found["moe_load"]),
+            "balance_loss": jnp.mean(jnp.stack(found["moe_balance_loss"])),
+            "z_loss": jnp.mean(jnp.stack(found["moe_z_loss"]))}
+
+
 def apply_rope(x, positions, theta: float = 10000.0):
     """Rotary position embedding on ``(B, S, H, D)`` q or k.
 
@@ -244,7 +336,8 @@ class Block(nn.Module):
         if rope and positions is None and cache is None:
             # standalone Block use (e.g. pipeline stages): local positions
             positions = jnp.arange(x.shape[1])[None, :]
-        y = nn.RMSNorm(dtype=cfg.dtype)(x)
+        eps = getattr(cfg, "rms_norm_eps", 1e-6)
+        y = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype)(x)
         B, S = y.shape[0], y.shape[1]
         if kv_h == h:
             qkv = nn.Dense(3 * cfg.embed_dim, use_bias=False,
@@ -266,6 +359,12 @@ class Block(nn.Module):
             kv = nn.Dense(2 * kv_h * d, use_bias=False, dtype=cfg.dtype,
                           name="kv")(y).reshape(B, S, kv_h, 2, d)
             k1, v1 = kv[..., 0, :], kv[..., 1, :]
+        if getattr(cfg, "qk_norm", False):
+            # over the whole projection, all heads together, before rope
+            q = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype, name="q_norm")(
+                q.reshape(B, S, h * d)).reshape(B, S, h, d)
+            k1 = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype, name="k_norm")(
+                k1.reshape(B, S, kv_h * d)).reshape(B, S, kv_h, d)
         if rope:
             # rotate the kv_h shared heads ONCE, before any fan-out to h
             q = apply_rope(q, positions, cfg.rope_theta)
@@ -304,9 +403,11 @@ class Block(nn.Module):
         attn = attn.reshape(B, S, cfg.embed_dim)
         x = x + nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
                          name="proj")(attn)
-        y = nn.RMSNorm(dtype=cfg.dtype)(x)
+        y = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype)(x)
         if getattr(cfg, "num_experts", 0) > 0:
-            x = x + SwitchMlp(cfg, name="moe")(y)
+            moe = (DroplessMoe if getattr(cfg, "mlp", "gelu") == "swiglu"
+                   else SwitchMlp)
+            x = x + moe(cfg, name="moe")(y)
         elif getattr(cfg, "mlp", "gelu") == "swiglu":
             hidden = cfg.mlp_ratio * cfg.embed_dim
             gate = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype,
@@ -384,7 +485,8 @@ class TransformerLM(nn.Module):
                 x = blk(x, positions)
             else:
                 x = blk(x)
-        x = nn.RMSNorm(dtype=cfg.dtype)(x)
+        x = nn.RMSNorm(epsilon=getattr(cfg, "rms_norm_eps", 1e-6),
+                       dtype=cfg.dtype)(x)
         head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                         name="lm_head")
         if return_hidden:

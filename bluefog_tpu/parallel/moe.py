@@ -17,15 +17,42 @@ capacity so every shape is fixed under jit:
 Differentiable end-to-end (the straight-through is unnecessary: top-1
 selection is constant w.r.t. parameters at a point; router gradients flow
 through the combine weights as in the Switch paper).
+
+**Dropless top-k** (``dropless_moe``, what ``models.transformer.DroplessMoe``
+runs): the other semantics, for the open sparse LMs of today (OLMoE,
+Moonlight, ...).  Every token goes to its ``k`` most probable experts and no
+expert has a capacity, so nothing is ever dropped and the cost is linear in
+the ``T * k`` assignments: the assignments are sorted by expert (stable), the
+rows gathered into that order, three grouped matmuls over the ragged groups
+(``grouped_matmul``: the Pallas kernels of ``jax.experimental.pallas.ops.
+tpu.megablox``) apply the SwiGLU experts, and the rows return to token
+order weighted by their router probabilities.  All experts live on the
+calling rank; the permutations are gathers in both directions (their
+transposes are written out), so no scatter runs forward or backward.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
-__all__ = ["moe_apply", "switch_dispatch", "load_balance_loss"]
+from bluefog_tpu.utils import telemetry, timeline
+
+__all__ = ["moe_apply", "switch_dispatch", "load_balance_loss",
+           "topk_load_balance_loss", "router_z_loss", "route_topk",
+           "grouped_matmul", "dropless_moe", "observe_load", "Routing"]
+
+# The module, not the package's ``gmm`` (a custom_vjp of its own that names
+# nothing): the kernels are called unjitted so that each takes the name of
+# the scope around it (``bf_moe_gmm_fwd.3``), as ``bf_flash_*`` do.
+_megablox = importlib.import_module(
+    "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
 
 def load_balance_loss(router_logits, valid=None):
@@ -52,6 +79,26 @@ def load_balance_loss(router_logits, valid=None):
     f = (routed * w[:, None]).sum(axis=0)
     p = (probs * w[:, None]).sum(axis=0)
     return E * (f * p).sum()
+
+
+def topk_load_balance_loss(probs, load, k: int):
+    """Load-balancing loss of a top-``k`` router: ``E * sum_e f_e * P_e``,
+    ``f_e`` the share of the ``T * k`` assignments that went to expert ``e``
+    (``load`` holds their counts, a constant for the gradient) and ``P_e``
+    the mean router probability over the ``T`` tokens of ``probs`` (T, E).
+    1 at a uniform router for every ``k``; with ``k == 1`` it is
+    ``load_balance_loss``."""
+    T, E = probs.shape
+    f = lax.stop_gradient(load.astype(jnp.float32)) / (T * k)
+    return E * (f * probs.mean(axis=0)).sum()
+
+
+def router_z_loss(router_logits):
+    """Router z-loss (ST-MoE): the mean over tokens of
+    ``logsumexp(router_logits)^2``; keeps the logits small enough for the
+    float32 softmax to stay exact."""
+    z = jax.nn.logsumexp(router_logits.astype(jnp.float32), axis=-1)
+    return jnp.mean(z * z)
 
 
 def switch_dispatch(router_logits, n_experts: int, capacity: int,
@@ -135,3 +182,202 @@ def moe_apply(expert_fn, expert_params, x, router_logits, *,
     if with_aux:
         return y, load_balance_loss(router_logits)
     return y
+
+
+# --- dropless top-k ----------------------------------------------------------
+
+class Routing(NamedTuple):
+    """A top-``k`` routing plan over ``T`` tokens and ``E`` experts."""
+    weights: jax.Array      # (T, k) float32: the chosen probabilities
+    experts: jax.Array      # (T, k) int32: the chosen experts
+    order: jax.Array        # (T*k,) int32: assignments (t*k + j) by expert
+    inverse: jax.Array      # (T*k,) int32: where each assignment landed
+    load: jax.Array         # (E,) int32: assignments per expert, sums to T*k
+    balance_loss: jax.Array  # topk_load_balance_loss of this plan
+    z_loss: jax.Array        # router_z_loss of these logits
+
+
+def route_topk(router_logits, k: int, *, renormalize: bool = False
+               ) -> Routing:
+    """Top-``k`` routing of (T, E) logits with no capacity: softmax in
+    float32, the ``k`` largest probabilities of each token (left as they
+    are, or ``renormalize``d to sum to one), the ``T * k`` assignments
+    sorted by expert with ties in token order, and the per-expert counts."""
+    T, E = router_logits.shape
+    logits = router_logits.astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = lax.top_k(probs, k)
+    if renormalize:
+        weights = weights / weights.sum(axis=-1, keepdims=True)
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    load = jax.nn.one_hot(flat, E, dtype=jnp.int32).sum(axis=0)
+    return Routing(weights, experts.astype(jnp.int32), order, inverse, load,
+                   topk_load_balance_loss(probs, load, k),
+                   router_z_loss(logits))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, index, back, fan: int):
+    """``x[index // fan]``: each row of ``x`` appears ``fan`` times in the
+    result, and ``back`` says where (``index[back] == arange``).  The
+    transpose is then a gather too, ``dy[back]`` summed over each row's
+    ``fan`` copies, where autodiff would emit a scatter-add."""
+    return x[index // fan] if fan > 1 else x[index]
+
+
+def _take_rows_fwd(x, index, back, fan):
+    return _take_rows(x, index, back, fan), (index, back)
+
+
+def _take_rows_bwd(fan, res, dy):
+    _, back = res
+    dx = dy[back]
+    if fan > 1:
+        dx = dx.reshape(-1, fan, dx.shape[-1]).sum(axis=1, dtype=dy.dtype)
+    return dx, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _fit(dim: int, target: int) -> int:
+    """The largest tile of ``target``, ``target / 2``, ... down to 128 that
+    divides ``dim``; failing that the whole dimension."""
+    tile = target
+    while tile >= 128:
+        if dim % tile == 0:
+            return tile
+        tile //= 2
+    return dim
+
+
+def _tiles(rows: int, k: int, n: int, dtype, *, whole_k: bool) -> tuple:
+    """Row, ``k`` and ``n`` tiles of a kernel over ``rows`` padded rows.
+    One v5e chip, 65536 rows in 64 groups, 2048 x 1024 and 1024 x 2048
+    bfloat16 matrices (PR 26 trial): 256 rows against the whole ``k`` and
+    1024 columns took 1.99 / 2.08 ms a product where megablox's default
+    128 x 128 x 128 took 28 and 512 x 1024 x 1024 2.41; the matrices'
+    gradient keeps a float32 ``k x n`` tile and took 2.81 ms at 256 x 1024
+    x 1024.  Wider tiles overflow the 16 MiB of scoped VMEM; float32
+    operands get half the width."""
+    wide = 4096 // jnp.dtype(dtype).itemsize
+    tm = next((t for t in (256, 128, 64, 32, 16, 8) if rows % t == 0))
+    return tm, _fit(k, wide if whole_k else wide // 2), _fit(n, wide // 2)
+
+
+def _kernel(name: str, fn, *args, **kw):
+    with timeline.device_scope(name):
+        return fn(*args, **kw)
+
+
+@jax.custom_vjp
+def grouped_matmul(rows, matrices, group_sizes):
+    """``rows[i] @ matrices[group of row i]``: (m, k) rows sorted by group,
+    (E, k, n) matrices, (E,) int32 ``group_sizes`` that sum to ``m``;
+    returns (m, n) in ``rows``' dtype.  The matrices are cast to that dtype
+    for the product and accumulated in float32; their gradient comes back
+    in their own dtype.  An empty group costs nothing and a full one takes
+    every row: no capacity.
+
+    Three Pallas kernels (megablox), named in the compiled program
+    ``bf_moe_gmm_fwd`` (also the remat recompute), ``bf_moe_gmm_dlhs`` (the
+    rows' gradient: the same kernel on the transposed matrices) and
+    ``bf_moe_gmm_drhs`` (the matrices' gradient, each group's rows
+    contracted).  Off the TPU they run in the Pallas interpreter."""
+    return _grouped_fwd(rows, matrices, group_sizes)[0]
+
+
+def _padded(rows):
+    """``rows`` padded to a whole number of the smallest row tile; the
+    padding belongs to no group and no kernel visits it."""
+    pad = -rows.shape[0] % 8
+    return jnp.pad(rows, ((0, pad), (0, 0))) if pad else rows
+
+
+def _interpret(x) -> bool:
+    from bluefog_tpu.ops.flash_attention import platform_in_use
+    return platform_in_use(x) != "tpu"
+
+
+def _grouped_fwd(rows, matrices, group_sizes):
+    m, k = rows.shape
+    n = matrices.shape[2]
+    lhs, rhs = _padded(rows), matrices.astype(rows.dtype)
+    out = _kernel(
+        "bf_moe_gmm_fwd", _megablox.gmm.__wrapped__, lhs, rhs, group_sizes,
+        rows.dtype, _tiles(lhs.shape[0], k, n, rows.dtype, whole_k=True),
+        interpret=_interpret(rows))
+    # the empty array carries the matrices' dtype to the backward pass
+    return out[:m], (lhs, rhs, group_sizes, jnp.zeros((0,), matrices.dtype))
+
+
+def _grouped_bwd(res, d_out):
+    lhs, rhs, group_sizes, like = res
+    interpret = _interpret(d_out)
+    m, (k, n) = d_out.shape[0], rhs.shape[1:]
+    d_out = _padded(d_out)
+    padded = lhs.shape[0]
+    d_rows = _kernel(
+        "bf_moe_gmm_dlhs", _megablox.gmm.__wrapped__, d_out, rhs,
+        group_sizes, lhs.dtype,
+        _tiles(padded, n, k, lhs.dtype, whole_k=True),
+        transpose_rhs=True, interpret=interpret)
+    d_matrices = _kernel(
+        "bf_moe_gmm_drhs", _megablox.tgmm.__wrapped__, lhs.swapaxes(0, 1),
+        d_out, group_sizes, like.dtype,
+        _tiles(padded, k, n, lhs.dtype, whole_k=False), interpret=interpret)
+    return d_rows[:m], d_matrices, None
+
+
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def dropless_moe(x, router_logits, gate, up, down, *, k: int,
+                 renormalize: bool = False):
+    """A dropless top-``k`` mixture of SwiGLU experts on this rank.
+
+    ``x``: (T, d) tokens in the compute dtype; ``router_logits``: (T, E);
+    ``gate``, ``up``: (E, d, f) and ``down``: (E, f, d), cast to ``x``'s
+    dtype for the products (float32 accumulation: ``grouped_matmul``).
+    Returns ``(y, routing)`` with ``y = sum_j w_j * down_j(silu(gate_j x) *
+    up_j x)`` over each token's ``k`` experts, (T, d) in ``x``'s dtype, and
+    the ``Routing`` that holds the per-expert load and the two auxiliary
+    losses.  No token is
+    dropped at any load: an expert takes as many rows as choose it.
+
+    Device scopes: ``bf.moe.route``, ``bf.moe.dispatch``, ``bf.moe.experts``
+    and ``bf.moe.combine``; the caller wraps the layer (the router matmul
+    included) in ``bf.moe``."""
+    T, d = x.shape
+    dt = x.dtype
+    with timeline.device_scope("bf.moe.route"):
+        plan = route_topk(router_logits, k, renormalize=renormalize)
+    with timeline.device_scope("bf.moe.dispatch"):
+        rows = _take_rows(x, plan.order, plan.inverse, k)       # (T*k, d)
+    with timeline.device_scope("bf.moe.experts"):
+        g = grouped_matmul(rows, gate, plan.load)
+        u = grouped_matmul(rows, up, plan.load)
+        out = grouped_matmul(jax.nn.silu(g) * u, down, plan.load)  # (T*k, d)
+    with timeline.device_scope("bf.moe.combine"):
+        back = _take_rows(out, plan.inverse, plan.order, 1)
+        y = (back.reshape(T, k, d).astype(jnp.float32)
+             * plan.weights[..., None]).sum(axis=1)
+    return y.astype(dt), plan
+
+
+def observe_load(load) -> float:
+    """Publish per-expert assignment counts a training loop has fetched:
+    ``load`` is ``(E,)`` or ``(..., E)`` (layers, ranks: summed).  Adds to
+    the counter ``bf_moe_assignments_total{expert}`` and sets the gauge
+    ``bf_moe_load_max_over_mean`` (1 at a perfectly even load, ``E`` when
+    one expert takes everything), which it returns."""
+    counts = np.asarray(load, np.float64)
+    counts = counts.reshape(-1, counts.shape[-1]).sum(axis=0)
+    for e, n in enumerate(counts):
+        telemetry.inc("bf_moe_assignments_total", float(n), expert=str(e))
+    mean = counts.mean()
+    ratio = float(counts.max() / mean) if mean > 0 else 0.0
+    telemetry.set_gauge("bf_moe_load_max_over_mean", ratio)
+    return ratio

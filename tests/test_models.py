@@ -276,7 +276,9 @@ def test_transformer_gqa_validates_divisibility():
     with pytest.raises(ValueError, match="even head dim"):
         TransformerConfig(embed_dim=90, num_heads=6, pos_encoding="rope")
     with pytest.raises(ValueError, match="contradictory"):
-        TransformerConfig(mlp="swiglu", num_experts=4)
+        # top-k > 1 belongs to the dropless SwiGLU experts; GELU is Switch
+        TransformerConfig(mlp="gelu", num_experts=4, num_experts_per_tok=2)
+    TransformerConfig(mlp="swiglu", num_experts=4)   # DroplessMoe, top-1
     with pytest.raises(ValueError, match="dots:<int>"):
         TransformerConfig(remat=True, remat_policy="dots:abc")
     with pytest.raises(ValueError, match="not in"):
