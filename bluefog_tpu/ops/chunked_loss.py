@@ -26,12 +26,25 @@ __all__ = ["chunked_softmax_cross_entropy"]
 
 
 def chunked_softmax_cross_entropy(hidden, lm_head, targets, *,
-                                  chunk: int = 1024):
+                                  chunk: int = 2048):
     """Mean next-token cross-entropy over ``(B, S)`` without full logits.
 
     ``hidden``: (B, S, E) final-layer activations; ``lm_head``: (E, V)
     projection (pass ``params["lm_head"]["kernel"]``); ``targets``: (B, S)
-    int labels.  ``chunk`` rows of logits exist at a time (per batch row).
+    int labels in ``[0, V)``.  ``chunk`` is the rows of logits that exist at
+    a time over the whole batch: a chunk takes ``c`` positions of every batch
+    row, ``c`` the largest divisor of ``S`` not above ``chunk // B`` (at
+    least 1).  So the memory the function needs does not grow with ``B``,
+    and one long row is cut into as few chunks (passes over the head's
+    float32 gradient) as several short ones.
+
+    The target's logit is taken as ``sum(where(iota == target, logits, 0))``
+    over the vocabulary, not with a gather: the gather's transpose is a
+    scatter-add, which the TPU compiler at ``B == 1`` serves by writing the
+    chunk's float32 logit gradient out, copying it into a flat buffer and
+    back (three passes over ``c x V`` that compute nothing).  The compare's
+    transpose is ``hit * g``, elementwise, and fuses into the two backward
+    products at every ``B``.  Value and gradient are the same numbers.
     """
     # One device scope over forward, remat recompute and transpose alike
     # (their metadata reads checkpoint/.../bf.loss.chunked and
@@ -40,15 +53,22 @@ def chunked_softmax_cross_entropy(hidden, lm_head, targets, *,
         return _chunked_loss(hidden, lm_head, targets, chunk)
 
 
-def _chunked_loss(hidden, lm_head, targets, chunk: int):
-    B, S, E = hidden.shape
+def _positions_per_chunk(chunk: int, B: int, S: int) -> int:
+    """Positions of each batch row in a chunk of ``chunk`` rows over ``B``
+    batch rows: the largest divisor of ``S`` not above ``chunk // B``, so
+    awkward S (odd, prime factors) still gets the biggest legal chunk instead
+    of degrading to 1 via halving."""
     if chunk < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")
-    # Largest divisor of S <= chunk, so awkward S (odd, prime factors) still
-    # gets the biggest legal chunk instead of degrading to 1 via halving.
-    c = min(chunk, S)
+    c = min(max(1, chunk // B), S)
     while S % c:
         c -= 1
+    return c
+
+
+def _chunked_loss(hidden, lm_head, targets, chunk: int):
+    B, S, E = hidden.shape
+    c = _positions_per_chunk(chunk, B, S)
     n_chunks = S // c
 
     h = hidden.reshape(B, n_chunks, c, E).transpose(1, 0, 2, 3)  # (n,B,c,E)
@@ -59,8 +79,9 @@ def _chunked_loss(hidden, lm_head, targets, chunk: int):
         logits = jnp.einsum("bce,ev->bcv", h_c.astype(jnp.float32),
                             lm_head.astype(jnp.float32))
         lse = jax.nn.logsumexp(logits, axis=-1)                  # (B, c)
-        correct = jnp.take_along_axis(logits, t_c[..., None],
-                                      axis=-1)[..., 0]
+        vocab = lax.broadcasted_iota(t_c.dtype, logits.shape, 2)
+        correct = jnp.sum(jnp.where(vocab == t_c[..., None], logits, 0.0),
+                          axis=-1)
         return jnp.sum(lse - correct)
 
     def body(acc, xs):

@@ -319,40 +319,85 @@ def test_transformer_remat_matches_plain(policy):
                                    rtol=1e-5, atol=1e-5)
 
 
-def test_chunked_loss_matches_dense():
-    """chunked_softmax_cross_entropy == optax dense CE in value and grad,
-    through the model's return_hidden path."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
+@pytest.fixture(scope="module")
+def tiny_lm():
+    """A float32 LM with 16 positions and 64 words, and its parameters."""
     from bluefog_tpu.models import TransformerLM, TransformerConfig
-    from bluefog_tpu.ops.chunked_loss import chunked_softmax_cross_entropy
-
     cfg = TransformerConfig(vocab_size=64, num_layers=2, num_heads=4,
                             embed_dim=32, max_seq_len=16, dtype=jnp.float32)
     model = TransformerLM(cfg)
-    tokens = jnp.asarray(np.random.RandomState(0).randint(0, 64, (2, 16)))
-    params = model.init(jax.random.PRNGKey(0), tokens)
+    return model, model.init(jax.random.PRNGKey(0),
+                             jnp.zeros((1, 16), jnp.int32))
+
+
+# chunk counts rows over the whole batch: 8 gives c = 8, 4, 2 at B = 1, 2, 3;
+# 7 divides no 16 and fits down to c = 4, 2, 2.
+@pytest.mark.parametrize("chunk", [8, 7])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("B", [1, 2, 3])
+def test_chunked_loss_matches_dense(tiny_lm, B, dtype, chunk):
+    """chunked_softmax_cross_entropy == optax dense CE in value and in the
+    gradients of hidden rows and head, on the model's return_hidden path."""
+    import optax
+    from bluefog_tpu.ops.chunked_loss import chunked_softmax_cross_entropy
+
+    model, params = tiny_lm
+    tokens = jnp.asarray(np.random.RandomState(B).randint(0, 64, (B, 16)))
     tgt = jnp.roll(tokens, -1, axis=1)
+    hidden = model.apply(params, tokens, return_hidden=True).astype(dtype)
+    head = params["params"]["lm_head"]["kernel"]
 
-    def dense_loss(p):
+    def dense_loss(h, w):
         return optax.softmax_cross_entropy_with_integer_labels(
-            model.apply(p, tokens), tgt).mean()
+            h.astype(jnp.float32) @ w, tgt).mean()
 
-    def chunked_loss(p):
-        h = model.apply(p, tokens, return_hidden=True)
-        return chunked_softmax_cross_entropy(
-            h, p["params"]["lm_head"]["kernel"], tgt, chunk=4)
+    def chunked_loss(h, w):
+        return chunked_softmax_cross_entropy(h, w, tgt, chunk=chunk)
 
-    np.testing.assert_allclose(float(chunked_loss(params)),
-                               float(dense_loss(params)), rtol=1e-5)
-    g_d = jax.grad(dense_loss)(params)
-    g_c = jax.grad(chunked_loss)(params)
-    for a, b in zip(jax.tree_util.tree_leaves(g_d),
-                    jax.tree_util.tree_leaves(g_c)):
-        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
-                                   rtol=2e-4, atol=2e-5)
+    l_d, g_d = jax.value_and_grad(dense_loss, argnums=(0, 1))(hidden, head)
+    l_c, g_c = jax.value_and_grad(chunked_loss, argnums=(0, 1))(hidden, head)
+    np.testing.assert_allclose(float(l_c), float(l_d), rtol=1e-5)
+    assert g_c[0].dtype == dtype and g_c[1].dtype == head.dtype
+    # Both sides round the same float32 gradient of the hidden rows to
+    # bfloat16 (8 bits of mantissa); the head's stays float32.
+    h_rtol = 2e-4 if dtype == jnp.float32 else 2 ** -7
+    for a, b, rtol in zip(g_d, g_c, (h_rtol, 2e-4)):
+        np.testing.assert_allclose(np.asarray(b, np.float32),
+                                   np.asarray(a, np.float32),
+                                   rtol=rtol, atol=2e-5)
+
+
+@pytest.mark.parametrize("B", [1, 2])
+def test_chunked_loss_gradient_has_no_gather_or_scatter(B):
+    """The target's logit is a compare and a sum: a gather's transpose is a
+    scatter-add, which costs a TPU three passes over a chunk at B == 1."""
+    from bluefog_tpu.ops.chunked_loss import chunked_softmax_cross_entropy
+    h = jnp.zeros((B, 16, 8), jnp.bfloat16)
+    w = jnp.zeros((8, 20), jnp.float32)
+    t = jnp.zeros((B, 16), jnp.int32)
+    text = jax.jit(jax.grad(
+        lambda h, w: chunked_softmax_cross_entropy(h, w, t, chunk=8),
+        argnums=(0, 1))).lower(h, w).as_text()
+    assert "stablehlo.while" in text            # the scan is there to read
+    assert "scatter" not in text and "gather" not in text
+
+
+def test_chunked_loss_chunk_counts_rows_over_the_batch():
+    from bluefog_tpu.ops import chunked_loss
+    default = chunked_loss.chunked_softmax_cross_entropy.__kwdefaults__["chunk"]
+    per_row = chunked_loss._positions_per_chunk
+    # one long row and two short ones work on as many rows at a time
+    assert per_row(default, 1, 16384) == 2 * per_row(default, 2, 4096)
+    for chunk, B, S in [(8, 1, 12), (8, 3, 12), (7, 2, 30), (2048, 2, 4099),
+                        (5, 1, 3)]:
+        c = per_row(chunk, B, S)
+        assert S % c == 0 and 1 <= c <= max(1, chunk // B), (chunk, B, S, c)
+    assert per_row(8, 1, 12) == 6 and per_row(8, 3, 12) == 2
+    assert per_row(4, 5, 12) == 1               # more batch rows than chunk
+    h = jnp.zeros((1, 4, 2))
+    with pytest.raises(ValueError, match="chunk must be >= 1"):
+        chunked_loss.chunked_softmax_cross_entropy(
+            h, jnp.zeros((2, 3)), jnp.zeros((1, 4), jnp.int32), chunk=0)
 
 
 def test_chunked_loss_uneven_chunk_fits_down():

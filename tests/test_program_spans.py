@@ -313,7 +313,8 @@ def test_scopes_change_no_instruction(monkeypatch):
     assert _stripped(scoped) == _stripped(plain)
 
 
-def test_lm_gradient_program_carries_the_loss_scope():
+@pytest.mark.parametrize("B", [1, 2])
+def test_lm_gradient_program_carries_the_loss_scope(B):
     from bluefog_tpu import models
     from bluefog_tpu.ops.chunked_loss import chunked_softmax_cross_entropy
     bf.init(devices=jax.devices()[:2])
@@ -321,7 +322,7 @@ def test_lm_gradient_program_carries_the_loss_scope():
                                    embed_dim=16, max_seq_len=16,
                                    dtype=jnp.float32)
     model = models.TransformerLM(cfg)
-    tokens = np.zeros((2, 1, 16), np.int32)
+    tokens = np.zeros((2, B, 16), np.int32)
 
     def loss(params, tokens):
         hidden = model.apply({"params": params}, tokens, return_hidden=True)
