@@ -5,7 +5,7 @@
 
 PYTEST = python -m pytest -q
 
-.PHONY: test test-fast test-slow test-all chip-smoke bench bench-comm \
+.PHONY: test test-fast test-slow test-all chip-smoke bench-comm \
         bench-comm-smoke native telemetry-smoke prof-smoke transport-smoke \
         stripe-smoke tracerec-smoke async-smoke ffi-smoke fused-smoke \
         probe-smoke placement-smoke synth-smoke hier-smoke sharded-smoke \
@@ -37,9 +37,6 @@ test-all:
 # LM on every TPU chip of this host; exits non-zero without a TPU.
 chip-smoke:
 	python chip_smoke.py
-
-bench:
-	python bench.py
 
 # Gossip hot-path microbench: rounds/edges/walltime, naive shift-distance
 # schedule vs the min-round repack (ops/schedule_opt.py), CPU-runnable.

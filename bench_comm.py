@@ -1,7 +1,7 @@
 """Communication microbenchmark: the gossip hot path's compiled schedule.
 
-Measures what ``bench.py`` (an end-to-end training benchmark) cannot
-isolate: the round count, edge count and per-op walltime of
+Measures what an end-to-end training benchmark (``benchmark/run.py``)
+cannot isolate: the round count, edge count and per-op walltime of
 ``neighbor_allreduce`` under the naive shift-distance schedule vs the
 min-round repack (``ops/schedule_opt.py``), across the topology families
 that matter — shift-structured (ring, Exp2: already optimal, the repack
@@ -52,7 +52,7 @@ virtual host-platform mesh, so schedule regressions are caught by
 forces ``--n`` virtual devices itself (before jax imports); on a real
 backend it uses the attached devices and clamps ``--n`` to them.
 
-Prints ONE JSON line like bench.py:
+Prints ONE JSON line:
   {"metric": "gossip_schedule_opt_round_reduction_random_regular",
    "value": <naive_rounds / optimized_rounds>, "unit": "x", ...}
 with per-topology detail: rounds/edges before/after, per-op walltime for

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import enum
 from functools import partial
-from typing import Callable, NamedTuple, Optional
+from typing import Callable, NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +42,7 @@ from bluefog_tpu.utils import timeline
 __all__ = [
     "CommunicationType",
     "DistOptState",
+    "Combiner",
     "make_combiner",
     "make_shard_combiner",
     "compress_combiner",
@@ -67,28 +68,43 @@ class DistOptState(NamedTuple):
     acc: Optional[object] = None  # grad accumulator (gradient_allreduce, J>1)
 
 
-Combiner = Callable[..., jnp.ndarray]  # (x, *, step, weights) -> x
+class Combiner(NamedTuple):
+    """What :func:`make_combiner` returns, and all a caller may know of it.
+
+    ``combine(parts, step, weights)`` takes the list of arrays one exchange
+    moves (:func:`_fused_apply`: the large leaves, then the packed buffer)
+    and returns a list of the same shapes and dtypes.  ``step`` is the
+    traced step counter (dynamic schedules choose their phase by it) and
+    ``weights`` an optional traced (n, n) matrix overriding the schedule's
+    weights (None: the weights baked in).  ``identity``: it exchanges
+    nothing.  ``replica_identical``: every rank receives the same result
+    (global allreduce), so a codec adds no per-rank residual back.
+    ``sched``, ``axis_name``: the compiled edge schedule (static or dynamic)
+    of a flat neighbor combiner and its mesh axis, which ``sparse:<frac>``
+    rides; None for every other communication type."""
+    combine: Callable
+    identity: bool = False
+    replica_identical: bool = False
+    sched: Optional[object] = None
+    axis_name: Optional[str] = None
+
+
+def _per_part(one):
+    """``combine`` of a combiner that works array by array."""
+    return lambda parts, step, weights: [one(x, step, weights) for x in parts]
 
 
 def make_combiner(
         comm: CommunicationType,
         *,
         axis_name: str,
-        sched: Optional[StaticSchedule] = None,
-        dyn_sched: Optional[DynamicSchedule] = None,
+        sched: Union[StaticSchedule, DynamicSchedule, None] = None,
         local_axis: Optional[str] = None,
         machine_axis: Optional[str] = None,
         hier: Optional[dict] = None,
 ) -> Combiner:
-    """Build the per-leaf ``combine`` function for a communication type.
-
-    The returned callable has signature ``combine(x, step, weights)`` where
-    ``step`` is the traced step counter (used by dynamic schedules) and
-    ``weights`` is an optional traced (n, n) matrix overriding the static
-    schedule's weights (None => baked-in weights).  A callable marked
-    ``takes_parts`` (dynamic neighbor averaging) also takes a list of
-    arrays for ``x`` and chooses its phase once for all of them.
-    """
+    """Build the :class:`Combiner` of a communication type; ``sched`` is
+    the compiled schedule of the neighbor types, static or dynamic."""
     def _no_weights(weights, what):
         if weights is not None:
             raise ValueError(
@@ -96,57 +112,47 @@ def make_combiner(
                 "they apply to (dynamic) neighbor_allreduce only")
 
     if comm == CommunicationType.empty:
-        def _empty(x, step=None, weights=None):
+        def _empty(parts, step, weights):
             _no_weights(weights, "CommunicationType.empty")
-            return x
-        _empty.is_identity = True  # lets _tree_combine skip fusion copies
-        return _empty
+            return parts
+        return Combiner(_empty, identity=True)
     if comm == CommunicationType.allreduce:
-        def _ar(x, step=None, weights=None):
+        def _ar(x, step, weights):
             _no_weights(weights, "CommunicationType.allreduce")
             return C.allreduce(x, axis_name, average=True)
-        _ar.is_allreduce = True  # replica-identical: compress without residual
-        return _ar
+        return Combiner(_per_part(_ar), replica_identical=True)
     if comm == CommunicationType.neighbor_allreduce:
-        if dyn_sched is not None:
-            def _dyn(x, step, weights=None):
+        if isinstance(sched, DynamicSchedule):
+            # The one combiner that takes the list whole: it chooses its
+            # phase once, and one lax.switch serves every part.
+            def _dyn(parts, step, weights):
                 if weights is None:
                     return C.dynamic_neighbor_allreduce(
-                        x, step, dyn_sched, axis_name)
+                        parts, step, sched, axis_name)
                 # Weight override on a dynamic topology: same phase switching,
                 # weights looked up from the traced matrix per active edge.
                 branches = [
                     partial(lambda ph, args: jax.tree.map(
                         lambda p: C.neighbor_allreduce_matrix(
                             p, args[1], ph, axis_name), args[0]), ph)
-                    for ph in dyn_sched.phases]
-                return lax.switch(step % dyn_sched.period, branches,
-                                  (x, weights))
-            # x may be the list of all parts of a step's exchange
-            # (_fused_apply): one lax.switch then serves them all.
-            _dyn.takes_parts = True
-            # Lets compress_combiner run the aligned rotating-block sparse
-            # exchange under the same lax.switch of phases
-            # (compression="sparse:<frac>" on dynamic topologies).
-            _dyn._sparse_dyn_args = (dyn_sched, axis_name)
-            return _dyn
+                    for ph in sched.phases]
+                return lax.switch(step % sched.period, branches,
+                                  (parts, weights))
+            return Combiner(_dyn, sched=sched, axis_name=axis_name)
         assert sched is not None, "static neighbor_allreduce needs a schedule"
 
-        def _nbr(x, step=None, weights=None):
+        def _nbr(x, step, weights):
             if weights is None:
                 return C.neighbor_allreduce(x, sched, axis_name)
             return C.neighbor_allreduce_matrix(x, weights, sched, axis_name)
-        # Lets compress_combiner build the top-k SPARSE exchange over the
-        # same compiled edge schedule (compression="sparse:<frac>").
-        _nbr._sparse_args = (sched, axis_name)
-        return _nbr
+        return Combiner(_per_part(_nbr), sched=sched, axis_name=axis_name)
     if comm == CommunicationType.hierarchical_gossip:
         assert local_axis and machine_axis, \
             "hierarchical gossip needs local/machine axis names"
         assert hier is not None, \
             "hierarchical gossip needs the compiled level bundle (hier=)"
 
-        def _hgossip(x, step, weights=None):
+        def _hgossip(x, step, weights):
             _no_weights(weights, "hierarchical_gossip")
             return C.hierarchical_gossip(
                 x, step, hier["inner_sched"], hier["outer_scheds"],
@@ -154,23 +160,23 @@ def make_combiner(
                 outer_every=hier.get("outer_every", 1),
                 outer_compression=hier.get("outer_compression", "none"),
                 outer_frac=hier.get("outer_frac"))
-        return _hgossip
+        return Combiner(_per_part(_hgossip))
     if comm == CommunicationType.hierarchical_neighbor_allreduce:
         assert local_axis and machine_axis, \
             "hierarchical combine needs local/machine axis names"
-        if dyn_sched is not None:
-            def _hdyn(x, step, weights=None):
+        if isinstance(sched, DynamicSchedule):
+            def _hdyn(x, step, weights):
                 _no_weights(weights, "hierarchical_neighbor_allreduce")
                 return C.dynamic_hierarchical_neighbor_allreduce(
-                    x, step, dyn_sched, local_axis, machine_axis)
-            return _hdyn
+                    x, step, sched, local_axis, machine_axis)
+            return Combiner(_per_part(_hdyn))
         assert sched is not None
 
-        def _hier(x, step=None, weights=None):
+        def _hier(x, step, weights):
             _no_weights(weights, "hierarchical_neighbor_allreduce")
             return C.hierarchical_neighbor_allreduce(
                 x, sched, local_axis, machine_axis)
-        return _hier
+        return Combiner(_per_part(_hier))
     raise ValueError(f"unknown communication type {comm}")
 
 
@@ -178,7 +184,7 @@ def make_shard_combiner(plan, group_combine, *, axis_name: str):
     """Per-replica-group combiner for the sharded leaves of a plan.
 
     ``plan`` is an :class:`ops.sharded.ShardPlan`; ``group_combine`` is a
-    regular combiner (``make_combiner`` output, optionally wrapped by
+    :class:`Combiner` (``make_combiner`` output, optionally wrapped by
     ``compress_combiner``) built over the plan's *merged group schedule*
     — its in-group-only edges are what keeps sharded bytes off the DCN.
 
@@ -197,8 +203,6 @@ def make_shard_combiner(plan, group_combine, *, axis_name: str):
         # stripped by shard_map), so the sharded model dim d IS array
         # axis d here — the host-side +1 offset applies only to the
         # rank-major tree the plan was built from.
-        if not leaves:
-            return leaves
         coord = coords[lax.axis_index(axis_name)]
         slices = []
         for leaf, d in zip(leaves, sh_dims):
@@ -206,7 +210,7 @@ def make_shard_combiner(plan, group_combine, *, axis_name: str):
             slices.append(lax.dynamic_slice_in_dim(
                 leaf, coord * chunk, chunk, axis=d))
         flat, unravel = ravel_pytree(slices)
-        combined = unravel(group_combine(flat, step=step, weights=None))
+        combined = unravel(group_combine.combine([flat], step, None)[0])
         out = []
         for leaf, d, sl in zip(leaves, sh_dims, combined):
             chunk = leaf.shape[d] // plan.n_shards
@@ -214,49 +218,6 @@ def make_shard_combiner(plan, group_combine, *, axis_name: str):
                 leaf, sl.astype(leaf.dtype), coord * chunk, axis=d))
         return out
     return shard_combine
-
-
-def _bucket_groups(leaves, fusion_buckets: Optional[int]):
-    """Partition flatten-order leaf indices into contiguous fusion buckets.
-
-    ``fusion_buckets`` (explicit count) wins over the
-    ``BLUEFOG_TPU_FUSION_BUCKET_MB`` size cap; with neither, one bucket —
-    today's whole-tree ravel.  Buckets are contiguous in tree-flatten
-    order, byte-balanced (count mode) or size-capped (MB mode), and
-    deterministic: every SPMD rank must build identical buffers.
-    """
-    from bluefog_tpu.utils import config
-    nbytes = [int(np.prod(l.shape)) * l.dtype.itemsize for l in leaves]
-    total = sum(nbytes)
-    if fusion_buckets is not None:
-        k = max(1, min(int(fusion_buckets), len(leaves)))
-        if k == 1:
-            return [list(range(len(leaves)))]
-        # Close bucket b once the running total crosses b/k of the bytes:
-        # balanced without look-ahead, never more than k buckets.
-        groups, cur, cum, b = [], [], 0, 1
-        for i, nb in enumerate(nbytes):
-            cur.append(i)
-            cum += nb
-            if cum * k >= b * total and b < k:
-                groups.append(cur)
-                cur, b = [], b + 1
-        if cur:
-            groups.append(cur)
-        return groups
-    cap = config.get().fusion_bucket_mb * (1 << 20)
-    if cap <= 0:
-        return [list(range(len(leaves)))]
-    groups, cur, cur_bytes = [], [], 0
-    for i, nb in enumerate(nbytes):
-        if cur and cur_bytes + nb > cap:
-            groups.append(cur)
-            cur, cur_bytes = [], 0
-        cur.append(i)
-        cur_bytes += nb
-    if cur:
-        groups.append(cur)
-    return groups
 
 
 # A leaf of at least this many bytes is exchanged as it is; smaller leaves
@@ -280,10 +241,10 @@ def _split_direct(leaves):
             [i for i, b in enumerate(big) if not b])
 
 
-def _fused_apply(fn, tree, fusion_buckets: Optional[int]):
+def _fused_apply(fn, tree):
     """Apply ``fn`` (list of arrays -> list of arrays, elementwise in shape
     and dtype) to a pytree's leaves: the large ones as they are, the small
-    ones through fusion buckets.
+    ones through one fusion buffer.
 
     A leaf of :data:`_DIRECT_LEAF_BYTES` or more reaches ``fn`` in its own
     shape and dtype: no copy into a buffer and none out of it, and its
@@ -291,113 +252,69 @@ def _fused_apply(fn, tree, fusion_buckets: Optional[int]):
     leaf's scale and add under another's permute.  (On the v5e the one flat
     buffer of a 2 GB tree cost three passes over it, 34 ms of a 244 ms
     step, and 8 GB of scratch: PERF.md, PR 23.)  The remaining leaves ravel
-    into one flat buffer per bucket (``_bucket_groups``), so a model with
-    hundreds of small parameters issues one collective set per bucket
-    instead of one per parameter; a tree with no large leaf lowers to the
-    program it lowered to before there was a direct path.  All parts, the
-    large leaves and then the buffers, reach ``fn`` in ONE call: a combiner
-    that takes a list (``takes_parts``) chooses its phase once for all."""
+    into one flat buffer, so a model with hundreds of small parameters
+    issues one collective set instead of one per parameter (the TPU-native
+    replacement for the reference's FusionBufferManager + fused-response
+    machinery, ``tensor_queue.h:70-92``, ``operations.cc:918-1001``).  The
+    leaf's size is all that decides: there is no option over it.  All
+    parts, the large leaves and then the buffer, reach ``fn`` in ONE call,
+    so the dynamic combiner chooses its phase once for all of them."""
     from jax.flatten_util import ravel_pytree
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
         return tree
     direct, packed = _split_direct(leaves)
-    groups = [[packed[j] for j in grp] for grp in _bucket_groups(
-        [leaves[i] for i in packed], fusion_buckets)] if packed else []
+    parts = [leaves[i] for i in direct]
     # Three device scopes (docs/timeline.md): a trace books every operation
     # of the step program to the one its metadata names.
-    with timeline.device_scope("bf.optim.fuse"):
-        raveled = [ravel_pytree([leaves[i] for i in grp]) for grp in groups]
+    if packed:
+        with timeline.device_scope("bf.optim.fuse"):
+            flat, unravel = ravel_pytree([leaves[i] for i in packed])
+        parts.append(flat)
     with timeline.device_scope("bf.optim.combine"):
-        parts = fn([leaves[i] for i in direct] + [flat for flat, _ in raveled])
+        parts = fn(parts)
     out = list(leaves)
     for i, part in zip(direct, parts):
         out[i] = part
-    with timeline.device_scope("bf.optim.unfuse"):
-        for grp, (_, unravel), flat in zip(groups, raveled,
-                                           parts[len(direct):]):
-            for i, leaf in zip(grp, unravel(flat)):
+    if packed:
+        with timeline.device_scope("bf.optim.unfuse"):
+            for i, leaf in zip(packed, unravel(parts[-1])):
                 out[i] = leaf
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def _combine_parts(combine, step, weights):
-    """``fn`` of :func:`_fused_apply` for a combiner: a combiner marked
-    ``takes_parts`` gets the list whole (one phase switch a step), every
-    other one is applied part by part, which without a switch is the same
-    program."""
-    if getattr(combine, "takes_parts", False):
-        return lambda parts: combine(parts, step=step, weights=weights)
-    return lambda parts: [combine(x, step=step, weights=weights)
-                          for x in parts]
-
-
-def _tree_combine(params, combine, step, weights, steps_per_comm: int,
-                  fuse: bool = True, fusion_buckets: Optional[int] = None,
-                  shard_plan=None, shard_combine=None):
+def _tree_combine(params, combine: Combiner, step, weights,
+                  steps_per_comm: int, shard_plan=None, shard_combine=None):
     """Apply ``combine`` to a pytree, skipping steps where
     ``step % steps_per_comm != 0`` (local aggregation).
 
-    ``fuse=True`` hands the leaves of 1 MiB and more to ``combine`` as they
-    are and ravels the smaller ones into fusion-bucket buffers (default:
-    one), so a model with hundreds of small parameters issues one ppermute
-    set per round per bucket instead of one per parameter — the TPU-native
-    replacement for the reference's FusionBufferManager + fused-response
-    machinery (``tensor_queue.h:70-92``, ``operations.cc:918-1001``).  The
-    copies into and out of a buffer are real passes over it on the device
-    (19 ms a step for a 2 GB buffer on the v5e: PERF.md, PR 23), which is
-    why only leaves too small to pay for a collective of their own are
-    packed.  ``fusion_buckets > 1`` (or the ``BLUEFOG_TPU_FUSION_BUCKET_MB``
-    cap) splits what is packed — see :func:`_fused_apply`.  ``fuse=False``
-    combines every leaf alone.
-
     With an active ``shard_plan`` (a plan whose mask marks some leaves
-    sharded) the tree is split by the mask: replicated leaves ride the
-    legacy fused path over the full topology, sharded leaves go through
+    sharded) the tree is split by the mask: replicated leaves ride
+    :func:`_fused_apply` over the full topology, sharded leaves go through
     ``shard_combine`` (:func:`make_shard_combiner`) — per-replica-group
-    gossip of each rank's own shard slice.  Without an active plan this
-    function is byte-for-byte the legacy replicated-only path, which is
-    what keeps fully replicated trees bit-identical under the knob.
+    gossip of each rank's own shard slice.  Without an active plan every
+    leaf is replicated, which is what keeps fully replicated trees
+    bit-identical under the knob.
     """
-    sharded_on = (shard_plan is not None and shard_combine is not None
-                  and shard_plan.any_sharded)
-    if not sharded_on:
-        if getattr(combine, "is_identity", False):
-            return params  # empty communication: no fusion copies, no cond
-
-        def comm_all(p):
-            if fuse:
-                return _fused_apply(_combine_parts(combine, step, weights),
-                                    p, fusion_buckets)
-            with timeline.device_scope("bf.optim.combine"):
-                return jax.tree.map(
-                    lambda x: combine(x, step=step, weights=weights), p)
-        if steps_per_comm == 1:
-            return comm_all(params)
-        # lax.cond keeps one compiled program; both branches cheap to trace.
-        return lax.cond(step % steps_per_comm == 0, comm_all,
-                        lambda p: p, params)
-
-    rep_idx = [i for i, m in enumerate(shard_plan.mask) if not m]
-    sh_idx = [i for i, m in enumerate(shard_plan.mask) if m]
+    sharded_on = shard_combine is not None and shard_plan.any_sharded
+    if combine.identity and not sharded_on:
+        return params  # empty communication: no fusion copies, no cond
 
     def comm_all(p):
         leaves, treedef = jax.tree_util.tree_flatten(p)
+        mask = shard_plan.mask if sharded_on else (False,) * len(leaves)
+        rep_idx = [i for i, m in enumerate(mask) if not m]
+        sh_idx = [i for i, m in enumerate(mask) if m]
         out = list(leaves)
-        if rep_idx and not getattr(combine, "is_identity", False):
-            rep = [leaves[i] for i in rep_idx]
-            if fuse:
-                rep_out = _fused_apply(
-                    _combine_parts(combine, step, weights),
-                    rep, fusion_buckets)
-            else:
-                rep_out = [combine(x, step=step, weights=weights)
-                           for x in rep]
-            for i, leaf in zip(rep_idx, rep_out):
-                out[i] = leaf
-        sh_out = shard_combine([leaves[i] for i in sh_idx], step=step)
-        for i, leaf in zip(sh_idx, sh_out):
+        rep_out = _fused_apply(
+            lambda parts: combine.combine(parts, step, weights),
+            [leaves[i] for i in rep_idx])
+        for i, leaf in zip(rep_idx, rep_out):
             out[i] = leaf
+        if sh_idx:
+            sh_out = shard_combine([leaves[i] for i in sh_idx], step=step)
+            for i, leaf in zip(sh_idx, sh_out):
+                out[i] = leaf
         return jax.tree_util.tree_unflatten(treedef, out)
     if steps_per_comm == 1:
         return comm_all(params)
@@ -407,8 +324,7 @@ def _tree_combine(params, combine, step, weights, steps_per_comm: int,
 
 def awc_step(base: optax.GradientTransformation, combine: Combiner,
              params, grads, state: DistOptState, *,
-             weights=None, steps_per_comm: int = 1, fuse: bool = True,
-             fusion_buckets: Optional[int] = None,
+             weights=None, steps_per_comm: int = 1,
              shard_plan=None, shard_combine=None):
     """Adapt-with-combine: communicate params, then apply the base update.
 
@@ -419,8 +335,7 @@ def awc_step(base: optax.GradientTransformation, combine: Combiner,
     depends only on its own combine (:func:`_fused_apply`).
     """
     combined = _tree_combine(params, combine, state.step, weights,
-                             steps_per_comm, fuse, fusion_buckets,
-                             shard_plan, shard_combine)
+                             steps_per_comm, shard_plan, shard_combine)
     with timeline.device_scope("bf.optim.update"):
         updates, base_state = base.update(grads, state.base, combined)
         new_params = optax.apply_updates(combined, updates)
@@ -429,8 +344,7 @@ def awc_step(base: optax.GradientTransformation, combine: Combiner,
 
 def atc_step(base: optax.GradientTransformation, combine: Combiner,
              params, grads, state: DistOptState, *,
-             weights=None, steps_per_comm: int = 1, fuse: bool = True,
-             fusion_buckets: Optional[int] = None,
+             weights=None, steps_per_comm: int = 1,
              shard_plan=None, shard_combine=None):
     """Adapt-then-combine: local base update first, then communicate.
 
@@ -444,18 +358,16 @@ def atc_step(base: optax.GradientTransformation, combine: Combiner,
         updates, base_state = base.update(grads, state.base, params)
         half = optax.apply_updates(params, updates)
     new_params = _tree_combine(half, combine, state.step, weights,
-                               steps_per_comm, fuse, fusion_buckets,
-                               shard_plan, shard_combine)
+                               steps_per_comm, shard_plan, shard_combine)
     return new_params, DistOptState(base_state, state.step + 1)
 
 
 def compress_combiner(combine: Combiner, compression: str,
-                      *, residual: bool = True,
-                      steps_per_comm: int = 1) -> Combiner:
+                      *, steps_per_comm: int = 1) -> Combiner:
     """Wrap a combiner so its payload crosses the wire compressed.
 
-    The wrapped combiner takes one array, so ``_fused_apply`` applies it
-    part by part: to each large leaf and to each packed buffer.
+    Both codecs work part by part: on each large leaf and on the packed
+    buffer, each handed to the wrapped combiner as a list of one.
 
     ``"bf16"`` casts to bfloat16 before the collective and back after —
     half the ICI/DCN bytes per round, the role of the reference family's
@@ -466,13 +378,15 @@ def compress_combiner(combine: Combiner, compression: str,
     size)`` entries of each part; every part is swept in full in
     ``ceil(1/frac)`` communication rounds (comment below).
 
-    ``residual=True`` (parameter-consensus orders) adds back the local
-    quantization residual ``x - q(x)`` after combining — difference
-    compression: the error becomes ``(W - I)(q(x) - x)`` instead of
-    ``W (q(x) - x)``, so a rank's own f32 master weights are never
-    truncated by its own round trips (with ``combine = identity`` the
-    wrapper is exact).  Set ``residual=False`` where every rank must apply
-    the bit-identical result (synchronous gradient averaging).
+    A decentralized combiner gets the local quantization residual
+    ``x - q(x)`` added back after combining — difference compression: the
+    error becomes ``(W - I)(q(x) - x)`` instead of ``W (q(x) - x)``, so a
+    rank's own f32 master weights are never truncated by its own round
+    trips (with ``combine = identity`` the wrapper is exact).  A
+    ``replica_identical`` combiner (global allreduce: parameter consensus
+    or synchronous gradient averaging) does not: every rank must apply the
+    bit-identical result, which is worth more there than the residual's
+    accuracy (with it the drift is bf16-scale and re-averaged each round).
     """
     if compression in (None, "none"):
         return combine
@@ -512,23 +426,17 @@ def compress_combiner(combine: Combiner, compression: str,
         if not 0.0 < frac <= 1.0:
             raise ValueError(
                 f"sparse fraction must be in (0, 1], got {frac}")
-        if getattr(combine, "is_identity", False):
+        if combine.identity:
             return combine  # empty communication: string validated above
-        args = getattr(combine, "_sparse_args", None)
-        dyn_args = getattr(combine, "_sparse_dyn_args", None)
-        if args is None and dyn_args is None:
+        sched, axis_name = combine.sched, combine.axis_name
+        if sched is None:
             raise ValueError(
                 "compression='sparse:<frac>' needs a (static or dynamic) "
                 "neighbor_allreduce combiner (the sparse exchange rides "
                 "the compiled edge schedule); use 'bf16' for the other "
                 "communication types")
-        if not residual:
-            raise ValueError(
-                "sparse compression requires residual error feedback "
-                "(decentralized orders); it cannot keep an allreduce "
-                "replica-identical")
 
-        def wrapped_sparse(x, step=None, weights=None):
+        def wrapped_sparse(x, step, weights):
             if weights is not None:
                 raise ValueError(
                     "per-step weight overrides are not supported under "
@@ -544,38 +452,35 @@ def compress_combiner(combine: Combiner, compression: str,
             rnd_idx = s // max(1, int(steps_per_comm))
             rot = ((jnp.arange(kk, dtype=jnp.int32) + rnd_idx * kk)
                    % x.size)
-            if args is not None:
-                sched, axis_name = args
+            if isinstance(sched, DynamicSchedule):
+                out, q = C.dynamic_sparse_neighbor_allreduce(
+                    x, s, sched, axis_name, indices=rot,
+                    return_sent=True)
+            else:
                 out, q = C.sparse_neighbor_allreduce(
                     x, sched, axis_name, indices=rot, aligned=True,
                     return_sent=True)
-            else:
-                dyn_sched, axis_name = dyn_args
-                out, q = C.dynamic_sparse_neighbor_allreduce(
-                    x, s, dyn_sched, axis_name, indices=rot,
-                    return_sent=True)
             return out + (x - q)
-        return wrapped_sparse
+        return combine._replace(combine=_per_part(wrapped_sparse))
     if compression != "bf16":
         raise ValueError(f"unknown compression {compression!r}; "
                          "expected 'none', 'bf16' or 'sparse:<frac>'")
-    if getattr(combine, "is_identity", False):
+    if combine.identity:
         return combine  # keep _tree_combine's identity fast path
 
-    def wrapped(x, **kw):
+    def wrapped(x, step, weights):
         q = x.astype(jnp.bfloat16)
-        out = combine(q, **kw).astype(x.dtype)
-        if residual:
+        out = combine.combine([q], step, weights)[0].astype(x.dtype)
+        if not combine.replica_identical:
             out = out + (x - q.astype(x.dtype))
         return out
-    return wrapped
+    return combine._replace(combine=_per_part(wrapped))
 
 
 def gradient_allreduce_step(base: optax.GradientTransformation,
                             params, grads, state: DistOptState, *,
                             axis_name: str, steps_per_comm: int = 1,
-                            compression: str = "none", fuse: bool = True,
-                            fusion_buckets: Optional[int] = None):
+                            compression: str = "none"):
     """Horovod-style synchronous gradient averaging
     (reference ``_DistributedOptimizer``, ``torch/optimizers.py:166-295``).
 
@@ -585,28 +490,27 @@ def gradient_allreduce_step(base: optax.GradientTransformation,
     replica-identical invariant (the reference's delayed-allreduce counters,
     ``torch/optimizers.py:348-383``).
 
-    ``fuse``/``fusion_buckets`` ride the same bucket machinery as the
-    parameter-consensus orders; for a uniform-dtype gradient tree the fused
-    averaging is bit-identical to per-leaf (psum and the bf16 casts are
-    elementwise), it just issues one allreduce per bucket instead of one
-    per gradient leaf.  Mixed-dtype trees stay on the per-leaf path: the
-    ravel would promote every leaf to a common dtype, changing the psum
-    rounding — this order's replica-identical numerics must not shift
-    underneath existing runs.
+    A uniform-dtype gradient tree rides :func:`_fused_apply` like the
+    parameter-consensus orders; the packed averaging is bit-identical to
+    per-leaf (psum and the bf16 casts are elementwise), it just issues one
+    allreduce for the small leaves instead of one each.  Mixed-dtype trees
+    stay on the per-leaf path: the ravel would promote every leaf to a
+    common dtype, changing the psum rounding — this order's
+    replica-identical numerics must not shift underneath existing runs.
     """
-    # residual=False: every rank must apply the bit-identical averaged
-    # gradient (the replica-identical invariant below).
-    one = compress_combiner(
-        lambda x, **kw: C.allreduce(x, axis_name, average=True),
-        compression, residual=False)
+    # The allreduce combiner is replica_identical: the codec adds no
+    # residual, so every rank applies the bit-identical averaged gradient.
+    average = compress_combiner(
+        make_combiner(CommunicationType.allreduce, axis_name=axis_name),
+        compression).combine
     uniform_dtype = len(
         {l.dtype for l in jax.tree_util.tree_leaves(grads)}) <= 1
 
     def comm(g):
-        if fuse and uniform_dtype:
-            return _fused_apply(lambda parts: [one(x) for x in parts], g,
-                                fusion_buckets)
-        return jax.tree.map(one, g)
+        if uniform_dtype:
+            return _fused_apply(lambda parts: average(parts, None, None), g)
+        return jax.tree.map(lambda x: average([x], None, None)[0], g)
+
     def update(avg):
         with timeline.device_scope("bf.optim.update"):
             updates, base_state = base.update(avg, state.base, params)
@@ -636,40 +540,15 @@ def dist_init(base: optax.GradientTransformation, params) -> DistOptState:
 
 def step_fn(order: str, base: optax.GradientTransformation,
             combine: Combiner, *, axis_name: str,
-            steps_per_comm: int = 1, fuse: bool = True,
-            fusion_buckets: Optional[int] = None,
-            compression: str = "none",
-            residual: Optional[bool] = None,
+            steps_per_comm: int = 1, compression: str = "none",
             shard_plan=None, shard_combine=None) -> Callable:
-    """Bind an execution order to a ``(params, grads, state[, weights])`` fn.
-
-    ``fusion_buckets`` splits the buffer of the packed leaves (those under
-    1 MiB: :func:`_fused_apply`) into that many byte-balanced buckets
-    (None: one bucket, or the ``BLUEFOG_TPU_FUSION_BUCKET_MB`` size cap
-    when set).
-
-    ``residual`` controls difference compression under ``compression='bf16'``.
-    A global-consensus allreduce must keep replicas bit-identical, so the
-    per-rank quantization residual is NOT re-added after combining (with
-    residual the drift is bf16-scale and re-averaged each round, but the
-    replica-identical invariant is worth more than the residual's accuracy
-    for that order); decentralized combiners keep difference compression.
-    Callers that know the communication type should pass this explicitly
-    (``optim.optimizers`` does); with ``None`` it falls back to the
-    ``is_allreduce`` tag ``make_combiner`` sets."""
-    if residual is None:
-        residual = not getattr(combine, "is_allreduce", False)
-    combine = compress_combiner(combine, compression, residual=residual,
-                                steps_per_comm=steps_per_comm)
-    if order == "awc":
-        return partial(awc_step, base, combine,
-                       steps_per_comm=steps_per_comm, fuse=fuse,
-                       fusion_buckets=fusion_buckets,
-                       shard_plan=shard_plan, shard_combine=shard_combine)
-    if order == "atc":
-        return partial(atc_step, base, combine,
-                       steps_per_comm=steps_per_comm, fuse=fuse,
-                       fusion_buckets=fusion_buckets,
+    """Bind an execution order to a ``(params, grads, state[, weights])`` fn
+    (``compression``: :func:`compress_combiner`)."""
+    if order in ("awc", "atc"):
+        combine = compress_combiner(combine, compression,
+                                    steps_per_comm=steps_per_comm)
+        return partial(awc_step if order == "awc" else atc_step, base,
+                       combine, steps_per_comm=steps_per_comm,
                        shard_plan=shard_plan, shard_combine=shard_combine)
     if order == "gradient_allreduce":
         if shard_plan is not None and shard_plan.any_sharded:
@@ -679,6 +558,5 @@ def step_fn(order: str, base: optax.GradientTransformation,
                 "and cannot restrict sharded leaves to replica groups")
         return partial(gradient_allreduce_step, base, axis_name=axis_name,
                        steps_per_comm=steps_per_comm,
-                       compression=compression, fuse=fuse,
-                       fusion_buckets=fusion_buckets)
+                       compression=compression)
     raise ValueError(f"unknown execution order {order!r}")

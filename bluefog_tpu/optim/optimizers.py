@@ -77,13 +77,6 @@ class DistributedOptimizer:
     use_dynamic_topology : cycle the one-peer phase table of the active
         topology (or ``phases`` if given) by step index.
     phases : explicit list of ``topology.DynamicPhase`` for dynamic mode.
-    fusion_buckets : with ``fusion=True`` a leaf of 1 MiB or more is
-        exchanged as it is and the smaller leaves are packed into one
-        flat buffer; this splits that buffer into this many byte-balanced
-        buckets.  ``None``: one bucket — unless
-        ``BLUEFOG_TPU_FUSION_BUCKET_MB`` caps bucket size instead.  It
-        governs the small leaves only (kilobytes in a transformer, the
-        batch-norm and bias vectors of a ResNet): see docs/performance.md.
     donate : donate the grads and state buffers to the jitted step so XLA
         aliases them into the outputs (grads, same tree shape as params,
         becomes the new params buffer) — peak memory drops by roughly one
@@ -125,8 +118,7 @@ class DistributedOptimizer:
                  *, order: str = "awc",
                  num_steps_per_communication: int = 1,
                  use_dynamic_topology: bool = False,
-                 phases=None, fusion: bool = True,
-                 fusion_buckets: Optional[int] = None,
+                 phases=None,
                  compression: str = "none", donate: bool = False,
                  profile_every: Optional[int] = None,
                  shard_specs=None, shard_groups=None,
@@ -144,13 +136,6 @@ class DistributedOptimizer:
         self.num_steps_per_communication = int(num_steps_per_communication)
         self.use_dynamic_topology = use_dynamic_topology
         self.phases = phases
-        if fusion_buckets is not None and int(fusion_buckets) < 1:
-            raise ValueError(f"fusion_buckets must be >= 1, got {fusion_buckets}")
-        # Fused communication buffers for the small leaves (reference
-        # FusionBufferManager); large leaves are exchanged as they are.
-        self.fusion = fusion
-        self.fusion_buckets = (None if fusion_buckets is None
-                               else int(fusion_buckets))
         # "bf16": halve the wire bytes per round (functional.
         # compress_combiner — the reference family's fp16 compression role).
         self.compression = compression
@@ -183,7 +168,9 @@ class DistributedOptimizer:
         self._shard_step0 = None  # state.step of the first sharded step
 
     # -- schedule resolution ------------------------------------------------
-    def _schedules(self):
+    def _schedule(self):
+        """The compiled schedule of the active (machine) topology: dynamic
+        under ``use_dynamic_topology``, else static."""
         ctx = basics._require_init()
         hier = (self.communication_type ==
                 CommunicationType.hierarchical_neighbor_allreduce)
@@ -192,21 +179,19 @@ class DistributedOptimizer:
         if topo is None:
             raise RuntimeError("no (machine) topology installed; call bf.init()")
         n = topo.number_of_nodes()
+        version = (ctx.machine_topology_version if hier
+                   else ctx.topology_version)
         if self.use_dynamic_topology:
-            version = (ctx.machine_topology_version if hier
-                       else ctx.topology_version)
             key = ("opt_dyn", version,
                    None if self.phases is None
                    else tuple(tuple(ph.pairs) for ph in self.phases))
             phases = self.phases
-            return None, ctx.static_schedule(key, lambda: S.compile_dynamic(
+            return ctx.static_schedule(key, lambda: S.compile_dynamic(
                 phases if phases is not None
                 else topology_util.dynamic_phase_table(topo), n))
-        version = (ctx.machine_topology_version if hier
-                   else ctx.topology_version)
         key = ("opt_static", version, weighted)
         return ctx.static_schedule(
-            key, lambda: S.compile_static(topo, use_topo_weights=weighted)), None
+            key, lambda: S.compile_static(topo, use_topo_weights=weighted))
 
     # -- sharded-gossip plan resolution ------------------------------------
     def _shard_plan(self, params):
@@ -250,9 +235,8 @@ class DistributedOptimizer:
                self.use_dynamic_topology)
         meta = self._shard_meta_cache.get(key)
         if meta is None:
-            sched, dyn = self._schedules()
             rep_ici, rep_dcn = SH.edge_level_counts(
-                plan.coords, sched if sched is not None else dyn)
+                plan.coords, self._schedule())
             grp_edges = 0.0
             if plan.any_sharded:
                 gsched, _per_group = self._group_schedule(ctx, plan)
@@ -267,18 +251,18 @@ class DistributedOptimizer:
         hier = (self.communication_type in (
                 CommunicationType.hierarchical_neighbor_allreduce,
                 CommunicationType.hierarchical_gossip))
-        sched, dyn = (None, None)
+        sched = None
         if self.communication_type in (
                 CommunicationType.neighbor_allreduce,
                 CommunicationType.hierarchical_neighbor_allreduce):
-            sched, dyn = self._schedules()
+            sched = self._schedule()
         hier_bundle = None
         if self.communication_type == CommunicationType.hierarchical_gossip:
             hier_bundle = self._hier_gossip_bundle(ctx)
         combine = F.make_combiner(
             self.communication_type,
             axis_name=RANK_AXIS if not hier else MACHINE_AXIS,
-            sched=sched, dyn_sched=dyn,
+            sched=sched,
             local_axis=LOCAL_AXIS if hier else None,
             machine_axis=MACHINE_AXIS if hier else None,
             hier=hier_bundle)
@@ -292,7 +276,7 @@ class DistributedOptimizer:
                 CommunicationType.neighbor_allreduce,
                 axis_name=RANK_AXIS, sched=gsched)
             gc = F.compress_combiner(
-                gc, self.compression, residual=True,
+                gc, self.compression,
                 steps_per_comm=self.num_steps_per_communication)
             shard_combine = F.make_shard_combiner(
                 plan, gc, axis_name=RANK_AXIS)
@@ -300,12 +284,7 @@ class DistributedOptimizer:
             self.order, self.base, combine,
             axis_name=RANK_AXIS,
             steps_per_comm=self.num_steps_per_communication,
-            fuse=self.fusion, fusion_buckets=self.fusion_buckets,
             compression=self.compression,
-            # Explicit residual policy: a global-consensus allreduce must
-            # stay replica-bit-identical under compression.
-            residual=(self.communication_type
-                      != CommunicationType.allreduce),
             shard_plan=plan, shard_combine=shard_combine)
         mesh = ctx.hier_mesh if hier else ctx.mesh
         spec = P((MACHINE_AXIS, LOCAL_AXIS)) if hier else P(RANK_AXIS)
@@ -323,12 +302,11 @@ class DistributedOptimizer:
         # over, the share of steps that communicate and the payload's size
         # against the tree's.  Computed here, once per built program.
         from bluefog_tpu.utils import config
-        wire = sched if sched is not None else dyn
         traffic = {
-            "sched_stats": (None if wire is None
-                            else C.schedule_wire_stats(wire)),
+            "sched_stats": (None if sched is None
+                            else C.schedule_wire_stats(sched)),
             "calls": 1.0 / self.num_steps_per_communication,
-            "factor": (0.0 if getattr(combine, "is_identity", False) else
+            "factor": (0.0 if combine.identity else
                        config.compression_byte_factor(self.compression)),
             "nbytes": None}   # of the combined tree: the first step fills it
         n_w = 1 if with_weights else 0
@@ -419,8 +397,8 @@ class DistributedOptimizer:
         if not factor:      # the identity combine exchanges nothing
             leaves = []
         # gradient_allreduce leaves a mixed-dtype tree unpacked
-        packs = self.fusion and (self.order != "gradient_allreduce"
-                                 or len({l.dtype for l in leaves}) <= 1)
+        packs = (self.order != "gradient_allreduce"
+                 or len({l.dtype for l in leaves}) <= 1)
         direct, packed = (F._split_direct(leaves) if packs
                           else (range(len(leaves)), ()))
         for path, idx in (("direct", direct), ("packed", packed)):
@@ -462,8 +440,7 @@ class DistributedOptimizer:
                 "optimizer_step", traffic["nbytes"] * traffic["factor"],
                 size=basics.size(), sched_stats=traffic["sched_stats"],
                 calls=traffic["calls"])
-        hier_meta = getattr(self, "_hier_meta", None)
-        if hier_meta is not None:
+        if self._hier_meta is not None:
             # Per-level wire accounting of the fused two-level step (the
             # compiled program never crosses Python per level).  The step
             # index must mirror the traced state.step the combiner's
@@ -478,7 +455,7 @@ class DistributedOptimizer:
                     np.asarray(state.step).reshape(-1)[0])
             t = self._hier_step0 + self._steps_seen
             if t % self.num_steps_per_communication == 0:
-                ht, inner_edges, comp, _frac = hier_meta
+                ht, inner_edges, comp, _frac = self._hier_meta
                 tree_bytes = float(sum(
                     x.nbytes for x in jax.tree_util.tree_leaves(params)))
                 basics._record_hier_levels(ht, t, tree_bytes,

@@ -75,7 +75,7 @@ import optax
 
 from bluefog_tpu import basics
 from bluefog_tpu.ops import window as W
-from bluefog_tpu.optim.functional import DistOptState
+from bluefog_tpu.optim.functional import DistOptState, _leaf_bytes
 
 __all__ = [
     "DistributedWinPutOptimizer",
@@ -87,6 +87,27 @@ __all__ = [
 def _leaf_names(tree, prefix: str):
     paths = jax.tree_util.tree_flatten_with_path(tree)[0]
     return [f"{prefix}.{jax.tree_util.keystr(p)}" for p, _ in paths]
+
+
+def _bucket_groups(leaves, k: int):
+    """Partition flatten-order leaf indices into at most ``k`` contiguous,
+    byte-balanced buckets (one window each).  Deterministic: every rank
+    must build identical windows."""
+    nbytes = [_leaf_bytes(l) for l in leaves]
+    total = sum(nbytes)
+    k = max(1, min(int(k), len(leaves)))
+    # Close bucket b once the running total crosses b/k of the bytes:
+    # balanced without look-ahead, never more than k buckets.
+    groups, cur, cum, b = [], [], 0, 1
+    for i, nb in enumerate(nbytes):
+        cur.append(i)
+        cum += nb
+        if cum * k >= b * total and b < k:
+            groups.append(cur)
+            cur, b = [], b + 1
+    if cur:
+        groups.append(cur)
+    return groups
 
 
 class _WindowOptimizerBase:
@@ -110,7 +131,7 @@ class _WindowOptimizerBase:
         # whether (update x concat x put) lowers into one XLA program.
         self.fused = fused
         # fusion_buckets=k partitions the fused tree over k windows
-        # (contiguous, byte-balanced — optim/functional._bucket_groups)
+        # (contiguous, byte-balanced — _bucket_groups)
         # so the fused program can issue one put per bucket as XLA
         # materializes it.  None keeps today's single window.
         self.fusion_buckets = fusion_buckets
@@ -349,7 +370,6 @@ class _WindowOptimizerBase:
                        [i for i, m in enumerate(plan.mask) if not m])
             if self.fusion_buckets is not None \
                     and int(self.fusion_buckets) > 1 and rep_idx:
-                from bluefog_tpu.optim.functional import _bucket_groups
                 rel = _bucket_groups([leaves[i] for i in rep_idx],
                                      int(self.fusion_buckets))
                 self._buckets = [[rep_idx[j] for j in grp] for grp in rel]

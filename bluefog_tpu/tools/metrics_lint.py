@@ -21,8 +21,8 @@ hygiene, not registration, and are ignored.
 Doc side: the code→doc direction accepts a metric mentioned in
 backticks ANYWHERE in the doc; the doc→code direction only audits the
 markdown inventory-table rows (lines starting ``| `bf_``), so prose
-references to event names, native symbols or out-of-tree metrics
-(``bf_bench_phase_seconds`` lives in ``bench.py``) never false-positive.
+references to event names, native symbols or out-of-tree metrics never
+false-positive.
 Histogram suffixes ``_bucket`` / ``_sum`` / ``_count`` are normalized
 off both sides; ``name{labels}`` rows and ``a / b`` multi-metric rows
 are split.

@@ -63,7 +63,6 @@ for the TPU rebuild.  Values are read lazily on first access and cached; call
 | BLUEFOG_TPU_PLACEMENT_ROUND_BUDGET | 2.0 | congestion-repack round budget (x König; 0=off) |
 | BLUEFOG_TPU_FAKE_TORUS        | unset | synthetic torus spec (e.g. 4x8) for CPU testing |
 | BLUEFOG_TPU_TORUS_WRAP        | auto  | real-coords wrap policy: auto / 1 (torus) / 0 (mesh) |
-| BLUEFOG_TPU_FUSION_BUCKET_MB  | 0     | fusion-buffer bucket cap in MiB (0=one bucket) |
 | BLUEFOG_TPU_HIER              | 0     | 1: enable two-level hierarchical gossip (dense ICI inner x sparse DCN outer) |
 | BLUEFOG_TPU_HIER_OUTER_EVERY  | 1     | outer (inter-slice) cadence: communicate over DCN every k steps |
 | BLUEFOG_TPU_HIER_INNER        | exp2  | intra-slice dense topology: exp2 / ring |
@@ -463,10 +462,6 @@ class Config:
     # "0" force mesh.  Modeling a wrap link that does not exist would let
     # the optimizer install a placement that is wrong on hardware.
     torus_wrap: str
-    # Fusion-buffer bucket cap in MiB for the distributed optimizers
-    # (optim/functional.py); 0 = one fused buffer (legacy behavior).  An
-    # explicit fusion_buckets= argument on the optimizer overrides this.
-    fusion_bucket_mb: float
     # Two-level hierarchical gossip (topology.HierarchicalTopology +
     # basics.hierarchical_gossip); OFF by default — with hier=0 no
     # hierarchical state exists anywhere and every flat path is
@@ -592,8 +587,6 @@ class Config:
                 "BLUEFOG_TPU_PLACEMENT_ROUND_BUDGET", "2.0")),
             fake_torus=os.environ.get("BLUEFOG_TPU_FAKE_TORUS"),
             torus_wrap=os.environ.get("BLUEFOG_TPU_TORUS_WRAP", "auto"),
-            fusion_bucket_mb=float(
-                os.environ.get("BLUEFOG_TPU_FUSION_BUCKET_MB", "0")),
             hier=_flag("BLUEFOG_TPU_HIER"),
             hier_outer_every=int(os.environ.get(
                 "BLUEFOG_TPU_HIER_OUTER_EVERY", "1")),

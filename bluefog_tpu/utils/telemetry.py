@@ -780,8 +780,9 @@ def record_comm_traffic(op: str, nbytes: float, *, size: int,
     the PHYSICAL wire cost: per-rank row bytes times weighted link
     crossings, i.e. what the traffic actually costs the torus/DCN, not
     just the logical edge count.  Used by the dispatch layer
-    (``basics._record_dispatch``) per call and by ``bench.py`` to account
-    a whole jitted run at once, so the two can never drift apart."""
+    (``basics._record_dispatch``) per call and by the distributed
+    optimizers per step program (``op="optimizer_step"``), so the two can
+    never drift apart."""
     if not config.get().telemetry:
         return
     inc("bf_comm_calls_total", calls, op=op)

@@ -132,7 +132,7 @@ def test_beyond_reference_surface_pinned():
     import inspect
     from bluefog_tpu.optim.optimizers import DistributedOptimizer
     sig = inspect.signature(DistributedOptimizer.__init__)
-    for kw in ("compression", "fusion", "donate"):
+    for kw in ("compression", "donate"):
         assert kw in sig.parameters, f"DistributedOptimizer lost {kw}="
     from bluefog_tpu.optim.window_optimizers import DistributedWinPutOptimizer
     sig = inspect.signature(DistributedWinPutOptimizer.__init__)

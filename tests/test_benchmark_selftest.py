@@ -1,0 +1,28 @@
+"""The benchmark's CPU self-tests as cases of tier-1 (ROADMAP I6).
+
+``benchmark/selftest/test_trace_reduce.py``, ``test_program_readers.py`` and
+``test_dropin.py`` hold the per-layer readers to a trace recorded on the chip
+and to the tiny twins of the cells, traced here on the CPU in child
+processes.  The readers find the step program's operations by its scopes
+(``bf.optim.fuse`` / ``combine`` / ``unfuse`` / ``update``, ``bf.loss.chunked``,
+``bf.moe*``), its spans and its counters, so these cases fail when a library
+change moves one of them: the ledger's per-layer metrics would read ``null``.
+Their tests are collected here under their own names behind the file's;
+``test_cells_cpu.py`` and ``test_moe_cell_cpu.py`` (three minutes) stay by hand.
+"""
+
+import importlib
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+for _file in ("trace_reduce", "program_readers", "dropin"):
+    _module = importlib.import_module(f"benchmark.selftest.test_{_file}")
+    for _name, _obj in vars(_module).items():
+        if _name.startswith("test_"):
+            globals()[f"test_{_file}_{_name[len('test_'):]}"] = _obj
+        elif type(_obj).__module__ == "_pytest.fixtures":
+            globals()[_name] = _obj     # a fixture its tests ask for by name

@@ -113,9 +113,16 @@ def test_neighbor_beats_local():
     assert global_mse(p_nbr["w"], A, y) < global_mse(p_loc["w"], A, y)
 
 
+def _set_threshold(monkeypatch, nbytes):
+    """Leaves of ``nbytes`` and more are exchanged alone: 0 is every leaf
+    alone, the program a user without packing would run."""
+    from bluefog_tpu.optim import functional
+    monkeypatch.setattr(functional, "_DIRECT_LEAF_BYTES", nbytes)
+
+
 @pytest.mark.parametrize("order", ["awc", "atc"])
 @pytest.mark.parametrize("dynamic", [False, True])
-def test_fusion_matches_unfused(order, dynamic):
+def test_fusion_matches_unfused(monkeypatch, order, dynamic):
     """Fused single-buffer communication must be numerically identical to
     per-parameter communication (reference fusion oracle tests,
     ``torch_ops_test.py:210-284,962``) — over a multi-leaf pytree so the
@@ -130,10 +137,11 @@ def test_fusion_matches_unfused(order, dynamic):
 
     outs = {}
     for fusion in (True, False):
+        _set_threshold(monkeypatch, (1 << 20) if fusion else 0)
         opt = bf.optim.DistributedOptimizer(
             optax.sgd(0.05, momentum=0.9),
             CommunicationType.neighbor_allreduce, order=order,
-            use_dynamic_topology=dynamic, fusion=fusion)
+            use_dynamic_topology=dynamic)
         p, s = params0, opt.init(params0)
         for _ in range(3):
             p, s = opt.step(p, grads, s)
@@ -142,45 +150,6 @@ def test_fusion_matches_unfused(order, dynamic):
         np.testing.assert_allclose(np.asarray(outs[True][k]),
                                    np.asarray(outs[False][k]),
                                    rtol=1e-6, atol=1e-7)
-
-
-def _multi_leaf_problem(seed=3):
-    rng = np.random.RandomState(seed)
-    params = {"a": jnp.asarray(rng.randn(N, DIM, 1)),
-              "b": jnp.asarray(rng.randn(N, 3)),
-              "c": jnp.asarray(rng.randn(N, 2, 2)),
-              "d": jnp.asarray(rng.randn(N, 5))}
-    grads = {k: jnp.asarray(rng.randn(*np.asarray(v).shape))
-             for k, v in params.items()}
-    return params, grads
-
-
-@pytest.mark.parametrize("order,comm", [
-    ("awc", CommunicationType.neighbor_allreduce),
-    ("atc", CommunicationType.neighbor_allreduce),
-    ("gradient_allreduce", CommunicationType.allreduce),
-], ids=["awc", "atc", "gradient_allreduce"])
-def test_bucketed_fusion_matches_single_buffer(order, comm):
-    """fusion_buckets splits the fused buffer so per-bucket collectives
-    pipeline against the other buckets' optimizer math — but it must be
-    numerically equivalent to the single-buffer ravel in all three
-    execution orders (<= fp32 tolerance; the only difference is float
-    summation grouping)."""
-    bf.init(lambda: topo.ExponentialTwoGraph(N))
-    params0, grads = _multi_leaf_problem()
-    outs = {}
-    for buckets in (None, 3):
-        opt = bf.optim.DistributedOptimizer(
-            optax.sgd(0.05, momentum=0.9), comm, order=order,
-            fusion_buckets=buckets)
-        p, s = params0, opt.init(params0)
-        for _ in range(3):
-            p, s = opt.step(p, grads, s)
-        outs[buckets] = p
-    for k in params0:
-        np.testing.assert_allclose(np.asarray(outs[None][k]),
-                                   np.asarray(outs[3][k]),
-                                   rtol=1e-6, atol=1e-6)
 
 
 # --- large leaves direct, small leaves packed (functional._fused_apply) ------
@@ -198,11 +167,6 @@ def _split_problem(seed=5):
     grads = {k: jnp.asarray(rng.randn(N, *v), jnp.float32)
              for k, v in shapes.items()}
     return params, grads
-
-
-def _set_threshold(monkeypatch, nbytes):
-    from bluefog_tpu.optim import functional
-    monkeypatch.setattr(functional, "_DIRECT_LEAF_BYTES", nbytes)
 
 
 def _run_split(order, dynamic, kw, steps=4):
@@ -226,9 +190,6 @@ def _run_split(order, dynamic, kw, steps=4):
     ("atc", False, {}),
     ("atc", True, {}),
     ("gradient_allreduce", False, {}),
-    ("atc", True, {"fusion_buckets": 1}),
-    ("atc", True, {"fusion_buckets": 3}),
-    ("awc", False, {"fusion_buckets": 3}),
     ("awc", True, {"num_steps_per_communication": 2}),
     ("gradient_allreduce", False, {"num_steps_per_communication": 2}),
     ("atc", True, {"compression": "bf16"}),
@@ -240,18 +201,19 @@ def _run_split(order, dynamic, kw, steps=4):
 def test_direct_leaves_match_unfused(monkeypatch, order, dynamic, kw):
     """A tree with leaves on both sides of the threshold: large leaves
     direct and small ones packed give bit for bit the parameters of every
-    leaf alone (``fusion=False``) and of every leaf packed: the same
-    multiply, permute and add on every element."""
+    leaf alone (threshold 0) and of every leaf packed: the same multiply,
+    permute and add on every element."""
     bf.init(lambda: topo.ExponentialTwoGraph(N))
     _set_threshold(monkeypatch, _SMALL_THRESHOLD)
     mixed = _run_split(order, dynamic, kw)
-    unfused = _run_split(order, dynamic, {**kw, "fusion": False})
+    _set_threshold(monkeypatch, 0)
+    unfused = _run_split(order, dynamic, kw)
     _set_threshold(monkeypatch, 1 << 40)
     all_packed = _run_split(order, dynamic, kw)
-    # One case is held to float32 rounding, as at the parent (fusion against
-    # fusion=False there: 31 elements of 119 off by an ulp after four
-    # steps): XLA's CPU backend contracts accumulate, average and update
-    # into other fused multiply-adds when the leaves sit in a buffer.
+    # One case is held to float32 rounding (every leaf alone against the
+    # buffer: 31 elements of 119 off by an ulp after four steps): XLA's CPU
+    # backend contracts accumulate, average and update into other fused
+    # multiply-adds when the leaves sit in a buffer.
     tol = (1e-6 if order == "gradient_allreduce"
            and "num_steps_per_communication" in kw else 0.0)
     for k in mixed:
@@ -293,6 +255,78 @@ def test_dynamic_exchange_is_one_switch(monkeypatch, large):
     assert text.count("stablehlo.case") == 1
     phases = 3                              # one-peer Exp2 over 8 ranks
     assert text.count("stablehlo.collective_permute") == phases * (large + 1)
+
+
+def _combiner_on_mesh(kind):
+    """``(Combiner, mesh, spec of a rank-major array)`` over the 8 ranks."""
+    from jax.sharding import PartitionSpec as P
+    from bluefog_tpu import basics
+    from bluefog_tpu.ops import schedule as S
+    from bluefog_tpu.optim import functional as F
+    ctx = basics._require_init()
+    rank, machine, local = (basics.RANK_AXIS, basics.MACHINE_AXIS,
+                            basics.LOCAL_AXIS)
+    if kind == "hierarchical":
+        sched = S.compile_static(ctx.machine_topology, use_topo_weights=False)
+        return (F.make_combiner(
+            CommunicationType.hierarchical_neighbor_allreduce,
+            axis_name=machine, sched=sched, local_axis=local,
+            machine_axis=machine), ctx.hier_mesh, P((machine, local)))
+    kw = {}
+    if kind == "static":
+        kw["sched"] = S.compile_static(ctx.topology, use_topo_weights=True)
+    elif kind == "dynamic":
+        kw["sched"] = S.compile_dynamic(
+            topo.dynamic_phase_table(ctx.topology), N)
+    comm = (CommunicationType[kind] if kind in ("empty", "allreduce")
+            else CommunicationType.neighbor_allreduce)
+    return F.make_combiner(comm, axis_name=rank, **kw), ctx.mesh, P(rank)
+
+
+@pytest.mark.parametrize("compression", ["none", "bf16"])
+@pytest.mark.parametrize("kind", ["empty", "allreduce", "static", "dynamic",
+                                  "hierarchical"])
+def test_every_combiner_maps_parts(kind, compression):
+    """The one protocol of ``functional.Combiner``: a list of arrays in, a
+    list of the same shapes and dtypes out, bit for bit what the combiner
+    gives each array as a list of one.  The dynamic combiner serves the
+    whole list under ONE phase switch; a codec hands it one part at a time
+    (``compress_combiner``), so there each part has its own."""
+    from jax.sharding import PartitionSpec as P
+    from bluefog_tpu.optim import functional as F
+    bf.init(lambda: topo.ExponentialTwoGraph(N), local_size=2)
+    comb, mesh, spec = _combiner_on_mesh(kind)
+    comb = F.compress_combiner(comb, compression)
+    rng = np.random.RandomState(7)
+    parts = [jnp.asarray(rng.randn(N, *shape), dtype) for shape, dtype in
+             (((4, 3), jnp.float32), ((5,), jnp.float32),
+              ((2, 2, 2), jnp.bfloat16))]
+
+    def whole(step, *xs):
+        out = comb.combine([x[0] for x in xs], step, None)
+        assert isinstance(out, list) and len(out) == len(xs)
+        return tuple(o[None] for o in out)
+
+    def one_by_one(step, *xs):
+        return tuple(comb.combine([x[0]], step, None)[0][None] for x in xs)
+
+    def on_mesh(fn):
+        return jax.jit(jax.shard_map(
+            fn, mesh=mesh, in_specs=(P(),) + (spec,) * len(parts),
+            out_specs=(spec,) * len(parts)))
+    for step in (0, 1, 2):      # every phase of the dynamic schedule
+        step = jnp.asarray(step, jnp.int32)
+        got, want = on_mesh(whole)(step, *parts), on_mesh(one_by_one)(
+            step, *parts)
+        for x, g, w in zip(parts, got, want):
+            assert (g.shape, g.dtype) == (x.shape, x.dtype)
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    if kind != "empty":
+        assert not np.array_equal(np.asarray(got[0]), np.asarray(parts[0]))
+    if kind == "dynamic":
+        text = on_mesh(whole).lower(step, *parts).as_text()
+        assert text.count("stablehlo.case") == (
+            1 if compression == "none" else len(parts))
 
 
 @pytest.mark.parametrize("dynamic", [False, True], ids=["static", "dynamic"])
@@ -344,19 +378,19 @@ def test_sparse_compression_with_direct_leaf_reaches_consensus(
         np.testing.assert_allclose(v.mean(axis=0), mean[k], atol=1e-4)
 
 
-@pytest.mark.parametrize("kw,want", [
-    ({}, {"direct": (2, 448), "packed": (3, 36)}),
-    ({"fusion": False}, {"direct": (5, 484), "packed": (0, 0)}),
-    ({"communication_type": CommunicationType.empty},
+@pytest.mark.parametrize("threshold,kw,want", [
+    (_SMALL_THRESHOLD, {}, {"direct": (2, 448), "packed": (3, 36)}),
+    (0, {}, {"direct": (5, 484), "packed": (0, 0)}),
+    (_SMALL_THRESHOLD, {"communication_type": CommunicationType.empty},
      {"direct": (0, 0), "packed": (0, 0)}),
 ], ids=["fused", "unfused", "identity"])
-def test_exchange_path_gauges(monkeypatch, kw, want):
+def test_exchange_path_gauges(monkeypatch, threshold, kw, want):
     """``bf_optim_exchange_leaves/bytes{path}`` read the leaves and the
     per-rank bytes of the tree the step program was built for."""
     from bluefog_tpu.utils import telemetry
     bf.init(lambda: topo.ExponentialTwoGraph(N))
     telemetry.reset()
-    _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    _set_threshold(monkeypatch, threshold)
     params, grads = _split_problem()
     opt = bf.optim.DistributedOptimizer(optax.sgd(0.05), **kw)
     opt.step(params, grads, opt.init(params))
@@ -366,53 +400,19 @@ def test_exchange_path_gauges(monkeypatch, kw, want):
         assert snap[f'bf_optim_exchange_bytes{{path="{path}"}}'] == nbytes
 
 
-def test_bucket_mb_env_cap_matches_single_buffer(monkeypatch):
-    """BLUEFOG_TPU_FUSION_BUCKET_MB caps bucket size instead of fixing a
-    count; a tiny cap (every leaf its own bucket) must still match the
-    single-buffer result."""
-    from bluefog_tpu.utils import config
-    bf.init(lambda: topo.ExponentialTwoGraph(N))
-    params0, grads = _multi_leaf_problem(seed=4)
-
-    def run():
-        opt = bf.optim.DistributedNeighborAllreduceOptimizer(optax.sgd(0.05))
-        p, s = params0, opt.init(params0)
-        for _ in range(2):
-            p, s = opt.step(p, grads, s)
-        return p
-    baseline = run()
-    monkeypatch.setenv("BLUEFOG_TPU_FUSION_BUCKET_MB", "0.00001")
-    config.reload()
-    try:
-        capped = run()
-    finally:
-        monkeypatch.delenv("BLUEFOG_TPU_FUSION_BUCKET_MB")
-        config.reload()
-    for k in params0:
-        np.testing.assert_allclose(np.asarray(capped[k]),
-                                   np.asarray(baseline[k]),
-                                   rtol=1e-6, atol=1e-6)
-
-
 def test_bucket_groups_partitioning():
-    """Unit contract of the bucket partitioner: contiguous, exhaustive,
-    byte-balanced in count mode, size-capped in MB mode."""
-    from bluefog_tpu.optim.functional import _bucket_groups
+    """Unit contract of the window family's bucket partitioner:
+    contiguous, exhaustive, byte-balanced."""
+    from bluefog_tpu.optim.window_optimizers import _bucket_groups
     leaves = [np.zeros(s, np.float32) for s in (100, 50, 200, 10, 40)]
-    assert _bucket_groups(leaves, None) == [[0, 1, 2, 3, 4]]
     g2 = _bucket_groups(leaves, 2)
     assert [i for grp in g2 for i in grp] == [0, 1, 2, 3, 4]
     assert len(g2) == 2
     # more buckets than leaves clamps to one leaf per bucket
     g9 = _bucket_groups(leaves, 9)
     assert len(g9) <= 5 and [i for g in g9 for i in g] == [0, 1, 2, 3, 4]
-    # fusion_buckets=1 is exactly the legacy single buffer
+    # fusion_buckets=1 is exactly the single window
     assert _bucket_groups(leaves, 1) == [[0, 1, 2, 3, 4]]
-
-
-def test_fusion_buckets_validation():
-    with pytest.raises(ValueError, match="fusion_buckets"):
-        bf.optim.DistributedOptimizer(optax.sgd(0.1), fusion_buckets=0)
 
 
 @pytest.mark.parametrize("factory,kind", [
@@ -455,16 +455,19 @@ def test_compress_combiner_residual_exact_for_identity():
     """Difference compression: with combine=identity the wrapper is exact
     (a rank's own master weights are never truncated by its own rounds);
     without the residual it quantizes."""
-    from bluefog_tpu.optim.functional import compress_combiner
+    from bluefog_tpu.optim.functional import Combiner, compress_combiner
     x = jnp.asarray(np.random.RandomState(0).randn(64).astype(np.float32))
-    ident = lambda v, **kw: v  # noqa: E731
-    with_res = compress_combiner(ident, "bf16", residual=True)
-    np.testing.assert_array_equal(np.asarray(with_res(x)), np.asarray(x))
-    no_res = compress_combiner(ident, "bf16", residual=False)
-    assert not np.array_equal(np.asarray(no_res(x)), np.asarray(x))
+    ident = lambda parts, step, weights: parts  # noqa: E731
+
+    def run(replica_identical):
+        wrapped = compress_combiner(
+            Combiner(ident, replica_identical=replica_identical), "bf16")
+        return np.asarray(wrapped.combine([x], None, None)[0])
+    np.testing.assert_array_equal(run(False), np.asarray(x))
+    # a replica-identical combiner gets no per-rank residual back
+    assert not np.array_equal(run(True), np.asarray(x))
     np.testing.assert_array_equal(
-        np.asarray(no_res(x)),
-        np.asarray(x.astype(jnp.bfloat16).astype(jnp.float32)))
+        run(True), np.asarray(x.astype(jnp.bfloat16).astype(jnp.float32)))
 
 
 def test_dynamic_topology_optimizer():
@@ -781,5 +784,4 @@ def test_compression_string_validated_even_for_empty_communication():
         with pytest.raises(ValueError):
             F.compress_combiner(ident, bad)
     for ok in ("bf16", "sparse:0.25", "none"):
-        out = F.compress_combiner(ident, ok)
-        assert getattr(out, "is_identity", False), ok
+        assert F.compress_combiner(ident, ok).identity, ok

@@ -245,7 +245,7 @@ def test_step_program_is_named_and_scoped():
 
 
 @pytest.mark.parametrize("order", ["awc", "gradient_allreduce", "unfused"])
-def test_other_orders_carry_the_scopes(order):
+def test_other_orders_carry_the_scopes(monkeypatch, order):
     bf.init(devices=jax.devices()[:4])
     params = {"w": np.ones((4, 3), np.float32),
               "b": np.ones((4, 2), np.float32)}
@@ -257,8 +257,10 @@ def test_other_orders_carry_the_scopes(order):
         want = ("bf.optim.update", "bf.optim.fuse", "bf.optim.combine",
                 "bf.optim.unfuse")
     else:
-        opt = bf.optim.DistributedNeighborAllreduceOptimizer(
-            optax.sgd(0.1), fusion=False)
+        # threshold 0: every leaf is exchanged alone, nothing is packed
+        from bluefog_tpu.optim import functional
+        monkeypatch.setattr(functional, "_DIRECT_LEAF_BYTES", 0)
+        opt = bf.optim.DistributedNeighborAllreduceOptimizer(optax.sgd(0.1))
         want = ("bf.optim.update", "bf.optim.combine")
     lowered = opt._step_callable(False).lower(
         params, params, opt.init(params)).as_text(debug_info=True)
