@@ -331,11 +331,16 @@ def dynamic_neighbor_allreduce(x, step: jnp.ndarray, sched: DynamicSchedule,
     replaces the reference's per-step send/recv-list plumbing
     (``mpi_controller.cc:418-454``) and its stop-the-world topology handshake.
 
-    ``x`` is an array or a pytree of arrays (the optimizer's parts: large
-    leaves and packed buffers).  The phase is chosen ONCE for the whole tree
-    and each branch averages every leaf: the scheduler moves no operation
+    ``x`` is an array or a pytree of arrays (the parts of one exchange:
+    large leaves and packed buffers).  The phase is chosen ONCE for the whole
+    tree and each branch averages every leaf: the scheduler moves no operation
     across a ``conditional``, so only inside one branch can one leaf's scale
-    and add run under another leaf's permute.
+    and add run under another leaf's permute, and nothing in front of the
+    switch runs under any.  This is the form for a caller who has only a
+    traced counter: the eager op, and ``functional.step_fn`` under a ``jit``
+    of the caller's own.  The optimizer classes know the counter on the host
+    and do without the switch: one program per phase over that phase's
+    :class:`StaticSchedule` (``optim/optimizers.py``).
     """
     idx = _axis_index(axis_name)
     branches = [partial(jax.tree.map, partial(

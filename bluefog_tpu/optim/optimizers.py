@@ -25,6 +25,18 @@ Dynamic topology (one-peer Exp2 etc.)::
         optax.sgd(0.1), use_dynamic_topology=True)
     # phase auto-advances with state.step; no recompilation per step.
 
+The class builds one step program per phase of the schedule, each over that
+phase's static edges, and ``step()`` launches the one that ``state.step %
+period`` names.  No program holds a ``conditional``, which XLA's scheduler
+moves nothing across: a leaf's update runs under another leaf's permute.
+The host follows the counter and does not fetch it: the state ``step()``
+returned last carries the counter remembered with it.  A state the
+optimizer did not return itself (a fresh ``init``, a restored checkpoint,
+the state of another optimizer object) costs one read of ``state.step`` from
+the device, which waits for whatever still computes it, and is followed from
+there (``bf_optim_phase_reads_total``).  A static topology, or a dynamic one
+of a single phase, has one program and none of this.
+
 Per-step weight mutation (reference README.rst:110-127 mutates
 ``opt.self_weight``/``opt.neighbor_weights``): pass ``self_weight=...,
 src_weights=...`` kwargs to ``step`` — they become *traced* inputs, so
@@ -64,6 +76,16 @@ __all__ = [
 ]
 
 
+def _read_counter(state: DistOptState) -> int:
+    """``state.step`` fetched from the device (one host sync).  It is
+    rank-major, one identical counter per rank row: any row is the value,
+    so a row this process holds is read (nothing crosses processes)."""
+    step = state.step
+    if isinstance(step, jax.Array):
+        step = step.addressable_shards[0].data
+    return int(np.asarray(step).reshape(-1)[0])
+
+
 class DistributedOptimizer:
     """Generic decentralized optimizer wrapper (see module docstring).
 
@@ -75,7 +97,9 @@ class DistributedOptimizer:
     num_steps_per_communication : communicate every J-th step (local
         aggregation, reference ``torch/optimizers.py:348-350``).
     use_dynamic_topology : cycle the one-peer phase table of the active
-        topology (or ``phases`` if given) by step index.
+        topology (or ``phases`` if given) by step index: one step program
+        per phase, chosen on the host from ``state.step`` (module
+        docstring).
     phases : explicit list of ``topology.DynamicPhase`` for dynamic mode.
     donate : donate the grads and state buffers to the jitted step so XLA
         aliases them into the outputs (grads, same tree shape as params,
@@ -160,6 +184,9 @@ class DistributedOptimizer:
         self.shard_groups = shard_groups
         self.num_shards = None if num_shards is None else int(num_shards)
         self._jitted = {}
+        # Phase choice under a dynamic topology: the step array of the state
+        # returned last and the counter it holds (see _phase).
+        self._followed = (None, 0)
         self._steps_seen = 0  # host-side counter for telemetry sampling
         self._hier_meta = None   # set by _hier_gossip_bundle
         self._hier_step0 = None  # state.step of the first hier step seen
@@ -168,6 +195,15 @@ class DistributedOptimizer:
         self._shard_step0 = None  # state.step of the first sharded step
 
     # -- schedule resolution ------------------------------------------------
+    def _exchange_schedule(self):
+        """:meth:`_schedule` for the communication types that exchange over
+        one, None for the others."""
+        if self.communication_type in (
+                CommunicationType.neighbor_allreduce,
+                CommunicationType.hierarchical_neighbor_allreduce):
+            return self._schedule()
+        return None
+
     def _schedule(self):
         """The compiled schedule of the active (machine) topology: dynamic
         under ``use_dynamic_topology``, else static."""
@@ -246,16 +282,19 @@ class DistributedOptimizer:
             self._shard_meta_cache[key] = meta
         return meta
 
-    def _build_step(self, with_weights: bool, plan=None):
+    def _build_step(self, with_weights: bool, plan=None, phase: int = 0):
         ctx = basics._require_init()
         hier = (self.communication_type in (
                 CommunicationType.hierarchical_neighbor_allreduce,
                 CommunicationType.hierarchical_gossip))
-        sched = None
-        if self.communication_type in (
-                CommunicationType.neighbor_allreduce,
-                CommunicationType.hierarchical_neighbor_allreduce):
-            sched = self._schedule()
+        sched = self._exchange_schedule()
+        if isinstance(sched, S.DynamicSchedule) and sched.period > 1:
+            # One program per phase, each over that phase's static schedule:
+            # the program holds no conditional, so the scheduler is free to
+            # run one leaf's update under another leaf's permute.  step()
+            # launches the one the state's counter names (_phase).  (A
+            # switch over one phase is no conditional to begin with.)
+            sched = sched.phases[phase]
         hier_bundle = None
         if self.communication_type == CommunicationType.hierarchical_gossip:
             hier_bundle = self._hier_gossip_bundle(ctx)
@@ -348,22 +387,46 @@ class DistributedOptimizer:
                 "outer_every": ht.outer_every, "outer_compression": comp,
                 "outer_frac": frac}
 
-    def _step_program(self, with_weights: bool, plan=None):
+    def _step_program(self, with_weights: bool, plan=None, phase: int = 0):
         """``(jitted step, its traffic)``, built on first use for each
-        topology version, weight-override arity and shard plan."""
+        topology version, weight-override arity, shard plan and phase of a
+        dynamic schedule."""
         ctx = basics._require_init()
         key = (ctx.topology_version, ctx.machine_topology_version,
                with_weights,
-               None if plan is None else plan.signature)
+               None if plan is None else plan.signature, phase)
         if key not in self._jitted:
             with op_span("optim", "build", key=str(key)):
                 telemetry.inc("bf_step_program_builds_total",
                               program="optim_step")
-                self._jitted[key] = self._build_step(with_weights, plan)
+                self._jitted[key] = self._build_step(with_weights, plan,
+                                                     phase)
         return self._jitted[key]
 
     def _step_callable(self, with_weights: bool, plan=None):
+        """The step program (of phase 0, where there are several)."""
         return self._step_program(with_weights, plan)[0]
+
+    def _phase(self, state: DistOptState):
+        """``(phase, counter)`` of the step program ``state`` runs next
+        where a dynamic schedule has more than one to choose from, else
+        ``(0, None)``.
+
+        The host follows the counter, it does not fetch it: the state
+        ``step()`` returned last carries the counter remembered with it,
+        and is known by its ``step`` array being that very object.  Any
+        other state (a fresh ``init``, a restored checkpoint, another
+        optimizer's) is read from the device once, which waits for
+        whatever still computes it, and followed from there."""
+        sched = (self._exchange_schedule() if self.use_dynamic_topology
+                 else None)
+        if sched is None or sched.period == 1:
+            return 0, None
+        step, counter = self._followed
+        if state.step is not step:
+            telemetry.inc("bf_optim_phase_reads_total")
+            counter = _read_counter(state)
+        return counter % sched.period, counter
 
     # -- public surface -----------------------------------------------------
     def init(self, params) -> DistOptState:
@@ -416,11 +479,14 @@ class DistributedOptimizer:
         with op_span("optim", "place", leaves=len(leaves)):
             params, grads = jax.tree_util.tree_unflatten(
                 treedef, [basics._place(x) for x in leaves])
+        phase, counter = self._phase(state)
         fn, traffic = self._step_program(with_weights=w is not None,
-                                         plan=plan)
+                                         plan=plan, phase=phase)
         extra = () if w is None else (jnp.asarray(w, jnp.float32),)
         with op_span("optim", "launch", step=self._steps_seen):
             out = fn(params, grads, state, *extra)
+        if counter is not None:
+            self._followed = (out[1].step, counter + 1)
         # The in-flight window holds the new parameters: the next step
         # donates the state this one returned (donate=True), never these.
         basics._throttle(out[0])
@@ -449,10 +515,7 @@ class DistributedOptimizer:
             # state once (one host sync, first call only) and advanced
             # host-side from there.
             if self._hier_step0 is None:
-                # state.step is rank-major (one identical counter per
-                # rank row); any row is the value.
-                self._hier_step0 = int(
-                    np.asarray(state.step).reshape(-1)[0])
+                self._hier_step0 = _read_counter(state)
             t = self._hier_step0 + self._steps_seen
             if t % self.num_steps_per_communication == 0:
                 ht, inner_edges, comp, _frac = self._hier_meta
@@ -466,8 +529,7 @@ class DistributedOptimizer:
             # the comm-step condition is reconstructed host-side).
             from bluefog_tpu.ops import sharded as SH
             if self._shard_step0 is None:
-                self._shard_step0 = int(
-                    np.asarray(state.step).reshape(-1)[0])
+                self._shard_step0 = _read_counter(state)
             t = self._shard_step0 + self._steps_seen
             if t % self.num_steps_per_communication == 0:
                 rep_ici, rep_dcn, grp_edges = \

@@ -241,8 +241,9 @@ def _lowered_step(opt, params, grads):
 
 @pytest.mark.parametrize("large", [1, 2, 5])
 def test_dynamic_exchange_is_one_switch(monkeypatch, large):
-    """However many large leaves the tree has, the dynamic step program
-    chooses its phase once: one ``case`` holds every part's permute."""
+    """The one phase switch of a dynamic topology is the host's: the class
+    builds a step program per phase, none holds a ``case``, and each holds
+    one permute for every large leaf and one for the packed buffer."""
     bf.init(lambda: topo.ExponentialTwoGraph(N))
     _set_threshold(monkeypatch, _SMALL_THRESHOLD)
     rng = np.random.RandomState(0)
@@ -251,10 +252,130 @@ def test_dynamic_exchange_is_one_switch(monkeypatch, large):
     params["bias"] = jnp.asarray(rng.randn(N, 3), jnp.float32)
     opt = bf.optim.DistributedAdaptThenCombineOptimizer(
         optax.sgd(0.05), use_dynamic_topology=True)
-    text = _lowered_step(opt, params, params)
-    assert text.count("stablehlo.case") == 1
-    phases = 3                              # one-peer Exp2 over 8 ranks
-    assert text.count("stablehlo.collective_permute") == phases * (large + 1)
+    state = opt.init(params)
+    period = 3                              # one-peer Exp2 over 8 ranks
+    assert opt._schedule().period == period
+    texts = [opt._step_program(False, None, phase)[0].lower(
+        params, params, state).as_text() for phase in range(period)]
+    assert texts[0] == _lowered_step(opt, params, params)
+    assert len(set(texts)) == period        # each its own peers
+    for text in texts:
+        assert "stablehlo.case" not in text
+        assert text.count("stablehlo.collective_permute") == large + 1
+
+
+def _traced_switch_step(order, base, n_w):
+    """The whole step under one ``jit`` of the caller's own, where the
+    counter is traced and the dynamic combiner's ``lax.switch`` chooses the
+    phase: what ``functional.step_fn`` gives a caller outside the class
+    (``n_w``: 1 if it takes a weight matrix, else 0)."""
+    from jax.sharding import PartitionSpec as P
+    from bluefog_tpu import basics
+    from bluefog_tpu.ops import schedule as S
+    from bluefog_tpu.optim import functional as F
+    ctx = basics._require_init()
+    sched = S.compile_dynamic(topo.dynamic_phase_table(ctx.topology), N)
+    inner = F.step_fn(order, base, F.make_combiner(
+        CommunicationType.neighbor_allreduce, axis_name=basics.RANK_AXIS,
+        sched=sched), axis_name=basics.RANK_AXIS)
+
+    def run(params, grads, state, *w):
+        p, g, s = jax.tree.map(lambda x: x[0], (params, grads, state))
+        out = inner(p, g, s, weights=w[0] if w else None)
+        return jax.tree.map(lambda x: x[None], out)
+    spec = P(basics.RANK_AXIS)
+    return jax.jit(jax.shard_map(
+        run, mesh=ctx.mesh, in_specs=(spec,) * 3 + (P(),) * n_w,
+        out_specs=(spec, spec)))
+
+
+def _override_matrix(seed=11):
+    """A full weight matrix, rows and columns of no special sum: the phase's
+    edges pick their entries."""
+    return np.random.RandomState(seed).uniform(0.2, 0.8, (N, N))
+
+
+_PHASE_CASES = pytest.mark.parametrize("order,override", [
+    ("atc", False), ("atc", True), ("awc", False), ("awc", True)],
+    ids=lambda v: v if isinstance(v, str) else
+    ("override" if v else "schedule-weights"))
+
+
+@_PHASE_CASES
+def test_phase_programs_match_the_traced_switch(monkeypatch, order,
+                                                override):
+    """Over two periods and a step the class (one program per phase, the
+    host choosing) gives bit for bit the parameters of the traced switch."""
+    bf.init(lambda: topo.ExponentialTwoGraph(N))
+    _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    base = optax.sgd(0.05, momentum=0.9)
+    opt = bf.optim.DistributedOptimizer(
+        base, CommunicationType.neighbor_allreduce, order=order,
+        use_dynamic_topology=True)
+    w = _override_matrix() if override else None
+    extra = (jnp.asarray(w, jnp.float32),) if override else ()
+    ref = _traced_switch_step(order, base, len(extra))
+    params, grads = _split_problem()
+    ref_params, ref_state = params, opt.init(params)
+    state = opt.init(params)
+    for t in range(2 * opt._schedule().period + 1):
+        params, state = opt.step(params, grads, state, src_weights=w)
+        ref_params, ref_state = ref(ref_params, grads, ref_state, *extra)
+        for k in params:
+            np.testing.assert_array_equal(
+                np.asarray(params[k]), np.asarray(ref_params[k]),
+                err_msg=f"step {t}, leaf {k}")
+    np.testing.assert_array_equal(np.asarray(state.step),
+                                  np.asarray(ref_state.step))
+
+
+@_PHASE_CASES
+def test_phase_follows_the_counter_of_the_state_handed_in(order, override):
+    """The benchmark's ``step`` check in small: after k steps a fresh
+    ``init`` state starts at phase 0 again, and a state whose counter says 5
+    runs phase ``5 % period``; each is what the mixing matrix of that step
+    gives, written out here from the phase table."""
+    graph = topo.ExponentialTwoGraph(N)
+    bf.init(lambda: graph)
+    table = topo.dynamic_phase_table(graph)
+    opt = bf.optim.DistributedOptimizer(
+        optax.sgd(0.0), CommunicationType.neighbor_allreduce, order=order,
+        use_dynamic_topology=True)      # no update: a step is W_t x
+    w = _override_matrix() if override else None
+
+    def mixed(x, t):
+        phase, out = table[t % len(table)], np.zeros_like(x)
+        for dst in range(N):
+            srcs = phase.recv_from(dst)
+            if override:
+                out[dst] = w[dst, dst] * x[dst] + sum(
+                    w[src, dst] * x[src] for src in srcs)
+            else:
+                out[dst] = (x[dst] + sum(x[src] for src in srcs)) / (
+                    len(srcs) + 1)
+        return out
+
+    x = np.random.RandomState(2).randn(N, 5).astype(np.float32)
+    params = {"w": jnp.asarray(x)}
+    zeros = {"w": jnp.zeros_like(params["w"])}
+    state = opt.init(params)
+    for _ in range(4):                  # leaves the class at counter 4
+        params, state = opt.step(params, zeros, state, src_weights=w)
+    for counter in (0, 5, 0):
+        fresh = opt.init({"w": jnp.asarray(x)})
+        if counter:
+            fresh = fresh._replace(
+                step=jnp.full_like(fresh.step, counter))
+        got, after = opt.step({"w": jnp.asarray(x)}, zeros, fresh,
+                              src_weights=w)
+        np.testing.assert_allclose(np.asarray(got["w"]), mixed(x, counter),
+                                   rtol=1e-6, atol=1e-6)
+        # ... and is followed from there without another read
+        got, _ = opt.step(got, zeros, after, src_weights=w)
+        np.testing.assert_allclose(
+            np.asarray(got["w"]), mixed(mixed(x, counter), counter + 1),
+            rtol=1e-5, atol=1e-6)
+    assert len(table) == 3 and not np.allclose(mixed(x, 0), mixed(x, 2))
 
 
 def _combiner_on_mesh(kind):

@@ -74,7 +74,8 @@ def _trace(tmp_path, body):
 def test_step_spans_nest_on_one_thread_with_rising_step(tmp_path):
     grad, opt, params, x = _job()
     state = opt.init(params)
-    params, state = opt.step(params, grad(params, x), state)   # builds
+    for _ in range(opt._schedule().period):     # builds, a program a phase
+        params, state = opt.step(params, grad(params, x), state)
 
     def three_steps():
         nonlocal params, state
@@ -94,31 +95,85 @@ def test_step_spans_nest_on_one_thread_with_rising_step(tmp_path):
             and launch[2] <= step[2]                        # nested, in order
         assert launch[4]["step"] == step[4]["step"]
         assert place[4]["leaves"] == "4"                    # params + grads
-    assert [int(s[4]["step"]) for s in steps] == [1, 2, 3]
+    assert [int(s[4]["step"]) for s in steps] == [2, 3, 4]
 
 
-def test_build_spans_fire_on_the_cache_miss_only(tmp_path):
-    grad, opt, params, x = _job()
+@pytest.mark.parametrize("dynamic", [True, False],
+                         ids=["dynamic", "static"])
+def test_build_spans_fire_on_the_cache_miss_only(tmp_path, dynamic):
+    """A dynamic topology builds one step program per phase, each on the
+    first step that runs its phase; a static one builds one."""
+    grad, opt, params, x = _job(use_dynamic_topology=dynamic)
     state = opt.init(params)
+    period = opt._schedule().period if dynamic else 1
+    assert period == (2 if dynamic else 1)   # one-peer Exp2 over 4 ranks
 
-    def two_steps():
+    def two_periods():
         nonlocal params, state
-        for _ in range(2):
+        for _ in range(2 * period):
             params, state = opt.step(params, grad(params, x), state)
-    spans = _trace(tmp_path, two_steps)
+    spans = _trace(tmp_path, two_periods)
     builds = [s for s in spans if s[0].endswith(".build")]
-    assert sorted(s[0] for s in builds) == ["bf.optim.build",
-                                            "bf.rank_map.build"]
-    key = next(s[4]["key"] for s in builds if s[0] == "bf.optim.build")
-    assert key.startswith("(") and "False" in key      # the cache key
+    assert sorted(s[0] for s in builds) == (
+        ["bf.optim.build"] * period + ["bf.rank_map.build"])
+    keys = [s[4]["key"] for s in builds if s[0] == "bf.optim.build"]
+    assert all(k.startswith("(") and "False" in k for k in keys)
+    assert len(set(keys)) == period            # the cache key: phase last
     snap = telemetry.snapshot()
     assert snap['bf_step_program_builds_total{program="rank_map"}'] == 1
-    assert snap['bf_step_program_builds_total{program="optim_step"}'] == 1
+    assert snap['bf_step_program_builds_total{program="optim_step"}'] \
+        == period
     bf.set_topology(topology_util.RingGraph(bf.size()))    # new version
     opt.step(params, grad(params, x), state)
     snap = telemetry.snapshot()
-    assert snap['bf_step_program_builds_total{program="optim_step"}'] == 2
+    assert snap['bf_step_program_builds_total{program="optim_step"}'] \
+        == period + 1
     assert snap['bf_step_program_builds_total{program="rank_map"}'] == 1
+
+
+_READS = "bf_optim_phase_reads_total"
+
+
+@pytest.mark.parametrize("order,override", [
+    ("atc", False), ("atc", True), ("awc", False), ("awc", True)])
+def test_phase_reads_count_the_states_not_followed(order, override):
+    """``step()`` reads the counter from the device once per state it did
+    not return itself (a fresh ``init``, a restore), never in between."""
+    n = 4
+    bf.init(devices=jax.devices()[:n])
+    opt = bf.optim.DistributedOptimizer(
+        optax.sgd(0.01), order=order, use_dynamic_topology=True)
+    w = np.full((n, n), 0.25) if override else None
+    params = {"w": np.ones((n, 3), np.float32)}
+    state = opt.init(params)
+    assert _READS not in telemetry.snapshot()
+    for _ in range(10):
+        params, state = opt.step(params, params, state, src_weights=w)
+    assert telemetry.snapshot()[_READS] == 1
+    state = opt.init(params)
+    for _ in range(3):
+        params, state = opt.step(params, params, state, src_weights=w)
+    snap = telemetry.snapshot()
+    assert snap[_READS] == 2
+    assert snap['bf_step_program_builds_total{program="optim_step"}'] == 2
+
+
+@pytest.mark.parametrize("n,dynamic", [(4, False), (1, True)],
+                         ids=["static", "period-1"])
+def test_one_phase_needs_no_read_and_one_program(n, dynamic):
+    bf.init(devices=jax.devices()[:n])
+    opt = bf.optim.DistributedAdaptThenCombineOptimizer(
+        optax.sgd(0.01), use_dynamic_topology=dynamic)
+    params = {"w": np.ones((n, 3), np.float32)}
+    state = opt.init(params)
+    for _ in range(5):
+        params, state = opt.step(params, params, state)
+    state = opt.init(params)            # a state it did not return
+    params, state = opt.step(params, params, state)
+    snap = telemetry.snapshot()
+    assert _READS not in snap
+    assert snap['bf_step_program_builds_total{program="optim_step"}'] == 1
+    assert opt._followed == (None, 0)
 
 
 def test_eager_op_spans_reach_the_profiler_too(tmp_path):
