@@ -14,6 +14,8 @@ static shapes throughout.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -27,10 +29,12 @@ __all__ = ["TransformerLM", "TransformerConfig", "local_attention",
            "init_cache", "generate", "DroplessMoe", "moe_stats"]
 
 
-def local_attention(q, k, v, *, causal: bool = True):
-    """Plain single-device attention: ``(B, S, H, D)`` inputs."""
+def local_attention(q, k, v, *, causal: bool = True, scale: float = None):
+    """Plain single-device attention: ``(B, S, H, D)`` inputs (``v`` may
+    have a last dim of its own); ``scale=None`` means ``1 / sqrt(D)``."""
     dt = q.dtype
-    scale = 1.0 / np.sqrt(q.shape[-1])
+    if scale is None:
+        scale = 1.0 / np.sqrt(q.shape[-1])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
@@ -50,7 +54,14 @@ class TransformerConfig:
                  rope_theta=10000.0, mlp="gelu", num_experts_per_tok=1,
                  expert_dim=None, norm_topk_prob=False, qk_norm=False,
                  rms_norm_eps=1e-6, router_aux_loss_coef=0.01,
-                 router_z_loss_coef=0.001):
+                 router_z_loss_coef=0.001, mlp_dim=None, dense_layers=0,
+                 num_shared_experts=0, router_scoring="softmax",
+                 routed_scaling_factor=1.0, experts_held=None,
+                 experts_first=0, kv_lora_rank=None, q_lora_rank=None,
+                 qk_nope_head_dim=None, qk_rope_head_dim=None,
+                 v_head_dim=None, rope_scaling=None, hyper_streams=1,
+                 hyper_sinkhorn_iters=20, hyper_eps=1e-6,
+                 hyper_res_clamp=(-30.0, 30.0)):
         self.vocab_size = vocab_size
         self.num_layers = num_layers
         self.num_heads = num_heads
@@ -146,6 +157,80 @@ class TransformerConfig:
         self.num_experts = num_experts
         self.expert_capacity_factor = expert_capacity_factor
         self.router_group_size = router_group_size
+        # Width of the dense MLP where it is no multiple of embed_dim
+        # (None = mlp_ratio * embed_dim), and the number of leading blocks
+        # that keep the dense MLP in a model whose other blocks hold experts
+        # (DeepSeek's first_k_dense_replace).
+        self.mlp_dim = mlp_dim
+        self.dense_layers = dense_layers
+        # DroplessMoe beyond OLMoE's: ``num_shared_experts`` SwiGLU experts
+        # of width expert_dim that every token passes through, beside the
+        # routed ones; ``router_scoring`` "sigmoid" chooses on sigmoid score
+        # + bias (the bias is the ``router_state`` collection's, moved by
+        # ``parallel.moe.update_router_bias`` and reached by no gradient)
+        # and multiplies the renormalised weights by
+        # ``routed_scaling_factor``; ``experts_held`` experts from
+        # ``experts_first`` on live on this rank (None = all num_experts):
+        # the router keeps its num_experts outputs, the expert leaves hold
+        # the held ones only and the layer computes their part of the result.
+        if router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_scoring {router_scoring!r} not in "
+                             "('softmax', 'sigmoid')")
+        if experts_held is not None and not (
+                0 <= experts_first
+                and 0 < experts_held <= num_experts - experts_first):
+            raise ValueError(
+                f"experts_held ({experts_held}) from experts_first "
+                f"({experts_first}) must lie within num_experts "
+                f"({num_experts})")
+        self.num_shared_experts = num_shared_experts
+        self.router_scoring = router_scoring
+        self.routed_scaling_factor = routed_scaling_factor
+        self.experts_held = experts_held
+        self.experts_first = experts_first
+        # Latent attention (DeepSeek's MLA) when kv_lora_rank is set: q and
+        # k heads of qk_nope_head_dim + qk_rope_head_dim (the rotary part of
+        # k is ONE head shared by all), v heads of v_head_dim, q through a
+        # rank-q_lora_rank bottleneck (None = one matrix), k and v expanded
+        # from a normed rank-kv_lora_rank latent.  ``rope_scaling``: YaRN's
+        # dict (factor, original_max_position_embeddings, beta_fast,
+        # beta_slow, mscale, mscale_all_dim), read by the latent path.
+        latent = (kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
+                  v_head_dim)
+        if any(v is not None for v in latent) and None in latent:
+            raise ValueError(
+                "latent attention needs kv_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim together")
+        if kv_lora_rank is not None and (
+                pos_encoding != "rope" or qk_rope_head_dim % 2
+                or num_kv_heads not in (None, num_heads)):
+            raise ValueError(
+                "latent attention takes pos_encoding='rope', an even "
+                "qk_rope_head_dim and no grouped K/V heads")
+        if rope_scaling is not None and kv_lora_rank is None:
+            raise ValueError("rope_scaling is read by the latent-attention "
+                             "path only (set kv_lora_rank)")
+        if rope_scaling is not None and rope_scaling.get("type") != "yarn":
+            raise ValueError(f"rope_scaling type "
+                             f"{rope_scaling.get('type')!r}: only 'yarn'")
+        self.kv_lora_rank = kv_lora_rank
+        self.q_lora_rank = q_lora_rank
+        self.qk_nope_head_dim = qk_nope_head_dim
+        self.qk_rope_head_dim = qk_rope_head_dim
+        self.v_head_dim = v_head_dim
+        self.rope_scaling = rope_scaling
+        # hyper_streams n > 1: the residual is n streams (manifold-
+        # constrained hyper-connections), carried as (B, S, n * embed_dim);
+        # every sub-layer reads a gated sum of them and writes back through
+        # a gate and an (n, n) Sinkhorn-normalised mixing matrix
+        # (``HyperConnection``).
+        if hyper_streams < 1:
+            raise ValueError(f"hyper_streams must be >= 1; got "
+                             f"{hyper_streams}")
+        self.hyper_streams = hyper_streams
+        self.hyper_sinkhorn_iters = hyper_sinkhorn_iters
+        self.hyper_eps = hyper_eps
+        self.hyper_res_clamp = tuple(hyper_res_clamp)
 
 
 class SwitchMlp(nn.Module):
@@ -221,7 +306,18 @@ class DroplessMoe(nn.Module):
     ``gate``, ``up`` (E, d, f) and ``down`` (E, f, d), ``f =
     cfg.expert_dim``.  Router matmul and softmax run in float32.  Sown into
     ``intermediates`` (read them with ``moe_stats``): ``moe_load`` (E,)
-    int32 assignment counts, ``moe_balance_loss`` and ``moe_z_loss``."""
+    int32 assignment counts, ``moe_balance_loss`` and ``moe_z_loss``.
+
+    With ``cfg.experts_held`` the three leaves hold that many experts, from
+    ``cfg.experts_first`` on, and the layer computes their part of the
+    result; the router and ``moe_load`` keep all ``E``.  Under
+    ``cfg.router_scoring == "sigmoid"`` the choice is made on score + the
+    variable ``bias`` (E,) of the collection ``router_state`` (zeros at
+    init; the training loop moves it with ``parallel.moe.
+    update_router_bias`` and no gradient reaches it).
+    ``cfg.num_shared_experts`` adds one SwiGLU of that many expert widths
+    (``shared_gate``, ``shared_up``, ``shared_down``) that every token
+    passes through, under the scope ``bf.moe.shared``."""
     cfg: Any
 
     @nn.compact
@@ -233,9 +329,19 @@ class DroplessMoe(nn.Module):
         f = cfg.expert_dim or cfg.mlp_ratio * d
         # batch_axis keeps fan_in per expert (= d / f), not E*d.
         init = nn.initializers.lecun_normal(batch_axis=(0,))
-        gate = self.param("gate", init, (E, d, f))
-        up = self.param("up", init, (E, d, f))
-        down = self.param("down", init, (E, f, d))
+        held = getattr(cfg, "experts_held", None)
+        n = E if held is None else held
+        gate = self.param("gate", init, (n, d, f))
+        up = self.param("up", init, (n, d, f))
+        down = self.param("down", init, (n, f, d))
+        routing = {}
+        if held is not None:
+            routing["held"] = (cfg.experts_first, held)
+        if getattr(cfg, "router_scoring", "softmax") == "sigmoid":
+            routing.update(
+                scoring="sigmoid", scale=cfg.routed_scaling_factor,
+                bias=self.variable("router_state", "bias", jnp.zeros, (E,),
+                                   jnp.float32).value)
         with timeline.device_scope("bf.moe"):
             xt = x.reshape(B * S, d)
             with timeline.device_scope("bf.moe.route"):
@@ -247,7 +353,17 @@ class DroplessMoe(nn.Module):
                                   name="router")(xt.astype(jnp.float32))
             y, plan = dropless_moe(
                 xt.astype(cfg.dtype), logits, gate, up, down,
-                k=cfg.num_experts_per_tok, renormalize=cfg.norm_topk_prob)
+                k=cfg.num_experts_per_tok, renormalize=cfg.norm_topk_prob,
+                **routing)
+            shared = getattr(cfg, "num_shared_experts", 0) * f
+            if shared:
+                with timeline.device_scope("bf.moe.shared"):
+                    dense = functools.partial(nn.Dense, use_bias=False,
+                                              dtype=cfg.dtype)
+                    xs = xt.astype(cfg.dtype)
+                    y = y + dense(d, name="shared_down")(
+                        nn.silu(dense(shared, name="shared_gate")(xs))
+                        * dense(shared, name="shared_up")(xs))
         self.sow("intermediates", "moe_load", plan.load)
         self.sow("intermediates", "moe_balance_loss", plan.balance_loss)
         self.sow("intermediates", "moe_z_loss", plan.z_loss)
@@ -274,20 +390,203 @@ def moe_stats(intermediates) -> dict:
             "z_loss": jnp.mean(jnp.stack(found["moe_z_loss"]))}
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``
+    (1 for a factor of one or less)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim: int, theta: float, scaling: dict) -> np.ndarray:
+    """The ``dim / 2`` rotary frequencies under YaRN (arXiv:2309.00071, as
+    DeepSeek-V3's modelling file computes them): pair ``i`` turns at
+    ``theta^(-2i/dim)``, divided by ``factor`` where it makes fewer than
+    ``beta_slow`` turns over the original context, left alone where it makes
+    more than ``beta_fast``, and blended linearly over the pairs between."""
+    factor = scaling["factor"]
+    original = scaling["original_max_position_embeddings"]
+    plain = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def pair_of(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(pair_of(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(pair_of(scaling.get("beta_slow", 1))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / ((high - low) or 0.001), 0.0, 1.0)
+    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
+
+
+def apply_rope(x, positions, theta: float = 10000.0, freq=None):
     """Rotary position embedding on ``(B, S, H, D)`` q or k.
 
     Pairs dimension ``i`` with ``i + D/2`` (the standard half-split layout)
-    and rotates by ``pos * theta^(-2i/D)``; angles computed in f32, result
+    and rotates by ``pos * theta^(-2i/D)`` (or by ``pos * freq[i]`` where
+    the ``D / 2`` frequencies are given); angles computed in f32, result
     cast back to the input dtype."""
     d2 = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(d2, dtype=jnp.float32) / d2)
+    if freq is None:
+        freq = theta ** (-jnp.arange(d2, dtype=jnp.float32) / d2)
     ang = positions[..., None].astype(jnp.float32) * freq  # (B, S, d2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
     x1, x2 = x[..., :d2].astype(jnp.float32), x[..., d2:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin,
                             x1 * sin + x2 * cos], -1).astype(x.dtype)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3's MLA), training and
+    prefill form: the keys and values of all heads are expanded from one
+    normed latent of ``cfg.kv_lora_rank`` values a token, the queries pass a
+    bottleneck of ``cfg.q_lora_rank``, and the rotary part of the key
+    (``cfg.qk_rope_head_dim``) is one head that all query heads share.
+
+    ``c_q = RMSNorm(y q_a)``; ``q = c_q q_b`` as ``(H, nope + rope)``;
+    ``[c_kv, k_r] = y kv_a``; ``[k_n, v] = RMSNorm(c_kv) kv_b`` as ``(H,
+    nope + v)``; rotary (half-split pairs, YaRN frequencies under
+    ``cfg.rope_scaling``) on the last ``rope`` dims of ``q`` and on ``k_r``;
+    ``k_h = [k_n,h ; k_r]``; softmax of ``q k^T * (nope + rope)^-0.5 *
+    m^2``, ``m = yarn_mscale(factor, mscale_all_dim)``; the heads' ``p v``
+    go through ``proj`` (``H * v`` to ``embed_dim``).  No bias anywhere.
+    ``attn_impl`` gets q and k heads of ``nope + rope`` and v heads of
+    ``v_head_dim`` (``ops.flash_attention`` takes both) and the scale.
+
+    Device scopes: ``bf.mla.q``, ``bf.mla.kv``, ``bf.mla.rope``,
+    ``bf.mla.attend`` (the key's assembly and the attention itself) and
+    ``bf.mla.out``."""
+    cfg: Any
+    attn_impl: Callable
+
+    @nn.compact
+    def __call__(self, y, positions):
+        cfg = self.cfg
+        B, S, _ = y.shape
+        h, nope, rope = (cfg.num_heads, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim)
+        dv, rank = cfg.v_head_dim, cfg.kv_lora_rank
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+        norm = functools.partial(nn.RMSNorm, epsilon=cfg.rms_norm_eps,
+                                 dtype=cfg.dtype)
+        scaling = cfg.rope_scaling
+        with timeline.device_scope("bf.mla.q"):
+            if cfg.q_lora_rank is None:
+                q = dense(h * (nope + rope), name="q")(y)
+            else:
+                q = dense(h * (nope + rope), name="q_b")(norm(name="q_a_norm")(
+                    dense(cfg.q_lora_rank, name="q_a")(y)))
+            q = q.reshape(B, S, h, nope + rope)
+        with timeline.device_scope("bf.mla.kv"):
+            latent = dense(rank + rope, name="kv_a")(y)
+            k_rope = latent[..., rank:].reshape(B, S, 1, rope)
+            kv = dense(h * (nope + dv), name="kv_b")(
+                norm(name="kv_a_norm")(latent[..., :rank]))
+            kv = kv.reshape(B, S, h, nope + dv)
+        with timeline.device_scope("bf.mla.rope"):
+            freq = None if scaling is None else yarn_frequencies(
+                rope, cfg.rope_theta, scaling)
+            q = jnp.concatenate(
+                [q[..., :nope], apply_rope(q[..., nope:], positions,
+                                           cfg.rope_theta, freq)], axis=-1)
+            k_rope = apply_rope(k_rope, positions, cfg.rope_theta, freq)
+            if scaling is not None:
+                # YaRN's factor on cos and sin (1 where mscale equals
+                # mscale_all_dim)
+                turn = yarn_mscale(scaling["factor"],
+                                   scaling.get("mscale", 1)) / yarn_mscale(
+                    scaling["factor"], scaling.get("mscale_all_dim", 0))
+                if turn != 1.0:
+                    q = q.at[..., nope:].multiply(turn)
+                    k_rope = k_rope * turn
+        scale = (nope + rope) ** -0.5
+        if scaling is not None and scaling.get("mscale_all_dim"):
+            scale *= yarn_mscale(scaling["factor"],
+                                 scaling["mscale_all_dim"]) ** 2
+        with timeline.device_scope("bf.mla.attend"):
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, h, rope))],
+                axis=-1)
+            attn = self.attn_impl(q, k, kv[..., nope:], causal=cfg.causal,
+                                  scale=scale)
+        with timeline.device_scope("bf.mla.out"):
+            return dense(cfg.embed_dim, name="proj")(
+                attn.reshape(B, S, h * dv))
+
+
+class HyperConnection(nn.Module):
+    """The three maps of manifold-constrained hyper-connections (mHC,
+    arXiv:2512.24880) around one sub-layer of a block whose residual is
+    ``n = cfg.hyper_streams`` streams, carried as ``x`` ``(B, S, n * d)``.
+
+    ``__call__(x)`` returns ``(u, mix)``: ``u`` ``(B, S, d)`` is what the
+    sub-layer reads, and ``mix(f)`` puts its result ``f`` back.  All of the
+    map is float32: ``z = RMSNorm_eps(x)`` over the ``n * d`` values of a
+    token (``eps = cfg.hyper_eps``, a learned scale); ``[l_pre (n), l_post
+    (n), l_res (n * n)] = z phi[:-1] + phi[-1]``; ``H_pre =
+    sigmoid(l_pre)``; ``H_post = 2 sigmoid(l_post)``; ``M = exp(clip(l_res,
+    *cfg.hyper_res_clamp))`` as ``(n, n)``, then
+    ``cfg.hyper_sinkhorn_iters`` times each row divided by its sum ``+
+    eps`` and each column by its sum ``+ eps``: ``H_res``, nearly doubly
+    stochastic.  ``u = sum_i H_pre[i] x_i`` and ``mix(f)_i = sum_j
+    H_res[i, j] x_j + H_post[i] f``.
+
+    Two leaves: ``scale`` ``(n d,)`` and ``phi`` ``(n d + 1, n n + 2 n)``,
+    whose last row holds the three biases.  The paper's gains ``alpha`` (a
+    logit is ``alpha * (z Phi) + b``, ``alpha`` 0.01 at first) are the
+    scale of ``phi``'s columns and no leaf of their own: the rows of
+    ``phi`` start at 0.01 times lecun normal, the same functions.  At init
+    ``sigmoid(b_pre) = 1 / n``, ``b_post`` is 0 and ``b_res`` 8 times the
+    identity, so a fresh block is a pre-norm residual block on the streams'
+    mean.  Device scopes: ``bf.mhc.map``, ``bf.mhc.sinkhorn``,
+    ``bf.mhc.mix``."""
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        n, d, eps = cfg.hyper_streams, cfg.embed_dim, cfg.hyper_eps
+        B, S, _ = x.shape
+        scale = self.param("scale", nn.initializers.ones, (n * d,))
+
+        def init_phi(key, shape):
+            bias = jnp.concatenate([
+                jnp.full((n,), -math.log(n - 1.0)), jnp.zeros((n,)),
+                8.0 * jnp.eye(n).ravel()])
+            rows = nn.initializers.lecun_normal()(key, (n * d, shape[1]))
+            return jnp.concatenate([0.01 * rows, bias[None]])
+
+        phi = self.param("phi", init_phi, (n * d + 1, n * n + 2 * n))
+        streams = [x[..., i * d:(i + 1) * d].astype(jnp.float32)
+                   for i in range(n)]
+        with timeline.device_scope("bf.mhc.map"):
+            x32 = x.astype(jnp.float32)
+            z = x32 * jax.lax.rsqrt(
+                jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps) * scale
+            # float32 for real, as the router's: the default precision of
+            # a float32 matmul on the TPU is one bfloat16 pass.  The tokens
+            # go last, so that the small (n, n) work below is over whole
+            # rows of tokens and not over 4 x 4 corners of padded tiles.
+            logit = jnp.einsum("bsk,km->mbs", z, phi[:-1],
+                               precision=jax.lax.Precision.HIGHEST) \
+                + phi[-1][:, None, None]
+            h_pre = nn.sigmoid(logit[:n])
+            h_post = 2.0 * nn.sigmoid(logit[n:2 * n])
+        with timeline.device_scope("bf.mhc.sinkhorn"):
+            lo, hi = cfg.hyper_res_clamp
+            m = jnp.exp(jnp.clip(logit[2 * n:].reshape(n, n, B, S), lo, hi))
+            for _ in range(cfg.hyper_sinkhorn_iters):
+                m = m / (m.sum(axis=1, keepdims=True) + eps)
+                m = m / (m.sum(axis=0, keepdims=True) + eps)
+        with timeline.device_scope("bf.mhc.mix"):
+            u = sum(h_pre[i][..., None] * streams[i] for i in range(n))
+
+        def mix(f):
+            with timeline.device_scope("bf.mhc.mix"):
+                f32 = f.astype(jnp.float32)
+                return jnp.concatenate([
+                    sum(m[i, j][..., None] * streams[j] for j in range(n))
+                    + h_post[i][..., None] * f32
+                    for i in range(n)], axis=-1).astype(x.dtype)
+        return u.astype(x.dtype), mix
 
 
 def block_class(cfg, layer_idx: int = None):
@@ -319,6 +618,8 @@ def block_class(cfg, layer_idx: int = None):
 class Block(nn.Module):
     cfg: Any
     attn_impl: Callable
+    layer_idx: int = 0      # which block of the model: the first
+                            # cfg.dense_layers keep the dense MLP
 
     @nn.compact
     def __call__(self, x, positions=None, cache=None):
@@ -337,8 +638,13 @@ class Block(nn.Module):
             # standalone Block use (e.g. pipeline stages): local positions
             positions = jnp.arange(x.shape[1])[None, :]
         eps = getattr(cfg, "rms_norm_eps", 1e-6)
+        x, join = self._residual(x, "hc_attn")
         y = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype)(x)
         B, S = y.shape[0], y.shape[1]
+        if getattr(cfg, "kv_lora_rank", None) is not None:
+            x = join(LatentAttention(cfg, self.attn_impl, name="mla")(
+                y, positions))
+            return self._ffn(x, eps)
         if kv_h == h:
             qkv = nn.Dense(3 * cfg.embed_dim, use_bias=False,
                            dtype=cfg.dtype, name="qkv")(y)
@@ -401,28 +707,48 @@ class Block(nn.Module):
             probs = nn.softmax(logits, axis=-1).astype(cfg.dtype)
             attn = jnp.einsum("bgrql,blgd->bqgrd", probs, cv)
         attn = attn.reshape(B, S, cfg.embed_dim)
-        x = x + nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
-                         name="proj")(attn)
+        x = join(nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
+                          name="proj")(attn))
+        x = self._ffn(x, eps)
+        return x if cache is None else (x, cache)
+
+    def _residual(self, x, name):
+        """What a sub-layer reads of the residual ``x``, and how its result
+        ``f`` goes back: ``x`` and ``x + f``, or under ``cfg.hyper_streams``
+        a gated sum of the streams and their mixing (``HyperConnection``
+        ``name``)."""
+        if getattr(self.cfg, "hyper_streams", 1) > 1:
+            return HyperConnection(self.cfg, name=name)(x)
+        return x, lambda f: x + f
+
+    def _ffn(self, x, eps):
+        """The block's second sub-layer on the residual ``x``: the experts
+        (past the leading ``cfg.dense_layers`` blocks of a model that has
+        any) or the dense MLP."""
+        cfg = self.cfg
+        x, join = self._residual(x, "hc_ffn")
         y = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype)(x)
-        if getattr(cfg, "num_experts", 0) > 0:
+        hidden = (getattr(cfg, "mlp_dim", None)
+                  or cfg.mlp_ratio * cfg.embed_dim)
+        if (getattr(cfg, "num_experts", 0) > 0
+                and self.layer_idx >= getattr(cfg, "dense_layers", 0)):
             moe = (DroplessMoe if getattr(cfg, "mlp", "gelu") == "swiglu"
                    else SwitchMlp)
-            x = x + moe(cfg, name="moe")(y)
+            x = join(moe(cfg, name="moe")(y))
         elif getattr(cfg, "mlp", "gelu") == "swiglu":
-            hidden = cfg.mlp_ratio * cfg.embed_dim
             gate = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype,
                             name="gate")(y)
             up = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype,
                           name="up")(y)
-            x = x + nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
-                             name="down")(nn.silu(gate) * up)
+            x = join(nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
+                              name="down")(nn.silu(gate) * up))
         else:
-            y = nn.Dense(cfg.mlp_ratio * cfg.embed_dim, use_bias=False,
-                         dtype=cfg.dtype, name="up")(y)
+            y = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype,
+                         name="up")(y)
             y = nn.gelu(y)
-            x = x + nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
-                             name="down")(y)
-        return x if cache is None else (x, cache)
+            x = join(nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
+                              name="down")(y))
+        return x
 
 
 class TransformerLM(nn.Module):
@@ -443,10 +769,15 @@ class TransformerLM(nn.Module):
         position ``positions``; returns ``(logits, new_cache)``."""
         cfg = self.cfg
         attn = self.attn_impl or local_attention
+        streams = getattr(cfg, "hyper_streams", 1)
         if cache is not None:
             if getattr(cfg, "num_experts", 0) > 0:
                 raise NotImplementedError(
                     "KV-cache decoding with MoE blocks is not supported")
+            if streams > 1 or getattr(cfg, "kv_lora_rank", None) is not None:
+                raise NotImplementedError(
+                    "KV-cache decoding with latent attention or several "
+                    "residual streams is not supported")
             if not getattr(cfg, "causal", True):
                 raise ValueError(
                     "KV-cache decoding requires causal=True: the decode "
@@ -474,10 +805,14 @@ class TransformerLM(nn.Module):
             x = x + pos
         positions = jnp.broadcast_to(positions,
                                      (tokens.shape[0], tokens.shape[1]))
+        if streams > 1:
+            # every stream starts as the embedding; the blocks carry them
+            # side by side, (B, S, n * d)
+            x = jnp.tile(x, (1, 1, streams))
         new_cache = []
         for i in range(cfg.num_layers):
             block_cls = Block if cache is not None else block_class(cfg, i)
-            blk = block_cls(cfg, attn, name=f"block_{i}")
+            blk = block_cls(cfg, attn, i, name=f"block_{i}")
             if cache is not None:
                 x, blk_cache = blk(x, positions, cache[i])
                 new_cache.append(blk_cache)
@@ -485,6 +820,10 @@ class TransformerLM(nn.Module):
                 x = blk(x, positions)
             else:
                 x = blk(x)
+        if streams > 1:
+            # read-out: the streams' sum (the hyper-connections paper's)
+            x = x.astype(jnp.float32).reshape(
+                x.shape[:2] + (streams, cfg.embed_dim)).sum(axis=2)
         x = nn.RMSNorm(epsilon=getattr(cfg, "rms_norm_eps", 1e-6),
                        dtype=cfg.dtype)(x)
         head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,

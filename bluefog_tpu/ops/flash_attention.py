@@ -18,7 +18,9 @@ saved per-row logsumexp (the standard flash backward) — a dq kernel over
 accumulation in f32 in VMEM; nothing S x S ever touches HBM.
 
 Layout: ``(B, S, H, D)`` like ``models.local_attention``; internally
-``(B*H, S, D)``.
+``(B*H, S, D)``.  The values may have a last dim ``Dv`` of their own (latent
+attention: query-key heads of 192, value heads of 128): ``o``, the
+accumulator, ``do`` and ``dv`` take it.
 """
 
 from __future__ import annotations
@@ -105,11 +107,14 @@ def _fit_block(want: int, seq_len: int) -> int:
     return b
 
 
-def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None):
+def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
+         scale=None):
     B, S, H, D = q.shape
-    scale = 1.0 / np.sqrt(D)
+    Dv = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / np.sqrt(D)
     bh = B * H
-    fold = lambda t: t.transpose(0, 2, 1, 3).reshape(bh, S, D)
+    fold = lambda t: t.transpose(0, 2, 1, 3).reshape(bh, S, t.shape[-1])
     qf, kf, vf = fold(q), fold(k), fold(v)
     block_q = _fit_block(block_q, S)
     block_k = _fit_block(block_k, S)
@@ -131,17 +136,17 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None):
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, block_k, D), kv_idx),
-            pl.BlockSpec((None, block_k, D), kv_idx),
+            pl.BlockSpec((None, block_k, Dv), kv_idx),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, S, D), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, S, Dv), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, S, 1), jnp.float32, vma=vma),
         ],
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, Dv), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
@@ -149,7 +154,7 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None):
         interpret=interpret,
     )(qf, kf, vf)
     lse = lse[..., 0]
-    unfold = lambda t: t.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+    unfold = lambda t: t.reshape(B, H, S, Dv).transpose(0, 2, 1, 3)
     return unfold(o), (qf, kf, vf, o, lse, (B, S, H, D, scale, causal))
 
 
@@ -249,9 +254,10 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
     ``d lse_i / d s_ij = p_ij``, so ``ds += dlse_i * p_ij`` — i.e.
     ``delta_eff = delta - dlse``."""
     qf, kf, vf, o, lse, (B, S, H, D, scale, causal) = res
+    Dv = vf.shape[-1]
     do, dlse = cotangents
     bh = B * H
-    dof = do.transpose(0, 2, 1, 3).reshape(bh, S, D)
+    dof = do.transpose(0, 2, 1, 3).reshape(bh, S, Dv)
     delta = jnp.sum(dof.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)               # (bh, S, 1)
     delta = delta - dlse.astype(jnp.float32).transpose(0, 2, 1) \
@@ -260,6 +266,13 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
 
     block_q = _fit_block(block_q, S)
     block_k = _fit_block(block_k, S)
+    if D > 128 and block_q * block_k > 512 * 1024:
+        # Heads wider than one tile of 128 lanes take two: the float32
+        # (block_q, block_k) score tiles beside q, k and their accumulators
+        # at 1024 x 1024 then overflow the 16 MiB of scoped VMEM (the v5e
+        # compiler refuses the dq kernel at D = 192), so the backward takes
+        # half as many keys a tile.
+        block_k = _fit_block(512, S)
     n_qb, n_kb = S // block_q, S // block_k
 
     # index helpers: i = this kernel's "own" block dim, j = reduction dim.
@@ -267,10 +280,10 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
     # range: on skipped (pl.when'd-out) steps the map then repeats the
     # previous block index, so Pallas elides the DMA — without this, masked
     # tiles would still stream their blocks from HBM (~2x input traffic).
-    q_at = lambda sel: pl.BlockSpec((None, block_q, D),
-                                    lambda b, i, j: (b, sel(i, j), 0))
-    k_at = lambda sel: pl.BlockSpec((None, block_k, D),
-                                    lambda b, i, j: (b, sel(i, j), 0))
+    at = lambda block, dim: lambda sel: pl.BlockSpec(
+        (None, block, dim), lambda b, i, j: (b, sel(i, j), 0))
+    q_at, k_at = at(block_q, D), at(block_k, D)
+    do_at, v_at = at(block_q, Dv), at(block_k, Dv)
     r_at = lambda sel: pl.BlockSpec((None, block_q, 1),
                                     lambda b, i, j: (b, sel(i, j), 0))
     own = lambda i, j: i
@@ -290,7 +303,7 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         name="bf_flash_dq", grid=(bh, n_qb, n_kb),
-        in_specs=[q_at(own), k_at(red_dq), k_at(red_dq), q_at(own),
+        in_specs=[q_at(own), k_at(red_dq), v_at(red_dq), do_at(own),
                   r_at(own), r_at(own)],
         out_specs=q_at(own),
         out_shape=jax.ShapeDtypeStruct((bh, S, D), qf.dtype, vma=vma),
@@ -302,20 +315,20 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
         name="bf_flash_dkv", grid=(bh, n_kb, n_qb),
-        in_specs=[q_at(red_kv), k_at(own), k_at(own), q_at(red_kv),
+        in_specs=[q_at(red_kv), k_at(own), v_at(own), do_at(red_kv),
                   r_at(red_kv), r_at(red_kv)],
-        out_specs=[k_at(own), k_at(own)],
+        out_specs=[k_at(own), v_at(own)],
         out_shape=[
             jax.ShapeDtypeStruct((bh, S, D), kf.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, S, D), vf.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, S, Dv), vf.dtype, vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, D), jnp.float32)],
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
         interpret=interpret, **params,
     )(qf, kf, vf, dof, lse3, delta)
 
-    unfold = lambda t, dt: t.reshape(B, H, S, D).transpose(0, 2, 1, 3) \
-        .astype(dt)
+    unfold = lambda t, dt: t.reshape(B, H, S, t.shape[-1]) \
+        .transpose(0, 2, 1, 3).astype(dt)
     return (unfold(dq, qf.dtype), unfold(dk, kf.dtype), unfold(dv, vf.dtype))
 
 
@@ -323,22 +336,27 @@ def _lse_bsh(lse, B, S, H):
     return lse.reshape(B, H, S).transpose(0, 2, 1)         # -> (B, S, H)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, block_q, block_k, interpret, vma=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, block_q, block_k, interpret, vma=None,
+           scale=None):
     out, (_, _, _, _, lse, (B, S, H, _, _, _)) = _fwd(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, vma=vma)
+        interpret=interpret, vma=vma, scale=scale)
     return out, _lse_bsh(lse, B, S, H)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, vma=None):
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, vma=None,
+               scale=None):
     out, res = _fwd(q, k, v, causal=causal, block_q=block_q,
-                    block_k=block_k, interpret=interpret, vma=vma)
+                    block_k=block_k, interpret=interpret, vma=vma,
+                    scale=scale)
     B, S, H = res[5][0], res[5][1], res[5][2]
     return (out, _lse_bsh(res[4], B, S, H)), res
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, vma, res, cotangents):
+def _flash_bwd(causal, block_q, block_k, interpret, vma, scale, res,
+               cotangents):
+    del scale       # the residuals carry the one the forward used
     return _bwd(block_q, block_k, interpret, vma, res, cotangents)
 
 
@@ -359,8 +377,12 @@ def platform_in_use(x) -> str:
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
-                    block_k: int = 1024, interpret: bool = None, vma=None):
-    """Memory-O(S) exact attention; inputs/outputs ``(B, S, H, D)``.
+                    block_k: int = 1024, interpret: bool = None, vma=None,
+                    scale: float = None):
+    """Memory-O(S) exact attention; ``q`` and ``k`` ``(B, S, H, D)``, ``v``
+    ``(B, S, H, Dv)`` (``Dv`` is ``D`` unless the values have a head dim of
+    their own), result ``(B, S, H, Dv)``.  ``scale`` multiplies the scores
+    before the softmax; ``None`` means ``1 / sqrt(D)``.
 
     ``interpret=None`` compiles the Mosaic kernel when the devices in use
     (:func:`platform_in_use`) are TPUs and runs the Pallas interpreter
@@ -374,12 +396,12 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
     10.5 ms at 1024 / 512 / 256 blocks)."""
     return flash_attention_lse(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret,
-                               vma=vma)[0]
+                               vma=vma, scale=scale)[0]
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = True, block_q: int = 1024,
                         block_k: int = 1024, interpret: bool = None,
-                        vma=None):
+                        vma=None, scale: float = None):
     """Like :func:`flash_attention` but also returns the per-row logsumexp
     ``(B, S, H)`` — the merge weight sequence-parallel consumers need
     (``parallel.ring_attention`` combines per-hop partials with it).
@@ -393,13 +415,15 @@ def flash_attention_lse(q, k, v, *, causal: bool = True, block_q: int = 1024,
         interpret = platform_in_use(q) != "tpu"
     if vma is None:
         vma = frozenset().union(*(jax.typeof(t).vma for t in (q, k, v)))
-    return _flash(q, k, v, causal, block_q, block_k, interpret, vma)
+    return _flash(q, k, v, causal, block_q, block_k, interpret, vma,
+                  None if scale is None else float(scale))
 
 
 def flash_attention_impl(block_q: int = 1024, block_k: int = 1024,
                          interpret: bool = None):
     """``attn_impl`` for ``models.TransformerLM`` / ``parallel.ulysses``."""
-    def impl(q, k, v, *, causal=True):
+    def impl(q, k, v, *, causal=True, scale=None):
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k, interpret=interpret)
+                               block_k=block_k, interpret=interpret,
+                               scale=scale)
     return impl

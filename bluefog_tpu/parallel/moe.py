@@ -29,6 +29,18 @@ tpu.megablox``) apply the SwiGLU experts, and the rows return to token
 order weighted by their router probabilities.  All experts live on the
 calling rank; the permutations are gathers in both directions (their
 transposes are written out), so no scatter runs forward or backward.
+
+**A held share** (``dropless_moe(..., held=(first, count))``): the layer is
+told which contiguous range of the ``E`` experts this rank holds, routes over
+all ``E``, and computes the part of the result its own ``count`` experts
+give; the grouped products visit the held experts' rows only and the rows of
+absent experts come back as zeros.  It is what expert parallelism asks of
+the layer, without the exchange: summed over the ranks that share a layer the
+parts are the whole layer's result.  **Sigmoid scoring with a bias**
+(``route_topk(..., scoring="sigmoid", bias=b)``, DeepSeek-V3's ``noaux_tc``):
+the experts are chosen on ``sigmoid(logits) + b`` and weighted by the scores
+alone; ``b`` is state that no gradient reaches and ``update_router_bias``
+moves toward an even load.
 """
 
 from __future__ import annotations
@@ -46,7 +58,8 @@ from bluefog_tpu.utils import telemetry, timeline
 
 __all__ = ["moe_apply", "switch_dispatch", "load_balance_loss",
            "topk_load_balance_loss", "router_z_loss", "route_topk",
-           "grouped_matmul", "dropless_moe", "observe_load", "Routing"]
+           "grouped_matmul", "dropless_moe", "observe_load", "Routing",
+           "update_router_bias"]
 
 # The module, not the package's ``gmm`` (a custom_vjp of its own that names
 # nothing): the kernels are called unjitted so that each takes the name of
@@ -197,18 +210,42 @@ class Routing(NamedTuple):
     z_loss: jax.Array        # router_z_loss of these logits
 
 
-def route_topk(router_logits, k: int, *, renormalize: bool = False
+def route_topk(router_logits, k: int, *, renormalize: bool = False,
+               scoring: str = "softmax", bias=None, scale: float = 1.0
                ) -> Routing:
     """Top-``k`` routing of (T, E) logits with no capacity: softmax in
     float32, the ``k`` largest probabilities of each token (left as they
     are, or ``renormalize``d to sum to one), the ``T * k`` assignments
-    sorted by expert with ties in token order, and the per-expert counts."""
+    sorted by expert with ties in token order, and the per-expert counts.
+
+    ``scoring="sigmoid"``: the scores are ``sigmoid(logits)``, the choice is
+    made on ``scores + bias`` (``bias``: (E,), a constant for the gradient;
+    None = no bias) and the weights are the chosen experts' scores alone,
+    ``renormalize``d as ``w / (sum w + 1e-20)`` and multiplied by ``scale``.
+    ``balance_loss`` then takes the scores normalised over the experts as
+    its probabilities."""
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring {scoring!r} not in ('softmax', 'sigmoid')")
     T, E = router_logits.shape
     logits = router_logits.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = lax.top_k(probs, k)
-    if renormalize:
-        weights = weights / weights.sum(axis=-1, keepdims=True)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        chosen_on = scores if bias is None else scores + lax.stop_gradient(
+            bias.astype(jnp.float32))
+        _, experts = lax.top_k(chosen_on, k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if renormalize:
+            weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+        weights = weights * scale
+        probs = scores / scores.sum(axis=-1, keepdims=True)
+    else:
+        if bias is not None or scale != 1.0:
+            raise ValueError("route_topk: bias and scale belong to "
+                             "scoring='sigmoid'")
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = lax.top_k(probs, k)
+        if renormalize:
+            weights = weights / weights.sum(axis=-1, keepdims=True)
     flat = experts.reshape(-1)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     inverse = jnp.argsort(order).astype(jnp.int32)
@@ -272,8 +309,8 @@ def _kernel(name: str, fn, *args, **kw):
         return fn(*args, **kw)
 
 
-@jax.custom_vjp
-def grouped_matmul(rows, matrices, group_sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_matmul(rows, matrices, group_sizes, first: int = None):
     """``rows[i] @ matrices[group of row i]``: (m, k) rows sorted by group,
     (E, k, n) matrices, (E,) int32 ``group_sizes`` that sum to ``m``;
     returns (m, n) in ``rows``' dtype.  The matrices are cast to that dtype
@@ -281,12 +318,17 @@ def grouped_matmul(rows, matrices, group_sizes):
     in their own dtype.  An empty group costs nothing and a full one takes
     every row: no capacity.
 
+    ``first``: the matrices are those of the groups ``first .. first + n``
+    of the ``E`` in ``group_sizes`` (a held share, ``n <= E``): the kernels
+    visit those groups' rows only, the result's other rows are zero, and
+    the matrices' gradient has ``n`` groups.
+
     Three Pallas kernels (megablox), named in the compiled program
     ``bf_moe_gmm_fwd`` (also the remat recompute), ``bf_moe_gmm_dlhs`` (the
     rows' gradient: the same kernel on the transposed matrices) and
     ``bf_moe_gmm_drhs`` (the matrices' gradient, each group's rows
     contracted).  Off the TPU they run in the Pallas interpreter."""
-    return _grouped_fwd(rows, matrices, group_sizes)[0]
+    return _grouped_fwd(rows, matrices, group_sizes, first)[0]
 
 
 def _padded(rows):
@@ -301,20 +343,27 @@ def _interpret(x) -> bool:
     return platform_in_use(x) != "tpu"
 
 
-def _grouped_fwd(rows, matrices, group_sizes):
+def _share(first) -> dict:
+    """megablox's own argument for a held share of the groups."""
+    return {} if first is None else {"group_offset": jnp.int32(first)}
+
+
+def _grouped_fwd(rows, matrices, group_sizes, first):
     m, k = rows.shape
     n = matrices.shape[2]
     lhs, rhs = _padded(rows), matrices.astype(rows.dtype)
     out = _kernel(
         "bf_moe_gmm_fwd", _megablox.gmm.__wrapped__, lhs, rhs, group_sizes,
         rows.dtype, _tiles(lhs.shape[0], k, n, rows.dtype, whole_k=True),
-        interpret=_interpret(rows))
+        interpret=_interpret(rows), **_share(first))
     # the empty array carries the matrices' dtype to the backward pass
     return out[:m], (lhs, rhs, group_sizes, jnp.zeros((0,), matrices.dtype))
 
 
-def _grouped_bwd(res, d_out):
+def _grouped_bwd(first, res, d_out):
     lhs, rhs, group_sizes, like = res
+    share = _share(first)
+    held = {} if first is None else {"num_actual_groups": rhs.shape[0]}
     interpret = _interpret(d_out)
     m, (k, n) = d_out.shape[0], rhs.shape[1:]
     d_out = _padded(d_out)
@@ -323,19 +372,32 @@ def _grouped_bwd(res, d_out):
         "bf_moe_gmm_dlhs", _megablox.gmm.__wrapped__, d_out, rhs,
         group_sizes, lhs.dtype,
         _tiles(padded, n, k, lhs.dtype, whole_k=True),
-        transpose_rhs=True, interpret=interpret)
+        transpose_rhs=True, interpret=interpret, **share)
     d_matrices = _kernel(
         "bf_moe_gmm_drhs", _megablox.tgmm.__wrapped__, lhs.swapaxes(0, 1),
         d_out, group_sizes, like.dtype,
-        _tiles(padded, k, n, lhs.dtype, whole_k=False), interpret=interpret)
+        _tiles(padded, k, n, lhs.dtype, whole_k=False), interpret=interpret,
+        **share, **held)
     return d_rows[:m], d_matrices, None
 
 
 grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+def update_router_bias(bias, load, rate: float):
+    """The rule that moves a sigmoid router's bias (DeepSeek-V3's
+    auxiliary-loss-free balancing): ``bias_e + rate * sign(mean(load) -
+    load_e)`` over the counts of all ``E`` experts, so an expert that took
+    fewer assignments than the mean is chosen more often at the next step.
+    No gradient is involved; the caller keeps the bias outside the
+    parameter tree."""
+    load = lax.stop_gradient(load).astype(jnp.float32)
+    return bias + rate * jnp.sign(load.mean(axis=-1, keepdims=True) - load)
+
+
 def dropless_moe(x, router_logits, gate, up, down, *, k: int,
-                 renormalize: bool = False):
+                 renormalize: bool = False, held: tuple = None,
+                 scoring: str = "softmax", bias=None, scale: float = 1.0):
     """A dropless top-``k`` mixture of SwiGLU experts on this rank.
 
     ``x``: (T, d) tokens in the compute dtype; ``router_logits``: (T, E);
@@ -347,19 +409,39 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
     losses.  No token is
     dropped at any load: an expert takes as many rows as choose it.
 
+    ``held=(first, count)``: this rank holds the experts ``first .. first +
+    count`` of the ``E`` the router scores, and ``gate``, ``up``, ``down``
+    are theirs, ``(count, ...)``.  The routing is over all ``E`` (and
+    ``routing.load`` counts all ``E``); the three products visit the held
+    experts' rows only (about ``T * k * count / E`` of the ``T * k``), an
+    assignment to an absent expert adds nothing to ``y`` and nothing is
+    computed or stored for it.  ``None``: all ``E`` are held.  ``scoring``,
+    ``bias`` and ``scale`` go to ``route_topk``.
+
     Device scopes: ``bf.moe.route``, ``bf.moe.dispatch``, ``bf.moe.experts``
     and ``bf.moe.combine``; the caller wraps the layer (the router matmul
     included) in ``bf.moe``."""
     T, d = x.shape
     dt = x.dtype
+    first = None
+    if held is not None:
+        first, count = held
+        E = router_logits.shape[-1]
+        if not (0 <= first and 0 < count and first + count <= E
+                and gate.shape[0] == up.shape[0] == down.shape[0] == count):
+            raise ValueError(
+                f"dropless_moe: held={held} of {E} experts with "
+                f"{gate.shape[0]}, {up.shape[0]}, {down.shape[0]} matrices")
     with timeline.device_scope("bf.moe.route"):
-        plan = route_topk(router_logits, k, renormalize=renormalize)
+        plan = route_topk(router_logits, k, renormalize=renormalize,
+                          scoring=scoring, bias=bias, scale=scale)
     with timeline.device_scope("bf.moe.dispatch"):
         rows = _take_rows(x, plan.order, plan.inverse, k)       # (T*k, d)
     with timeline.device_scope("bf.moe.experts"):
-        g = grouped_matmul(rows, gate, plan.load)
-        u = grouped_matmul(rows, up, plan.load)
-        out = grouped_matmul(jax.nn.silu(g) * u, down, plan.load)  # (T*k, d)
+        g = grouped_matmul(rows, gate, plan.load, first)
+        u = grouped_matmul(rows, up, plan.load, first)
+        out = grouped_matmul(jax.nn.silu(g) * u, down, plan.load,
+                             first)                             # (T*k, d)
     with timeline.device_scope("bf.moe.combine"):
         back = _take_rows(out, plan.inverse, plan.order, 1)
         y = (back.reshape(T, k, d).astype(jnp.float32)
@@ -367,16 +449,25 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
     return y.astype(dt), plan
 
 
-def observe_load(load) -> float:
+def observe_load(load, held: tuple = None) -> float:
     """Publish per-expert assignment counts a training loop has fetched:
     ``load`` is ``(E,)`` or ``(..., E)`` (layers, ranks: summed).  Adds to
     the counter ``bf_moe_assignments_total{expert}`` and sets the gauge
     ``bf_moe_load_max_over_mean`` (1 at a perfectly even load, ``E`` when
-    one expert takes everything), which it returns."""
+    one expert takes everything), which it returns.  With ``held=(first,
+    count)`` it also adds the assignments that went to the held experts to
+    ``bf_moe_held_assignments_total`` and sets the gauge
+    ``bf_moe_held_share`` to their share of all (``count / E`` at an even
+    router)."""
     counts = np.asarray(load, np.float64)
     counts = counts.reshape(-1, counts.shape[-1]).sum(axis=0)
     for e, n in enumerate(counts):
         telemetry.inc("bf_moe_assignments_total", float(n), expert=str(e))
+    if held is not None:
+        mine = float(counts[held[0]:held[0] + held[1]].sum())
+        telemetry.inc("bf_moe_held_assignments_total", mine)
+        telemetry.set_gauge("bf_moe_held_share",
+                            mine / counts.sum() if counts.sum() > 0 else 0.0)
     mean = counts.mean()
     ratio = float(counts.max() / mean) if mean > 0 else 0.0
     telemetry.set_gauge("bf_moe_load_max_over_mean", ratio)

@@ -8,7 +8,10 @@ processes.  The readers find the step program's operations by its scopes
 ``bf.moe*``), its spans and its counters, so these cases fail when a library
 change moves one of them: the ledger's per-layer metrics would read ``null``.
 Their tests are collected here under their own names behind the file's;
-``test_cells_cpu.py`` and ``test_moe_cell_cpu.py`` (three minutes) stay by hand.
+``test_cells_cpu.py``, ``test_moe_cell_cpu.py`` (three minutes) and the two
+``test_twin_*`` cases of ``test_xing_cell_cpu.py`` (whose other cases, the cell's
+declaration, its published widths and its two roofline readers, run here) stay
+by hand.
 """
 
 import importlib
@@ -19,9 +22,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-for _file in ("trace_reduce", "program_readers", "dropin"):
+for _file in ("trace_reduce", "program_readers", "dropin", "xing_cell_cpu"):
     _module = importlib.import_module(f"benchmark.selftest.test_{_file}")
     for _name, _obj in vars(_module).items():
+        if _name.startswith("test_twin_"):
+            continue    # a cell's twin end to end, two minutes: by hand
         if _name.startswith("test_"):
             globals()[f"test_{_file}_{_name[len('test_'):]}"] = _obj
         elif type(_obj).__module__ == "_pytest.fixtures":
