@@ -157,28 +157,167 @@ def _tree_sum(terms: list) -> jnp.ndarray:
     return terms[0]
 
 
-def _apply_rounds(x: jnp.ndarray, sched: StaticSchedule, axis_name: str,
-                  idx) -> jnp.ndarray:
-    """``self_scale[i] * x + sum_r ppermute(x * send_scale_r)`` — the weighted
-    neighbor combine, with weights applied source-side (see schedule.py).
-    Permuted terms accumulate via a balanced tree-sum: the old serial chain
-    made round r's add depend on rounds 0..r-1, an artificial dependency
-    the scheduler had to respect."""
-    dt = x.dtype
-    terms = [x * _const(sched.self_scale, dt)[idx]]
-    for rnd in sched.rounds:
-        scaled = x * _const(rnd.send_scale, dt)[idx]
-        terms.append(lax.ppermute(scaled, axis_name, rnd.pairs))
-    return _tree_sum(terms)
+# A part of more bytes than this goes on the wire as blocks of at most this
+# many, cut along its leading axis; each block is a transfer of its own.
+# What follows the last byte of an exchange is then one block's add (0.3 ms
+# for 64 MiB on a v5e) and not the largest part's (3.3 ms for the 758 MB
+# leaves of internlm2-1.8b), and a block is added while the next is on the
+# wire.  Smaller blocks buy nothing more at the end and cost a transfer to
+# issue each; larger ones leave more behind the last byte.  Chosen from one
+# sweep on the chip (PERF.md, PR 32); there is no option over it.
+_BLOCK_BYTES = 64 << 20
+
+# How many pieces ahead of the one being added a piece is made ready to
+# send.  The TPU compiler keeps at most five collective-permutes in flight
+# and a v5e link serves them in the order they start, so with five on the
+# wire and the next one ready the link never waits for the compute units.
+_DEPTH = 6
 
 
-def neighbor_allreduce(x: jnp.ndarray, sched: StaticSchedule,
-                       axis_name: str) -> jnp.ndarray:
+def _nbytes(x) -> int:
+    return int(np.prod(x.shape)) * x.dtype.itemsize
+
+
+def _blocks(x) -> list:
+    """Row ranges ``[(lo, hi), ...]`` of the blocks ``x`` goes on the wire
+    as; one range over all of it for a part of at most :data:`_BLOCK_BYTES`
+    or without an axis to cut.  Blocks are runs of whole rows of the leading
+    axis, a multiple of 8 where a block holds that many (the TPU tiles the
+    last two axes 8 x 128, so such a block is cut out and put back without
+    a change of layout), the last one shorter; no view is reshaped, so a
+    part with few, long rows goes as blocks of one row each, whatever their
+    size."""
+    nbytes = _nbytes(x)
+    if x.ndim == 0 or nbytes <= _BLOCK_BYTES:
+        return [(0, x.shape[0] if x.ndim else 1)]
+    rows = x.shape[0]
+    per = max(1, _BLOCK_BYTES // (nbytes // rows))
+    if per >= 8:
+        per -= per % 8
+    return [(lo, min(lo + per, rows)) for lo in range(0, rows, per)]
+
+
+def _apply_rounds(x, sched: StaticSchedule, axis_name: str, idx, w=None):
+    """``self_scale[i] * x + sum_r ppermute(x * send_scale_r)`` for every
+    part of ``x``, an array or a pytree of arrays — the weighted neighbor
+    combine, with weights applied source-side (see schedule.py; ``w``: a
+    traced (n, n) matrix to take them from instead) — written as ONE
+    pipeline whose order is the wire's.
+
+    *Who orders.*  This function: the parts go on the wire in ascending
+    order of bytes (ties in the order given), so the first byte leaves
+    behind the smallest part's scale and every later part is made ready
+    under the transfers queued before it.  *Who cuts.*  This function: a
+    part over :data:`_BLOCK_BYTES` goes as the blocks of :func:`_blocks`,
+    adjacent in the order, each a ``ppermute`` of its own, and their sums
+    land one by one in the part's result (``dynamic_update_slice`` into a
+    buffer that starts as zeros, which XLA does in place).  *Who chains.*
+    This function: a piece is a part or a block; piece ``m`` is added when
+    it has arrived and piece ``m - 1`` has been added, and that moment also
+    starts piece ``m + _DEPTH`` and scales piece ``m + _DEPTH + 1``
+    (``lax.optimization_barrier`` over the four: a data dependency is all
+    the scheduler respects, and without it the compiler awaits the pieces
+    in an order of its own, or fuses one piece's add with the next one's
+    scale and so sends nothing while it waits).  A piece's terms are summed
+    by :func:`_tree_sum` over the rounds as before.
+
+    Every element sees the multiply, permute and add it saw when parts were
+    exchanged one by one: the result is theirs bit for bit.  A schedule
+    without rounds has no wire and nothing to order: each part is scaled
+    where it stands.  A list of one part under the block size lowers to one
+    scale per round, one permute per round and one sum, with no barrier."""
+    rounds = sched.rounds
+    parts, treedef = jax.tree.flatten(x)
+
+    def scales(dt):
+        if w is None:
+            return (_const(sched.self_scale, dt)[idx],
+                    [_const(rnd.send_scale, dt)[idx] for rnd in rounds])
+        # Static per-round dst of each src (-1 = silent, precomputed on the
+        # round); silent ranks get a zero scale so the value they permute
+        # is masked out.
+        send = []
+        for rnd in rounds:
+            dst = _const(rnd.dst_of, jnp.int32)[idx]
+            send.append(jnp.where(
+                dst >= 0, w[idx, jnp.maximum(dst, 0)], 0.0).astype(dt))
+        return w[idx, idx].astype(dt), send
+
+    if not rounds:
+        return jax.tree.unflatten(
+            treedef, [part * scales(part.dtype)[0] for part in parts])
+    # Where every rank keeps the share it sends (one round, self weight ==
+    # edge weight: the one-peer walks), the kept term IS the sent array and
+    # is not made twice.
+    kept_is_sent = (w is None and len(rounds) == 1 and np.array_equal(
+        sched.self_scale, rounds[0].send_scale))
+    # The pieces in wire order: (part, first row, rows or None for all of
+    # it), and what each waits for: its row offset and, on a part's first
+    # piece, the part's scales.
+    pieces, held = [], []
+    for i in sorted(range(len(parts)), key=lambda i: _nbytes(parts[i])):
+        blocks = _blocks(parts[i])
+        for lo, hi in blocks:
+            pieces.append((i, lo, hi - lo if len(blocks) > 1 else None))
+            held.append((jnp.int32(lo),
+                         None if lo else scales(parts[i].dtype)))
+    whole, ready, kept_of, flying = {}, {}, {}, {}
+
+    def prepare(p, got):
+        """Scale piece ``p``: its part whole on the part's first piece
+        (one fusion with whatever made the part), then its rows of that."""
+        (i, _, rows), (at, scale) = pieces[p], got
+        if scale is not None:
+            sent = [parts[i] * s for s in scale[1]]
+            whole[i] = sent, (sent[0] if kept_is_sent
+                              else parts[i] * scale[0])
+        sent, kept = whole[i]
+        if rows is not None:
+            sent = [lax.dynamic_slice_in_dim(s, at, rows) for s in sent]
+            kept = lax.dynamic_slice_in_dim(kept, at, rows)
+        ready[p], kept_of[p] = sent, (kept, at)
+
+    def start(p, sent):
+        flying[p] = [lax.ppermute(s, axis_name, rnd.pairs)
+                     for s, rnd in zip(sent, rounds)]
+
+    for p in range(min(_DEPTH + 1, len(pieces))):
+        prepare(p, held[p])
+    for p in range(min(_DEPTH, len(pieces))):
+        start(p, ready.pop(p))
+    out = [None] * len(parts)
+    last = None     # the part of the piece added last
+    for m, (i, lo, rows) in enumerate(pieces):
+        arrivals = flying.pop(m)
+        a, b = m + _DEPTH, m + _DEPTH + 1
+        if last is not None or a < len(pieces):
+            done, arrivals, sent, waited = lax.optimization_barrier((
+                None if last is None else out[last], arrivals,
+                ready.pop(a, None), held[b] if b < len(pieces) else None))
+            if last is not None:
+                out[last] = done
+            if sent is not None:
+                start(a, sent)
+            if waited is not None:
+                prepare(b, waited)
+        kept, at = kept_of.pop(m)
+        total = _tree_sum([kept] + arrivals)
+        if rows is not None:
+            total = lax.dynamic_update_slice_in_dim(
+                out[i] if lo else jnp.zeros_like(parts[i]), total, at, 0)
+        out[i], last = total, i
+    return jax.tree.unflatten(treedef, out)
+
+
+def neighbor_allreduce(x, sched: StaticSchedule, axis_name: str):
     """Weighted neighbor averaging over a static topology.
 
     ``out_i = W[i,i] * x_i + sum_{j -> i} W[j,i] * x_j`` with ``W`` baked into
     ``sched``.  One ``lax.ppermute`` per shift-distance class of the topology
     (Exp2 over n ranks: log2(n) permutes, all riding ICI concurrently).
+    ``x`` is an array or a pytree of arrays, the parts of one exchange,
+    which go through one pipeline (:func:`_apply_rounds`: smallest first,
+    a part over :data:`_BLOCK_BYTES` as blocks).
     """
     return _apply_rounds(x, sched, axis_name, _axis_index(axis_name))
 
@@ -298,10 +437,10 @@ def dynamic_sparse_neighbor_allreduce(
     return out
 
 
-def neighbor_allreduce_matrix(x: jnp.ndarray, w: jnp.ndarray,
-                              sched: StaticSchedule,
-                              axis_name: str) -> jnp.ndarray:
-    """Neighbor averaging with a *traced* (n, n) weight matrix ``w``.
+def neighbor_allreduce_matrix(x, w: jnp.ndarray, sched: StaticSchedule,
+                              axis_name: str):
+    """Neighbor averaging with a *traced* (n, n) weight matrix ``w``, of an
+    array or of a pytree of arrays (one pipeline: :func:`_apply_rounds`).
 
     The permutation structure (which edges exist) is static and comes from
     ``sched``; the weights are a runtime argument, so per-step weight mutation
@@ -309,17 +448,7 @@ def neighbor_allreduce_matrix(x: jnp.ndarray, w: jnp.ndarray,
     (README.rst:110-127) — changes no compiled code.  ``w[s, d]`` scales the
     ``s -> d`` edge; ``w[i, i]`` is the self weight.
     """
-    idx = _axis_index(axis_name)
-    dt = x.dtype
-    terms = [x * w[idx, idx].astype(dt)]
-    for rnd in sched.rounds:
-        # Static per-round dst of each src (-1 = silent, precomputed on the
-        # round); silent ranks get a zero scale so the value they permute
-        # is masked out.
-        dst = _const(rnd.dst_of, jnp.int32)[idx]
-        scale = jnp.where(dst >= 0, w[idx, jnp.maximum(dst, 0)], 0.0).astype(dt)
-        terms.append(lax.ppermute(x * scale, axis_name, rnd.pairs))
-    return _tree_sum(terms)
+    return _apply_rounds(x, sched, axis_name, _axis_index(axis_name), w)
 
 
 def dynamic_neighbor_allreduce(x, step: jnp.ndarray, sched: DynamicSchedule,
@@ -333,19 +462,20 @@ def dynamic_neighbor_allreduce(x, step: jnp.ndarray, sched: DynamicSchedule,
 
     ``x`` is an array or a pytree of arrays (the parts of one exchange:
     large leaves and packed buffers).  The phase is chosen ONCE for the whole
-    tree and each branch averages every leaf: the scheduler moves no operation
-    across a ``conditional``, so only inside one branch can one leaf's scale
-    and add run under another leaf's permute, and nothing in front of the
-    switch runs under any.  This is the form for a caller who has only a
+    tree and each branch is the pipeline of :func:`_apply_rounds` over that
+    phase's edges, which orders the parts, cuts the large ones and chains
+    the adds: the scheduler moves no operation across a ``conditional``, so
+    only inside one branch can one part's scale and add run under another's
+    permute, and nothing in front of the switch runs under any.  This is
+    the form for a caller who has only a
     traced counter: the eager op, and ``functional.step_fn`` under a ``jit``
     of the caller's own.  The optimizer classes know the counter on the host
     and do without the switch: one program per phase over that phase's
     :class:`StaticSchedule` (``optim/optimizers.py``).
     """
     idx = _axis_index(axis_name)
-    branches = [partial(jax.tree.map, partial(
-        _apply_rounds, sched=ph, axis_name=axis_name, idx=idx))
-        for ph in sched.phases]
+    branches = [partial(_apply_rounds, sched=ph, axis_name=axis_name, idx=idx)
+                for ph in sched.phases]
     return lax.switch(step % sched.period, branches, x)
 
 

@@ -73,7 +73,12 @@ class Combiner(NamedTuple):
 
     ``combine(parts, step, weights)`` takes the list of arrays one exchange
     moves (:func:`_fused_apply`: the large leaves, then the packed buffer)
-    and returns a list of the same shapes and dtypes.  ``step`` is the
+    and returns a list of the same shapes and dtypes, in the order given.
+    The neighbor combiners hand the list whole to ``ops.collective``, which
+    alone decides the order on the wire (smallest first), cuts a part over
+    its block size into blocks and chains each piece's add behind its own
+    arrival (``collective._apply_rounds``); allreduce and the hierarchical
+    types work part by part (:func:`_per_part`).  ``step`` is the
     traced step counter (dynamic schedules choose their phase by it) and
     ``weights`` an optional traced (n, n) matrix overriding the schedule's
     weights (None: the weights baked in).  ``identity``: it exchanges
@@ -123,8 +128,8 @@ def make_combiner(
         return Combiner(_per_part(_ar), replica_identical=True)
     if comm == CommunicationType.neighbor_allreduce:
         if isinstance(sched, DynamicSchedule):
-            # The one combiner that takes the list whole: it chooses its
-            # phase once, and one lax.switch serves every part.
+            # It chooses its phase once, and one lax.switch serves every
+            # part: each branch is one pipeline over the list.
             def _dyn(parts, step, weights):
                 if weights is None:
                     return C.dynamic_neighbor_allreduce(
@@ -132,20 +137,20 @@ def make_combiner(
                 # Weight override on a dynamic topology: same phase switching,
                 # weights looked up from the traced matrix per active edge.
                 branches = [
-                    partial(lambda ph, args: jax.tree.map(
-                        lambda p: C.neighbor_allreduce_matrix(
-                            p, args[1], ph, axis_name), args[0]), ph)
+                    partial(lambda ph, args: C.neighbor_allreduce_matrix(
+                        args[0], args[1], ph, axis_name), ph)
                     for ph in sched.phases]
                 return lax.switch(step % sched.period, branches,
                                   (parts, weights))
             return Combiner(_dyn, sched=sched, axis_name=axis_name)
         assert sched is not None, "static neighbor_allreduce needs a schedule"
 
-        def _nbr(x, step, weights):
+        def _nbr(parts, step, weights):
             if weights is None:
-                return C.neighbor_allreduce(x, sched, axis_name)
-            return C.neighbor_allreduce_matrix(x, weights, sched, axis_name)
-        return Combiner(_per_part(_nbr), sched=sched, axis_name=axis_name)
+                return C.neighbor_allreduce(parts, sched, axis_name)
+            return C.neighbor_allreduce_matrix(parts, weights, sched,
+                                               axis_name)
+        return Combiner(_nbr, sched=sched, axis_name=axis_name)
     if comm == CommunicationType.hierarchical_gossip:
         assert local_axis and machine_axis, \
             "hierarchical gossip needs local/machine axis names"
@@ -229,8 +234,7 @@ def make_shard_combiner(plan, group_combine, *, axis_name: str):
 _DIRECT_LEAF_BYTES = 1 << 20
 
 
-def _leaf_bytes(leaf) -> int:
-    return int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+_leaf_bytes = C._nbytes
 
 
 def _split_direct(leaves):
@@ -257,8 +261,12 @@ def _fused_apply(fn, tree):
     replacement for the reference's FusionBufferManager + fused-response
     machinery, ``tensor_queue.h:70-92``, ``operations.cc:918-1001``).  The
     leaf's size is all that decides: there is no option over it.  All
-    parts, the large leaves and then the buffer, reach ``fn`` in ONE call,
-    so the dynamic combiner chooses its phase once for all of them."""
+    parts, the large leaves and then the buffer, reach ``fn`` in ONE call
+    and in flatten order.  This function neither orders, cuts nor chains
+    them: a neighbor combiner hands the list to
+    ``ops.collective._apply_rounds``, which sends the parts smallest first,
+    the ones over its block size as blocks, and adds each piece behind its
+    own arrival; the dynamic combiner chooses its phase once for all."""
     from jax.flatten_util import ravel_pytree
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:

@@ -447,28 +447,53 @@ class DistributedOptimizer:
             basics._name_program(run, "optim_init"), mesh=mesh,
             in_specs=(spec,), out_specs=spec))(placed)
 
-    def _record_exchange_paths(self, params, plan, factor) -> None:
+    def _record_exchange_paths(self, params, plan, traffic) -> None:
         """``bf_optim_exchange_leaves/bytes{path}``: how much of one rank's
         exchanged tree the program built last hands to the combiner as it
-        is (``direct``) and how much through a fusion buffer (``packed``).
-        Set on the first step of a built program, from the rule
-        ``functional._fused_apply`` traced it with."""
+        is (``direct``) and how much through a fusion buffer (``packed``),
+        and which of the direct leaves go on the wire as blocks (``cut``);
+        ``bf_optim_exchange_transfers``: the pieces one round of the
+        exchange moves, a cut part counting its blocks.  Set on the first
+        step of a built program, from the rules ``functional._fused_apply``
+        and ``collective._apply_rounds`` traced it with."""
         leaves = [jax.ShapeDtypeStruct(x.shape[1:], x.dtype)
                   for x in jax.tree_util.tree_leaves(params)]
         if plan is not None and plan.any_sharded:
             leaves = [l for l, m in zip(leaves, plan.mask) if not m]
-        if not factor:      # the identity combine exchanges nothing
+        if not traffic["factor"]:   # the identity combine exchanges nothing
             leaves = []
         # gradient_allreduce leaves a mixed-dtype tree unpacked
         packs = (self.order != "gradient_allreduce"
                  or len({l.dtype for l in leaves}) <= 1)
         direct, packed = (F._split_direct(leaves) if packs
                           else (range(len(leaves)), ()))
-        for path, idx in (("direct", direct), ("packed", packed)):
+        # Only the flat neighbor combiners hand their parts to the pipeline
+        # that cuts; under bf16 it cuts what the codec hands on, and over a
+        # schedule without rounds (one rank) there is no wire to cut for.
+        stats = traffic["sched_stats"]
+        wired = stats is None or stats[0] > 0
+        cuts = (wired and self.communication_type ==
+                CommunicationType.neighbor_allreduce
+                and self.compression in ("none", "bf16"))
+
+        def pieces(part) -> int:
+            if self.compression == "bf16":
+                part = jax.ShapeDtypeStruct(part.shape, jnp.bfloat16)
+            return len(C._blocks(part)) if cuts else 1
+        parts = [leaves[i] for i in direct]
+        if packed:
+            parts.append(jax.ShapeDtypeStruct(
+                (sum(int(np.prod(leaves[i].shape)) for i in packed),),
+                jnp.result_type(*(leaves[i].dtype for i in packed))))
+        cut = [i for i in direct if pieces(leaves[i]) > 1]
+        for path, idx in (("direct", direct), ("packed", packed),
+                          ("cut", cut)):
             telemetry.set_gauge("bf_optim_exchange_leaves", len(idx),
                                 path=path)
             telemetry.set_gauge("bf_optim_exchange_bytes", sum(
                 F._leaf_bytes(leaves[i]) for i in idx), path=path)
+        telemetry.set_gauge("bf_optim_exchange_transfers",
+                            sum(pieces(part) for part in parts) * wired)
 
     def _dispatch(self, params, grads, state, w):
         """Place the trees, launch the step program and book what it puts
@@ -501,7 +526,7 @@ class DistributedOptimizer:
                     plan.rep_bytes if plan is not None and plan.any_sharded
                     else sum(x.nbytes for x in
                              jax.tree_util.tree_leaves(params)))
-                self._record_exchange_paths(params, plan, traffic["factor"])
+                self._record_exchange_paths(params, plan, traffic)
             telemetry.record_comm_traffic(
                 "optimizer_step", traffic["nbytes"] * traffic["factor"],
                 size=basics.size(), sched_stats=traffic["sched_stats"],

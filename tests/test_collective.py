@@ -600,3 +600,145 @@ def test_dynamic_sparse_partial_block_oracle(devices):
                                        0.5 * qi + 0.5 * qj,
                                        rtol=1e-5, atol=1e-6)
             np.testing.assert_allclose(np.asarray(q)[i], qi, rtol=1e-6)
+
+
+# --- the exchange of a list of parts is one pipeline (C._apply_rounds) -------
+
+# Per-rank shapes of one exchange: a leading axis the block rows do not
+# divide, a 1-D part, a scalar, few rows longer than a block, a part under
+# the block size, and the largest last but one in the list.
+_PIPE_SHAPES = [(13, 6), (50,), (), (2, 40), (3,), (9, 4, 2)]
+_PIPE_BLOCK = 64        # bytes: every shape but () and (3,) is cut
+
+
+def _pipe_parts(dtype, shapes=_PIPE_SHAPES, seed=0):
+    import jax.numpy as jnp
+    rng = np.random.RandomState(seed)
+    return [jnp.asarray(rng.randn(N, *s), jnp.float32).astype(dtype)
+            for s in shapes]
+
+
+def _pipe_case(kind):
+    """``(fn(parts) -> parts, W)`` of one way to reach the pipeline, where
+    ``fn`` runs inside ``shard_map`` on per-rank parts and ``W`` is the
+    mixing matrix it applies (``out = W^T x``)."""
+    import jax.numpy as jnp
+    from bluefog_tpu.ops import collective as C
+    G = topo.ExponentialTwoGraph(N)
+    if kind == "static":            # three rounds a call
+        sched = S.compile_static(G, use_topo_weights=True)
+        assert len(sched.rounds) == 3
+        return (lambda p: C.neighbor_allreduce(p, sched, "dp"),
+                topo.weight_matrix(G))
+    dyn = S.compile_dynamic(topo.dynamic_phase_table(G), N)
+    w = np.zeros((N, N))
+    for i in range(N):              # phase 1 of the one-peer walk: i -> i+2
+        w[i, i] = w[i, (i + 2) % N] = 0.5
+    if kind == "phase":
+        return lambda p: C.neighbor_allreduce(p, dyn.phases[1], "dp"), w
+    if kind == "switch":
+        return (lambda p: C.dynamic_neighbor_allreduce(
+            p, jnp.asarray(4, jnp.int32), dyn, "dp"), w)
+    assert kind == "override"
+    over = np.random.RandomState(3).uniform(0.2, 0.8, (N, N))
+    used = np.zeros((N, N))
+    for i in range(N):
+        used[i, i], used[i, (i + 2) % N] = over[i, i], over[i, (i + 2) % N]
+    return (lambda p: C.neighbor_allreduce_matrix(
+        p, jnp.asarray(over, jnp.float32), dyn.phases[1], "dp"), used)
+
+
+def _pipe_run(devices, fn, parts):
+    import jax
+    from jax.sharding import Mesh, PartitionSpec as P
+    mesh = Mesh(np.asarray(devices), ("dp",))
+    spec = [P("dp")] * len(parts)
+    return jax.jit(jax.shard_map(
+        lambda *p: [o[None] for o in fn([x[0] for x in p])], mesh=mesh,
+        in_specs=tuple(spec), out_specs=spec, check_vma=False))(*parts)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("kind", ["static", "phase", "switch", "override"])
+def test_cut_exchange_is_the_uncut_exchange_bit_for_bit(
+        monkeypatch, devices, kind, dtype):
+    """A list with cut parts, ordered and chained, gives every part the
+    bits of that part exchanged alone and whole, and ``W^T x`` written out:
+    over several rounds a call, one phase of a dynamic schedule, the traced
+    ``lax.switch`` and a weight override."""
+    from bluefog_tpu.ops import collective as C
+    fn, w = _pipe_case(kind)
+    parts = _pipe_parts(dtype)
+    monkeypatch.setattr(C, "_BLOCK_BYTES", _PIPE_BLOCK)
+    assert [len(C._blocks(p[0])) for p in parts] == (
+        [7, 4, 1, 2, 1, 5] if dtype == "float32" else [3, 2, 1, 2, 1, 3])
+    cut = _pipe_run(devices, fn, parts)
+    monkeypatch.setattr(C, "_BLOCK_BYTES", 1 << 40)
+    for x, got in zip(parts, cut):
+        alone, = _pipe_run(devices, fn, [x])
+        assert got.dtype == x.dtype and got.shape == x.shape
+        np.testing.assert_array_equal(np.asarray(got.astype("float32")),
+                                      np.asarray(alone.astype("float32")))
+        np.testing.assert_allclose(
+            np.asarray(got.astype("float32")),
+            _expected_neighbor_allreduce(
+                np.asarray(x.astype("float32")), w),
+            rtol=2e-2 if dtype == "bfloat16" else 1e-5,
+            atol=2e-2 if dtype == "bfloat16" else 1e-6)
+
+
+@pytest.mark.parametrize("shape", [(13, 6), (50,), ()])
+def test_a_list_of_one_part_cut_or_not(monkeypatch, devices, shape):
+    """One part alone: cut where it is over the block size (a scalar never
+    is), and the same bits either way."""
+    from bluefog_tpu.ops import collective as C
+    fn, _ = _pipe_case("static")
+    part = _pipe_parts("float32", [shape])
+    monkeypatch.setattr(C, "_BLOCK_BYTES", _PIPE_BLOCK)
+    cut, = _pipe_run(devices, fn, part)
+    monkeypatch.setattr(C, "_BLOCK_BYTES", 1 << 40)
+    whole, = _pipe_run(devices, fn, part)
+    np.testing.assert_array_equal(np.asarray(cut), np.asarray(whole))
+
+
+def test_blocks_are_whole_rows_of_the_leading_axis():
+    """The rule of ``_blocks``: at most the block size where a row allows
+    it, a multiple of 8 rows where a block holds 8, the last block shorter;
+    a part is never reshaped, so rows longer than a block go one by one;
+    the size is 64 MiB."""
+    import jax
+    import jax.numpy as jnp
+    from bluefog_tpu.ops import collective as C
+    assert C._BLOCK_BYTES == 64 << 20
+
+    def blocks(shape, dtype=jnp.float32):
+        return C._blocks(jax.ShapeDtypeStruct(shape, dtype))
+    assert blocks((2048, 8192)) == [(0, 2048)]              # 64 MiB: whole
+    big = blocks((92544, 2048))                             # 8 KiB a row
+    assert big[0] == (0, 8192) and big[-1] == (90112, 92544)
+    assert len(big) == 12
+    wide = blocks((2048, 92544))                            # 361.5 KiB a row
+    assert wide[0] == (0, 176) and wide[-1] == (1936, 2048)
+    assert len(wide) == 12
+    assert blocks((3, 40 << 20)) == [(0, 1), (1, 2), (2, 3)]
+    assert blocks((1 << 25,), jnp.bfloat16) == [(0, 1 << 25)]
+    assert blocks((1 << 26,), jnp.bfloat16) == [(0, 1 << 25),
+                                                (1 << 25, 1 << 26)]
+    assert blocks(()) == [(0, 1)]
+
+
+def test_one_part_under_the_block_size_lowers_without_a_barrier(devices):
+    """The eager op on one small array: a scale and a permute a round and
+    one sum, as before the pipeline."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, PartitionSpec as P
+    fn, _ = _pipe_case("static")
+    mesh = Mesh(np.asarray(devices), ("dp",))
+    text = jax.jit(jax.shard_map(
+        lambda x: fn(x[0])[None], mesh=mesh, in_specs=P("dp"),
+        out_specs=P("dp"), check_vma=False)).lower(
+            jnp.zeros((N, 4, 4), jnp.float32)).as_text()
+    assert text.count("stablehlo.collective_permute") == 3
+    assert "optimization_barrier" not in text
+    assert "dynamic_update_slice" not in text

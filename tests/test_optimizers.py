@@ -120,6 +120,13 @@ def _set_threshold(monkeypatch, nbytes):
     monkeypatch.setattr(functional, "_DIRECT_LEAF_BYTES", nbytes)
 
 
+def _set_block(monkeypatch, nbytes):
+    """Parts of more than ``nbytes`` go on the wire as blocks of at most
+    that many (``collective._BLOCK_BYTES``; 64 MiB on the chip)."""
+    from bluefog_tpu.ops import collective
+    monkeypatch.setattr(collective, "_BLOCK_BYTES", nbytes)
+
+
 @pytest.mark.parametrize("order", ["awc", "atc"])
 @pytest.mark.parametrize("dynamic", [False, True])
 def test_fusion_matches_unfused(monkeypatch, order, dynamic):
@@ -234,6 +241,124 @@ def test_direct_threshold_is_one_mebibyte_inclusive():
     assert F._split_direct(leaves) == ([0, 2], [1, 3])
 
 
+_CUT_CASES = pytest.mark.parametrize("order,dynamic,override,kw", [
+    ("atc", False, False, {}),
+    ("awc", False, False, {}),
+    ("atc", True, False, {}),
+    ("awc", True, False, {}),
+    ("atc", True, True, {}),
+    ("awc", False, True, {}),
+    ("atc", True, False, {"compression": "bf16"}),
+    ("awc", False, False, {"compression": "bf16"}),
+    ("atc", True, False, {"donate": True}),
+], ids=lambda v: v if isinstance(v, str) else
+    (("dynamic" if v else "static") if isinstance(v, bool) else
+     "-".join(f"{k}={x}" for k, x in v.items()) or "plain"))
+
+
+@_CUT_CASES
+def test_cut_leaves_match_uncut(monkeypatch, order, dynamic, override, kw):
+    """With blocks of 64 bytes the two direct leaves of the tree go as 4
+    and 3 blocks, ordered and chained with the packed buffer; the
+    parameters after four steps are bit for bit those of the same tree
+    with no leaf cut: static rounds, the per-phase programs, a weight
+    override, ``bf16`` on top, ``atc`` and ``awc``."""
+    bf.init(lambda: topo.ExponentialTwoGraph(N))
+    _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    w = _override_matrix() if override else None
+
+    def run():
+        params, grads = _split_problem()
+        opt = bf.optim.DistributedOptimizer(
+            optax.sgd(0.05, momentum=0.9),
+            CommunicationType.neighbor_allreduce, order=order,
+            use_dynamic_topology=dynamic, **kw)
+        state = opt.init(params)
+        for _ in range(4):
+            params, state = opt.step(
+                params, jax.tree.map(jnp.copy, grads), state, src_weights=w)
+        return {k: np.asarray(v) for k, v in params.items()}
+    _set_block(monkeypatch, 64)
+    cut = run()
+    _set_block(monkeypatch, 1 << 40)
+    whole = run()
+    # Under ``bf16`` the two are held to bfloat16 rounding: XLA keeps a
+    # fused bfloat16 multiply-add in float32 and rounds where a fusion ends
+    # (``xla_allow_excess_precision``; with it off the bits are equal), and
+    # a block's sum ends its fusion where the whole part's does not.
+    tol = 2e-2 if kw.get("compression") == "bf16" else 0.0
+    for k in cut:
+        np.testing.assert_allclose(cut[k], whole[k], rtol=tol, atol=tol,
+                                   err_msg=k)
+
+
+def _permuted_types(text):
+    """The operand types of a lowered program's ``collective_permute``s,
+    in program order."""
+    import re
+    return re.findall(
+        r"stablehlo\.collective_permute.*?: \(tensor<([^>]*)>\)", text)
+
+
+@pytest.mark.parametrize("order", ["atc", "awc"])
+def test_permutes_lower_in_wire_order(monkeypatch, order):
+    """In the step program's StableHLO the permutes stand in ascending
+    order of their part's bytes, whatever the flatten order, with a cut
+    leaf's blocks adjacent and in row order (the last one shorter)."""
+    bf.init(lambda: topo.ExponentialTwoGraph(N))
+    _set_threshold(monkeypatch, 48)
+    _set_block(monkeypatch, 64)
+    rng = np.random.RandomState(1)
+    shapes = {"a_wide": (7, 12),    # 336 B: rows of 48 B, seven blocks
+              "b_bias": (3,),       # packed
+              "c_square": (9, 8),   # 288 B: rows of 32 B, 2 + 2 + 2 + 2 + 1
+              "d_whole": (4, 4),    # 64 B: direct, not cut
+              "e_bias": (5,)}       # packed: 32 B with b_bias
+    params = {k: jnp.asarray(rng.randn(N, *v), jnp.float32)
+              for k, v in shapes.items()}
+    opt = bf.optim.DistributedOptimizer(
+        optax.sgd(0.05), CommunicationType.neighbor_allreduce, order=order,
+        use_dynamic_topology=True)
+    text = _lowered_step(opt, params, params)
+    assert _permuted_types(text) == (
+        ["8xf32", "4x4xf32"] + ["2x8xf32"] * 4 + ["1x8xf32"]
+        + ["1x12xf32"] * 7)
+    assert "stablehlo.optimization_barrier" in text
+
+
+@pytest.mark.parametrize("order,base,kw", [
+    ("atc", "sgdm", {"use_dynamic_topology": True}),
+    ("atc", "adamw", {"use_dynamic_topology": True}),
+    ("awc", "sgdm", {"use_dynamic_topology": True}),
+    ("atc", "sgdm", {}),
+    ("atc", "sgdm", {"use_dynamic_topology": True, "compression": "bf16"}),
+], ids=["atc-dynamic", "adamw", "awc", "static", "bf16"])
+def test_one_device_step_holds_no_pipeline(monkeypatch, order, base, kw):
+    """On one device a schedule has no round and the exchange no wire: the
+    step program scales each part where it stands, in flatten order, and
+    lowers to the same text whatever the block size, with no barrier, no
+    block written back and no permute (the one-chip cells run this
+    program)."""
+    bf.init(devices=jax.devices()[:1])
+    assert bf.size() == 1
+    _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    rng = np.random.RandomState(2)
+    params = {k: jnp.asarray(rng.randn(1, *v), jnp.float32)
+              for k, v in {"a": (8, 8), "b": (3,), "d": (6, 8)}.items()}
+    make = {"sgdm": lambda: optax.sgd(0.05, momentum=0.9),
+            "adamw": lambda: optax.adamw(1e-3)}[base]
+    texts = []
+    for block in (64, 1 << 40):
+        _set_block(monkeypatch, block)
+        opt = bf.optim.DistributedOptimizer(
+            make(), CommunicationType.neighbor_allreduce, order=order, **kw)
+        texts.append(_lowered_step(opt, params, params))
+    assert texts[0] == texts[1]
+    for word in ("collective_permute", "optimization_barrier",
+                 "dynamic_update_slice"):
+        assert word not in texts[0], word
+
+
 def _lowered_step(opt, params, grads):
     return opt._step_callable(with_weights=False).lower(
         params, grads, opt.init(params)).as_text()
@@ -243,9 +368,11 @@ def _lowered_step(opt, params, grads):
 def test_dynamic_exchange_is_one_switch(monkeypatch, large):
     """The one phase switch of a dynamic topology is the host's: the class
     builds a step program per phase, none holds a ``case``, and each holds
-    one permute for every large leaf and one for the packed buffer."""
+    the permutes the pipeline's rule gives: two blocks for every large leaf
+    (256 bytes against blocks of 128) and one for the packed buffer."""
     bf.init(lambda: topo.ExponentialTwoGraph(N))
     _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    _set_block(monkeypatch, 128)
     rng = np.random.RandomState(0)
     params = {f"w{i}": jnp.asarray(rng.randn(N, 8, 8), jnp.float32)
               for i in range(large)}
@@ -261,7 +388,7 @@ def test_dynamic_exchange_is_one_switch(monkeypatch, large):
     assert len(set(texts)) == period        # each its own peers
     for text in texts:
         assert "stablehlo.case" not in text
-        assert text.count("stablehlo.collective_permute") == large + 1
+        assert text.count("stablehlo.collective_permute") == 2 * large + 1
 
 
 def _traced_switch_step(order, base, n_w):
@@ -499,19 +626,34 @@ def test_sparse_compression_with_direct_leaf_reaches_consensus(
         np.testing.assert_allclose(v.mean(axis=0), mean[k], atol=1e-4)
 
 
-@pytest.mark.parametrize("threshold,kw,want", [
-    (_SMALL_THRESHOLD, {}, {"direct": (2, 448), "packed": (3, 36)}),
-    (0, {}, {"direct": (5, 484), "packed": (0, 0)}),
-    (_SMALL_THRESHOLD, {"communication_type": CommunicationType.empty},
-     {"direct": (0, 0), "packed": (0, 0)}),
-], ids=["fused", "unfused", "identity"])
-def test_exchange_path_gauges(monkeypatch, threshold, kw, want):
+@pytest.mark.parametrize("threshold,block,kw,want,transfers", [
+    (_SMALL_THRESHOLD, None, {},
+     {"direct": (2, 448), "packed": (3, 36), "cut": (0, 0)}, 3),
+    (_SMALL_THRESHOLD, 64, {},
+     {"direct": (2, 448), "packed": (3, 36), "cut": (2, 448)}, 8),
+    (_SMALL_THRESHOLD, 64, {"compression": "bf16"},
+     {"direct": (2, 448), "packed": (3, 36), "cut": (2, 448)}, 5),
+    (0, None, {},
+     {"direct": (5, 484), "packed": (0, 0), "cut": (0, 0)}, 5),
+    (_SMALL_THRESHOLD, 64, {"communication_type": CommunicationType.empty},
+     {"direct": (0, 0), "packed": (0, 0), "cut": (0, 0)}, 0),
+    (_SMALL_THRESHOLD, 64,
+     {"communication_type": CommunicationType.allreduce},
+     {"direct": (2, 448), "packed": (3, 36), "cut": (0, 0)}, 3),
+], ids=["fused", "cut", "cut-bf16", "unfused", "identity", "allreduce"])
+def test_exchange_path_gauges(monkeypatch, threshold, block, kw, want,
+                              transfers):
     """``bf_optim_exchange_leaves/bytes{path}`` read the leaves and the
-    per-rank bytes of the tree the step program was built for."""
+    per-rank bytes of the tree the step program was built for, ``cut``
+    those of the direct leaves that go as blocks (256 and 192 bytes against
+    64: four and three blocks; as bfloat16 two each), and
+    ``bf_optim_exchange_transfers`` the pieces a round moves."""
     from bluefog_tpu.utils import telemetry
     bf.init(lambda: topo.ExponentialTwoGraph(N))
     telemetry.reset()
     _set_threshold(monkeypatch, threshold)
+    if block is not None:
+        _set_block(monkeypatch, block)
     params, grads = _split_problem()
     opt = bf.optim.DistributedOptimizer(optax.sgd(0.05), **kw)
     opt.step(params, grads, opt.init(params))
@@ -519,6 +661,25 @@ def test_exchange_path_gauges(monkeypatch, threshold, kw, want):
     for path, (leaves, nbytes) in want.items():
         assert snap[f'bf_optim_exchange_leaves{{path="{path}"}}'] == leaves
         assert snap[f'bf_optim_exchange_bytes{{path="{path}"}}'] == nbytes
+    assert snap["bf_optim_exchange_transfers"] == transfers
+
+
+def test_one_rank_cuts_and_transfers_nothing(monkeypatch):
+    """One rank: the schedule has no round, so no leaf is cut and no piece
+    moves, whatever the block size (the one-chip cells read 0)."""
+    from bluefog_tpu.utils import telemetry
+    bf.init(devices=jax.devices()[:1])
+    telemetry.reset()
+    _set_threshold(monkeypatch, _SMALL_THRESHOLD)
+    _set_block(monkeypatch, 64)
+    params, grads = jax.tree.map(lambda x: x[:1], _split_problem())
+    opt = bf.optim.DistributedOptimizer(optax.sgd(0.05),
+                                        use_dynamic_topology=True)
+    opt.step(params, grads, opt.init(params))
+    snap = telemetry.snapshot()
+    assert snap['bf_optim_exchange_leaves{path="cut"}'] == 0
+    assert snap['bf_optim_exchange_bytes{path="cut"}'] == 0
+    assert snap["bf_optim_exchange_transfers"] == 0
 
 
 def test_bucket_groups_partitioning():
