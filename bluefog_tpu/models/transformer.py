@@ -23,10 +23,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bluefog_tpu.utils import timeline
+from bluefog_tpu.utils import telemetry, timeline
 
 __all__ = ["TransformerLM", "TransformerConfig", "local_attention",
-           "init_cache", "generate", "DroplessMoe", "moe_stats"]
+           "init_cache", "generate", "DroplessMoe", "moe_stats", "ShortConv",
+           "head_matrix"]
 
 
 def local_attention(q, k, v, *, causal: bool = True, scale: float = None):
@@ -61,7 +62,9 @@ class TransformerConfig:
                  qk_nope_head_dim=None, qk_rope_head_dim=None,
                  v_head_dim=None, rope_scaling=None, hyper_streams=1,
                  hyper_sinkhorn_iters=20, hyper_eps=1e-6,
-                 hyper_res_clamp=(-30.0, 30.0)):
+                 hyper_res_clamp=(-30.0, 30.0), layer_types=None,
+                 conv_kernel=3, tie_embeddings=False,
+                 router_renorm_eps=1e-20):
         self.vocab_size = vocab_size
         self.num_layers = num_layers
         self.num_heads = num_heads
@@ -113,8 +116,15 @@ class TransformerConfig:
         # coef * load-balancing loss and coef * router z-loss (OLMoE's).
         self.router_aux_loss_coef = router_aux_loss_coef
         self.router_z_loss_coef = router_z_loss_coef
-        # RMSNorm over the WHOLE projected q and k (all heads together)
-        # before the head split and the rotary embedding (OLMoE, OLMo 2).
+        # RMSNorm of q and k before the rotary embedding, in one of two
+        # forms: True = over the WHOLE projection, all heads together, with
+        # a scale of its width (OLMoE, OLMo 2); "head" = over each head's
+        # own values, with ONE scale of the head dim that the heads share
+        # (LFM2).
+        if qk_norm not in (False, True, "head"):
+            raise ValueError(
+                f"qk_norm {qk_norm!r} not in (False, True, 'head'): True "
+                "norms the whole projection, 'head' each head's values")
         self.qk_norm = qk_norm
         self.rms_norm_eps = rms_norm_eps
         self.embed_dim = embed_dim
@@ -231,6 +241,27 @@ class TransformerConfig:
         self.hyper_sinkhorn_iters = hyper_sinkhorn_iters
         self.hyper_eps = hyper_eps
         self.hyper_res_clamp = tuple(hyper_res_clamp)
+        # The token mixer of each block, one entry a layer: "full_attention"
+        # (what the other fields describe) or "conv", a gated short
+        # convolution of ``conv_kernel`` taps (``ShortConv``).  None = all
+        # attention.
+        if layer_types is not None:
+            layer_types = tuple(layer_types)
+            odd = set(layer_types) - {"conv", "full_attention"}
+            if len(layer_types) != num_layers or odd:
+                raise ValueError(
+                    f"layer_types needs num_layers ({num_layers}) entries "
+                    f"of 'conv' or 'full_attention'; got {len(layer_types)}"
+                    + (f" with {sorted(odd)}" if odd else ""))
+        if conv_kernel < 1:
+            raise ValueError(f"conv_kernel must be >= 1; got {conv_kernel}")
+        self.layer_types = layer_types
+        self.conv_kernel = conv_kernel
+        # The output head is the transposed embedding: no ``lm_head`` leaf.
+        self.tie_embeddings = tie_embeddings
+        # What a sigmoid router adds to the sum of the chosen scores before
+        # it divides by it (``norm_topk_prob``).
+        self.router_renorm_eps = router_renorm_eps
 
 
 class SwitchMlp(nn.Module):
@@ -340,6 +371,7 @@ class DroplessMoe(nn.Module):
         if getattr(cfg, "router_scoring", "softmax") == "sigmoid":
             routing.update(
                 scoring="sigmoid", scale=cfg.routed_scaling_factor,
+                renorm_eps=getattr(cfg, "router_renorm_eps", 1e-20),
                 bias=self.variable("router_state", "bias", jnp.zeros, (E,),
                                    jnp.float32).value)
         with timeline.device_scope("bf.moe"):
@@ -589,6 +621,47 @@ class HyperConnection(nn.Module):
         return u.astype(x.dtype), mix
 
 
+class ShortConv(nn.Module):
+    """Gated short convolution (LFM2's ``conv`` layers): the token mixer of
+    a block whose ``cfg.layer_types`` entry is ``"conv"``.
+
+    ``[B, C, X] = split_3(y in)``; ``u = B * X``; ``c_t = sum_j w[:, j] *
+    u_{t - (L - 1) + j}`` over the ``L = cfg.conv_kernel`` taps, each channel
+    on its own (depthwise), causal, zeros left of the sequence; the result
+    is ``(C * c) out``.  No bias, no normalisation and no activation inside.
+    Leaves: ``in/kernel`` ``(d, 3 d)``, ``w`` ``(d, L)`` and ``out/kernel``
+    ``(d, d)``.  The taps are ``L`` shifted multiply-adds in plain
+    ``jax.numpy``, in ``cfg.dtype``.  Training and prefill only: the layer
+    keeps no state for decoding.
+
+    Device scopes: ``bf.sconv.in``, ``bf.sconv.conv`` (both gates and the
+    convolution) and ``bf.sconv.out``."""
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, y):
+        cfg = self.cfg
+        d, taps = cfg.embed_dim, cfg.conv_kernel
+        S = y.shape[1]
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+        with timeline.device_scope("bf.sconv.in"):
+            gates = dense(3 * d, name="in")(y)
+        # fan-in of one channel: its ``taps`` values
+        w = self.param("w", nn.initializers.lecun_normal(in_axis=1,
+                                                         out_axis=0),
+                       (d, taps)).astype(cfg.dtype)
+        with timeline.device_scope("bf.sconv.conv"):
+            b, c, x = jnp.split(gates, 3, axis=-1)
+            u = b * x
+            conv = w[:, taps - 1] * u
+            for back in range(1, taps):
+                conv = conv + w[:, taps - 1 - back] * jnp.pad(
+                    u, ((0, 0), (back, 0), (0, 0)))[:, :S]
+            gated = c * conv
+        with timeline.device_scope("bf.sconv.out"):
+            return dense(d, name="out")(gated)
+
+
 def block_class(cfg, layer_idx: int = None):
     """The (possibly remat-wrapped) Block class for a config — shared by
     ``TransformerLM`` and ``models.vit.ViT`` so ``remat_policy`` behaves
@@ -628,7 +701,16 @@ class Block(nn.Module):
         new token per sequence (S == 1) written at position ``positions``
         and attended against the cache — returns ``(x, new_cache)``.  The
         cache stores the kv_h *shared* heads, so GQA shrinks it by
-        ``h / kv_h`` (the reason GQA exists)."""
+        ``h / kv_h`` (the reason GQA exists).
+
+        The mixer is the block's entry of ``cfg.layer_types`` (None: every
+        block attends).  Only plain attention (MHA / GQA) takes a cache;
+        latent attention and the gated short convolution keep no decode
+        state and raise on one.
+
+        Device scopes of the plain attention branch: ``bf.attn.qkv``,
+        ``bf.attn.norm``, ``bf.attn.rope``, ``bf.attn.attend`` (the K/V
+        fan-out and the attention itself) and ``bf.attn.out``."""
         cfg = self.cfg
         h = cfg.num_heads
         d = cfg.embed_dim // h
@@ -641,40 +723,65 @@ class Block(nn.Module):
         x, join = self._residual(x, "hc_attn")
         y = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype)(x)
         B, S = y.shape[0], y.shape[1]
-        if getattr(cfg, "kv_lora_rank", None) is not None:
+        layer_types = getattr(cfg, "layer_types", None)
+        conv = layer_types is not None \
+            and layer_types[self.layer_idx] == "conv"
+        latent = getattr(cfg, "kv_lora_rank", None) is not None
+        if cache is not None and (conv or latent):
+            raise NotImplementedError(
+                "only plain attention takes a decode cache: latent "
+                "attention and the gated short convolution do not")
+        if conv:
+            x = join(ShortConv(cfg, name="conv")(y))
+            return self._ffn(x, eps)
+        if latent:
             x = join(LatentAttention(cfg, self.attn_impl, name="mla")(
                 y, positions))
             return self._ffn(x, eps)
-        if kv_h == h:
-            qkv = nn.Dense(3 * cfg.embed_dim, use_bias=False,
-                           dtype=cfg.dtype, name="qkv")(y)
-            # Head-interleaved fused layout [q_h0 k_h0 v_h0 | q_h1 ...]: a
-            # pure relabeling of kernel columns that keeps tensor-parallel
-            # shard boundaries (tp_param_specs' column split) aligned to
-            # heads, so GSPMD runs attention head-parallel with one psum
-            # per block instead of per-activation resharding.
-            qkv = qkv.reshape(B, S, h, 3, d)
-            q, k1, v1 = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
-        else:
-            # GQA: h query heads, kv_h shared K/V heads (same interleaved
-            # column layout per projection; head-aligned TP only up to
-            # kv_h ways — beyond that GSPMD re-gathers K/V per block,
-            # acceptable since the kv kernel is the small one).
-            q = nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
-                         name="q")(y).reshape(B, S, h, d)
-            kv = nn.Dense(2 * kv_h * d, use_bias=False, dtype=cfg.dtype,
-                          name="kv")(y).reshape(B, S, kv_h, 2, d)
-            k1, v1 = kv[..., 0, :], kv[..., 1, :]
-        if getattr(cfg, "qk_norm", False):
-            # over the whole projection, all heads together, before rope
-            q = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype, name="q_norm")(
-                q.reshape(B, S, h * d)).reshape(B, S, h, d)
-            k1 = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype, name="k_norm")(
-                k1.reshape(B, S, kv_h * d)).reshape(B, S, kv_h, d)
+        with timeline.device_scope("bf.attn.qkv"):
+            if kv_h == h:
+                qkv = nn.Dense(3 * cfg.embed_dim, use_bias=False,
+                               dtype=cfg.dtype, name="qkv")(y)
+                # Head-interleaved fused layout [q_h0 k_h0 v_h0 | q_h1 ...]:
+                # a pure relabeling of kernel columns that keeps tensor-
+                # parallel shard boundaries (tp_param_specs' column split)
+                # aligned to heads, so GSPMD runs attention head-parallel
+                # with one psum per block instead of per-activation
+                # resharding.
+                qkv = qkv.reshape(B, S, h, 3, d)
+                q, k1, v1 = qkv[..., 0, :], qkv[..., 1, :], qkv[..., 2, :]
+            else:
+                # GQA: h query heads, kv_h shared K/V heads (same
+                # interleaved column layout per projection; head-aligned TP
+                # only up to kv_h ways — beyond that GSPMD re-gathers K/V
+                # per block, acceptable since the kv kernel is the small
+                # one).
+                q = nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
+                             name="q")(y).reshape(B, S, h, d)
+                kv = nn.Dense(2 * kv_h * d, use_bias=False, dtype=cfg.dtype,
+                              name="kv")(y).reshape(B, S, kv_h, 2, d)
+                k1, v1 = kv[..., 0, :], kv[..., 1, :]
+        qk_norm = getattr(cfg, "qk_norm", False)
+        with timeline.device_scope("bf.attn.norm"):
+            norm = functools.partial(nn.RMSNorm, epsilon=eps,
+                                     dtype=cfg.dtype)
+            if qk_norm == "head":
+                # over each head's own d values, one scale of d for all
+                # the heads, before rope
+                q = norm(name="q_norm")(q)
+                k1 = norm(name="k_norm")(k1)
+            elif qk_norm:
+                # over the whole projection, all heads together, before
+                # rope
+                q = norm(name="q_norm")(
+                    q.reshape(B, S, h * d)).reshape(B, S, h, d)
+                k1 = norm(name="k_norm")(
+                    k1.reshape(B, S, kv_h * d)).reshape(B, S, kv_h, d)
         if rope:
             # rotate the kv_h shared heads ONCE, before any fan-out to h
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k1 = apply_rope(k1, positions, cfg.rope_theta)
+            with timeline.device_scope("bf.attn.rope"):
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k1 = apply_rope(k1, positions, cfg.rope_theta)
         rep = h // kv_h
         if cache is None:
             if (self.is_mutable_collection("kv_cache")
@@ -684,10 +791,11 @@ class Block(nn.Module):
                 # Gated out of init(), which would otherwise bake a stale
                 # entry into the variables users carry around.
                 self.sow("kv_cache", "kv_entries", (k1, v1))
-            k = jnp.repeat(k1, rep, axis=2) if rep > 1 else k1
-            v = jnp.repeat(v1, rep, axis=2) if rep > 1 else v1
-            attn = self.attn_impl(
-                q, k, v, causal=getattr(self.cfg, "causal", True))
+            with timeline.device_scope("bf.attn.attend"):
+                k = jnp.repeat(k1, rep, axis=2) if rep > 1 else k1
+                v = jnp.repeat(v1, rep, axis=2) if rep > 1 else v1
+                attn = self.attn_impl(
+                    q, k, v, causal=getattr(self.cfg, "causal", True))
         else:
             ck, cv = cache
             idx = positions[0, 0]  # decode positions are batch-uniform
@@ -699,16 +807,19 @@ class Block(nn.Module):
             # grouped attention of the single query over the cache — never
             # materializes h-head K/V
             L = ck.shape[1]
-            qg = q.reshape(B, S, kv_h, rep, d)
-            logits = jnp.einsum("bqgrd,blgd->bgrql", qg, ck) / np.sqrt(d)
-            mask = (jnp.arange(L) <= idx)[None, None, None, None, :]
-            logits = jnp.where(mask, logits.astype(jnp.float32),
-                               jnp.finfo(jnp.float32).min)
-            probs = nn.softmax(logits, axis=-1).astype(cfg.dtype)
-            attn = jnp.einsum("bgrql,blgd->bqgrd", probs, cv)
-        attn = attn.reshape(B, S, cfg.embed_dim)
-        x = join(nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
-                          name="proj")(attn))
+            with timeline.device_scope("bf.attn.attend"):
+                qg = q.reshape(B, S, kv_h, rep, d)
+                logits = jnp.einsum("bqgrd,blgd->bgrql", qg, ck) \
+                    / np.sqrt(d)
+                mask = (jnp.arange(L) <= idx)[None, None, None, None, :]
+                logits = jnp.where(mask, logits.astype(jnp.float32),
+                                   jnp.finfo(jnp.float32).min)
+                probs = nn.softmax(logits, axis=-1).astype(cfg.dtype)
+                attn = jnp.einsum("bgrql,blgd->bqgrd", probs, cv)
+        with timeline.device_scope("bf.attn.out"):
+            attn = attn.reshape(B, S, cfg.embed_dim)
+            x = join(nn.Dense(cfg.embed_dim, use_bias=False,
+                              dtype=cfg.dtype, name="proj")(attn))
         x = self._ffn(x, eps)
         return x if cache is None else (x, cache)
 
@@ -770,6 +881,13 @@ class TransformerLM(nn.Module):
         cfg = self.cfg
         attn = self.attn_impl or local_attention
         streams = getattr(cfg, "hyper_streams", 1)
+        mixers = getattr(cfg, "layer_types", None) \
+            or ("full_attention",) * cfg.num_layers
+        for kind in ("conv", "full_attention"):
+            # what was built last, by kind of mixer; set, not added to: a
+            # model is traced more than once
+            telemetry.set_gauge("bf_model_layers_total",
+                                mixers.count(kind), mixer=kind)
         if cache is not None:
             if getattr(cfg, "num_experts", 0) > 0:
                 raise NotImplementedError(
@@ -778,6 +896,10 @@ class TransformerLM(nn.Module):
                 raise NotImplementedError(
                     "KV-cache decoding with latent attention or several "
                     "residual streams is not supported")
+            if "conv" in mixers:
+                raise NotImplementedError(
+                    "KV-cache decoding through a gated short convolution "
+                    "is not supported: the layer keeps no decode state")
             if not getattr(cfg, "causal", True):
                 raise ValueError(
                     "KV-cache decoding requires causal=True: the decode "
@@ -794,8 +916,9 @@ class TransformerLM(nn.Module):
                     "cache decoding requires explicit positions (the "
                     "cache write index); defaulting to 0 would overwrite "
                     "slot 0 every step")
-        x = nn.Embed(cfg.vocab_size, cfg.embed_dim,
-                     dtype=cfg.dtype, name="wte")(tokens)
+        wte = nn.Embed(cfg.vocab_size, cfg.embed_dim, dtype=cfg.dtype,
+                       name="wte")
+        x = wte(tokens)
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :]
         rope = getattr(cfg, "pos_encoding", "learned") == "rope"
@@ -826,14 +949,34 @@ class TransformerLM(nn.Module):
                 x.shape[:2] + (streams, cfg.embed_dim)).sum(axis=2)
         x = nn.RMSNorm(epsilon=getattr(cfg, "rms_norm_eps", 1e-6),
                        dtype=cfg.dtype)(x)
-        head = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-                        name="lm_head")
-        if return_hidden:
-            head(x[:, :1])  # materialize the lm_head param without S x V
-            return x
+        if getattr(cfg, "tie_embeddings", False):
+            # the embedding leaf serves both ends: its gradient is the sum
+            # of the lookup's and the head's (``head_matrix``)
+            def head(h):
+                return jnp.dot(h.astype(jnp.float32),
+                               wte.embedding.astype(jnp.float32).T)
+            if return_hidden:
+                return x
+        else:
+            head = nn.Dense(cfg.vocab_size, use_bias=False,
+                            dtype=jnp.float32, name="lm_head")
+            if return_hidden:
+                head(x[:, :1])  # materialize the lm_head param without S x V
+                return x
         if cache is not None:
             return head(x), new_cache
         return head(x)
+
+
+def head_matrix(cfg, params):
+    """The ``(embed_dim, vocab)`` output matrix of a ``TransformerLM``'s
+    parameters, for ``ops.chunked_loss.chunked_softmax_cross_entropy`` beside
+    ``return_hidden=True``: the ``lm_head`` kernel, or under
+    ``cfg.tie_embeddings`` the transposed embedding (a view of the one leaf:
+    no second copy in the tree, no second optimizer state)."""
+    if getattr(cfg, "tie_embeddings", False):
+        return params["wte"]["embedding"].T
+    return params["lm_head"]["kernel"]
 
 
 def init_cache(cfg, batch: int, max_len: int):

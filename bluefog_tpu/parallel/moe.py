@@ -211,8 +211,8 @@ class Routing(NamedTuple):
 
 
 def route_topk(router_logits, k: int, *, renormalize: bool = False,
-               scoring: str = "softmax", bias=None, scale: float = 1.0
-               ) -> Routing:
+               scoring: str = "softmax", bias=None, scale: float = 1.0,
+               renorm_eps: float = 1e-20) -> Routing:
     """Top-``k`` routing of (T, E) logits with no capacity: softmax in
     float32, the ``k`` largest probabilities of each token (left as they
     are, or ``renormalize``d to sum to one), the ``T * k`` assignments
@@ -221,7 +221,8 @@ def route_topk(router_logits, k: int, *, renormalize: bool = False,
     ``scoring="sigmoid"``: the scores are ``sigmoid(logits)``, the choice is
     made on ``scores + bias`` (``bias``: (E,), a constant for the gradient;
     None = no bias) and the weights are the chosen experts' scores alone,
-    ``renormalize``d as ``w / (sum w + 1e-20)`` and multiplied by ``scale``.
+    ``renormalize``d as ``w / (sum w + renorm_eps)`` (DeepSeek-V3's 1e-20;
+    LFM2 takes 1e-6) and multiplied by ``scale``.
     ``balance_loss`` then takes the scores normalised over the experts as
     its probabilities."""
     if scoring not in ("softmax", "sigmoid"):
@@ -235,7 +236,8 @@ def route_topk(router_logits, k: int, *, renormalize: bool = False,
         _, experts = lax.top_k(chosen_on, k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
         if renormalize:
-            weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+            weights = weights / (weights.sum(axis=-1, keepdims=True)
+                                 + renorm_eps)
         weights = weights * scale
         probs = scores / scores.sum(axis=-1, keepdims=True)
     else:
@@ -397,7 +399,8 @@ def update_router_bias(bias, load, rate: float):
 
 def dropless_moe(x, router_logits, gate, up, down, *, k: int,
                  renormalize: bool = False, held: tuple = None,
-                 scoring: str = "softmax", bias=None, scale: float = 1.0):
+                 scoring: str = "softmax", bias=None, scale: float = 1.0,
+                 renorm_eps: float = 1e-20):
     """A dropless top-``k`` mixture of SwiGLU experts on this rank.
 
     ``x``: (T, d) tokens in the compute dtype; ``router_logits``: (T, E);
@@ -416,7 +419,7 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
     experts' rows only (about ``T * k * count / E`` of the ``T * k``), an
     assignment to an absent expert adds nothing to ``y`` and nothing is
     computed or stored for it.  ``None``: all ``E`` are held.  ``scoring``,
-    ``bias`` and ``scale`` go to ``route_topk``.
+    ``bias``, ``scale`` and ``renorm_eps`` go to ``route_topk``.
 
     Device scopes: ``bf.moe.route``, ``bf.moe.dispatch``, ``bf.moe.experts``
     and ``bf.moe.combine``; the caller wraps the layer (the router matmul
@@ -434,7 +437,8 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
                 f"{gate.shape[0]}, {up.shape[0]}, {down.shape[0]} matrices")
     with timeline.device_scope("bf.moe.route"):
         plan = route_topk(router_logits, k, renormalize=renormalize,
-                          scoring=scoring, bias=bias, scale=scale)
+                          scoring=scoring, bias=bias, scale=scale,
+                          renorm_eps=renorm_eps)
     with timeline.device_scope("bf.moe.dispatch"):
         rows = _take_rows(x, plan.order, plan.inverse, k)       # (T*k, d)
     with timeline.device_scope("bf.moe.experts"):

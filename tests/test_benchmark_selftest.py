@@ -9,9 +9,9 @@ processes.  The readers find the step program's operations by its scopes
 change moves one of them: the ledger's per-layer metrics would read ``null``.
 Their tests are collected here under their own names behind the file's;
 ``test_cells_cpu.py``, ``test_moe_cell_cpu.py`` (three minutes) and the two
-``test_twin_*`` cases of ``test_xing_cell_cpu.py`` (whose other cases, the cell's
-declaration, its published widths and its two roofline readers, run here) stay
-by hand.
+``test_twin_*`` cases each of ``test_xing_cell_cpu.py`` and
+``test_lfm2_cell_cpu.py`` (whose other cases, the cell's declaration, its
+published widths and its roofline readers, run here) stay by hand.
 """
 
 import importlib
@@ -22,7 +22,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-for _file in ("trace_reduce", "program_readers", "dropin", "xing_cell_cpu"):
+for _file in ("trace_reduce", "program_readers", "dropin", "xing_cell_cpu",
+              "lfm2_cell_cpu"):
     _module = importlib.import_module(f"benchmark.selftest.test_{_file}")
     for _name, _obj in vars(_module).items():
         if _name.startswith("test_twin_"):

@@ -212,21 +212,42 @@ def _layer(E=8, d=16, f=8, tokens=64):
     return x, logits, gate, up, normal(14, (E, f, d), 0.3)
 
 
-def test_the_shares_add_up_to_the_uncut_layer_of_the_reference(toy):
-    """Eight experts in four shares of two, the shared expert counted once:
-    the sum is what the reference gives when it is told that it holds all
-    eight."""
-    config, _, ref = toy
+# (the configuration whose reference is asked, its keys for the experts held
+# and their width, its renormalisation's epsilon, whether it has a shared
+# expert)
+SHARED_CASES = {
+    "xing4.0-29b-a4b": dict(
+        held_key="n_routed_experts", scale=2.0, eps=1e-20, shared=True,
+        extra={}),
+    "lfm2-24b-a2b": dict(
+        held_key="num_experts", scale=1.0, eps=1e-6, shared=False,
+        extra={"router_width": 8, "num_experts_per_tok": 2,
+               "moe_intermediate_size": 32, "norm_topk_prob": True,
+               "use_expert_bias": True, "router_renorm_eps": 1e-6,
+               "routed_scaling_factor": 1.0}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SHARED_CASES))
+def test_the_shares_add_up_to_the_uncut_layer_of_the_reference(toy, name):
+    """Eight experts in four shares of two, a shared expert (where the
+    configuration has one) counted once: the sum is what the configuration's
+    reference gives when it is told that it holds all eight."""
+    config, _, _ = toy
+    case = SHARED_CASES[name]
+    ref = spec.load_module(f"reference/{name}.py")
+    held = case["held_key"]
     x, logits, gate, up, down = _layer(d=64, f=32)
     router = normal(15, (64, 8), 0.2)
     shared = {f"shared_{n}": {"kernel": normal(16 + i, s, 0.2)} for i, (n, s)
               in enumerate((("gate", (64, 32)), ("up", (64, 32)),
-                            ("down", (32, 64))))}
+                            ("down", (32, 64))))} if case["shared"] else {}
     bias = normal(19, (8,), 0.1)
-    whole = dict(config, n_routed_experts=8, experts_first=0)
+    whole = dict(config, **case["extra"], **{held: 8}, experts_first=0)
     params = dict(shared, router={"kernel": router}, gate=gate, up=up,
                   down=down)
-    kw = dict(k=2, renormalize=True, scoring="sigmoid", bias=bias, scale=2.0)
+    kw = dict(k=2, renormalize=True, scoring="sigmoid", bias=bias,
+              scale=case["scale"], renorm_eps=case["eps"])
     with HIGHEST():
         want, load, _ = ref._experts(x[None], params, bias, whole)
         logits = x @ router
@@ -234,12 +255,12 @@ def test_the_shares_add_up_to_the_uncut_layer_of_the_reference(toy):
                                   down[i:i + 2], held=(i, 2), **kw)
                  for i in range(0, 8, 2)]
         once = ref._swiglu(x, *(shared[f"shared_{n}"]["kernel"]
-                                for n in ("gate", "up", "down")))
+                                for n in ("gate", "up", "down"))) \
+            if case["shared"] else 0.0
         # one share alone is what the reference gives for that share
         share = dict(params, gate=gate[2:4], up=up[2:4], down=down[2:4])
         alone, _, _ = ref._experts(
-            x[None], share, bias, dict(whole, n_routed_experts=2,
-                                       experts_first=2))
+            x[None], share, bias, dict(whole, **{held: 2}, experts_first=2))
     got = sum(y for y, _ in parts) + once
     np.testing.assert_allclose(got, want[0], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(parts[1][0] + once, alone[0], rtol=1e-5,
