@@ -33,10 +33,21 @@ transposes are written out), so no scatter runs forward or backward.
 **A held share** (``dropless_moe(..., held=(first, count))``): the layer is
 told which contiguous range of the ``E`` experts this rank holds, routes over
 all ``E``, and computes the part of the result its own ``count`` experts
-give; the grouped products visit the held experts' rows only and the rows of
-absent experts come back as zeros.  It is what expert parallelism asks of
-the layer, without the exchange: summed over the ranks that share a layer the
-parts are the whole layer's result.  **Sigmoid scoring with a bias**
+give.  It is what expert parallelism asks of the layer, without the
+exchange: summed over the ranks that share a layer the parts are the whole
+layer's result.  The sort puts the held experts' rows in one run of the
+order, whose place is data (``load[:first].sum()``) and whose length is
+bounded by a shape: the share works on a **window** of ``C =
+held_window(T * k, count, E)`` rows from the run's start (twice the even
+share, in whole 256-row tiles).  It gathers ``C`` rows of ``x``, runs the
+three grouped products and the SwiGLU over ``(C, .)`` arrays with the held
+groups' sizes, and each token selects its held assignments' rows from the
+``(C, d)`` result; every other slot adds zero.  A step whose run is longer
+than ``C`` takes, behind one ``lax.cond``, the overflow branch: the same
+work window after window until the run is covered, so nothing is dropped at
+any load and no capacity enters the result.  Where ``C`` would be all ``T *
+k`` rows (half the experts held, or all) there is no window and the layer is
+the dropless layer above.  **Sigmoid scoring with a bias**
 (``route_topk(..., scoring="sigmoid", bias=b)``, DeepSeek-V3's ``noaux_tc``):
 the experts are chosen on ``sigmoid(logits) + b`` and weighted by the scores
 alone; ``b`` is state that no gradient reaches and ``update_router_bias``
@@ -59,7 +70,7 @@ from bluefog_tpu.utils import telemetry, timeline
 __all__ = ["moe_apply", "switch_dispatch", "load_balance_loss",
            "topk_load_balance_loss", "router_z_loss", "route_topk",
            "grouped_matmul", "dropless_moe", "observe_load", "Routing",
-           "update_router_bias"]
+           "update_router_bias", "held_window"]
 
 # The module, not the package's ``gmm`` (a custom_vjp of its own that names
 # nothing): the kernels are called unjitted so that each takes the name of
@@ -306,6 +317,23 @@ def _tiles(rows: int, k: int, n: int, dtype, *, whole_k: bool) -> tuple:
     return tm, _fit(k, wide if whole_k else wide // 2), _fit(n, wide // 2)
 
 
+def held_window(assignments: int, count: int, experts: int) -> int:
+    """Rows of the window a share of ``count`` of ``experts`` experts works
+    on when the layer routes ``assignments`` rows: twice the even share, in
+    whole 256-row tiles (the kernels' row tile, ``_tiles``), at most all of
+    them.  Twice, because a router that balances stays inside it (a layer
+    of ``lfm2-s8192-1chip`` sent its held eighth at most 1.26 times the
+    even share in PR 35's runs, and ``update_router_bias`` pulls every
+    expert toward the mean) while the window is still a quarter of the rows
+    at an eighth held; a step over it takes several windows and loses
+    nothing (5 of 160 layer-steps in ``xing4-s4096-1chip``, whose router
+    collapses on uniform ids).
+    ``dropless_moe`` sizes its window and ``observe_load`` counts the
+    overflows by this one rule."""
+    even = -(-assignments * count // experts)
+    return min(assignments, -(-2 * even // 256) * 256)
+
+
 def _kernel(name: str, fn, *args, **kw):
     with timeline.device_scope(name):
         return fn(*args, **kw)
@@ -385,6 +413,14 @@ def _grouped_bwd(first, res, d_out):
 
 grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
 
+# The same two functions behind ``jax.jit`` for the held window, whose
+# program calls each product from four places (the first pass, the remat
+# recompute and both branches): a jitted function is traced and lowered
+# once for each shape, where a kernel that is called bare is lowered again
+# at every call (0.6 s each in a warm ``setup_s`` of the benchmark).
+_product = jax.jit(_grouped_fwd, static_argnums=3)
+_product_transpose = jax.jit(_grouped_bwd, static_argnums=0)
+
 
 def update_router_bias(bias, load, rate: float):
     """The rule that moves a sigmoid router's bias (DeepSeek-V3's
@@ -415,11 +451,21 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
     ``held=(first, count)``: this rank holds the experts ``first .. first +
     count`` of the ``E`` the router scores, and ``gate``, ``up``, ``down``
     are theirs, ``(count, ...)``.  The routing is over all ``E`` (and
-    ``routing.load`` counts all ``E``); the three products visit the held
-    experts' rows only (about ``T * k * count / E`` of the ``T * k``), an
-    assignment to an absent expert adds nothing to ``y`` and nothing is
-    computed or stored for it.  ``None``: all ``E`` are held.  ``scoring``,
-    ``bias``, ``scale`` and ``renorm_eps`` go to ``route_topk``.
+    ``routing.load`` counts all ``E``); an assignment to an absent expert
+    adds nothing to ``y`` and nothing is computed or stored for it.  The
+    dispatch, the products, the SwiGLU and the combine's transpose work on
+    a window of ``held_window(T * k, count, E)`` rows of the sorted
+    assignments (twice the even share ``T * k * count / E``, in 256-row
+    tiles), which begins at the held experts' first row; the two gathers
+    that produce ``(T, k, d)`` from the token side stay.  When a step
+    sends the held experts more rows than the window has, the layer covers
+    their run window after window (the overflow branch of its one
+    ``lax.cond``): ``y`` and every gradient are then what one window as
+    long as the run would give, the tokens' sums to the bit.  With a window
+    as large as ``T * k`` (``count * 2 >= E``) the layer is the one it is
+    with ``held=None`` over the held matrices.  ``None``: all ``E`` are
+    held.  ``scoring``, ``bias``, ``scale`` and ``renorm_eps`` go to
+    ``route_topk``.
 
     Device scopes: ``bf.moe.route``, ``bf.moe.dispatch``, ``bf.moe.experts``
     and ``bf.moe.combine``; the caller wraps the layer (the router matmul
@@ -439,18 +485,280 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
         plan = route_topk(router_logits, k, renormalize=renormalize,
                           scoring=scoring, bias=bias, scale=scale,
                           renorm_eps=renorm_eps)
+    share = _whole_share
+    if held is not None and held_window(T * k, count, E) < T * k:
+        share = _held_share
+    return share(x, plan.weights, gate, up, down, plan.order, plan.inverse,
+                 plan.load, k, first), plan
+
+
+def _combine(back, weights, dtype):
+    """A token's ``k`` rows of ``back`` (T*k, d), weighted and summed in
+    float32 in slot order."""
+    T, k = weights.shape
+    y = (back.reshape(T, k, -1).astype(jnp.float32)
+         * weights[..., None]).sum(axis=1)
+    return y.astype(dtype)
+
+
+def _swiglu(g, u):
+    return jax.nn.silu(g) * u
+
+
+def _whole_share(x, weights, gate, up, down, order, inverse, load, k: int,
+                 first):
+    """The held experts' part of the layer's result over all ``T * k``
+    sorted assignments: every row is gathered, the products visit the held
+    groups' rows (``first``: ``grouped_matmul``'s) and zero the others, and
+    every row returns to its token.  The layer where all experts are held,
+    or so many that the window would be the whole order."""
     with timeline.device_scope("bf.moe.dispatch"):
-        rows = _take_rows(x, plan.order, plan.inverse, k)       # (T*k, d)
+        rows = _take_rows(x, order, inverse, k)                 # (T*k, d)
     with timeline.device_scope("bf.moe.experts"):
-        g = grouped_matmul(rows, gate, plan.load, first)
-        u = grouped_matmul(rows, up, plan.load, first)
-        out = grouped_matmul(jax.nn.silu(g) * u, down, plan.load,
-                             first)                             # (T*k, d)
+        g = grouped_matmul(rows, gate, load, first)
+        u = grouped_matmul(rows, up, load, first)
+        out = grouped_matmul(_swiglu(g, u), down, load, first)  # (T*k, d)
     with timeline.device_scope("bf.moe.combine"):
-        back = _take_rows(out, plan.inverse, plan.order, 1)
-        y = (back.reshape(T, k, d).astype(jnp.float32)
-             * plan.weights[..., None]).sum(axis=1)
-    return y.astype(dt), plan
+        return _combine(_take_rows(out, inverse, order, 1), weights,
+                        x.dtype)
+
+
+class _Window(NamedTuple):
+    """``C`` consecutive rows of the sorted assignments, from inside the
+    held experts' run on."""
+    start: jax.Array    # () int32: its first row in the sorted order
+    rows: jax.Array     # () int32: how many of its rows the run covers
+    sizes: jax.Array    # (count,) int32: the held groups' rows inside it
+
+
+class _Saved(NamedTuple):
+    """What a window's transpose needs of its forward, all ``(C, .)``."""
+    win: jax.Array      # (C,) int32: its assignments, ``t * k + j``
+    rows: jax.Array     # (C, d): their tokens' rows of ``x``
+    g: jax.Array        # (C, f)
+    u: jax.Array        # (C, f)
+    out: jax.Array      # (C, d): the experts' result
+
+
+def _held_rows(load, first: int, count: int):
+    with timeline.device_scope("bf.moe.dispatch"):
+        return load[first:first + count].sum()
+
+
+def _window(load, first: int, count: int, size: int, index) -> _Window:
+    """The ``index``-th window of ``size`` rows over the held run; the
+    first is the whole run when the run fits."""
+    with timeline.device_scope("bf.moe.dispatch"):
+        held = load[first:first + count]
+        ends, lo = jnp.cumsum(held), index * size
+        sizes = (jnp.clip(ends, lo, lo + size)
+                 - jnp.clip(ends - held, lo, lo + size))
+        return _Window(load[:first].sum() + lo, sizes.sum(), sizes)
+
+
+def _from_window(values, inverse, w: _Window):
+    """Each assignment's row of ``values`` (window order, (C, ...)), zeros
+    for the assignments outside the window's held rows: a select and no
+    product, so whatever the kernels left in the rows of the window that
+    belong to no group reaches no sum."""
+    at = inverse - w.start
+    mine = (at >= 0) & (at < w.rows)
+    took = values[jnp.clip(at, 0, values.shape[0] - 1)]
+    return jnp.where(mine.reshape(mine.shape + (1,) * (values.ndim - 1)),
+                     took, jnp.zeros((), values.dtype))
+
+
+def _cast(matrices, dtype) -> tuple:
+    """The held matrices in the compute dtype, once for both branches."""
+    with timeline.device_scope("bf.moe.experts"):
+        return tuple(m.astype(dtype) for m in matrices)
+
+
+def _sizes(cast, order, load) -> tuple:
+    """How many experts are held, the window's rows, and the rows of a
+    buffer of whole windows that holds any run."""
+    count = cast[0].shape[0]
+    size = held_window(order.shape[0], count, load.shape[0])
+    return count, size, -(-order.shape[0] // size) * size
+
+
+def _window_experts(x, cast, order, load, k, first, index=0):
+    """One window's rows through the held experts (``cast``: their three
+    matrices in ``x``'s dtype)."""
+    count, size, _ = _sizes(cast, order, load)
+    w = _window(load, first, count, size, index)
+    with timeline.device_scope("bf.moe.dispatch"):
+        # padded, so that a window at the tail of the order is not clamped
+        # back; what it takes past the run belongs to no group
+        win = lax.dynamic_slice(
+            jnp.concatenate([order, jnp.zeros((size,), order.dtype)]),
+            (w.start,), (size,))
+        rows = x[win // k]                                      # (C, d)
+    with timeline.device_scope("bf.moe.experts"):
+        g = _product(rows, cast[0], w.sizes, None)[0]
+        u = _product(rows, cast[1], w.sizes, None)[0]
+        out = _product(_swiglu(g, u), cast[2], w.sizes, None)[0]
+    return w, _Saved(win, rows, g, u, out)
+
+
+def _window_transpose(saved: _Saved, w: _Window, cast, like, dy, weights, k):
+    """A window's part of the transpose, in window order: the gradients
+    of its rows (C, d) and of its assignments' weights (C,), and of the
+    three matrices (in the dtype of ``like``, their own)."""
+    with timeline.device_scope("bf.moe.combine"):
+        dyw = dy[saved.win // k].astype(jnp.float32)            # (C, d)
+        d_out = (dyw * weights.reshape(-1)[saved.win][:, None]).astype(
+            dy.dtype)
+        d_weights = (dyw * saved.out.astype(jnp.float32)).sum(axis=-1)
+    with timeline.device_scope("bf.moe.experts"):
+        h, swiglu_t = jax.vjp(_swiglu, saved.g, saved.u)
+        d_h, d_down, _ = _product_transpose(
+            None, (h, cast[2], w.sizes, like), d_out)
+        d_g, d_u = swiglu_t(d_h)
+        d_rows_u, d_up, _ = _product_transpose(
+            None, (saved.rows, cast[1], w.sizes, like), d_u)
+        d_rows_g, d_gate, _ = _product_transpose(
+            None, (saved.rows, cast[0], w.sizes, like), d_g)
+    return d_rows_u + d_rows_g, d_weights, d_gate, d_up, d_down
+
+
+def _to_tokens(d_rows, d_weights, inverse, w: _Window, weights, dtype):
+    """The gradients of ``x`` and of the weights from those of a window's
+    (or the run's) rows and assignments."""
+    T, k = weights.shape
+    with timeline.device_scope("bf.moe.combine"):
+        d_weights = _from_window(d_weights, inverse, w).reshape(T, k)
+    with timeline.device_scope("bf.moe.dispatch"):
+        return _from_window(d_rows, inverse, w).reshape(T, k, -1).sum(
+            axis=1, dtype=dtype), d_weights
+
+
+def _window_fwd(x, weights, cast, order, inverse, load, k, first):
+    """The share of a step whose held run fits the window."""
+    w, saved = _window_experts(x, cast, order, load, k, first)
+    with timeline.device_scope("bf.moe.combine"):
+        return _combine(_from_window(saved.out, inverse, w), weights,
+                        x.dtype), saved
+
+
+def _window_bwd(weights, cast, like, inverse, load, k, first, saved, dy):
+    w = _window(load, first, cast[0].shape[0], saved.win.shape[0], 0)
+    d_rows, d_weights, *d_matrices = _window_transpose(
+        saved, w, cast, like, dy, weights, k)
+    return _to_tokens(d_rows, d_weights, inverse, w, weights,
+                      dy.dtype) + tuple(d_matrices)
+
+
+def _overflow(load, first: int, count: int, size: int, rows: int, window,
+              *carry):
+    """``window(index, *carry)`` over the windows of ``size`` rows that
+    the held run takes, and the run itself as the one window of the
+    ``rows``-long buffers they filled."""
+    with timeline.device_scope("bf.moe.dispatch"):
+        windows = (_held_rows(load, first, count) + size - 1) // size
+    carry = lax.fori_loop(0, windows, lambda i, c: window(i, *c), carry)
+    return _window(load, first, count, rows, 0), carry
+
+
+def _overflow_fwd(x, weights, cast, order, inverse, load, k, first):
+    """The share of a step whose held run is longer than the window:
+    window after window into one buffer in the run's order, from which
+    every assignment selects its row as it does from the one window; no row
+    is dropped at any load and the tokens' sums are the same."""
+    count, size, rows = _sizes(cast, order, load)
+
+    def window(index, out):
+        _, saved = _window_experts(x, cast, order, load, k, first, index)
+        with timeline.device_scope("bf.moe.experts"):
+            return lax.dynamic_update_slice(out, saved.out,
+                                            (index * size, 0)),
+    run, (out,) = _overflow(load, first, count, size, rows, window,
+                            jnp.zeros((rows, x.shape[1]), x.dtype))
+    with timeline.device_scope("bf.moe.combine"):
+        return _combine(_from_window(out, inverse, run), weights, x.dtype)
+
+
+def _overflow_bwd(x, weights, cast, like, order, inverse, load, k, first,
+                  dy):
+    """Its transpose keeps nothing of its forward: each window is run
+    again (the step is the rare one) and the matrices' gradients add
+    up."""
+    count, size, rows = _sizes(cast, order, load)
+
+    def window(index, d_rows, d_weights, *d_matrices):
+        w, saved = _window_experts(x, cast, order, load, k, first, index)
+        mine = _window_transpose(saved, w, cast, like, dy, weights, k)
+        with timeline.device_scope("bf.moe.experts"):
+            return (lax.dynamic_update_slice(d_rows, mine[0],
+                                             (index * size, 0)),
+                    lax.dynamic_update_slice(d_weights, mine[1],
+                                             (index * size,))) + tuple(
+                a + b for a, b in zip(d_matrices, mine[2:]))
+    run, (d_rows, d_weights, *d_matrices) = _overflow(
+        load, first, count, size, rows, window,
+        jnp.zeros((rows, x.shape[1]), dy.dtype),
+        jnp.zeros((rows,), jnp.float32),
+        *(jnp.zeros(m.shape, like.dtype) for m in cast))
+    return _to_tokens(d_rows, d_weights, inverse, run, weights,
+                      dy.dtype) + tuple(d_matrices)
+
+
+def _branch(load, first, count, size, window, overflow):
+    """One ``lax.cond`` on whether the held run fits the window.  The
+    barrier keeps the two branches' ends apart: XLA moves a tail that both
+    share out of the conditional, and the window branch then hands over its
+    ``(T, k, d)`` rows (268 MB at 16384 x 4 x 2048) where it hands over
+    their sum."""
+    with timeline.device_scope("bf.moe.dispatch"):
+        return lax.cond(_held_rows(load, first, count) <= size, window,
+                        lambda: lax.optimization_barrier(overflow()))
+
+
+def _held_args(x, weights, gate, up, down, order, inverse, load, k, first):
+    """What both branches take, and the window's size."""
+    return ((x, weights, _cast((gate, up, down), x.dtype), order, inverse,
+             load, k, first),
+            held_window(order.shape[0], gate.shape[0], load.shape[0]))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _held_share(x, weights, gate, up, down, order, inverse, load, k: int,
+                first: int):
+    """The held experts' part of the layer's result over a window of the
+    sorted assignments (module docstring).  The transpose is written out,
+    because autodiff of a ``lax.cond`` keeps the residuals of both
+    branches: here the window branch keeps its ``(C, .)`` arrays and the
+    overflow branch nothing."""
+    args, size = _held_args(x, weights, gate, up, down, order, inverse,
+                            load, k, first)
+    return _branch(load, first, gate.shape[0], size,
+                   lambda: _window_fwd(*args)[0],
+                   lambda: _overflow_fwd(*args))
+
+
+def _held_fwd(x, weights, gate, up, down, order, inverse, load, k, first):
+    args, size = _held_args(x, weights, gate, up, down, order, inverse,
+                            load, k, first)
+    saved = jax.eval_shape(lambda: _window_fwd(*args)[1])
+    y, saved = _branch(
+        load, first, gate.shape[0], size, lambda: _window_fwd(*args),
+        lambda: (_overflow_fwd(*args), jax.tree.map(
+            lambda s: jnp.zeros(s.shape, s.dtype), saved)))
+    # the empty array carries the matrices' dtype to the backward pass
+    return y, (args[:6], jnp.zeros((0,), gate.dtype), saved)
+
+
+def _held_bwd(k, first, res, dy):
+    (x, weights, cast, order, inverse, load), like, saved = res
+    return _branch(
+        load, first, cast[0].shape[0], saved.win.shape[0],
+        lambda: _window_bwd(weights, cast, like, inverse, load, k, first,
+                            saved, dy),
+        lambda: _overflow_bwd(x, weights, cast, like, order, inverse, load,
+                              k, first, dy)) + (None, None, None)
+
+
+_held_share.defvjp(_held_fwd, _held_bwd)
 
 
 def observe_load(load, held: tuple = None) -> float:
@@ -462,16 +770,30 @@ def observe_load(load, held: tuple = None) -> float:
     count)`` it also adds the assignments that went to the held experts to
     ``bf_moe_held_assignments_total`` and sets the gauge
     ``bf_moe_held_share`` to their share of all (``count / E`` at an even
-    router)."""
-    counts = np.asarray(load, np.float64)
-    counts = counts.reshape(-1, counts.shape[-1]).sum(axis=0)
+    router); and, each row of ``load`` being one layer's counts of one
+    step, adds the rows whose held count is over the layer's window
+    (``held_window`` of the row's sum: the steps that took the whole path)
+    to ``bf_moe_held_window_overflow_total`` and sets the gauge
+    ``bf_moe_held_window_fill`` to the largest held count over its window
+    (0.5 at an even router with an eighth held)."""
+    by_row = np.asarray(load, np.float64)
+    by_row = by_row.reshape(-1, by_row.shape[-1])
+    counts = by_row.sum(axis=0)
     for e, n in enumerate(counts):
         telemetry.inc("bf_moe_assignments_total", float(n), expert=str(e))
     if held is not None:
-        mine = float(counts[held[0]:held[0] + held[1]].sum())
-        telemetry.inc("bf_moe_held_assignments_total", mine)
+        first, count = held
+        mine = by_row[:, first:first + count].sum(axis=1)
+        telemetry.inc("bf_moe_held_assignments_total", float(mine.sum()))
         telemetry.set_gauge("bf_moe_held_share",
-                            mine / counts.sum() if counts.sum() > 0 else 0.0)
+                            float(mine.sum() / counts.sum())
+                            if counts.sum() > 0 else 0.0)
+        window = np.array([max(1, held_window(int(n), count, len(counts)))
+                           for n in by_row.sum(axis=1)])
+        telemetry.inc("bf_moe_held_window_overflow_total",
+                      float((mine > window).sum()))
+        telemetry.set_gauge("bf_moe_held_window_fill",
+                            float((mine / window).max()))
     mean = counts.mean()
     ratio = float(counts.max() / mean) if mean > 0 else 0.0
     telemetry.set_gauge("bf_moe_load_max_over_mean", ratio)

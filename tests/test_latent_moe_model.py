@@ -300,6 +300,119 @@ def test_observe_load_counts_the_held_share():
     assert snap["bf_moe_held_share"] == pytest.approx(0.125)
 
 
+def test_observe_load_counts_the_window_and_its_overflows():
+    """An even load fills half the window; one layer of four over its
+    window is one overflow; the rule is the layer's own."""
+    from bluefog_tpu.utils import telemetry
+    name = "bf_moe_held_window_overflow_total"
+    assert moe.held_window(16384, 8, 64) == 4096
+    assert moe.held_window(65536, 8, 64) == 16384
+    assert moe.held_window(2048, 2, 16) == 512
+    assert moe.held_window(2000, 3, 64) == 256      # whole tiles
+    assert moe.held_window(96, 2, 8) == 96          # never more than all
+    assert moe.held_window(16384, 32, 64) == 16384  # half held: no window
+    moe.observe_load(np.full((4, 64), 256), held=(0, 8))
+    before = telemetry.snapshot().get(name, 0.0)
+    assert telemetry.snapshot()["bf_moe_held_window_fill"] == 0.5
+    load = np.full((4, 64), 256)
+    # 4104 held rows of the same 16384: the window is 4096
+    load[2, :8], load[2, 8:16], load[2, 16] = 513, 0, 248
+    moe.observe_load(load, held=(0, 8))
+    snap = telemetry.snapshot()
+    assert snap[name] - before == 1
+    assert snap["bf_moe_held_window_fill"] == pytest.approx(4104 / 4096)
+    moe.observe_load(np.full((4, 64), 256), held=(0, 8))
+    assert telemetry.snapshot()[name] - before == 1
+
+
+# 1024 tokens, top-2 of 16 experts, 2 held: a window of 512 of 2048 rows
+WINDOW_CASES = {                # (first, held assignments, compute dtype)
+    "well-under": (4, 100, jnp.float32),
+    "well-under-bfloat16": (4, 100, jnp.bfloat16),
+    "exactly-the-window": (4, 512, jnp.float32),
+    "over-the-window": (4, 700, jnp.float32),
+    "two-full-windows": (4, 1024, jnp.float32),
+    "no-row-held": (4, 0, jnp.float32),
+    "at-the-tail": (14, 100, jnp.float32),
+    "exactly-the-window-at-the-tail": (14, 512, jnp.float32),
+    "first-zero": (0, 100, jnp.float32),
+    "poisoned": (4, 100, jnp.float32),
+}
+
+
+def _window_layer(first, n_held, tokens=1024, E=16, d=16, f=24):
+    """A layer whose first ``n_held`` tokens (of a shuffled order) send
+    their first choice to one of the two held experts and nothing else
+    goes there."""
+    x, noise = normal(30, (tokens, d)), normal(31, (tokens, E), 0.1)
+    absent = np.array([e for e in range(E) if not first <= e < first + 2])
+    t = np.arange(tokens)
+    top = np.where(t < n_held, first + t % 2, absent[t % len(absent)])
+    second = absent[(t + 3) % len(absent)]
+    logits = noise.at[t, top].add(8.0).at[t, second].add(4.0)
+    shuffle = np.random.default_rng(0).permutation(tokens)
+    gate, up = normal(32, (2, d, f), 0.3), normal(33, (2, d, f), 0.3)
+    return (x, logits[shuffle], gate, up, normal(34, (2, f, d), 0.3),
+            shuffle < n_held)
+
+
+@pytest.mark.parametrize("name", sorted(WINDOW_CASES))
+def test_the_window_is_the_whole_path(monkeypatch, name):
+    """A held share over its window against the path over all ``T * k``
+    rows (the layer with a window as large as the order, which is also the
+    branch an overflowing step takes): ``y`` and ``d x`` to the last bit,
+    the router's and the matrices' gradients to float32 rounding (their
+    sums run over other tiles); whatever lies in the window's rows past the
+    held ones reaches nothing."""
+    first, n_held, dtype = WINDOW_CASES[name]
+    x, logits, gate, up, down, holds = _window_layer(first, n_held)
+    assert moe.held_window(2048, 2, 16) == 512
+    if name == "poisoned":      # the tokens whose rows fill the window's end
+        x = jnp.where(holds[:, None], x, jnp.nan)
+
+    def run(x, logits, gate, up, down):
+        y, plan = moe.dropless_moe(x.astype(dtype), logits, gate, up, down,
+                                   k=2, held=(first, 2))
+        return (y.astype(jnp.float32) ** 2).sum(), (y, plan.load)
+    grad = jax.jit(jax.value_and_grad(run, (0, 1, 2, 3, 4), has_aux=True))
+    (_, (y, load)), got = grad(x, logits, gate, up, down)
+    assert int(load[first:first + 2].sum()) == n_held
+    monkeypatch.setattr(moe, "held_window", lambda n, count, E: n)
+    (_, (y_whole, _)), want = jax.jit(jax.value_and_grad(
+        run, (0, 1, 2, 3, 4), has_aux=True))(x, logits, gate, up, down)
+    np.testing.assert_array_equal(y, y_whole)
+    assert np.isfinite(np.asarray(y, np.float32)).all()
+    assert bool(jnp.abs(y[holds]).sum() > 0) == (n_held > 0)
+    np.testing.assert_array_equal(got[0][holds], want[0][holds])
+    np.testing.assert_array_equal(got[0][~holds], 0.0)
+    for a, b in zip(got[1:], want[1:]):
+        assert np.isfinite(a).all()
+        np.testing.assert_allclose(a, b, rtol=1e-5,
+                                   atol=1e-6 * float(jnp.abs(b).max()))
+
+
+def test_a_held_share_differentiates_one_branch(monkeypatch):
+    """The gradient program of a held share keeps no ``(T * k, .)`` array
+    between its forward and its transpose: what crosses is the window's
+    rows.  ``held=(0, E)`` and a share of half the experts have no window
+    and are the whole path's jaxpr."""
+    x, logits, gate, up, down, _ = _window_layer(4, 100)
+
+    def out(*a, **kw):
+        return moe.dropless_moe(*a, k=2, **kw)[0]
+    _, residuals = jax.vjp(functools.partial(out, held=(4, 2)), x, logits,
+                           gate, up, down)
+    assert max(leaf.shape[0] for leaf in jax.tree.leaves(residuals)
+               if leaf.ndim == 2) == 1024       # x itself; the window is 512
+    wide = normal(35, (8, 16, 24), 0.3), normal(36, (8, 16, 24), 0.3), \
+        normal(37, (8, 24, 16), 0.3)
+    half = str(jax.make_jaxpr(functools.partial(out, held=(8, 8)))(
+        x, logits, *wide))
+    monkeypatch.setattr(moe, "_held_share", None)   # not reached
+    assert half == str(jax.make_jaxpr(functools.partial(out, held=(8, 8)))(
+        x, logits, *wide))
+
+
 # --- (f) the residual maps -------------------------------------------------------
 
 def _hyper(n=4, d=32, **kw):
@@ -416,6 +529,29 @@ def test_toy_model_loss_and_every_gradient_leaf_in_float32(toy):
                 key=lambda kv: kv[1])
     assert worst[1] < 1e-3, jax.tree_util.keystr(worst[0])
     assert float(np.median(jax.tree.leaves(errs))) < 1e-5
+
+
+def test_toy_model_with_a_window_against_the_reference(toy):
+    """One expert of eight held, two rows of 128 tokens: the expert layers
+    (under remat, their statistics sown) work on a window of 256 of 512
+    assignments; loss and every gradient leaf against the float32
+    reference."""
+    config, task, ref = toy
+    held_one = dict(copy.deepcopy(config), n_routed_experts=1,
+                    experts_first=2)
+    assert moe.held_window(2 * 128 * 2, 1, 8) == 256
+    _, params, aux, tokens, program, reference = _model_case(
+        (held_one, task, ref), "float32", seq=128)
+    with HIGHEST():
+        (loss, new), grads = program(params, aux, tokens)
+        (want, ref_new), ref_grads = reference(params, aux, tokens)
+    assert params["block_1"]["moe"]["gate"].shape == (1, 64, 32)
+    assert abs(float(loss) - float(want)) / float(want) < 1e-5
+    np.testing.assert_array_equal(new["load"], ref_new["load"])
+    errs = jax.tree.map(rel, grads, ref_grads)
+    worst = max(jax.tree_util.tree_leaves_with_path(errs),
+                key=lambda kv: kv[1])
+    assert worst[1] < 1e-3, jax.tree_util.keystr(worst[0])
 
 
 def _sampled(errs, bound, draws=50):
