@@ -14,6 +14,9 @@ Public surface mirrors ``import bluefog.torch as bf`` (reference
 >>> y = bf.neighbor_allreduce(x)
 """
 
+import time as _time
+_import_t0 = _time.perf_counter()
+
 from bluefog_tpu import topology  # noqa: F401
 from bluefog_tpu import topology as topology_util  # parity alias  # noqa: F401
 
@@ -158,3 +161,8 @@ from bluefog_tpu.ops import gang  # noqa: F401
 
 from bluefog_tpu.utils import profiler  # noqa: F401
 from bluefog_tpu.utils.profiler import step_profile  # noqa: F401
+
+# What the import itself took of the way to the first step (the other parts
+# come from bf.init() and opt.init(): docs/observability.md).
+telemetry.set_gauge("bf_startup_seconds", _time.perf_counter() - _import_t0,
+                    part="import")

@@ -154,6 +154,9 @@ def _reset_for_tests():
     _placement_search_cache.clear()
     from bluefog_tpu.ops import synthesis as _synthesis
     _synthesis.clear_synth_cache()
+    # bf.init() listens to jax's compile events; no context, no listener.
+    from bluefog_tpu.utils import timeline as _timeline
+    _timeline.unwatch_builds()
 
 
 def _require_init() -> _Context:
@@ -188,28 +191,35 @@ def init(topology_fn=None, is_weighted: bool = False, *,
     size (single virtual machine).
     """
     global _ctx
+    from bluefog_tpu.utils import timeline
     if _ctx.initialized:
         shutdown()  # re-init tears down stale meshes, schedules, jit caches
-    devs = list(devices) if devices is not None else list(jax.devices())
-    n = len(devs)
-    _ctx.devices = devs
-    _ctx.base_devices = list(devs)
-    _ctx.mesh = Mesh(np.asarray(devs), (RANK_AXIS,))
-    if local_size is None:
-        local_size = jax.local_device_count() if jax.process_count() > 1 else n
-    assert n % local_size == 0, "world size must be divisible by local_size"
-    _ctx.local_size = local_size
-    _ctx.hier_mesh = Mesh(
-        np.asarray(devs).reshape(n // local_size, local_size),
-        (MACHINE_AXIS, LOCAL_AXIS))
-    _ctx.initialized = True
-    _configure_compile_cache()
-    topo = topology_fn() if topology_fn is not None \
-        else topology_util.ExponentialGraph(n)
-    set_topology(topo, is_weighted=is_weighted)
-    if n // local_size > 1:
-        set_machine_topology(
-            topology_util.ExponentialGraph(n // local_size), is_weighted=False)
+    timeline.watch_builds()
+    with timeline.startup_span("init", "devices", part="init_devices"):
+        devs = list(devices) if devices is not None else list(jax.devices())
+        n = len(devs)
+        _ctx.devices = devs
+        _ctx.base_devices = list(devs)
+        _ctx.mesh = Mesh(np.asarray(devs), (RANK_AXIS,))
+        if local_size is None:
+            local_size = (jax.local_device_count()
+                          if jax.process_count() > 1 else n)
+        assert n % local_size == 0, \
+            "world size must be divisible by local_size"
+        _ctx.local_size = local_size
+        _ctx.hier_mesh = Mesh(
+            np.asarray(devs).reshape(n // local_size, local_size),
+            (MACHINE_AXIS, LOCAL_AXIS))
+        _ctx.initialized = True
+        _configure_compile_cache()
+    with timeline.startup_span("init", "topology", part="init_topology"):
+        topo = topology_fn() if topology_fn is not None \
+            else topology_util.ExponentialGraph(n)
+        set_topology(topo, is_weighted=is_weighted)
+        if n // local_size > 1:
+            set_machine_topology(
+                topology_util.ExponentialGraph(n // local_size),
+                is_weighted=False)
     # Opt-in /metrics + /healthz endpoint (BLUEFOG_TPU_TELEMETRY_PORT);
     # idempotent across re-init.
     from bluefog_tpu.utils import telemetry

@@ -34,6 +34,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from bluefog_tpu.utils import telemetry
+
 __all__ = ["flash_attention", "flash_attention_lse",
            "flash_attention_impl", "platform_in_use"]
 
@@ -130,6 +132,9 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
         block_k=block_k)
+    # A wrapper's Python runs at trace time: once a shape behind ``jax.jit``,
+    # once a call for a bare kernel, and each staging is a Mosaic lowering.
+    telemetry.inc("bf_kernel_stagings_total", kernel="bf_flash_fwd")
     o, lse = pl.pallas_call(
         kernel, name="bf_flash_fwd",
         grid=(bh, S // block_q, S // block_k),
@@ -299,6 +304,7 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
     params = dict(compiler_params=pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary")))
 
+    telemetry.inc("bf_kernel_stagings_total", kernel="bf_flash_dq")
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
@@ -311,6 +317,7 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
         interpret=interpret, **params,
     )(qf, kf, vf, dof, lse3, delta)
 
+    telemetry.inc("bf_kernel_stagings_total", kernel="bf_flash_dkv")
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),

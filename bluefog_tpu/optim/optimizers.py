@@ -61,7 +61,7 @@ from bluefog_tpu.ops import schedule as S
 from bluefog_tpu.optim import functional as F
 from bluefog_tpu.optim.functional import CommunicationType, DistOptState
 from bluefog_tpu.utils import telemetry
-from bluefog_tpu.utils.timeline import op_span
+from bluefog_tpu.utils.timeline import op_span, startup_span
 
 __all__ = [
     "CommunicationType",
@@ -431,6 +431,10 @@ class DistributedOptimizer:
     # -- public surface -----------------------------------------------------
     def init(self, params) -> DistOptState:
         """Build rank-major optimizer state for rank-major ``params``."""
+        with startup_span("optim", "init", part="optim_init"):
+            return self._init(params)
+
+    def _init(self, params) -> DistOptState:
         ctx = basics._require_init()
         hier = (self.communication_type in (
                 CommunicationType.hierarchical_neighbor_allreduce,

@@ -335,6 +335,10 @@ def held_window(assignments: int, count: int, experts: int) -> int:
 
 
 def _kernel(name: str, fn, *args, **kw):
+    """Stage one Pallas kernel under its name.  This runs where the Python
+    wrapper runs, at trace time: once a shape behind ``jax.jit``, once a
+    call for a bare kernel, and each staging is one Mosaic lowering."""
+    telemetry.inc("bf_kernel_stagings_total", kernel=name)
     with timeline.device_scope(name):
         return fn(*args, **kw)
 

@@ -28,7 +28,11 @@ import time
 from contextlib import contextmanager
 from typing import Dict
 
+import jax.monitoring
 import jax.profiler
+from jax._src.core import trace_state_clean as _trace_state_clean
+
+from bluefog_tpu.utils import telemetry
 
 __all__ = [
     "timeline_enabled",
@@ -44,6 +48,9 @@ __all__ = [
     "thread_name",
     "set_op_span_hook",
     "op_span",
+    "startup_span",
+    "watch_builds",
+    "unwatch_builds",
     "device_scope",
     "CLOCK_ANCHOR_NAME",
 ]
@@ -122,6 +129,9 @@ def _make_writer(path: str):
 
 _writer = None
 _active: Dict[str, object] = {}
+# The synthetic lane of the ``bf.build.<stage>`` spans, beside the probe
+# reconciler's 998, 999 and 1000+.
+_BUILD_LANE = 997
 _lock = threading.Lock()
 
 
@@ -198,6 +208,7 @@ def start_timeline(path: str) -> bool:
             atexit.register(stop_timeline)
             _atexit_installed = True
     _emit_clock_anchor()
+    thread_name(_BUILD_LANE, "bf.build")
     return True
 
 
@@ -407,3 +418,93 @@ class op_span:
                 _span_hook(self._op, self._phase,
                            time.perf_counter() - self._t0)
         return False
+
+
+@contextmanager
+def startup_span(op_name: str, phase: str, part: str):
+    """A span of the way to the first step (``bf.init.devices``,
+    ``bf.init.topology``, ``bf.optim.init``) whose seconds also land in
+    the gauge ``bf_startup_seconds{part}``: a start-up is over before
+    anyone opens a trace, so the figure lives in the registry as well."""
+    t0 = time.perf_counter()
+    with op_span(op_name, phase):
+        yield
+    telemetry.set_gauge("bf_startup_seconds", time.perf_counter() - t0,
+                        part=part)
+
+
+# ---------------------------------------------------------------------------
+# What stands between process start and the first step: jax's own events
+# ---------------------------------------------------------------------------
+
+_BUILD_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_RESULTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_watching = False
+
+
+def _on_build_stage(event: str, start: float, end: float, *,
+                    fun_name: str = "", **_) -> None:
+    """jax reports every trace, lowering and backend compile as a time
+    span with the function's name (``jax/_src/dispatch.py``).  Each goes
+    into ``bf_program_build_seconds{program, stage}`` and, with a timeline
+    open, onto the lane ``bf.build`` as ``bf.build.<stage>`` with ``cat``
+    the program.  The library's own programs (``bf_*``) keep their names
+    and every other function is ``other``.  A trace that runs inside
+    another trace (a jitted helper, every ``jnp`` function) is part of that
+    one's seconds: a ``bf_*`` one is booked as ``trace_nested``, another is
+    not booked at all."""
+    stage = _BUILD_STAGES.get(event)
+    if stage is None:
+        return
+    program = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+    if not program.startswith("bf_"):
+        program = "other"
+    if stage == "trace" and not _trace_state_clean():
+        if program == "other":
+            return
+        stage = "trace_nested"
+    telemetry.observe("bf_program_build_seconds", end - start,
+                      program=program, stage=stage)
+    if _writer is None and os.environ.get("BLUEFOG_TIMELINE"):
+        _maybe_autostart()
+    if _writer is not None:
+        # jax stamps wall time; the timeline runs on the monotonic clock
+        offset = time.monotonic() - time.time()
+        probe_span(f"bf.build.{stage}", int((start + offset) * 1e6),
+                   int((end - start) * 1e6), _BUILD_LANE, cat=program)
+
+
+def _on_cache_event(event: str, **_) -> None:
+    result = _CACHE_RESULTS.get(event)
+    if result is not None:
+        telemetry.inc("bf_compile_cache_total", result=result)
+
+
+def watch_builds() -> None:
+    """Listen to jax's compile-stage and compile-cache events
+    (``bf.init()`` calls this; a second call changes nothing)."""
+    global _watching
+    with _lock:
+        if _watching:
+            return
+        _watching = True
+    jax.monitoring.register_event_time_span_listener(_on_build_stage)
+    jax.monitoring.register_event_listener(_on_cache_event)
+
+
+def unwatch_builds() -> None:
+    """Stop listening (``bf.shutdown()``)."""
+    global _watching
+    with _lock:
+        if not _watching:
+            return
+        _watching = False
+    jax.monitoring.unregister_event_time_span_listener(_on_build_stage)
+    jax.monitoring.unregister_event_listener(_on_cache_event)
