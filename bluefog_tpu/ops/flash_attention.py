@@ -1,21 +1,47 @@
-"""Flash attention: Pallas TPU kernel (forward) + blockwise custom VJP.
+"""Flash attention: three Pallas TPU kernels (forward, dq, dk/dv) behind one
+custom VJP.
 
 The hot op of the long-context path.  ``parallel.ring_attention`` and
-``parallel.ulysses`` shard the *sequence*; this kernel makes the per-device
+``parallel.ulysses`` shard the *sequence*; these kernels make the per-device
 block attention itself O(S) in memory by streaming K/V blocks through VMEM
 with the online-softmax recurrence — logits never materialize in HBM.
 
-Forward: grid (batch*head, q-block, k-block) with the online-softmax state
-(acc, m, l) carried in f32 VMEM scratch across the sequential k dimension —
-every operand is a block, so VMEM stays O(block) regardless of S.  Causal
-tiles above the diagonal are skipped (``pl.when``) and their K/V DMAs elided
-by clamping the index map to the frontier.
+Forward (``bf_flash_fwd``): grid (batch*head, q-block, k-block) with the
+online-softmax state (acc, m, l) carried in f32 VMEM scratch across the
+sequential k dimension — every operand is a block, so VMEM stays O(block)
+regardless of S.
 
-Backward: two Pallas kernels recomputing probabilities blockwise from the
-saved per-row logsumexp (the standard flash backward) — a dq kernel over
-(batch*head, q-block) scanning K blocks, and a dk/dv kernel over
-(batch*head, k-block) scanning Q blocks from the causal frontier.  All
-accumulation in f32 in VMEM; nothing S x S ever touches HBM.
+Backward: two kernels recomputing probabilities blockwise from the saved
+per-row logsumexp (the standard flash backward) — ``bf_flash_dq`` over
+(batch*head, q-block) scanning K blocks, and ``bf_flash_dkv`` over
+(batch*head, k-block) scanning Q blocks from the causal frontier.  Nothing
+S x S ever touches HBM.
+
+A tile does only what that tile needs.  Every product takes float32
+operands and accumulates in float32, and the softmax state, ``lse``,
+``delta``, ``exp`` and the mask are float32.  On the chip the array rounds
+a default-precision float32 operand to bfloat16 as it enters, one pass, so
+the casts of bfloat16 inputs cost nothing there (PR 37: products on the
+operands as they arrive gave the same bits in the same time); off the chip
+they keep the products exact.  A causal tile is *skipped* when its first
+key is past its last query (``pl.when``; its K/V DMAs are elided by clamping
+the index map to the frontier), *interior* when its last key is at or
+before its first query, and *crossed* otherwise.  An interior tile builds
+no mask.  A crossed tile (8 of 36 tiles a head at S 8192, 16 of 136 at S
+16384, 4 of 10 at S 4096) knows where the diagonal runs through it, so it
+works in chunks of rows and gives each chunk only the keys at or before its
+last query: five eighths of the tile's products in the backward, three
+quarters in the forward.  ``bf_flash_tiles_total{kernel, kind}`` counts the
+three kinds at staging.  ``bf_flash_dkv`` computes its scores as ``k q^T``,
+keys along the rows as its accumulators have them, so it transposes no
+tile.
+
+What bounds a tile (one v5e chip, PR 37, ``PERF.md``): the backward runs its
+products at 85 to 91% of the array's peak; the forward spends 1.1 to 1.4 us
+of every 4.2 us tile on the softmax state (two reductions along the lanes,
+the ``(BQ, 1)`` columns), which neither fewer vector operations nor a
+lane-dense state took off it.  A head of 64 fills half the 128-wide array,
+so its ceiling is half the peak.
 
 Layout: ``(B, S, H, D)`` like ``models.local_attention``; internally
 ``(B*H, S, D)``.  The values may have a last dim ``Dv`` of their own (latent
@@ -26,11 +52,11 @@ accumulator, ``do`` and ``dv`` take it.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -40,6 +66,79 @@ __all__ = ["flash_attention", "flash_attention_lse",
            "flash_attention_impl", "platform_in_use"]
 
 _NEG_INF = -1e30
+
+# Rows of one chunk of a tile that the diagonal crosses (such a tile computes,
+# chunk by chunk, only the keys at or before the chunk's last query).  The
+# forward's products lose more to short chunks than the skipped keys save
+# under 512 rows; the backward's do not (one v5e chip, PR 37: PERF.md).
+_FWD_CHUNK = 512
+_BWD_CHUNK = 256
+_LANES = 128
+
+
+def _crossings(block_q: int, block_k: int) -> list:
+    """The offsets ``first query - first key`` at which the diagonal runs
+    through a ``(block_q, block_k)`` tile: the multiples of the blocks' gcd
+    strictly between ``-block_q`` (the first key is past the last query:
+    *skipped*) and ``block_k - 1`` (the last key is at or before the first
+    query: *interior*).  One for equal blocks, two where one is twice the
+    other (the backward at heads over 128 runs 1024 x 512)."""
+    g = math.gcd(block_q, block_k)
+    return [m * g for m in range(1 - block_q // g, block_k // g)
+            if m * g < block_k - 1]
+
+
+def _on_tiles(tile, qi, kb, *, causal: bool, block_q: int, block_k: int):
+    """Run ``tile(offset)`` as the tile ``(qi, kb)`` needs.  ``offset`` is
+    ``None`` for an *interior* tile and every tile of a non-causal call: no
+    score is masked, so it builds no iota, compare or select.  A *crossed*
+    tile gets its diagonal's offset as a Python int, a body for each of the
+    blocks' crossings, so the mask and the keys it may skip are static.  A
+    *skipped* tile runs nothing."""
+    if not causal:
+        tile(None)
+        return
+    offset = qi * block_q - kb * block_k
+    pl.when(offset >= block_k - 1)(functools.partial(tile, None))
+    for crossing in _crossings(block_q, block_k):
+        pl.when(offset == crossing)(functools.partial(tile, crossing))
+
+
+def _chunk_rows(offset, block: int, want: int) -> int:
+    """Rows of a chunk of a tile's own dimension: ``want`` for a crossed
+    tile whose block it divides, the whole block otherwise."""
+    return want if offset is not None and block % want == 0 else block
+
+
+def _keys_of(offset, q0: int, rows: int, block_k: int):
+    """``(hi, masked)`` for the queries ``[q0, q0 + rows)`` of a tile: they
+    need the keys ``[0, hi)`` (whole lane tiles; 0: none), and ``masked``
+    says whether some pair among those is past the diagonal."""
+    if offset is None:
+        return block_k, False
+    hi = min(block_k, -(-(offset + q0 + rows) // _LANES) * _LANES)
+    return (hi, offset + q0 < hi - 1) if hi > 0 else (0, False)
+
+
+def _queries_of(offset, k0: int, rows: int, block_q: int):
+    """``(lo, masked)`` for the keys ``[k0, k0 + rows)`` of a tile: they are
+    seen by the queries ``[lo, block_q)`` (``block_q``: none)."""
+    if offset is None:
+        return 0, False
+    lo = max((k0 - offset) // _LANES * _LANES, 0)
+    return ((lo, k0 + rows - 1 > lo + offset) if lo < block_q
+            else (block_q, False))
+
+
+def _causal(s, *, key0, query0, keys_axis: int):
+    """``s`` with the pairs whose key is past their query at ``-1e30``;
+    keys run along ``keys_axis`` from ``key0``, queries along the other axis
+    from ``query0`` (the tile's offset included)."""
+    along = lambda axis, first: first + jax.lax.broadcasted_iota(
+        jnp.int32, tuple(n if a == axis else 1 for a, n in enumerate(s.shape)),
+        axis)
+    return jnp.where(along(keys_axis, key0) <= along(1 - keys_axis, query0),
+                     s, _NEG_INF)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
@@ -56,31 +155,30 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def tile():
-        q = q_ref[:].astype(jnp.float32)                   # (BQ, D)
-        k = k_ref[:].astype(jnp.float32)                   # (BK, D)
-        v = v_ref[:].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
-            s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_ref[:] * corr + p.sum(axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+    def tile(offset):
+        rows = _chunk_rows(offset, block_q, _FWD_CHUNK)
+        for q0 in range(0, block_q, rows):
+            hi, masked = _keys_of(offset, q0, rows, block_k)
+            if not hi:
+                continue
+            own = slice(q0, q0 + rows)
+            q = q_ref[own, :].astype(jnp.float32)          # (rows, D)
+            k = k_ref[:hi, :].astype(jnp.float32)          # (hi, D)
+            v = v_ref[:hi, :].astype(jnp.float32)          # (hi, Dv)
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = _causal(s, key0=0, query0=q0 + offset, keys_axis=1)
+            m_prev = m_ref[own, :]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[own, :] = l_ref[own, :] * corr + p.sum(
+                axis=-1, keepdims=True)
+            acc_ref[own, :] = acc_ref[own, :] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[own, :] = m_new
 
-    if causal:
-        # Skip tiles entirely above the diagonal.
-        pl.when(kb * block_k <= (qi + 1) * block_q - 1)(tile)
-    else:
-        tile()
+    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k)
 
     @pl.when(kb == pl.num_programs(2) - 1)
     def _store():
@@ -109,18 +207,37 @@ def _fit_block(want: int, seq_len: int) -> int:
     return b
 
 
-def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
-         scale=None):
-    B, S, H, D = q.shape
-    Dv = v.shape[-1]
-    if scale is None:
-        scale = 1.0 / np.sqrt(D)
-    bh = B * H
-    fold = lambda t: t.transpose(0, 2, 1, 3).reshape(bh, S, t.shape[-1])
-    qf, kf, vf = fold(q), fold(k), fold(v)
-    block_q = _fit_block(block_q, S)
-    block_k = _fit_block(block_k, S)
+def _staged(kernel: str, heads: int, n_qb: int, n_kb: int, block_q: int,
+            block_k: int, causal: bool):
+    """Count one staging of ``kernel`` and the tiles of its call by kind
+    (``heads`` grids of ``n_qb x n_kb``; a non-causal call's are all
+    interior).  A wrapper's Python runs at trace time: once a shape behind
+    ``jax.jit``, once a call for a bare kernel, and each staging is a Mosaic
+    lowering."""
+    telemetry.inc("bf_kernel_stagings_total", kernel=kernel)
+    offsets = [qi * block_q - kb * block_k
+               for qi in range(n_qb) for kb in range(n_kb)]
+    skipped = sum(causal and o <= -block_q for o in offsets)
+    interior = sum(not causal or o >= block_k - 1 for o in offsets)
+    for kind, n in (("skipped", skipped), ("interior", interior),
+                    ("crossed", len(offsets) - skipped - interior)):
+        telemetry.inc("bf_flash_tiles_total", heads * n, kernel=kernel,
+                      kind=kind)
 
+
+# Each kernel call sits behind ``jax.jit``: the stagings of one shape (a
+# layer's primal, its rule's forward and its remat recompute, layer after
+# layer) then trace the kernel's tile bodies once and not once each.
+_STATIC = ("scale", "causal", "block_q", "block_k", "interpret", "vma")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _fwd_call(qf, kf, vf, *, scale, causal, block_q, block_k, interpret,
+              vma):
+    """``bf_flash_fwd`` on folded ``(B*H, S, D)`` operands: ``o`` and the
+    per-row logsumexp ``(B*H, S, 1)``."""
+    bh, S, D = qf.shape
+    Dv = vf.shape[-1]
     if causal:
         # Clamp the k index into this q-block's un-masked range: skipped
         # steps repeat the previous block index and Pallas elides the DMA.
@@ -128,15 +245,10 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
             b, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
     else:
         kv_idx = lambda b, i, j: (b, j, 0)
-
-    kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k)
-    # A wrapper's Python runs at trace time: once a shape behind ``jax.jit``,
-    # once a call for a bare kernel, and each staging is a Mosaic lowering.
-    telemetry.inc("bf_kernel_stagings_total", kernel="bf_flash_fwd")
-    o, lse = pl.pallas_call(
-        kernel, name="bf_flash_fwd",
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        name="bf_flash_fwd",
         grid=(bh, S // block_q, S // block_k),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
@@ -148,7 +260,7 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
             pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, S, Dv), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, S, Dv), qf.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, S, 1), jnp.float32, vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((block_q, Dv), jnp.float32),
@@ -158,58 +270,64 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
+
+
+def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
+         scale=None):
+    B, S, H, D = q.shape
+    Dv = v.shape[-1]
+    if scale is None:
+        scale = 1.0 / np.sqrt(D)
+    bh = B * H
+    fold = lambda t: t.transpose(0, 2, 1, 3).reshape(bh, S, t.shape[-1])
+    qf, kf, vf = fold(q), fold(k), fold(v)
+    block_q = _fit_block(block_q, S)
+    block_k = _fit_block(block_k, S)
+    _staged("bf_flash_fwd", bh, S // block_q, S // block_k, block_q, block_k,
+            causal)
+    o, lse = _fwd_call(qf, kf, vf, scale=float(scale), causal=causal,
+                       block_q=block_q, block_k=block_k, interpret=interpret,
+                       vma=vma)
     lse = lse[..., 0]
     unfold = lambda t: t.reshape(B, H, S, Dv).transpose(0, 2, 1, 3)
     return unfold(o), (qf, kf, vf, o, lse, (B, S, H, D, scale, causal))
 
 
-def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
-              scale: float, causal: bool, block_q: int, block_k: int,
-              qi, kb):
-    """Shared (BQ, BK) tile math of the flash backward: recompute P from the
-    saved logsumexp, return (p, ds)."""
-    q = q_ref[:].astype(jnp.float32)                       # (BQ, D)
-    k = k_ref[:].astype(jnp.float32)                       # (BK, D)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if causal:
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, 1), 0)
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        s = jnp.where(k_pos <= q_pos, s, _NEG_INF)
-    p = jnp.exp(s - lse_ref[:])                            # masked -> 0
-    do = do_ref[:].astype(jnp.float32)                     # (BQ, D)
-    v = v_ref[:].astype(jnp.float32)
-    dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-    ds = p * (dp - delta_ref[:]) * scale
-    return p, ds
-
-
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                acc_ref, *, scale: float, causal: bool, block_q: int,
                block_k: int):
-    """Grid (bh, q-block, k-block): accumulate ds @ K into a f32 VMEM scratch
-    across the (sequential, innermost) k dimension; one cast-and-store to the
-    output block on the last step.  Every operand is a block — VMEM stays
-    O(block), never O(S)."""
+    """Grid (bh, q-block, k-block): recompute P from the saved logsumexp and
+    accumulate ds @ K into a f32 VMEM scratch across the (sequential,
+    innermost) k dimension; one cast-and-store to the output block on the
+    last step.  Every operand is a block — VMEM stays O(block), never
+    O(S)."""
     qi, kb = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kb == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def tile():
-        _, ds = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          scale=scale, causal=causal, block_q=block_q,
-                          block_k=block_k, qi=qi, kb=kb)
-        acc_ref[:] += jnp.dot(ds, k_ref[:].astype(jnp.float32),
-                              preferred_element_type=jnp.float32)
+    def tile(offset):
+        rows = _chunk_rows(offset, block_q, _BWD_CHUNK)
+        for q0 in range(0, block_q, rows):
+            hi, masked = _keys_of(offset, q0, rows, block_k)
+            if not hi:
+                continue
+            own = slice(q0, q0 + rows)
+            q = q_ref[own, :].astype(jnp.float32)          # (rows, D)
+            k = k_ref[:hi, :].astype(jnp.float32)          # (hi, D)
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            if masked:
+                s = _causal(s, key0=0, query0=q0 + offset, keys_axis=1)
+            p = jnp.exp(s - lse_ref[own, :])               # masked -> 0
+            dp = jnp.dot(do_ref[own, :].astype(jnp.float32),
+                         v_ref[:hi, :].astype(jnp.float32).T,
+                         preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_ref[own, :]) * scale
+            acc_ref[own, :] += jnp.dot(ds, k,
+                                       preferred_element_type=jnp.float32)
 
-    if causal:
-        # Skip tiles entirely above the diagonal.
-        pl.when(kb * block_k <= (qi + 1) * block_q - 1)(tile)
-    else:
-        tile()
+    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k)
 
     @pl.when(kb == pl.num_programs(2) - 1)
     def _store():
@@ -219,8 +337,12 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
                 causal: bool, block_q: int, block_k: int):
-    """Grid (bh, k-block, q-block): accumulate ds.T @ Q and P.T @ dO into f32
-    VMEM scratches across the (sequential, innermost) q dimension."""
+    """Grid (bh, k-block, q-block): accumulate P.T @ dO and ds.T @ Q into f32
+    VMEM scratches across the (sequential, innermost) q dimension.  The
+    scores are computed as ``k q^T``, (BK, BQ) with the keys along the rows
+    as the accumulators have them, so no (BQ, BK) tile is transposed; the
+    per-query ``lse`` and ``delta`` columns are turned to rows once a
+    tile."""
     kb, qi = pl.program_id(1), pl.program_id(2)
 
     @pl.when(qi == 0)
@@ -228,24 +350,97 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def tile():
-        p, ds = _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          scale=scale, causal=causal, block_q=block_q,
-                          block_k=block_k, qi=qi, kb=kb)
-        do = do_ref[:].astype(jnp.float32)
-        q = q_ref[:].astype(jnp.float32)
-        dv_acc[:] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dk_acc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+    def tile(offset):
+        lse, delta = lse_ref[:].T, delta_ref[:].T          # (1, BQ)
+        rows = _chunk_rows(offset, block_k, _BWD_CHUNK)
+        for k0 in range(0, block_k, rows):
+            lo, masked = _queries_of(offset, k0, rows, block_q)
+            if lo == block_q:
+                continue
+            own = slice(k0, k0 + rows)
+            q = q_ref[lo:, :].astype(jnp.float32)          # (BQ - lo, D)
+            do = do_ref[lo:, :].astype(jnp.float32)
+            st = jnp.dot(k_ref[own, :].astype(jnp.float32), q.T,
+                         preferred_element_type=jnp.float32) * scale
+            if masked:
+                st = _causal(st, key0=k0, query0=lo + offset, keys_axis=0)
+            pt = jnp.exp(st - lse[:, lo:])                 # masked -> 0
+            dpt = jnp.dot(v_ref[own, :].astype(jnp.float32), do.T,
+                          preferred_element_type=jnp.float32)
+            dst = pt * (dpt - delta[:, lo:]) * scale
+            dv_acc[own, :] += jnp.dot(pt, do,
+                                      preferred_element_type=jnp.float32)
+            dk_acc[own, :] += jnp.dot(dst, q,
+                                      preferred_element_type=jnp.float32)
 
-    if causal:
-        pl.when((qi + 1) * block_q - 1 >= kb * block_k)(tile)
-    else:
-        tile()
+    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k)
 
     @pl.when(qi == pl.num_programs(2) - 1)
     def _store():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _bwd_calls(qf, kf, vf, dof, lse3, delta, *, scale, causal, block_q,
+               block_k, interpret, vma):
+    """``bf_flash_dq`` and ``bf_flash_dkv`` on folded operands, the per-row
+    ``lse3`` and ``delta`` as ``(B*H, S, 1)`` float32: ``dq, dk, dv``."""
+    bh, S, D = qf.shape
+    Dv = vf.shape[-1]
+    n_qb, n_kb = S // block_q, S // block_k
+
+    # index helpers: i = this kernel's "own" block dim, j = reduction dim.
+    # For causal runs the reduction index is clamped into the un-masked
+    # range: on skipped (pl.when'd-out) steps the map then repeats the
+    # previous block index, so Pallas elides the DMA — without this, masked
+    # tiles would still stream their blocks from HBM (~2x input traffic).
+    at = lambda block, dim: lambda sel: pl.BlockSpec(
+        (None, block, dim), lambda b, i, j: (b, sel(i, j), 0))
+    q_at, k_at = at(block_q, D), at(block_k, D)
+    do_at, v_at = at(block_q, Dv), at(block_k, Dv)
+    r_at = at(block_q, 1)
+    own = lambda i, j: i
+    if causal:
+        # dq grid: j = k-block; never past this q-block's diagonal.
+        red_dq = lambda i, j: jnp.minimum(
+            j, ((i + 1) * block_q - 1) // block_k)
+        # dkv grid: j = q-block; never before this k-block's frontier.
+        red_kv = lambda i, j: jnp.maximum(j, (i * block_k) // block_q)
+    else:
+        red_dq = red_kv = lambda i, j: j
+
+    params = dict(compiler_params=pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary")))
+    kernel = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k)
+
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, **kernel),
+        name="bf_flash_dq", grid=(bh, n_qb, n_kb),
+        in_specs=[q_at(own), k_at(red_dq), v_at(red_dq), do_at(own),
+                  r_at(own), r_at(own)],
+        out_specs=q_at(own),
+        out_shape=jax.ShapeDtypeStruct((bh, S, D), qf.dtype, vma=vma),
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        interpret=interpret, **params,
+    )(qf, kf, vf, dof, lse3, delta)
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, **kernel),
+        name="bf_flash_dkv", grid=(bh, n_kb, n_qb),
+        in_specs=[q_at(red_kv), k_at(own), v_at(own), do_at(red_kv),
+                  r_at(red_kv), r_at(red_kv)],
+        out_specs=[k_at(own), v_at(own)],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, S, D), kf.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, S, Dv), vf.dtype, vma=vma),
+        ],
+        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                        pltpu.VMEM((block_k, Dv), jnp.float32)],
+        interpret=interpret, **params,
+    )(qf, kf, vf, dof, lse3, delta)
+    return dq, dk, dv
 
 
 def _bwd(block_q, block_k, interpret, vma, res, cotangents):
@@ -267,7 +462,6 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
                     axis=-1, keepdims=True)               # (bh, S, 1)
     delta = delta - dlse.astype(jnp.float32).transpose(0, 2, 1) \
         .reshape(bh, S)[..., None]
-    lse3 = lse[..., None]                                 # (bh, S, 1)
 
     block_q = _fit_block(block_q, S)
     block_k = _fit_block(block_k, S)
@@ -278,65 +472,16 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
         # compiler refuses the dq kernel at D = 192), so the backward takes
         # half as many keys a tile.
         block_k = _fit_block(512, S)
-    n_qb, n_kb = S // block_q, S // block_k
-
-    # index helpers: i = this kernel's "own" block dim, j = reduction dim.
-    # For causal runs the reduction index is clamped into the un-masked
-    # range: on skipped (pl.when'd-out) steps the map then repeats the
-    # previous block index, so Pallas elides the DMA — without this, masked
-    # tiles would still stream their blocks from HBM (~2x input traffic).
-    at = lambda block, dim: lambda sel: pl.BlockSpec(
-        (None, block, dim), lambda b, i, j: (b, sel(i, j), 0))
-    q_at, k_at = at(block_q, D), at(block_k, D)
-    do_at, v_at = at(block_q, Dv), at(block_k, Dv)
-    r_at = lambda sel: pl.BlockSpec((None, block_q, 1),
-                                    lambda b, i, j: (b, sel(i, j), 0))
-    own = lambda i, j: i
-    if causal:
-        # dq grid: j = k-block; never past this q-block's diagonal.
-        red_dq = lambda i, j: jnp.minimum(
-            j, ((i + 1) * block_q - 1) // block_k)
-        # dkv grid: j = q-block; never before this k-block's frontier.
-        red_kv = lambda i, j: jnp.maximum(j, (i * block_k) // block_q)
-    else:
-        red_dq = red_kv = lambda i, j: j
-
-    params = dict(compiler_params=pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary")))
-
-    telemetry.inc("bf_kernel_stagings_total", kernel="bf_flash_dq")
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        name="bf_flash_dq", grid=(bh, n_qb, n_kb),
-        in_specs=[q_at(own), k_at(red_dq), v_at(red_dq), do_at(own),
-                  r_at(own), r_at(own)],
-        out_specs=q_at(own),
-        out_shape=jax.ShapeDtypeStruct((bh, S, D), qf.dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret, **params,
-    )(qf, kf, vf, dof, lse3, delta)
-
-    telemetry.inc("bf_kernel_stagings_total", kernel="bf_flash_dkv")
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        name="bf_flash_dkv", grid=(bh, n_kb, n_qb),
-        in_specs=[q_at(red_kv), k_at(own), v_at(own), do_at(red_kv),
-                  r_at(red_kv), r_at(red_kv)],
-        out_specs=[k_at(own), v_at(own)],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, S, D), kf.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, S, Dv), vf.dtype, vma=vma),
-        ],
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                        pltpu.VMEM((block_k, Dv), jnp.float32)],
-        interpret=interpret, **params,
-    )(qf, kf, vf, dof, lse3, delta)
-
-    unfold = lambda t, dt: t.reshape(B, H, S, t.shape[-1]) \
-        .transpose(0, 2, 1, 3).astype(dt)
-    return (unfold(dq, qf.dtype), unfold(dk, kf.dtype), unfold(dv, vf.dtype))
+    for kernel in ("bf_flash_dq", "bf_flash_dkv"):
+        _staged(kernel, bh, S // block_q, S // block_k, block_q, block_k,
+                causal)
+    # (the residuals' Python scalars come back as jax literals: not hashable)
+    dq, dk, dv = _bwd_calls(qf, kf, vf, dof, lse[..., None], delta,
+                            scale=float(scale), causal=bool(causal),
+                            block_q=block_q, block_k=block_k,
+                            interpret=interpret, vma=vma)
+    unfold = lambda t: t.reshape(B, H, S, t.shape[-1]).transpose(0, 2, 1, 3)
+    return unfold(dq), unfold(dk), unfold(dv)
 
 
 def _lse_bsh(lse, B, S, H):
@@ -397,10 +542,13 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
     names the outputs vary over inside ``shard_map``; default: those the
     inputs vary over.
 
-    Block sizes default to 1024 (fitted down to divide S): tall tiles
-    amortize the per-program overhead (one v5e chip, PR 21 probe, causal
-    S=8192 H=8 D=64 bf16: forward 1.4 / 2.2 / 4.4 ms and backward 4.6 / 5.7 /
-    10.5 ms at 1024 / 512 / 256 blocks)."""
+    Block sizes default to 1024 (fitted down to divide S): every tile pays
+    one step of the softmax state, 1.1 to 1.4 us of the forward's 4.2 us at
+    1024 x 1024, and a step over half the keys costs as much (one v5e chip,
+    PR 37, the kernels alone on causal bfloat16 ``(B*H, S, D)``; forward /
+    dq / dkv in ms: (64, 8192, 64) 11.1 / 13.7 / 15.7; (16, 16384, 128) 9.0 /
+    10.9 / 13.2; (32, 4096, 128) 1.44 / 1.79 / 2.03; (32, 4096, 192) with
+    values of 128, the backward at 1024 x 512, 2.22 / 3.29 / 3.43)."""
     return flash_attention_lse(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret,
                                vma=vma, scale=scale)[0]

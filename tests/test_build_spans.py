@@ -342,3 +342,41 @@ def test_a_bare_kernel_is_staged_once_a_call(staged, kernel):
 @pytest.mark.parametrize("kernel", MOE_KERNELS + FLASH_KERNELS)
 def test_a_kernel_behind_jit_is_staged_once_a_shape(staged, kernel):
     assert staged[kernel][2:] == [3, 3, 4]
+
+
+# ---------------------------------------------------------------------------
+# bf_flash_tiles_total{kernel, kind}
+# ---------------------------------------------------------------------------
+
+# One staged call on (B, S, H, D) = (1, 64, 2, 8): two heads, a grid of
+# 2 x 4 (block_q 32, block_k 16) or 4 x 2 tiles a head.  By hand, causal at
+# 32/16: q-block 0 (rows 0..31) crosses k-blocks 0 and 1 and skips 2 and 3;
+# q-block 1 (rows 32..63) has k-blocks 0 and 1 interior and crosses 2 and 3.
+# At 16/32 each k-block of 32 is crossed by its two q-blocks; k-block 0 is
+# interior to q-blocks 2 and 3, k-block 1 skipped by q-blocks 0 and 1.
+@pytest.mark.parametrize("causal, blocks, by_hand", [
+    (True, (32, 16), {"skipped": 2, "crossed": 4, "interior": 2}),
+    (True, (16, 32), {"skipped": 2, "crossed": 4, "interior": 2}),
+    (True, (64, 64), {"skipped": 0, "crossed": 1, "interior": 0}),
+    (False, (32, 16), {"skipped": 0, "crossed": 0, "interior": 8}),
+    (False, (16, 32), {"skipped": 0, "crossed": 0, "interior": 8}),
+])
+def test_a_staged_flash_call_counts_its_tiles_by_kind(causal, blocks,
+                                                      by_hand):
+    from bluefog_tpu.ops.flash_attention import flash_attention
+    block_q, block_k = blocks
+    heads, grid = 2, (64 // block_q) * (64 // block_k)
+
+    def attend(q):
+        return flash_attention(q, q, q, causal=causal, block_q=block_q,
+                               block_k=block_k).sum()
+    telemetry.reset()
+    jax.jit(jax.grad(attend))(jnp.ones((1, 64, heads, 8)))
+    staged, snap = _stagings(), telemetry.snapshot()
+    for kernel in FLASH_KERNELS:
+        # the forward is staged once by the rule's forward alone
+        assert staged[kernel] == 1
+        counted = {kind: snap['bf_flash_tiles_total{kernel="%s",kind="%s"}'
+                              % (kernel, kind)] for kind in by_hand}
+        assert counted == {kind: heads * n for kind, n in by_hand.items()}
+        assert sum(counted.values()) == heads * grid

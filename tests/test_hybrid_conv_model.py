@@ -395,11 +395,13 @@ def test_float8_rounded_matrices_fail_the_bounds(toy):
 # the tiny twins, taken at the parent of PR 34 (commit 739c0c9) with
 # ``benchmark.spec``'s own task and configuration files; the cells' own
 # programs were compared at their full shapes the same way (``CHANGES.md``).
-# A PR that means to change one of these programs replaces its digest.
+# A PR that means to change one of these programs replaces its digest: PR 37
+# replaced the three that run the flash kernels (their tile bodies and the
+# ``jax.jit`` around each call; ``tiny-resnet`` is as at 739c0c9).
 PARENT_JAXPR = {
-    ("tiny-lm", "causal_lm"): "1e3a7602d3d55b23",
-    ("tiny-olmoe", "moe_causal_lm"): "18d3d3328f09213e",
-    ("tiny-xing", "latent_moe_causal_lm"): "8a4284fde43d9db5",
+    ("tiny-lm", "causal_lm"): "a993a641bee0ab34",
+    ("tiny-olmoe", "moe_causal_lm"): "cc5642989b155419",
+    ("tiny-xing", "latent_moe_causal_lm"): "25911be33315599d",
     ("tiny-resnet", "image_classification"): "cd85047ddb144986",
 }
 
