@@ -30,19 +30,31 @@ __all__ = ["TransformerLM", "TransformerConfig", "local_attention",
            "head_matrix"]
 
 
-def local_attention(q, k, v, *, causal: bool = True, scale: float = None):
+def local_attention(q, k, v, *, causal: bool = True, scale: float = None,
+                    window: int = None):
     """Plain single-device attention: ``(B, S, H, D)`` inputs (``v`` may
-    have a last dim of its own); ``scale=None`` means ``1 / sqrt(D)``."""
+    have a last dim of its own); ``scale=None`` means ``1 / sqrt(D)``.
+    ``window=W`` (with ``causal``): a query sees its own key and the ``W -
+    1`` before it."""
     dt = q.dtype
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
+    if window is not None and not causal:
+        raise ValueError("local_attention: a window reaches back from the "
+                         "diagonal and needs causal=True")
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     if causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), bool), s_k - s_q)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((s_q, s_k), bool),
+                              s_k - s_q - window)
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = nn.softmax(logits.astype(jnp.float32), axis=-1).astype(dt)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+MIXERS = ("conv", "full_attention", "sliding_attention")
 
 
 class TransformerConfig:
@@ -64,7 +76,9 @@ class TransformerConfig:
                  hyper_sinkhorn_iters=20, hyper_eps=1e-6,
                  hyper_res_clamp=(-30.0, 30.0), layer_types=None,
                  conv_kernel=3, tie_embeddings=False,
-                 router_renorm_eps=1e-20):
+                 router_renorm_eps=1e-20, head_dim=None,
+                 num_heads_per_layer=None, sliding_window=None,
+                 attn_gate=None, rope_parameters=None):
         self.vocab_size = vocab_size
         self.num_layers = num_layers
         self.num_heads = num_heads
@@ -76,6 +90,20 @@ class TransformerConfig:
             raise ValueError(f"num_heads ({num_heads}) must be divisible "
                              f"by num_kv_heads ({num_kv_heads})")
         self.num_kv_heads = num_kv_heads
+        # The size of a head where it is not embed_dim // num_heads (the
+        # q projection is then num_heads * head_dim wide and the output
+        # projection comes back from it), and the number of query heads
+        # layer by layer where the layers differ (None: num_heads in all).
+        self.head_dim = head_dim
+        if num_heads_per_layer is not None:
+            num_heads_per_layer = tuple(num_heads_per_layer)
+            if len(num_heads_per_layer) != num_layers or any(
+                    h % (num_kv_heads or h) for h in num_heads_per_layer):
+                raise ValueError(
+                    f"num_heads_per_layer needs num_layers ({num_layers}) "
+                    f"entries divisible by num_kv_heads ({num_kv_heads}); "
+                    f"got {num_heads_per_layer}")
+        self.num_heads_per_layer = num_heads_per_layer
         # "learned" = absolute wpe table (default); "rope" = rotary applied
         # to q/k inside each block — positions flow in explicitly, so
         # sequence-parallel shards (ring/Ulysses) embed their own offsets
@@ -83,10 +111,12 @@ class TransformerConfig:
         if pos_encoding not in ("learned", "rope"):
             raise ValueError(f"pos_encoding {pos_encoding!r} not in "
                              "('learned', 'rope')")
-        if pos_encoding == "rope" and (embed_dim // num_heads) % 2:
+        if pos_encoding == "rope" and (head_dim
+                                       or embed_dim // num_heads) % 2:
             raise ValueError(
-                f"rope needs an even head dim; got embed_dim {embed_dim} / "
-                f"num_heads {num_heads} = {embed_dim // num_heads}")
+                f"rope needs an even head dim; got "
+                f"{head_dim or embed_dim // num_heads} (head_dim, or "
+                f"embed_dim {embed_dim} / num_heads {num_heads})")
         self.pos_encoding = pos_encoding
         self.rope_theta = rope_theta
         if mlp not in ("gelu", "swiglu"):
@@ -177,8 +207,8 @@ class TransformerConfig:
         # of width expert_dim that every token passes through, beside the
         # routed ones; ``router_scoring`` "sigmoid" chooses on sigmoid score
         # + bias (the bias is the ``router_state`` collection's, moved by
-        # ``parallel.moe.update_router_bias`` and reached by no gradient)
-        # and multiplies the renormalised weights by
+        # ``parallel.moe.update_router_bias`` and reached by no gradient);
+        # under either scoring the (renormalised) weights are multiplied by
         # ``routed_scaling_factor``; ``experts_held`` experts from
         # ``experts_first`` on live on this rank (None = all num_experts):
         # the router keeps its num_experts outputs, the expert leaves hold
@@ -204,7 +234,8 @@ class TransformerConfig:
         # rank-q_lora_rank bottleneck (None = one matrix), k and v expanded
         # from a normed rank-kv_lora_rank latent.  ``rope_scaling``: YaRN's
         # dict (factor, original_max_position_embeddings, beta_fast,
-        # beta_slow, mscale, mscale_all_dim), read by the latent path.
+        # beta_slow, mscale, mscale_all_dim) for every layer's rotary
+        # embedding, latent or plain (``rope_scheme``).
         latent = (kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim,
                   v_head_dim)
         if any(v is not None for v in latent) and None in latent:
@@ -217,9 +248,6 @@ class TransformerConfig:
             raise ValueError(
                 "latent attention takes pos_encoding='rope', an even "
                 "qk_rope_head_dim and no grouped K/V heads")
-        if rope_scaling is not None and kv_lora_rank is None:
-            raise ValueError("rope_scaling is read by the latent-attention "
-                             "path only (set kv_lora_rank)")
         if rope_scaling is not None and rope_scaling.get("type") != "yarn":
             raise ValueError(f"rope_scaling type "
                              f"{rope_scaling.get('type')!r}: only 'yarn'")
@@ -242,17 +270,46 @@ class TransformerConfig:
         self.hyper_eps = hyper_eps
         self.hyper_res_clamp = tuple(hyper_res_clamp)
         # The token mixer of each block, one entry a layer: "full_attention"
-        # (what the other fields describe) or "conv", a gated short
-        # convolution of ``conv_kernel`` taps (``ShortConv``).  None = all
-        # attention.
+        # (what the other fields describe), "sliding_attention" (the same
+        # attention over the last ``sliding_window`` keys only) or "conv", a
+        # gated short convolution of ``conv_kernel`` taps (``ShortConv``).
+        # None = all full attention.
         if layer_types is not None:
             layer_types = tuple(layer_types)
-            odd = set(layer_types) - {"conv", "full_attention"}
+            odd = set(layer_types) - set(MIXERS)
             if len(layer_types) != num_layers or odd:
                 raise ValueError(
                     f"layer_types needs num_layers ({num_layers}) entries "
-                    f"of 'conv' or 'full_attention'; got {len(layer_types)}"
+                    f"of {MIXERS}; got {len(layer_types)}"
                     + (f" with {sorted(odd)}" if odd else ""))
+            if "sliding_attention" in layer_types and not (
+                    causal and sliding_window and sliding_window >= 1):
+                raise ValueError(
+                    "a 'sliding_attention' layer needs causal=True and a "
+                    f"sliding_window >= 1; got {sliding_window}")
+        self.sliding_window = sliding_window
+        # "head": each head's attention output is multiplied by the sigmoid
+        # of one value, a linear map of the block's normed input, before
+        # the output projection (arXiv:2505.06708's head-wise gate).
+        if attn_gate not in (None, "head"):
+            raise ValueError(f"attn_gate {attn_gate!r} not in (None, 'head')")
+        self.attn_gate = attn_gate
+        # The rotary scheme by layer type, as Hugging Face's
+        # ``rope_parameters`` gives it: {"full_attention": {"rope_theta",
+        # "rope_type" ("default" | "yarn"), "partial_rotary_factor", and
+        # under yarn "factor", "original_max_position_embeddings",
+        # "beta_fast", "beta_slow", "attention_factor"}, ...}.  A layer type
+        # without an entry (and every layer where this is None) takes
+        # ``rope_theta`` and ``rope_scaling``.  Keys that name no layer
+        # type are not read.
+        for kind, scheme in (rope_parameters or {}).items():
+            if kind in MIXERS and scheme.get("rope_type", "default") not in (
+                    "default", "yarn"):
+                raise ValueError(
+                    f"rope_parameters[{kind!r}]: rope_type "
+                    f"{scheme.get('rope_type')!r} not in ('default', "
+                    "'yarn')")
+        self.rope_parameters = rope_parameters
         if conv_kernel < 1:
             raise ValueError(f"conv_kernel must be >= 1; got {conv_kernel}")
         self.layer_types = layer_types
@@ -365,12 +422,12 @@ class DroplessMoe(nn.Module):
         gate = self.param("gate", init, (n, d, f))
         up = self.param("up", init, (n, d, f))
         down = self.param("down", init, (n, f, d))
-        routing = {}
+        routing = {"scale": getattr(cfg, "routed_scaling_factor", 1.0)}
         if held is not None:
             routing["held"] = (cfg.experts_first, held)
         if getattr(cfg, "router_scoring", "softmax") == "sigmoid":
             routing.update(
-                scoring="sigmoid", scale=cfg.routed_scaling_factor,
+                scoring="sigmoid",
                 renorm_eps=getattr(cfg, "router_renorm_eps", 1e-20),
                 bias=self.variable("router_state", "bias", jnp.zeros, (E,),
                                    jnp.float32).value)
@@ -448,22 +505,66 @@ def yarn_frequencies(dim: int, theta: float, scaling: dict) -> np.ndarray:
     return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
 
 
-def apply_rope(x, positions, theta: float = 10000.0, freq=None):
+def apply_rope(x, positions, theta: float = 10000.0, freq=None,
+               scale: float = 1.0, rot: int = None):
     """Rotary position embedding on ``(B, S, H, D)`` q or k.
 
     Pairs dimension ``i`` with ``i + D/2`` (the standard half-split layout)
     and rotates by ``pos * theta^(-2i/D)`` (or by ``pos * freq[i]`` where
-    the ``D / 2`` frequencies are given); angles computed in f32, result
-    cast back to the input dtype."""
-    d2 = x.shape[-1] // 2
+    the ``D / 2`` frequencies are given); cos and sin are multiplied by
+    ``scale`` (YaRN's attention factor); angles computed in f32, result
+    cast back to the input dtype.  ``rot`` < D: only the first ``rot`` dims
+    of a head are rotated (they are the ``D`` above) and the rest pass
+    through untouched."""
+    rot = x.shape[-1] if rot is None else rot
+    d2 = rot // 2
     if freq is None:
         freq = theta ** (-jnp.arange(d2, dtype=jnp.float32) / d2)
     ang = positions[..., None].astype(jnp.float32) * freq  # (B, S, d2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
-    x1, x2 = x[..., :d2].astype(jnp.float32), x[..., d2:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x1 * sin + x2 * cos], -1).astype(x.dtype)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    x1 = x[..., :d2].astype(jnp.float32)
+    x2 = x[..., d2:rot].astype(jnp.float32)
+    turned = jnp.concatenate([x1 * cos - x2 * sin,
+                              x1 * sin + x2 * cos], -1).astype(x.dtype)
+    if rot == x.shape[-1]:
+        return turned
+    return jnp.concatenate([turned, x[..., rot:]], -1)
+
+
+def rope_scheme(cfg, kind: str, dim: int) -> tuple:
+    """``(rot, theta, freq, factor)`` of the rotary embedding that a plain
+    attention layer of type ``kind`` puts on its heads of ``dim``: the first
+    ``rot`` dims of a head are rotated (``partial_rotary_factor``; the rest
+    pass through untouched), at ``theta``'s frequencies or under YaRN at
+    ``freq`` (``yarn_frequencies`` over the ``rot`` dims) with cos and sin
+    times ``factor``: the scheme's ``attention_factor``, else the ratio of
+    ``yarn_mscale`` at ``mscale`` and ``mscale_all_dim`` where both are
+    given, else ``yarn_mscale(factor)``.  The scheme is
+    ``cfg.rope_parameters[kind]``; failing that ``cfg.rope_theta`` with
+    ``cfg.rope_scaling``."""
+    scheme = (getattr(cfg, "rope_parameters", None) or {}).get(kind)
+    if scheme is None:
+        scaling = getattr(cfg, "rope_scaling", None)
+        scheme = dict(scaling or {}, rope_theta=cfg.rope_theta,
+                      rope_type="yarn" if scaling else "default")
+    theta = scheme.get("rope_theta", cfg.rope_theta)
+    rot = int(dim * scheme.get("partial_rotary_factor", 1.0))
+    if rot % 2 or not 0 < rot <= dim:
+        raise ValueError(f"{kind}: the rotary part of a head of {dim} is "
+                         f"{rot} dims; it must be even and within the head")
+    if scheme.get("rope_type", "default") != "yarn":
+        return rot, theta, None, 1.0
+    factor = scheme.get("attention_factor")
+    if factor is None:
+        mscale, all_dim = scheme.get("mscale"), scheme.get("mscale_all_dim")
+        factor = yarn_mscale(scheme["factor"])
+        if mscale and all_dim:
+            factor = (yarn_mscale(scheme["factor"], mscale)
+                      / yarn_mscale(scheme["factor"], all_dim))
+    return rot, theta, yarn_frequencies(rot, theta, scheme), float(factor)
 
 
 class LatentAttention(nn.Module):
@@ -704,16 +805,31 @@ class Block(nn.Module):
         ``h / kv_h`` (the reason GQA exists).
 
         The mixer is the block's entry of ``cfg.layer_types`` (None: every
-        block attends).  Only plain attention (MHA / GQA) takes a cache;
-        latent attention and the gated short convolution keep no decode
-        state and raise on one.
+        block attends).  Only plain full attention (MHA / GQA) takes a
+        cache; latent attention, a sliding window and the gated short
+        convolution keep no decode state and raise on one.
 
-        Device scopes of the plain attention branch: ``bf.attn.qkv``,
-        ``bf.attn.norm``, ``bf.attn.rope``, ``bf.attn.attend`` (the K/V
-        fan-out and the attention itself) and ``bf.attn.out``."""
+        Plain attention reads its sizes by layer: ``cfg.head_dim`` (None:
+        ``embed_dim // num_heads``), the block's entry of
+        ``cfg.num_heads_per_layer``, the rotary scheme of its layer type
+        (``rope_scheme``), the window of a ``"sliding_attention"`` layer
+        (handed to ``attn_impl`` as ``window=``), and under
+        ``cfg.attn_gate == "head"`` the leaf ``attn_gate`` ``(embed_dim,
+        heads)``: ``o_h <- sigmoid(y W_g)_h o_h`` before the output
+        projection.
+
+        Device scopes of the plain attention branch, a full layer's:
+        ``bf.attn.qkv``, ``bf.attn.norm``, ``bf.attn.rope``,
+        ``bf.attn.attend`` (the K/V fan-out and the attention itself),
+        ``bf.attn.gate`` and ``bf.attn.out``; a sliding layer's are the
+        family ``bf.swa.*`` of the same names."""
         cfg = self.cfg
-        h = cfg.num_heads
-        d = cfg.embed_dim // h
+        layer_types = getattr(cfg, "layer_types", None)
+        kind = (layer_types[self.layer_idx] if layer_types is not None
+                else "full_attention")
+        per_layer = getattr(cfg, "num_heads_per_layer", None)
+        h = per_layer[self.layer_idx] if per_layer else cfg.num_heads
+        d = getattr(cfg, "head_dim", None) or cfg.embed_dim // cfg.num_heads
         kv_h = cfg.num_kv_heads or h
         rope = getattr(cfg, "pos_encoding", "learned") == "rope"
         if rope and positions is None and cache is None:
@@ -723,14 +839,13 @@ class Block(nn.Module):
         x, join = self._residual(x, "hc_attn")
         y = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype)(x)
         B, S = y.shape[0], y.shape[1]
-        layer_types = getattr(cfg, "layer_types", None)
-        conv = layer_types is not None \
-            and layer_types[self.layer_idx] == "conv"
+        conv, sliding = kind == "conv", kind == "sliding_attention"
         latent = getattr(cfg, "kv_lora_rank", None) is not None
-        if cache is not None and (conv or latent):
+        if cache is not None and (conv or latent or sliding):
             raise NotImplementedError(
-                "only plain attention takes a decode cache: latent "
-                "attention and the gated short convolution do not")
+                "only plain full attention takes a decode cache: latent "
+                "attention, a sliding window and the gated short "
+                "convolution do not")
         if conv:
             x = join(ShortConv(cfg, name="conv")(y))
             return self._ffn(x, eps)
@@ -738,9 +853,10 @@ class Block(nn.Module):
             x = join(LatentAttention(cfg, self.attn_impl, name="mla")(
                 y, positions))
             return self._ffn(x, eps)
-        with timeline.device_scope("bf.attn.qkv"):
+        scope = "bf.swa" if sliding else "bf.attn"
+        with timeline.device_scope(f"{scope}.qkv"):
             if kv_h == h:
-                qkv = nn.Dense(3 * cfg.embed_dim, use_bias=False,
+                qkv = nn.Dense(3 * h * d, use_bias=False,
                                dtype=cfg.dtype, name="qkv")(y)
                 # Head-interleaved fused layout [q_h0 k_h0 v_h0 | q_h1 ...]:
                 # a pure relabeling of kernel columns that keeps tensor-
@@ -756,13 +872,13 @@ class Block(nn.Module):
                 # only up to kv_h ways — beyond that GSPMD re-gathers K/V
                 # per block, acceptable since the kv kernel is the small
                 # one).
-                q = nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
+                q = nn.Dense(h * d, use_bias=False, dtype=cfg.dtype,
                              name="q")(y).reshape(B, S, h, d)
                 kv = nn.Dense(2 * kv_h * d, use_bias=False, dtype=cfg.dtype,
                               name="kv")(y).reshape(B, S, kv_h, 2, d)
                 k1, v1 = kv[..., 0, :], kv[..., 1, :]
         qk_norm = getattr(cfg, "qk_norm", False)
-        with timeline.device_scope("bf.attn.norm"):
+        with timeline.device_scope(f"{scope}.norm"):
             norm = functools.partial(nn.RMSNorm, epsilon=eps,
                                      dtype=cfg.dtype)
             if qk_norm == "head":
@@ -779,9 +895,10 @@ class Block(nn.Module):
                     k1.reshape(B, S, kv_h * d)).reshape(B, S, kv_h, d)
         if rope:
             # rotate the kv_h shared heads ONCE, before any fan-out to h
-            with timeline.device_scope("bf.attn.rope"):
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k1 = apply_rope(k1, positions, cfg.rope_theta)
+            with timeline.device_scope(f"{scope}.rope"):
+                rot, theta, freq, factor = rope_scheme(cfg, kind, d)
+                q = apply_rope(q, positions, theta, freq, factor, rot)
+                k1 = apply_rope(k1, positions, theta, freq, factor, rot)
         rep = h // kv_h
         if cache is None:
             if (self.is_mutable_collection("kv_cache")
@@ -791,11 +908,15 @@ class Block(nn.Module):
                 # Gated out of init(), which would otherwise bake a stale
                 # entry into the variables users carry around.
                 self.sow("kv_cache", "kv_entries", (k1, v1))
-            with timeline.device_scope("bf.attn.attend"):
+            with timeline.device_scope(f"{scope}.attend"):
                 k = jnp.repeat(k1, rep, axis=2) if rep > 1 else k1
                 v = jnp.repeat(v1, rep, axis=2) if rep > 1 else v1
+                # only a sliding layer names a window: an attn_impl that
+                # knows none serves every model without one
+                reach = {"window": cfg.sliding_window} if sliding else {}
                 attn = self.attn_impl(
-                    q, k, v, causal=getattr(self.cfg, "causal", True))
+                    q, k, v, causal=getattr(self.cfg, "causal", True),
+                    **reach)
         else:
             ck, cv = cache
             idx = positions[0, 0]  # decode positions are batch-uniform
@@ -807,7 +928,7 @@ class Block(nn.Module):
             # grouped attention of the single query over the cache — never
             # materializes h-head K/V
             L = ck.shape[1]
-            with timeline.device_scope("bf.attn.attend"):
+            with timeline.device_scope(f"{scope}.attend"):
                 qg = q.reshape(B, S, kv_h, rep, d)
                 logits = jnp.einsum("bqgrd,blgd->bgrql", qg, ck) \
                     / np.sqrt(d)
@@ -816,8 +937,14 @@ class Block(nn.Module):
                                    jnp.finfo(jnp.float32).min)
                 probs = nn.softmax(logits, axis=-1).astype(cfg.dtype)
                 attn = jnp.einsum("bgrql,blgd->bqgrd", probs, cv)
-        with timeline.device_scope("bf.attn.out"):
-            attn = attn.reshape(B, S, cfg.embed_dim)
+        if getattr(cfg, "attn_gate", None) == "head":
+            with timeline.device_scope(f"{scope}.gate"):
+                gate = nn.Dense(h, use_bias=False, dtype=cfg.dtype,
+                                name="attn_gate")(y)
+                attn = attn.reshape(B, S, h, d) * nn.sigmoid(
+                    gate.astype(jnp.float32)).astype(cfg.dtype)[..., None]
+        with timeline.device_scope(f"{scope}.out"):
+            attn = attn.reshape(B, S, h * d)
             x = join(nn.Dense(cfg.embed_dim, use_bias=False,
                               dtype=cfg.dtype, name="proj")(attn))
         x = self._ffn(x, eps)
@@ -883,7 +1010,7 @@ class TransformerLM(nn.Module):
         streams = getattr(cfg, "hyper_streams", 1)
         mixers = getattr(cfg, "layer_types", None) \
             or ("full_attention",) * cfg.num_layers
-        for kind in ("conv", "full_attention"):
+        for kind in MIXERS:
             # what was built last, by kind of mixer; set, not added to: a
             # model is traced more than once
             telemetry.set_gauge("bf_model_layers_total",
@@ -900,6 +1027,11 @@ class TransformerLM(nn.Module):
                 raise NotImplementedError(
                     "KV-cache decoding through a gated short convolution "
                     "is not supported: the layer keeps no decode state")
+            if "sliding_attention" in mixers:
+                raise NotImplementedError(
+                    "KV-cache decoding through a sliding-window layer is "
+                    "not supported: its cache of the last sliding_window "
+                    "keys is not written")
             if not getattr(cfg, "causal", True):
                 raise ValueError(
                     "KV-cache decoding requires causal=True: the decode "
@@ -984,7 +1116,7 @@ def init_cache(cfg, batch: int, max_len: int):
     ``(batch, max_len, kv_heads, head_dim)`` — kv_heads, not num_heads, so
     GQA/MQA caches are ``num_heads / num_kv_heads`` times smaller."""
     h = cfg.num_heads
-    d = cfg.embed_dim // h
+    d = getattr(cfg, "head_dim", None) or cfg.embed_dim // h
     kv_h = cfg.num_kv_heads or h
     z = jnp.zeros((batch, max_len, kv_h, d), cfg.dtype)
     return [(z, z) for _ in range(cfg.num_layers)]

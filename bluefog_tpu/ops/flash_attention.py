@@ -36,6 +36,18 @@ three kinds at staging.  ``bf_flash_dkv`` computes its scores as ``k q^T``,
 keys along the rows as its accumulators have them, so it transposes no
 tile.
 
+A window (``window=W``, with ``causal``: query ``i`` sees the keys ``i - W <
+j <= i``) runs as ``bf_flash_win_fwd / dq / dkv``.  The band of visible
+pairs has two edges, and a tile is crossed by the diagonal, by the window's
+lower edge or by both (a body at each static offset, as above), interior
+between them, and does not exist beyond them: the grid's reduction
+dimension covers the blocks a block reaches and no more (``_Band``), so the
+cost grows with ``S x W``.  The forward of a narrow band (up to
+``_BAND_KEYS`` keys a query block) has no reduction dimension at all: a grid
+step is handed the band's key blocks as pieces and each row chunk runs one
+softmax over its visible range (``_fwd_band_kernel``), since under a narrow
+window a step of the running state cost more than the products it served.
+
 What bounds a tile (one v5e chip, PR 37, ``PERF.md``): the backward runs its
 products at 85 to 91% of the array's peak; the forward spends 1.1 to 1.4 us
 of every 4.2 us tile on the softmax state (two reductions along the lanes,
@@ -53,6 +65,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -76,32 +89,103 @@ _BWD_CHUNK = 256
 _LANES = 128
 
 
-def _crossings(block_q: int, block_k: int) -> list:
-    """The offsets ``first query - first key`` at which the diagonal runs
-    through a ``(block_q, block_k)`` tile: the multiples of the blocks' gcd
-    strictly between ``-block_q`` (the first key is past the last query:
-    *skipped*) and ``block_k - 1`` (the last key is at or before the first
-    query: *interior*).  One for equal blocks, two where one is twice the
-    other (the backward at heads over 128 runs 1024 x 512)."""
+def _crossings(block_q: int, block_k: int, window: int = None) -> list:
+    """The offsets ``first query - first key`` at which an edge of the
+    visible pairs runs through a ``(block_q, block_k)`` tile: the multiples
+    of the blocks' gcd strictly between ``-block_q`` (the first key is past
+    the last query: *skipped*) and ``block_k - 1`` (the last key is at or
+    before the first query: *interior*).  One for equal blocks, two where
+    one is twice the other (the backward at heads over 128 runs 1024 x
+    512).  Under a ``window`` the pairs are the band ``0 <= query - key <
+    window``: the interior ends at ``window - block_q``, and from there to
+    ``window + block_k - 1`` (the last key is ``window`` or more before the
+    first query: the tile is dead again) the lower edge crosses.  A tile
+    may be crossed by both edges (a window of 512 under blocks of 1024
+    always is)."""
     g = math.gcd(block_q, block_k)
-    return [m * g for m in range(1 - block_q // g, block_k // g)
-            if m * g < block_k - 1]
+    if window is None:
+        return [m * g for m in range(1 - block_q // g, block_k // g)
+                if m * g < block_k - 1]
+    return [m * g for m in range(1 - block_q // g,
+                                 -(-(window + block_k - 1) // g))
+            if not block_k - 1 <= m * g <= window - block_q]
 
 
-def _on_tiles(tile, qi, kb, *, causal: bool, block_q: int, block_k: int):
+class _Band(NamedTuple):
+    """The reduction dimension of one windowed grid.  The ``own`` blocks
+    are the query blocks, which reach ``window - 1`` positions back to their
+    keys and none ahead, or (``by_keys``: ``bf_flash_dkv``) the key blocks,
+    which reach none back and ``window - 1`` ahead to their queries.  The
+    grid's steps count from the first block an own block reaches; its index
+    maps, kernel bodies and tile counts share the one rule."""
+    window: int
+    own: int        # positions of an own block
+    other: int      # positions of a block of the reduction dimension
+    by_keys: bool
+    seq: int
+
+    def reach(self, np_, i) -> tuple:
+        """``(first, last)`` blocks that own block ``i`` reaches; ``np_`` is
+        ``numpy`` at staging and ``jax.numpy`` for a grid index."""
+        back, ahead = ((0, self.window - 1) if self.by_keys
+                       else (self.window - 1, 0))
+        return (np_.maximum(i * self.own - back, 0) // self.other,
+                np_.minimum((i + 1) * self.own - 1 + ahead,
+                            self.seq - 1) // self.other)
+
+    @classmethod
+    def pair(cls, window, block_q: int, block_k: int, seq: int) -> tuple:
+        """The bands by query blocks (``bf_flash_fwd``, ``bf_flash_dq``)
+        and by key blocks (``bf_flash_dkv``); None twice without a
+        window."""
+        if window is None:
+            return None, None
+        return (cls(window, block_q, block_k, False, seq),
+                cls(window, block_k, block_q, True, seq))
+
+    @property
+    def steps(self) -> int:
+        """The most blocks any own block reaches."""
+        first, last = self.reach(np, np.arange(self.seq // self.own))
+        return int((last - first + 1).max())
+
+    def block(self, np_, i, step) -> tuple:
+        """``(block, live)`` of step ``step`` of own block ``i``: a step
+        past the last block it reaches is not live."""
+        first, last = self.reach(np_, i)
+        return first + step, first + step <= last
+
+
+def _step_of(band, i, step) -> tuple:
+    """``(block, live, window)`` of a grid step: the step itself, nothing
+    to be live for and no window without a ``band``."""
+    if band is None:
+        return step, None, None
+    return band.block(jnp, i, step) + (band.window,)
+
+
+def _on_tiles(tile, qi, kb, *, causal: bool, block_q: int, block_k: int,
+              window: int = None, live=None):
     """Run ``tile(offset)`` as the tile ``(qi, kb)`` needs.  ``offset`` is
     ``None`` for an *interior* tile and every tile of a non-causal call: no
     score is masked, so it builds no iota, compare or select.  A *crossed*
-    tile gets its diagonal's offset as a Python int, a body for each of the
-    blocks' crossings, so the mask and the keys it may skip are static.  A
-    *skipped* tile runs nothing."""
+    tile gets its offset as a Python int, a body for each of the blocks'
+    crossings, so the mask and the keys it may skip are static.  A
+    *skipped* tile runs nothing.  Under a ``window`` the interior ends
+    where the lower edge begins to cross, and ``live`` (a step of a
+    windowed grid past its block's reach is not) joins every condition."""
     if not causal:
         tile(None)
         return
     offset = qi * block_q - kb * block_k
-    pl.when(offset >= block_k - 1)(functools.partial(tile, None))
-    for crossing in _crossings(block_q, block_k):
-        pl.when(offset == crossing)(functools.partial(tile, crossing))
+    when = lambda hit: pl.when(hit if live is None else live & hit)
+    interior = offset >= block_k - 1
+    if window is not None:
+        interior &= offset <= window - block_q
+    if window is None or block_k - 1 <= window - block_q:
+        when(interior)(functools.partial(tile, None))
+    for crossing in _crossings(block_q, block_k, window):
+        when(offset == crossing)(functools.partial(tile, crossing))
 
 
 def _chunk_rows(offset, block: int, want: int) -> int:
@@ -110,46 +194,76 @@ def _chunk_rows(offset, block: int, want: int) -> int:
     return want if offset is not None and block % want == 0 else block
 
 
-def _keys_of(offset, q0: int, rows: int, block_k: int):
-    """``(hi, masked)`` for the queries ``[q0, q0 + rows)`` of a tile: they
-    need the keys ``[0, hi)`` (whole lane tiles; 0: none), and ``masked``
-    says whether some pair among those is past the diagonal."""
+def _keys_of(offset, q0: int, rows: int, block_k: int, window: int = None):
+    """``(lo, hi, masked)`` for the queries ``[q0, q0 + rows)`` of a tile:
+    they need the keys ``[lo, hi)`` (whole lane tiles; ``hi == lo``: none),
+    and ``masked`` says whether some pair among those is not visible."""
     if offset is None:
-        return block_k, False
-    hi = min(block_k, -(-(offset + q0 + rows) // _LANES) * _LANES)
-    return (hi, offset + q0 < hi - 1) if hi > 0 else (0, False)
+        return 0, block_k, False
+    first, last = offset + q0, offset + q0 + rows - 1  # as keys of the tile
+    hi = min(block_k, -(-(last + 1) // _LANES) * _LANES)
+    lo = 0 if window is None else max(
+        (first - window + 1) // _LANES * _LANES, 0)
+    if hi <= lo:
+        return 0, 0, False
+    return lo, hi, first < hi - 1 or (window is not None
+                                      and lo <= last - window)
 
 
-def _queries_of(offset, k0: int, rows: int, block_q: int):
-    """``(lo, masked)`` for the keys ``[k0, k0 + rows)`` of a tile: they are
-    seen by the queries ``[lo, block_q)`` (``block_q``: none)."""
+def _queries_of(offset, k0: int, rows: int, block_q: int,
+                window: int = None):
+    """``(lo, hi, masked)`` for the keys ``[k0, k0 + rows)`` of a tile:
+    they are seen by the queries ``[lo, hi)`` (``hi == lo``: none)."""
     if offset is None:
-        return 0, False
+        return 0, block_q, False
     lo = max((k0 - offset) // _LANES * _LANES, 0)
-    return ((lo, k0 + rows - 1 > lo + offset) if lo < block_q
-            else (block_q, False))
+    hi = block_q if window is None else min(
+        block_q, -(-(k0 + rows - 1 - offset + window) // _LANES) * _LANES)
+    if hi <= lo:
+        return 0, 0, False
+    return lo, hi, k0 + rows - 1 > lo + offset or (
+        window is not None and k0 <= hi - 1 + offset - window)
 
 
-def _causal(s, *, key0, query0, keys_axis: int):
-    """``s`` with the pairs whose key is past their query at ``-1e30``;
-    keys run along ``keys_axis`` from ``key0``, queries along the other axis
-    from ``query0`` (the tile's offset included)."""
+def _visible(s, *, key0, query0, keys_axis: int, window: int = None,
+             floor=None):
+    """``s`` with the pairs that are not visible at ``-1e30``: those whose
+    key is past their query and, under a ``window``, those whose key is
+    ``window`` or more before it.  Keys run along ``keys_axis`` from
+    ``key0``, queries along the other axis from ``query0`` (the tile's
+    offset included); an edge that does not run through ``s`` costs no
+    compare, and ``s`` comes back as it is where none does.  ``floor`` (a
+    traced scalar): keys under it do not exist."""
+    n_keys, n_queries = s.shape[keys_axis], s.shape[1 - keys_axis]
     along = lambda axis, first: first + jax.lax.broadcasted_iota(
         jnp.int32, tuple(n if a == axis else 1 for a, n in enumerate(s.shape)),
         axis)
-    return jnp.where(along(keys_axis, key0) <= along(1 - keys_axis, query0),
-                     s, _NEG_INF)
+    edges = []
+    if key0 + n_keys - 1 > query0:
+        edges.append(along(keys_axis, key0) <= along(1 - keys_axis, query0))
+    if window is not None and key0 <= query0 + n_queries - 1 - window:
+        edges.append(along(keys_axis, key0) > along(1 - keys_axis,
+                                                    query0 - window))
+    if floor is not None:
+        edges.append(along(keys_axis, key0) >= floor)
+    if not edges:
+        return s
+    return jnp.where(functools.reduce(jnp.logical_and, edges), s, _NEG_INF)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale: float, causal: bool, block_q: int, block_k: int):
+                *, scale: float, causal: bool, block_q: int, block_k: int,
+                band: _Band = None):
     """Grid (bh, q-block, k-block): online-softmax recurrence with the
     running (acc, m, l) state in f32 VMEM scratch across the sequential
     innermost k dimension.  Every operand is a block — VMEM stays O(block),
-    so sequence length is bounded by HBM, not VMEM."""
-    qi, kb = pl.program_id(1), pl.program_id(2)
+    so sequence length is bounded by HBM, not VMEM.  In a windowed grid
+    (``band``) the innermost dimension counts from the first key block the
+    query block reaches."""
+    qi, step = pl.program_id(1), pl.program_id(2)
+    kb, live, window = _step_of(band, qi, step)
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -158,16 +272,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     def tile(offset):
         rows = _chunk_rows(offset, block_q, _FWD_CHUNK)
         for q0 in range(0, block_q, rows):
-            hi, masked = _keys_of(offset, q0, rows, block_k)
-            if not hi:
+            lo, hi, masked = _keys_of(offset, q0, rows, block_k, window)
+            if hi == lo:
                 continue
             own = slice(q0, q0 + rows)
             q = q_ref[own, :].astype(jnp.float32)          # (rows, D)
-            k = k_ref[:hi, :].astype(jnp.float32)          # (hi, D)
-            v = v_ref[:hi, :].astype(jnp.float32)          # (hi, Dv)
+            k = k_ref[lo:hi, :].astype(jnp.float32)        # (hi - lo, D)
+            v = v_ref[lo:hi, :].astype(jnp.float32)        # (hi - lo, Dv)
             s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
             if masked:
-                s = _causal(s, key0=0, query0=q0 + offset, keys_axis=1)
+                s = _visible(s, key0=lo, query0=q0 + offset, keys_axis=1,
+                             window=window)
             m_prev = m_ref[own, :]
             m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -178,9 +293,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 p, v, preferred_element_type=jnp.float32)
             m_ref[own, :] = m_new
 
-    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k)
+    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k,
+              window=window, live=live)
 
-    @pl.when(kb == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _store():
         l = jnp.maximum(l_ref[:], 1e-30)
         o_ref[:] = (acc_ref[:] / l).astype(o_ref.dtype)
@@ -189,6 +305,68 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         # (8,128)-tiled or full) — a flat (block_q,) lse block fails to
         # lower on TPU.
         lse_ref[:] = m_ref[:] + jnp.log(l)
+
+
+# Keys of a narrow band: up to this many (a query block's own and the
+# window's before them) the windowed forward takes a row chunk's whole
+# visible range in one softmax pass (512 rows x 2048 float32 scores are 4
+# MiB of VMEM); a wider band goes block by block through the grid.
+_BAND_KEYS = 2048
+
+
+def _fwd_band_kernel(q_ref, *refs, scale: float, block_q: int, block_k: int,
+                     window: int, pieces: int, first: int):
+    """Grid (bh, q-block), no reduction dimension: the forward of a narrow
+    window.  ``refs`` are ``pieces`` key blocks, as many value blocks, then
+    ``o`` and ``lse``: piece ``j`` is key block ``qi * block_q / block_k +
+    first + j`` (``first <= 0``: the window's blocks before the query
+    block's own), so its offset from the query block is static.  A row
+    chunk takes the lane tiles of keys between its two edges from the
+    pieces they lie in and runs ONE softmax over them: no running state,
+    nothing rescaled (one v5e chip, PR 40: a step of the online state cost
+    1.3 us a 512-row chunk, more than the chunk's products)."""
+    k_refs, v_refs = refs[:pieces], refs[pieces:2 * pieces]
+    o_ref, lse_ref = refs[2 * pieces:]
+    offset = -first * block_k           # first query - first key of piece 0
+    # piece j of the first query blocks may lie before the sequence: its
+    # block index was clamped to 0 and its keys do not exist
+    floor = -(pl.program_id(1) * block_q - offset)
+    rows = _chunk_rows(offset, block_q, _FWD_CHUNK)
+    for q0 in range(0, block_q, rows):
+        lo, hi, _ = _keys_of(offset, q0, rows, pieces * block_k, window)
+        own = slice(q0, q0 + rows)
+        q = q_ref[own, :].astype(jnp.float32)              # (rows, D)
+        scores, values = [], []
+        for j in range(pieces):
+            a, b = max(lo, j * block_k), min(hi, (j + 1) * block_k)
+            if b <= a:
+                continue
+            part = slice(a - j * block_k, b - j * block_k)
+            k = k_refs[j][part, :].astype(jnp.float32)
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            scores.append(_visible(
+                s, key0=a, query0=q0 + offset, keys_axis=1, window=window,
+                floor=floor if j < -first else None))
+            values.append(v_refs[j][part, :].astype(jnp.float32))
+        m = functools.reduce(jnp.maximum, [
+            s.max(axis=-1, keepdims=True) for s in scores])
+        probs = [jnp.exp(s - m) for s in scores]
+        l = sum(p.sum(axis=-1, keepdims=True) for p in probs)
+        acc = sum(jnp.dot(p, v, preferred_element_type=jnp.float32)
+                  for p, v in zip(probs, values))
+        o_ref[own, :] = (acc / l).astype(o_ref.dtype)
+        lse_ref[own, :] = m + jnp.log(l)
+
+
+def _band_pieces(window, block_q: int, block_k: int):
+    """``(pieces, first)`` of the banded forward, or None where the call
+    goes through the grid: no window, blocks that do not nest, or a band of
+    more than ``_BAND_KEYS`` keys."""
+    if window is None or block_q % block_k:
+        return None
+    first = (1 - window) // block_k
+    pieces = block_q // block_k - first
+    return (pieces, first) if pieces * block_k <= _BAND_KEYS else None
 
 
 def _fit_block(want: int, seq_len: int) -> int:
@@ -207,73 +385,147 @@ def _fit_block(want: int, seq_len: int) -> int:
     return b
 
 
-def _staged(kernel: str, heads: int, n_qb: int, n_kb: int, block_q: int,
-            block_k: int, causal: bool):
-    """Count one staging of ``kernel`` and the tiles of its call by kind
-    (``heads`` grids of ``n_qb x n_kb``; a non-causal call's are all
-    interior).  A wrapper's Python runs at trace time: once a shape behind
-    ``jax.jit``, once a call for a bare kernel, and each staging is a Mosaic
-    lowering."""
+def _window_block(want: int, window) -> int:
+    """The key block of a windowed forward: no longer than the window
+    rounded up to a power of two (128 lanes at least), so that no piece of
+    a band is crossed by both edges.  From the window alone;
+    ``window=None`` leaves ``want``."""
+    if window is None:
+        return want
+    return min(want, max(_LANES, 1 << (window - 1).bit_length()))
+
+
+def _grid_offsets(n_qb: int, n_kb: int, block_q: int, block_k: int,
+                  band: _Band = None) -> list:
+    """Of every step of one head's grid the offset ``first query - first
+    key`` of its tile (None: a step of a windowed grid past its block's
+    reach); of a banded forward, of every piece of every query block."""
+    if band is None:
+        return [qi * block_q - kb * block_k
+                for qi in range(n_qb) for kb in range(n_kb)]
+    pieces = not band.by_keys and _band_pieces(band.window, block_q, block_k)
+    if pieces:      # a piece before the sequence's start is a dead step
+        return [-(pieces[1] + j) * block_k
+                if qi * block_q + (pieces[1] + j) * block_k >= 0 else None
+                for qi in range(n_qb) for j in range(pieces[0])]
+    sign = -1 if band.by_keys else 1
+    offsets = []
+    for i in range(band.seq // band.own):
+        for step in range(band.steps):
+            j, live = band.block(np, i, step)
+            offsets.append(sign * int(i * band.own - j * band.other)
+                           if live else None)
+    return offsets
+
+
+def _kernel_name(kind: str, band) -> str:
+    """``bf_flash_<kind>``, or ``bf_flash_win_<kind>`` for a windowed call:
+    the device trace tells a model's window layers from its full ones."""
+    return f"bf_flash_{'win_' if band is not None else ''}{kind}"
+
+
+def _staged(kind: str, heads: int, seq: int, block_q: int, block_k: int,
+            causal: bool, band: _Band = None):
+    """Count one staging of the kernel ``kind`` and the tiles of its call
+    by kind (``heads`` grids of ``_grid_offsets``; a non-causal call's are
+    all interior, a windowed grid's dead steps are skipped).  A wrapper's
+    Python runs at trace time: once a shape behind ``jax.jit``, once a call
+    for a bare kernel, and each staging is a Mosaic lowering."""
+    kernel = _kernel_name(kind, band)
     telemetry.inc("bf_kernel_stagings_total", kernel=kernel)
-    offsets = [qi * block_q - kb * block_k
-               for qi in range(n_qb) for kb in range(n_kb)]
-    skipped = sum(causal and o <= -block_q for o in offsets)
-    interior = sum(not causal or o >= block_k - 1 for o in offsets)
-    for kind, n in (("skipped", skipped), ("interior", interior),
-                    ("crossed", len(offsets) - skipped - interior)):
+    offsets = _grid_offsets(seq // block_q, seq // block_k, block_q, block_k,
+                            band)
+    last_interior = math.inf if band is None else band.window - block_q
+    skipped = sum(o is None or (causal and o <= -block_q) for o in offsets)
+    interior = sum(o is not None and (
+        not causal or block_k - 1 <= o <= last_interior) for o in offsets)
+    for tiles, n in (("skipped", skipped), ("interior", interior),
+                     ("crossed", len(offsets) - skipped - interior)):
         telemetry.inc("bf_flash_tiles_total", heads * n, kernel=kernel,
-                      kind=kind)
+                      kind=tiles)
+
+
+def _reduction_index(band, causal_clamp):
+    """``(i, j) ->`` the block of the reduction dimension that step ``j`` of
+    own block ``i`` loads.  Without a window the step itself, clamped into
+    the causal range by ``causal_clamp`` (falsy: not causal): a skipped
+    step then repeats the previous block index and Pallas elides the DMA —
+    without this, masked tiles would still stream their blocks from HBM (~2x
+    input traffic).  In a windowed grid the steps count from the first
+    block that ``i`` reaches and stop at the last."""
+    if band is not None:
+        def reached(i, j):
+            first, last = band.reach(jnp, i)
+            return jnp.minimum(first + j, last)
+        return reached
+    return causal_clamp or (lambda i, j: j)
 
 
 # Each kernel call sits behind ``jax.jit``: the stagings of one shape (a
 # layer's primal, its rule's forward and its remat recompute, layer after
 # layer) then trace the kernel's tile bodies once and not once each.
-_STATIC = ("scale", "causal", "block_q", "block_k", "interpret", "vma")
+_STATIC = ("scale", "causal", "block_q", "block_k", "interpret", "vma",
+           "window")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _fwd_call(qf, kf, vf, *, scale, causal, block_q, block_k, interpret,
-              vma):
+              vma, window=None):
     """``bf_flash_fwd`` on folded ``(B*H, S, D)`` operands: ``o`` and the
     per-row logsumexp ``(B*H, S, 1)``."""
     bh, S, D = qf.shape
     Dv = vf.shape[-1]
-    if causal:
-        # Clamp the k index into this q-block's un-masked range: skipped
-        # steps repeat the previous block index and Pallas elides the DMA.
-        kv_idx = lambda b, i, j: (
-            b, jnp.minimum(j, ((i + 1) * block_q - 1) // block_k), 0)
-    else:
-        kv_idx = lambda b, i, j: (b, j, 0)
+    band, _ = _Band.pair(window, block_q, block_k, S)
+    own = lambda b, i, *_: (b, i, 0)
+    out = dict(
+        out_specs=[pl.BlockSpec((None, block_q, Dv), own),
+                   pl.BlockSpec((None, block_q, 1), own)],
+        out_shape=[jax.ShapeDtypeStruct((bh, S, Dv), qf.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((bh, S, 1), jnp.float32, vma=vma)],
+        interpret=interpret)
+    pieces = _band_pieces(window, block_q, block_k)
+    if pieces:
+        n, first = pieces
+        piece = lambda dim, j: pl.BlockSpec(
+            (None, block_k, dim), lambda b, i: (b, jnp.maximum(
+                i * (block_q // block_k) + first + j, 0), 0))
+        return pl.pallas_call(
+            functools.partial(_fwd_band_kernel, scale=scale, block_q=block_q,
+                              block_k=block_k, window=window, pieces=n,
+                              first=first),
+            name=_kernel_name("fwd", band), grid=(bh, S // block_q),
+            in_specs=[pl.BlockSpec((None, block_q, D), own)]
+            + [piece(D, j) for j in range(n)]
+            + [piece(Dv, j) for j in range(n)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            **out,
+        )(qf, *[kf] * n, *[vf] * n)
+    # never past this q-block's diagonal
+    red = _reduction_index(band, causal and (lambda i, j: jnp.minimum(
+        j, ((i + 1) * block_q - 1) // block_k)))
+    kv_idx = lambda b, i, j: (b, red(i, j), 0)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k),
-        name="bf_flash_fwd",
-        grid=(bh, S // block_q, S // block_k),
+                          block_q=block_q, block_k=block_k, band=band),
+        name=_kernel_name("fwd", band),
+        grid=(bh, S // block_q, band.steps if band else S // block_k),
         in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, block_q, D), own),
             pl.BlockSpec((None, block_k, D), kv_idx),
             pl.BlockSpec((None, block_k, Dv), kv_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, Dv), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, S, Dv), qf.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, S, 1), jnp.float32, vma=vma),
         ],
         scratch_shapes=[pltpu.VMEM((block_q, Dv), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        **out,
     )(qf, kf, vf)
 
 
 def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
-         scale=None):
+         scale=None, window=None):
     B, S, H, D = q.shape
     Dv = v.shape[-1]
     if scale is None:
@@ -282,12 +534,12 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(bh, S, t.shape[-1])
     qf, kf, vf = fold(q), fold(k), fold(v)
     block_q = _fit_block(block_q, S)
-    block_k = _fit_block(block_k, S)
-    _staged("bf_flash_fwd", bh, S // block_q, S // block_k, block_q, block_k,
-            causal)
+    block_k = _fit_block(_window_block(block_k, window), S)
+    _staged("fwd", bh, S, block_q, block_k, causal,
+            _Band.pair(window, block_q, block_k, S)[0])
     o, lse = _fwd_call(qf, kf, vf, scale=float(scale), causal=causal,
                        block_q=block_q, block_k=block_k, interpret=interpret,
-                       vma=vma)
+                       vma=vma, window=window)
     lse = lse[..., 0]
     unfold = lambda t: t.reshape(B, H, S, Dv).transpose(0, 2, 1, 3)
     return unfold(o), (qf, kf, vf, o, lse, (B, S, H, D, scale, causal))
@@ -295,57 +547,62 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                acc_ref, *, scale: float, causal: bool, block_q: int,
-               block_k: int):
+               block_k: int, band: _Band = None):
     """Grid (bh, q-block, k-block): recompute P from the saved logsumexp and
     accumulate ds @ K into a f32 VMEM scratch across the (sequential,
     innermost) k dimension; one cast-and-store to the output block on the
     last step.  Every operand is a block — VMEM stays O(block), never
     O(S)."""
-    qi, kb = pl.program_id(1), pl.program_id(2)
+    qi, step = pl.program_id(1), pl.program_id(2)
+    kb, live, window = _step_of(band, qi, step)
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     def tile(offset):
         rows = _chunk_rows(offset, block_q, _BWD_CHUNK)
         for q0 in range(0, block_q, rows):
-            hi, masked = _keys_of(offset, q0, rows, block_k)
-            if not hi:
+            lo, hi, masked = _keys_of(offset, q0, rows, block_k, window)
+            if hi == lo:
                 continue
             own = slice(q0, q0 + rows)
             q = q_ref[own, :].astype(jnp.float32)          # (rows, D)
-            k = k_ref[:hi, :].astype(jnp.float32)          # (hi, D)
+            k = k_ref[lo:hi, :].astype(jnp.float32)        # (hi - lo, D)
             s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
             if masked:
-                s = _causal(s, key0=0, query0=q0 + offset, keys_axis=1)
+                s = _visible(s, key0=lo, query0=q0 + offset, keys_axis=1,
+                             window=window)
             p = jnp.exp(s - lse_ref[own, :])               # masked -> 0
             dp = jnp.dot(do_ref[own, :].astype(jnp.float32),
-                         v_ref[:hi, :].astype(jnp.float32).T,
+                         v_ref[lo:hi, :].astype(jnp.float32).T,
                          preferred_element_type=jnp.float32)
             ds = p * (dp - delta_ref[own, :]) * scale
             acc_ref[own, :] += jnp.dot(ds, k,
                                        preferred_element_type=jnp.float32)
 
-    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k)
+    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k,
+              window=window, live=live)
 
-    @pl.when(kb == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _store():
         dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                causal: bool, block_q: int, block_k: int):
+                causal: bool, block_q: int, block_k: int,
+                band: _Band = None):
     """Grid (bh, k-block, q-block): accumulate P.T @ dO and ds.T @ Q into f32
     VMEM scratches across the (sequential, innermost) q dimension.  The
     scores are computed as ``k q^T``, (BK, BQ) with the keys along the rows
     as the accumulators have them, so no (BQ, BK) tile is transposed; the
     per-query ``lse`` and ``delta`` columns are turned to rows once a
     tile."""
-    kb, qi = pl.program_id(1), pl.program_id(2)
+    kb, step = pl.program_id(1), pl.program_id(2)
+    qi, live, window = _step_of(band, kb, step)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -354,28 +611,30 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lse, delta = lse_ref[:].T, delta_ref[:].T          # (1, BQ)
         rows = _chunk_rows(offset, block_k, _BWD_CHUNK)
         for k0 in range(0, block_k, rows):
-            lo, masked = _queries_of(offset, k0, rows, block_q)
-            if lo == block_q:
+            lo, hi, masked = _queries_of(offset, k0, rows, block_q, window)
+            if hi == lo:
                 continue
             own = slice(k0, k0 + rows)
-            q = q_ref[lo:, :].astype(jnp.float32)          # (BQ - lo, D)
-            do = do_ref[lo:, :].astype(jnp.float32)
+            q = q_ref[lo:hi, :].astype(jnp.float32)        # (hi - lo, D)
+            do = do_ref[lo:hi, :].astype(jnp.float32)
             st = jnp.dot(k_ref[own, :].astype(jnp.float32), q.T,
                          preferred_element_type=jnp.float32) * scale
             if masked:
-                st = _causal(st, key0=k0, query0=lo + offset, keys_axis=0)
-            pt = jnp.exp(st - lse[:, lo:])                 # masked -> 0
+                st = _visible(st, key0=k0, query0=lo + offset, keys_axis=0,
+                              window=window)
+            pt = jnp.exp(st - lse[:, lo:hi])               # masked -> 0
             dpt = jnp.dot(v_ref[own, :].astype(jnp.float32), do.T,
                           preferred_element_type=jnp.float32)
-            dst = pt * (dpt - delta[:, lo:]) * scale
+            dst = pt * (dpt - delta[:, lo:hi]) * scale
             dv_acc[own, :] += jnp.dot(pt, do,
                                       preferred_element_type=jnp.float32)
             dk_acc[own, :] += jnp.dot(dst, q,
                                       preferred_element_type=jnp.float32)
 
-    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k)
+    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k,
+              window=window, live=live)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _store():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -383,32 +642,28 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _bwd_calls(qf, kf, vf, dof, lse3, delta, *, scale, causal, block_q,
-               block_k, interpret, vma):
+               block_k, interpret, vma, window=None):
     """``bf_flash_dq`` and ``bf_flash_dkv`` on folded operands, the per-row
     ``lse3`` and ``delta`` as ``(B*H, S, 1)`` float32: ``dq, dk, dv``."""
     bh, S, D = qf.shape
     Dv = vf.shape[-1]
     n_qb, n_kb = S // block_q, S // block_k
+    by_queries, by_keys = _Band.pair(window, block_q, block_k, S)
 
-    # index helpers: i = this kernel's "own" block dim, j = reduction dim.
-    # For causal runs the reduction index is clamped into the un-masked
-    # range: on skipped (pl.when'd-out) steps the map then repeats the
-    # previous block index, so Pallas elides the DMA — without this, masked
-    # tiles would still stream their blocks from HBM (~2x input traffic).
+    # index helpers: i = this kernel's "own" block dim, j = reduction dim
+    # (``_reduction_index``: clamped into the un-masked range).
     at = lambda block, dim: lambda sel: pl.BlockSpec(
         (None, block, dim), lambda b, i, j: (b, sel(i, j), 0))
     q_at, k_at = at(block_q, D), at(block_k, D)
     do_at, v_at = at(block_q, Dv), at(block_k, Dv)
     r_at = at(block_q, 1)
     own = lambda i, j: i
-    if causal:
-        # dq grid: j = k-block; never past this q-block's diagonal.
-        red_dq = lambda i, j: jnp.minimum(
-            j, ((i + 1) * block_q - 1) // block_k)
-        # dkv grid: j = q-block; never before this k-block's frontier.
-        red_kv = lambda i, j: jnp.maximum(j, (i * block_k) // block_q)
-    else:
-        red_dq = red_kv = lambda i, j: j
+    # dq grid: j = k-block; never past this q-block's diagonal.
+    red_dq = _reduction_index(by_queries, causal and (
+        lambda i, j: jnp.minimum(j, ((i + 1) * block_q - 1) // block_k)))
+    # dkv grid: j = q-block; never before this k-block's frontier.
+    red_kv = _reduction_index(by_keys, causal and (
+        lambda i, j: jnp.maximum(j, (i * block_k) // block_q)))
 
     params = dict(compiler_params=pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary")))
@@ -416,8 +671,9 @@ def _bwd_calls(qf, kf, vf, dof, lse3, delta, *, scale, causal, block_q,
                   block_k=block_k)
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **kernel),
-        name="bf_flash_dq", grid=(bh, n_qb, n_kb),
+        functools.partial(_dq_kernel, **kernel, band=by_queries),
+        name=_kernel_name("dq", by_queries),
+        grid=(bh, n_qb, by_queries.steps if by_queries else n_kb),
         in_specs=[q_at(own), k_at(red_dq), v_at(red_dq), do_at(own),
                   r_at(own), r_at(own)],
         out_specs=q_at(own),
@@ -427,8 +683,9 @@ def _bwd_calls(qf, kf, vf, dof, lse3, delta, *, scale, causal, block_q,
     )(qf, kf, vf, dof, lse3, delta)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kernel),
-        name="bf_flash_dkv", grid=(bh, n_kb, n_qb),
+        functools.partial(_dkv_kernel, **kernel, band=by_keys),
+        name=_kernel_name("dkv", by_keys),
+        grid=(bh, n_kb, by_keys.steps if by_keys else n_qb),
         in_specs=[q_at(red_kv), k_at(own), v_at(own), do_at(red_kv),
                   r_at(red_kv), r_at(red_kv)],
         out_specs=[k_at(own), v_at(own)],
@@ -443,7 +700,7 @@ def _bwd_calls(qf, kf, vf, dof, lse3, delta, *, scale, causal, block_q,
     return dq, dk, dv
 
 
-def _bwd(block_q, block_k, interpret, vma, res, cotangents):
+def _bwd(block_q, block_k, interpret, vma, window, res, cotangents):
     """Flash backward as two Pallas kernels (dq accumulating over k-blocks;
     dk/dv accumulating over q-blocks) — O(block) VMEM, O(S) HBM, and no
     S x S materialization anywhere.
@@ -472,14 +729,14 @@ def _bwd(block_q, block_k, interpret, vma, res, cotangents):
         # compiler refuses the dq kernel at D = 192), so the backward takes
         # half as many keys a tile.
         block_k = _fit_block(512, S)
-    for kernel in ("bf_flash_dq", "bf_flash_dkv"):
-        _staged(kernel, bh, S // block_q, S // block_k, block_q, block_k,
-                causal)
+    for kind, band in zip(("dq", "dkv"),
+                          _Band.pair(window, block_q, block_k, S)):
+        _staged(kind, bh, S, block_q, block_k, causal, band)
     # (the residuals' Python scalars come back as jax literals: not hashable)
     dq, dk, dv = _bwd_calls(qf, kf, vf, dof, lse[..., None], delta,
                             scale=float(scale), causal=bool(causal),
                             block_q=block_q, block_k=block_k,
-                            interpret=interpret, vma=vma)
+                            interpret=interpret, vma=vma, window=window)
     unfold = lambda t: t.reshape(B, H, S, t.shape[-1]).transpose(0, 2, 1, 3)
     return unfold(dq), unfold(dk), unfold(dv)
 
@@ -488,28 +745,28 @@ def _lse_bsh(lse, B, S, H):
     return lse.reshape(B, H, S).transpose(0, 2, 1)         # -> (B, S, H)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash(q, k, v, causal, block_q, block_k, interpret, vma=None,
-           scale=None):
+           scale=None, window=None):
     out, (_, _, _, _, lse, (B, S, H, _, _, _)) = _fwd(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, vma=vma, scale=scale)
+        interpret=interpret, vma=vma, scale=scale, window=window)
     return out, _lse_bsh(lse, B, S, H)
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, vma=None,
-               scale=None):
+               scale=None, window=None):
     out, res = _fwd(q, k, v, causal=causal, block_q=block_q,
                     block_k=block_k, interpret=interpret, vma=vma,
-                    scale=scale)
+                    scale=scale, window=window)
     B, S, H = res[5][0], res[5][1], res[5][2]
     return (out, _lse_bsh(res[4], B, S, H)), res
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, vma, scale, res,
+def _flash_bwd(causal, block_q, block_k, interpret, vma, scale, window, res,
                cotangents):
     del scale       # the residuals carry the one the forward used
-    return _bwd(block_q, block_k, interpret, vma, res, cotangents)
+    return _bwd(block_q, block_k, interpret, vma, window, res, cotangents)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -530,11 +787,18 @@ def platform_in_use(x) -> str:
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
                     block_k: int = 1024, interpret: bool = None, vma=None,
-                    scale: float = None):
+                    scale: float = None, window: int = None):
     """Memory-O(S) exact attention; ``q`` and ``k`` ``(B, S, H, D)``, ``v``
     ``(B, S, H, Dv)`` (``Dv`` is ``D`` unless the values have a head dim of
     their own), result ``(B, S, H, Dv)``.  ``scale`` multiplies the scores
     before the softmax; ``None`` means ``1 / sqrt(D)``.
+
+    ``window=W`` (with ``causal``): query ``i`` sees the keys ``i - W < j <=
+    i``.  The kernels are then ``bf_flash_win_fwd / dq / dkv`` on grids that
+    cover only the blocks a window reaches, so the cost grows with ``S x
+    W``; the forward of a band of at most 2048 keys a query block takes it
+    in pieces of ``W`` rounded up to a power of two, one softmax pass a row
+    chunk.  ``W >= S`` is the causal kernel.
 
     ``interpret=None`` compiles the Mosaic kernel when the devices in use
     (:func:`platform_in_use`) are TPUs and runs the Pallas interpreter
@@ -551,12 +815,12 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
     values of 128, the backward at 1024 x 512, 2.22 / 3.29 / 3.43)."""
     return flash_attention_lse(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret,
-                               vma=vma, scale=scale)[0]
+                               vma=vma, scale=scale, window=window)[0]
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = True, block_q: int = 1024,
                         block_k: int = 1024, interpret: bool = None,
-                        vma=None, scale: float = None):
+                        vma=None, scale: float = None, window: int = None):
     """Like :func:`flash_attention` but also returns the per-row logsumexp
     ``(B, S, H)`` — the merge weight sequence-parallel consumers need
     (``parallel.ring_attention`` combines per-hop partials with it).
@@ -566,19 +830,26 @@ def flash_attention_lse(q, k, v, *, causal: bool = True, block_q: int = 1024,
     ``vma``: frozenset of mesh axis names the outputs vary over inside
     ``shard_map(..., check_vma=True)`` (Pallas outputs must declare their
     varying axes); default: the axes the inputs vary over."""
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"flash_attention: window={window} needs causal=True and at "
+                "least one key (a query's own): the window reaches back "
+                "from the diagonal")
+        window = int(window) if window < q.shape[1] else None
     if interpret is None:
         interpret = platform_in_use(q) != "tpu"
     if vma is None:
         vma = frozenset().union(*(jax.typeof(t).vma for t in (q, k, v)))
     return _flash(q, k, v, causal, block_q, block_k, interpret, vma,
-                  None if scale is None else float(scale))
+                  None if scale is None else float(scale), window)
 
 
 def flash_attention_impl(block_q: int = 1024, block_k: int = 1024,
                          interpret: bool = None):
     """``attn_impl`` for ``models.TransformerLM`` / ``parallel.ulysses``."""
-    def impl(q, k, v, *, causal=True, scale=None):
+    def impl(q, k, v, *, causal=True, scale=None, window=None):
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret,
-                               scale=scale)
+                               scale=scale, window=window)
     return impl

@@ -233,7 +233,8 @@ def route_topk(router_logits, k: int, *, renormalize: bool = False,
     made on ``scores + bias`` (``bias``: (E,), a constant for the gradient;
     None = no bias) and the weights are the chosen experts' scores alone,
     ``renormalize``d as ``w / (sum w + renorm_eps)`` (DeepSeek-V3's 1e-20;
-    LFM2 takes 1e-6) and multiplied by ``scale``.
+    LFM2 takes 1e-6).  Under either scoring the weights are multiplied by
+    ``scale`` last (a routed scaling factor).
     ``balance_loss`` then takes the scores normalised over the experts as
     its probabilities."""
     if scoring not in ("softmax", "sigmoid"):
@@ -252,13 +253,15 @@ def route_topk(router_logits, k: int, *, renormalize: bool = False,
         weights = weights * scale
         probs = scores / scores.sum(axis=-1, keepdims=True)
     else:
-        if bias is not None or scale != 1.0:
-            raise ValueError("route_topk: bias and scale belong to "
+        if bias is not None:
+            raise ValueError("route_topk: a bias belongs to "
                              "scoring='sigmoid'")
         probs = jax.nn.softmax(logits, axis=-1)
         weights, experts = lax.top_k(probs, k)
         if renormalize:
             weights = weights / weights.sum(axis=-1, keepdims=True)
+        if scale != 1.0:        # 1.0: the program it always was, to the bit
+            weights = weights * scale
     flat = experts.reshape(-1)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     inverse = jnp.argsort(order).astype(jnp.int32)

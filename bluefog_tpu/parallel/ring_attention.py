@@ -60,13 +60,21 @@ def _merge(o, lse, o_h, lse_h):
     return o * w[..., None] + o_h * w_h[..., None], lse_new
 
 
-def ring_attention(q, k, v, *, axis_name: str, causal: bool = True):
+def ring_attention(q, k, v, *, axis_name: str, causal: bool = True,
+                   window: int = None):
     """Exact attention with K/V rotating around the ``axis_name`` ring.
 
     Per-device blocks ``(B, S_local, H, D)``; the global sequence is the
     concatenation of blocks in axis-index order.  Returns the local output
     block, bit-for-bit a blockwise-stable evaluation of full attention.
+    A ``window`` (a model's sliding-attention layer) is not supported.
     """
+    if window is not None:
+        raise NotImplementedError(
+            "ring_attention: a sliding window is not supported: a hop's "
+            "block lies whole inside the window, across its edge or whole "
+            "outside it, and the hops have no such kinds yet; run the "
+            "window layers with ops.flash_attention on one device")
     n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     B, S, H, D = q.shape

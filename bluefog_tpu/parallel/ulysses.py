@@ -22,8 +22,9 @@ __all__ = ["ulysses_attention", "ulysses_attention_impl"]
 
 
 def ulysses_attention(q, k, v, *, axis_name: str, causal: bool = True,
-                      inner_attention=None):
-    """All-to-all head-parallel attention over ``axis_name``.
+                      inner_attention=None, window: int = None):
+    """All-to-all head-parallel attention over ``axis_name``.  A ``window``
+    (a model's sliding-attention layer) is not supported.
 
     ``inner_attention(q, k, v, causal=...)`` runs on the gathered-sequence /
     sharded-head layout.  Default: the compiled flash kernel when the
@@ -31,6 +32,11 @@ def ulysses_attention(q, k, v, *, axis_name: str, causal: bool = True,
     memory matters), dense ``local_attention`` elsewhere (the Pallas
     interpreter would dominate CPU-mesh test time).
     """
+    if window is not None:
+        raise NotImplementedError(
+            "ulysses_attention: a sliding window is not supported: the "
+            "inner attention is called without one; run the window layers "
+            "with ops.flash_attention on one device")
     n = lax.axis_size(axis_name)
     H = q.shape[2]
     assert H % n == 0, f"num_heads {H} must be divisible by axis size {n}"

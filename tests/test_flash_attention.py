@@ -1,5 +1,7 @@
 """Flash attention kernel tests (interpreter mode on CPU) vs dense oracle."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -248,35 +250,42 @@ def test_every_tile_is_skipped_crossed_or_interior(blocks):
             assert (offset in crossings) == (keep.any() and not keep.all())
 
 
+def _chunks_cover(block_q, block_k, offset, keep, window=None):
+    """Per chunk of a tile at ``offset`` whose visible pairs are ``keep``:
+    every kept pair is inside the keys (queries) the chunk computes, and a
+    chunk that builds no mask keeps every pair it computes."""
+    from bluefog_tpu.ops.flash_attention import (
+        _BWD_CHUNK, _FWD_CHUNK, _chunk_rows, _keys_of, _queries_of)
+    for want in (_FWD_CHUNK, _BWD_CHUNK):
+        rows = _chunk_rows(offset, block_q, want)
+        for q0 in range(0, block_q, rows):
+            lo, hi, masked = _keys_of(offset, q0, rows, block_k, window)
+            part = keep[q0:q0 + rows]
+            assert 0 <= lo <= hi <= block_k
+            assert not part[:, :lo].any() and not part[:, hi:].any()
+            assert masked == (not part[:, lo:hi].all())
+        rows = _chunk_rows(offset, block_k, want)
+        for k0 in range(0, block_k, rows):
+            lo, hi, masked = _queries_of(offset, k0, rows, block_q, window)
+            part = keep[:, k0:k0 + rows]
+            assert 0 <= lo <= hi <= block_q
+            assert not part[:lo].any() and not part[hi:].any()
+            assert masked == (not part[lo:hi].all())
+
+
 @pytest.mark.parametrize("blocks", PLANNED_BLOCKS)
 def test_a_crossed_tiles_chunks_cover_what_the_diagonal_keeps(blocks):
-    """Per chunk of a crossed tile: every kept pair is inside
-    the keys (queries) the chunk computes, and a chunk that builds no mask
-    keeps every pair it computes."""
     from bluefog_tpu.ops.flash_attention import (
-        _BWD_CHUNK, _FWD_CHUNK, _chunk_rows, _crossings, _keys_of,
-        _queries_of)
+        _FWD_CHUNK, _chunk_rows, _crossings, _keys_of, _queries_of)
     block_q, block_k = blocks
     for offset in _crossings(block_q, block_k):
         keep = (np.arange(block_k)[None, :]
                 <= np.arange(block_q)[:, None] + offset)
-        for want in (_FWD_CHUNK, _BWD_CHUNK):
-            rows = _chunk_rows(offset, block_q, want)
-            for q0 in range(0, block_q, rows):
-                hi, masked = _keys_of(offset, q0, rows, block_k)
-                part = keep[q0:q0 + rows]
-                assert 0 <= hi <= block_k and not part[:, hi:].any()
-                assert masked == (not part[:, :hi].all())
-            rows = _chunk_rows(offset, block_k, want)
-            for k0 in range(0, block_k, rows):
-                lo, masked = _queries_of(offset, k0, rows, block_q)
-                part = keep[:, k0:k0 + rows]
-                assert 0 <= lo <= block_q and not part[:lo].any()
-                assert masked == (not part[lo:].all())
+        _chunks_cover(block_q, block_k, offset, keep)
     # an interior tile computes the whole tile, in one piece
     assert _chunk_rows(None, block_q, _FWD_CHUNK) == block_q
-    assert _keys_of(None, 0, block_q, block_k) == (block_k, False)
-    assert _queries_of(None, 0, block_k, block_q) == (0, False)
+    assert _keys_of(None, 0, block_q, block_k) == (0, block_k, False)
+    assert _queries_of(None, 0, block_k, block_q) == (0, block_q, False)
 
 
 @pytest.fixture(scope="module")
@@ -394,3 +403,285 @@ def test_cells_kernels_keep_names_grids_blocks_and_operands(cell):
                 [((b * h, s, d), bf16), ((b * h, s, dv), bf16)]),
         }[call.params["name"]]
         assert (blocks, results) == expected
+
+
+# ---------------------------------------------------------------------------
+# A window: query i sees the keys i - W < j <= i.  The grid's reduction
+# dimension covers the blocks a window reaches and no more.
+# ---------------------------------------------------------------------------
+
+def _band(n_q, n_k, offset, window):
+    """Visible pairs of a tile: ``0 <= query - key < window``."""
+    back = np.arange(n_q)[:, None] + offset - np.arange(n_k)[None, :]
+    return (back >= 0) & (back < window)
+
+
+def _dense_window(q, k, v, window):
+    """Float32 attention over a full ``(S, S)`` score matrix with the
+    window as a mask, and its per-row logsumexp ``(B, S, H)``."""
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    seq = logits.shape[-1]
+    logits = jnp.where(_band(seq, seq, 0, window), logits, -jnp.inf)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(logits - lse[..., None]), v)
+    return out, lse.transpose(0, 2, 1)
+
+
+WINDOW_BLOCKS = [(64, 64), (64, 32), (32, 64), (128, 64), (256, 256)]
+# one key; no multiple of any block; a multiple; both edges in one tile
+WINDOWS = [1, 37, 64, 100, 128, 200]
+
+
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("blocks", WINDOW_BLOCKS)
+def test_a_windowed_tile_is_dead_crossed_or_interior(blocks, window,
+                                                     monkeypatch):
+    """Every step of a windowed grid is a dead step (past its block's
+    reach), a tile some edge crosses (a body at its static offset whose
+    chunks cover the band) or an interior tile; no visible pair lies in a
+    tile outside the grid.  The same of the pieces of a banded forward."""
+    from bluefog_tpu.ops import flash_attention as fa
+    block_q, block_k = blocks
+    seq = 4 * max(block_q, block_k)
+    crossings = fa._crossings(block_q, block_k, window)
+    assert len(crossings) == len(set(crossings))
+    n_qb, n_kb = seq // block_q, seq // block_k
+    whole = _band(seq, seq, 0, window)
+
+    def check(offsets, tiles_of):
+        """``tiles_of(own block)``: the blocks of the other side its steps
+        load, dead ones included."""
+        seen = np.zeros((n_qb, n_kb), bool)
+        for at, o in enumerate(offsets):
+            own, step = divmod(at, len(offsets) // len(tiles_of))
+            if o is None:
+                continue
+            qi, kb = tiles_of[own](step)
+            assert o == qi * block_q - kb * block_k and not seen[qi, kb]
+            seen[qi, kb] = True
+            keep = _band(block_q, block_k, o, window)
+            assert keep.any()
+            if block_k - 1 <= o <= window - block_q:
+                assert keep.all() and o not in crossings
+            else:
+                assert not keep.all() and o in crossings
+                _chunks_cover(block_q, block_k, o, keep, window)
+        for qi in range(n_qb):
+            for kb in range(n_kb):
+                tile = whole[qi * block_q:(qi + 1) * block_q,
+                             kb * block_k:(kb + 1) * block_k]
+                assert tile.any() == seen[qi, kb]
+
+    pieces = fa._band_pieces(window, block_q, block_k)
+    by_queries, by_keys = fa._Band.pair(window, block_q, block_k, seq)
+    if pieces:
+        n, first = pieces
+        offsets = fa._grid_offsets(n_qb, n_kb, block_q, block_k, by_queries)
+        assert len(offsets) == n_qb * n
+        check(offsets, [lambda j, qi=qi: (
+            qi, qi * (block_q // block_k) + first + j) for qi in range(n_qb)])
+    monkeypatch.setattr(fa, "_BAND_KEYS", 0)        # through the grid
+    for band in (by_queries, by_keys):
+        offsets = fa._grid_offsets(n_qb, n_kb, block_q, block_k, band)
+        own = n_kb if band.by_keys else n_qb
+        assert len(offsets) == own * band.steps
+        assert band.steps <= -(-(window - 1 + band.own) // band.other) + 1
+        firsts = [int(band.reach(np, i)[0]) for i in range(own)]
+        check(offsets, [lambda j, i=i, f=f: (
+            (f + j, i) if band.by_keys else (i, f + j))
+            for i, f in enumerate(firsts)])
+
+
+@pytest.fixture(scope="module")
+def window_qkv():
+    """Two heads over 512 positions at head sizes 64 and 128."""
+    rng = np.random.RandomState(5)
+    mk = lambda d: jnp.asarray(rng.randn(1, 512, 2, d), jnp.float32)
+    return {d: (mk(d), mk(d), mk(d)) for d in (64, 128)}
+
+
+# W of 1, no multiple of the blocks, a multiple, and the whole sequence and
+# more; blocks that differ; heads of 64 and 128
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("window, blocks", [
+    (1, (128, 128)), (100, (128, 64)), (100, (64, 128)), (128, (128, 128)),
+    (200, (128, 128)), (256, (64, 128)), (300, (256, 128)), (512, (128, 64)),
+    (4096, (128, 64))])
+def test_windowed_kernels_match_a_masked_softmax(window_qkv, window, blocks,
+                                                 head_dim):
+    """``o``, ``lse``, ``dq``, ``dk`` and ``dv`` against a float32 softmax
+    over the full score matrix with the window as a mask, and its
+    ``jax.grad``."""
+    from bluefog_tpu.ops.flash_attention import flash_attention_lse
+    block_q, block_k = blocks
+    q, k, v = window_qkv[head_dim]
+    w = jnp.asarray(np.random.RandomState(6).randn(1, 512, 2), jnp.float32)
+
+    def flash(q, k, v):
+        return flash_attention_lse(q, k, v, window=window, block_q=block_q,
+                                   block_k=block_k)
+
+    def loss(fn):
+        def of(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out ** 2) + jnp.sum(w * lse)
+        return of
+
+    out, lse = flash(q, k, v)
+    want, want_lse = _dense_window(q, k, v, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=2e-5, atol=2e-5)
+    _assert_grads_match(loss(flash), loss(functools.partial(
+        _dense_window, window=window)), (q, k, v), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("window", [None, 512, 10 ** 6])
+def test_no_window_and_a_window_over_the_sequence_are_the_causal_kernel(
+        window_qkv, window, dtype):
+    """Bit for bit: ``window=None`` and ``window >= S`` run the kernels
+    ``causal=True`` always ran, under their names."""
+    from bluefog_tpu.ops.flash_attention import flash_attention_lse
+    from bluefog_tpu.utils import telemetry
+    q, k, v = (t.astype(dtype) for t in window_qkv[64])
+
+    def run(**kw):
+        def loss(q, k, v):
+            out, lse = flash_attention_lse(q, k, v, block_q=128, block_k=64,
+                                           **kw)
+            return jnp.sum(out.astype(jnp.float32) ** 2) + jnp.sum(lse)
+        return (flash_attention_lse(q, k, v, block_q=128, block_k=64, **kw),
+                jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+
+    before = dict(telemetry.snapshot())
+    got = run(window=window)
+    staged = {key: n - before.get(key, 0)
+              for key, n in telemetry.snapshot().items()
+              if key.startswith("bf_kernel_stagings_total") and "flash" in key
+              and n != before.get(key, 0)}
+    assert staged and not any("win" in key for key in staged), staged
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(run())):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_a_window_needs_causal_and_a_key():
+    q = jnp.zeros((1, 64, 1, 16))
+    with pytest.raises(ValueError, match="causal=True"):
+        flash_attention(q, q, q, causal=False, window=8)
+    with pytest.raises(ValueError, match="at least one key"):
+        flash_attention(q, q, q, window=0)
+    with pytest.raises(ValueError, match="causal=True"):
+        local_attention(q, q, q, causal=False, window=8)
+
+
+@pytest.mark.parametrize("window", [5, 16, 40])
+def test_local_attention_takes_the_window(qkv, window):
+    """The tests' plain twin masks the same band."""
+    q, k, v = qkv
+    want, _ = _dense_window(q, k, v, window)
+    np.testing.assert_allclose(
+        np.asarray(local_attention(q, k, v, window=window)),
+        np.asarray(want), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, window=window, block_q=16,
+                                   block_k=32)),
+        np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+# the cell's window layers: (B, S, H, D) = (1, 8192, 64, 128), W 512: the
+# forward takes a query block's band in three pieces of 512 keys, the
+# backward two steps a block; narrower and wider windows, whose forward goes
+# through the grid as the backward does
+WINDOWED_GRIDS = [
+    ((1, 8192, 64, 128), 512, (64, 8), 3, (64, 8, 2)),
+    ((1, 8192, 8, 128), 300, (8, 8), 3, (8, 8, 2)),
+    ((1, 8192, 8, 128), 4096, (8, 8, 5), 1, (8, 8, 5)),
+    ((2, 2048, 4, 64), 100, (8, 2), 9, (8, 2, 2)),
+]
+
+
+@pytest.mark.parametrize("shape, window, grid_fwd, pieces, grid_bwd",
+                         WINDOWED_GRIDS)
+def test_windowed_calls_have_names_grids_and_tile_counts_of_their_own(
+        shape, window, grid_fwd, pieces, grid_bwd):
+    """Traced, nothing runs: the windowed kernels' names, the grids that
+    grow with ``S x W`` (a narrow band's forward has no reduction dimension
+    but ``pieces`` key blocks a step), and ``bf_flash_tiles_total``'s kinds
+    adding up to each grid."""
+    from bluefog_tpu.utils import telemetry
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, window=window).astype(
+            jnp.float32).sum()
+
+    def tiles():
+        return {key: n for key, n in telemetry.snapshot().items()
+                if key.startswith("bf_flash_tiles_total")}
+
+    before = tiles()
+    calls = _pallas_calls(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x).jaxpr, [])
+    after = tiles()
+    assert [c.params["name"] for c in calls] == [
+        "bf_flash_win_fwd", "bf_flash_win_dq", "bf_flash_win_dkv"]
+    for call, grid, steps in zip(calls, (grid_fwd, grid_bwd, grid_bwd),
+                                 (pieces, 1, 1)):
+        mapping = call.params["grid_mapping"]
+        assert mapping.grid == grid
+        assert mapping.block_mappings[0].block_shape[1].block_size == 1024
+        # q, the pieces of k and of v, then what the kernel's kind adds
+        assert len(mapping.block_mappings) == 2 * steps + {
+            "bf_flash_win_fwd": 3, "bf_flash_win_dq": 5,
+            "bf_flash_win_dkv": 6}[call.params["name"]]
+        name = call.params["name"]
+        counted = {kind: after.get(key, 0) - before.get(key, 0)
+                   for kind in ("skipped", "crossed", "interior")
+                   for key in [f'bf_flash_tiles_total{{kernel="{name}",'
+                               f'kind="{kind}"}}']}
+        assert sum(counted.values()) == int(np.prod(grid)) * steps, counted
+        # dead steps: the first blocks' windows, cut by the sequence's start
+        assert counted["skipped"] * 3 < sum(counted.values())
+        assert counted["crossed"] > 0
+        assert (counted["interior"] > 0) == (window >= 2048)
+
+
+@pytest.mark.parametrize("window, blocks", [(100, (128, 64)),
+                                            (200, (128, 128)),
+                                            (300, (256, 128))])
+def test_a_wide_bands_forward_goes_through_the_grid(window_qkv, window,
+                                                    blocks, monkeypatch):
+    """The forward has two ways through a window: a narrow band in one
+    softmax pass a row chunk (``_fwd_band_kernel``), a band of more than
+    ``_BAND_KEYS`` keys block by block with the running state.  With the
+    limit at nothing every band is wide: the same ``o`` and ``lse`` to
+    rounding, and the gradients (whose kernels take ``lse`` from it)."""
+    from bluefog_tpu.ops import flash_attention as fa
+    block_q, block_k = blocks
+    q, k, v = window_qkv[64]
+
+    def run():
+        jax.clear_caches()
+        def loss(q, k, v):
+            out, lse = fa.flash_attention_lse(
+                q, k, v, window=window, block_q=block_q, block_k=block_k)
+            return jnp.sum(out ** 2) + jnp.sum(lse), (out, lse)
+        return jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    assert fa._band_pieces(window, block_q, min(
+        block_k, fa._window_block(block_k, window))) is not None
+    banded = run()
+    monkeypatch.setattr(fa, "_BAND_KEYS", 0)
+    gridded = run()
+    jax.clear_caches()
+    want, want_lse = _dense_window(q, k, v, window)
+    for got in (banded, gridded):
+        np.testing.assert_allclose(got[1][0], want, rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(got[1][1], want_lse, rtol=2e-5, atol=2e-5)
+    for a, b in zip(banded[0], gridded[0]):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)

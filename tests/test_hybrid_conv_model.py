@@ -403,6 +403,9 @@ PARENT_JAXPR = {
     ("tiny-olmoe", "moe_causal_lm"): "cc5642989b155419",
     ("tiny-xing", "latent_moe_causal_lm"): "25911be33315599d",
     ("tiny-resnet", "image_classification"): "cd85047ddb144986",
+    # taken at the parent of PR 40 (commit d2e6ec1): the window, the heads
+    # by layer, the gate and the rotary schemes leave this program as it was
+    ("tiny-lfm2", "hybrid_moe_causal_lm"): "5b2fff31f184d5fe",
 }
 
 

@@ -156,8 +156,12 @@ def test_latent_attention_config_is_checked():
         models.TransformerConfig(pos_encoding="rope", kv_lora_rank=16)
     with pytest.raises(ValueError, match="rope"):
         models.TransformerConfig(**dict(base, pos_encoding="learned"))
-    with pytest.raises(ValueError, match="latent-attention path"):
-        models.TransformerConfig(pos_encoding="rope", rope_scaling=YARN)
+    # since PR 40 the plain attention branch reads YaRN's dict too
+    # (``transformer.rope_scheme``); another type is still refused
+    models.TransformerConfig(pos_encoding="rope", rope_scaling=YARN)
+    with pytest.raises(ValueError, match="only 'yarn'"):
+        models.TransformerConfig(pos_encoding="rope",
+                                 rope_scaling=dict(YARN, type="linear"))
     with pytest.raises(ValueError, match="experts_held"):
         models.TransformerConfig(num_experts=8, mlp="swiglu",
                                  experts_held=6, experts_first=4)
