@@ -505,33 +505,85 @@ def yarn_frequencies(dim: int, theta: float, scaling: dict) -> np.ndarray:
     return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
 
 
+def _half_swap(dim: int, rot: int, first: int = 0) -> np.ndarray:
+    """The constant ``(dim, dim)`` matrix ``R`` of zeros and ones with which
+    ``x @ R`` has, of the ``rot`` lanes of a head from ``first`` on, the
+    second half where the first was and the first where the second was, and
+    zero at every other lane.  Every output is one input or none, so the
+    product is exact in any dtype."""
+    swap = np.zeros((dim, dim), np.float32)
+    i = first + np.arange(rot // 2)
+    swap[i + rot // 2, i] = swap[i, i + rot // 2] = 1.0
+    return swap
+
+
+@jax.custom_vjp
+def _turn(x, swap, c, s):
+    """``x * c + (x @ swap) * s`` in float32 over whole heads, cast back:
+    one pass that names no array narrower than a head (``apply_rope`` says
+    why the half-swap is a product)."""
+    # the array rounds the operands of a default-precision product to
+    # bfloat16, which would not be the values of any wider x
+    swapped = jnp.einsum(
+        "bshd,de->bshe", x, swap.astype(x.dtype),
+        preferred_element_type=jnp.float32,
+        precision=None if x.dtype == jnp.bfloat16
+        else jax.lax.Precision.HIGHEST)
+    return (x.astype(jnp.float32) * c + swapped * s).astype(x.dtype)
+
+
+def _turn_fwd(x, swap, c, s):
+    return _turn(x, swap, c, s), (swap, c, s)
+
+
+def _turn_bwd(residuals, dy):
+    # The transpose of a rotation is the rotation back: the same pass with
+    # -sin.  Written out because autodiff of the product form would round
+    # dy * s to the cotangent's dtype inside the array before the swap,
+    # where this swaps the cotangent exactly and multiplies in float32.
+    # The angles come from integer positions and constants: no cotangent.
+    swap, c, s = residuals
+    return _turn(dy, swap, c, -s), None, None, None
+
+
+_turn.defvjp(_turn_fwd, _turn_bwd)
+
+
 def apply_rope(x, positions, theta: float = 10000.0, freq=None,
-               scale: float = 1.0, rot: int = None):
+               scale: float = 1.0, rot: int = None, first: int = 0):
     """Rotary position embedding on ``(B, S, H, D)`` q or k.
 
     Pairs dimension ``i`` with ``i + D/2`` (the standard half-split layout)
     and rotates by ``pos * theta^(-2i/D)`` (or by ``pos * freq[i]`` where
     the ``D / 2`` frequencies are given); cos and sin are multiplied by
     ``scale`` (YaRN's attention factor); angles computed in f32, result
-    cast back to the input dtype.  ``rot`` < D: only the first ``rot`` dims
-    of a head are rotated (they are the ``D`` above) and the rest pass
-    through untouched."""
-    rot = x.shape[-1] if rot is None else rot
+    cast back to the input dtype.  ``rot`` < D: only the ``rot`` dims of a
+    head from ``first`` on are rotated (they are the ``D`` above) and the
+    rest pass through untouched.
+
+    A head is turned in one pass over its whole width, forward and
+    transposed: ``x * [cos, cos, 1] + (x @ R) * [-sin, sin, 0]`` with the
+    half-swap ``R`` a constant of zeros and ones (``docs/ops.md``: two
+    half-width slices and a concatenate, or slices of the input
+    concatenated, cost four times the bytes on a TPU)."""
+    dim = x.shape[-1]
+    rot = dim - first if rot is None else rot
+    if rot % 2 or not 0 <= rot <= dim - first:
+        raise ValueError(f"apply_rope: the rotary part of a head of {dim} "
+                         f"is {rot} dims from {first} on; it must be even "
+                         f"and within the head")
     d2 = rot // 2
     if freq is None:
         freq = theta ** (-jnp.arange(d2, dtype=jnp.float32) / d2)
     ang = positions[..., None].astype(jnp.float32) * freq  # (B, S, d2)
-    cos = jnp.cos(ang)[:, :, None, :]
-    sin = jnp.sin(ang)[:, :, None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
     if scale != 1.0:
         cos, sin = cos * scale, sin * scale
-    x1 = x[..., :d2].astype(jnp.float32)
-    x2 = x[..., d2:rot].astype(jnp.float32)
-    turned = jnp.concatenate([x1 * cos - x2 * sin,
-                              x1 * sin + x2 * cos], -1).astype(x.dtype)
-    if rot == x.shape[-1]:
-        return turned
-    return jnp.concatenate([turned, x[..., rot:]], -1)
+    rest = ((0, 0), (0, 0), (first, dim - first - rot))
+    c = jnp.pad(jnp.concatenate([cos, cos], -1), rest, constant_values=1.0)
+    s = jnp.pad(jnp.concatenate([-sin, sin], -1), rest)
+    return _turn(x, _half_swap(dim, rot, first),
+                 c[:, :, None, :], s[:, :, None, :])
 
 
 def rope_scheme(cfg, kind: str, dim: int) -> tuple:
@@ -617,9 +669,7 @@ class LatentAttention(nn.Module):
         with timeline.device_scope("bf.mla.rope"):
             freq = None if scaling is None else yarn_frequencies(
                 rope, cfg.rope_theta, scaling)
-            q = jnp.concatenate(
-                [q[..., :nope], apply_rope(q[..., nope:], positions,
-                                           cfg.rope_theta, freq)], axis=-1)
+            q = apply_rope(q, positions, cfg.rope_theta, freq, first=nope)
             k_rope = apply_rope(k_rope, positions, cfg.rope_theta, freq)
             if scaling is not None:
                 # YaRN's factor on cos and sin (1 where mscale equals

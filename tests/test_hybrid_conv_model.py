@@ -397,15 +397,16 @@ def test_float8_rounded_matrices_fail_the_bounds(toy):
 # programs were compared at their full shapes the same way (``CHANGES.md``).
 # A PR that means to change one of these programs replaces its digest: PR 37
 # replaced the three that run the flash kernels (their tile bodies and the
-# ``jax.jit`` around each call; ``tiny-resnet`` is as at 739c0c9).
+# ``jax.jit`` around each call), PR 41 the four that run ``apply_rope`` (a
+# product with a constant half-swap and a written transpose where two
+# half-width slices and a concatenate were; ``tests/test_rope.py`` holds the
+# values to the bit).  ``tiny-resnet`` is as at 739c0c9.
 PARENT_JAXPR = {
-    ("tiny-lm", "causal_lm"): "a993a641bee0ab34",
-    ("tiny-olmoe", "moe_causal_lm"): "cc5642989b155419",
-    ("tiny-xing", "latent_moe_causal_lm"): "25911be33315599d",
+    ("tiny-lm", "causal_lm"): "a4de682606a8a8cd",
+    ("tiny-olmoe", "moe_causal_lm"): "88f1130aabf52fc8",
+    ("tiny-xing", "latent_moe_causal_lm"): "8b4108d454904332",
     ("tiny-resnet", "image_classification"): "cd85047ddb144986",
-    # taken at the parent of PR 40 (commit d2e6ec1): the window, the heads
-    # by layer, the gate and the rotary schemes leave this program as it was
-    ("tiny-lfm2", "hybrid_moe_causal_lm"): "5b2fff31f184d5fe",
+    ("tiny-lfm2", "hybrid_moe_causal_lm"): "c593f86020260dce",
 }
 
 
