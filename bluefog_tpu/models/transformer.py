@@ -27,7 +27,7 @@ from bluefog_tpu.utils import telemetry, timeline
 
 __all__ = ["TransformerLM", "TransformerConfig", "local_attention",
            "init_cache", "generate", "DroplessMoe", "moe_stats", "ShortConv",
-           "head_matrix"]
+           "Mamba2Mixer", "head_matrix"]
 
 
 def local_attention(q, k, v, *, causal: bool = True, scale: float = None,
@@ -54,7 +54,10 @@ def local_attention(q, k, v, *, causal: bool = True, scale: float = None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-MIXERS = ("conv", "full_attention", "sliding_attention")
+MIXERS = ("conv", "full_attention", "sliding_attention", "mamba")
+# what an entry of ``layer_types`` may say: a mixer, or "ffn" for a block
+# that is its feed-forward part alone
+LAYER_KINDS = MIXERS + ("ffn",)
 
 
 class TransformerConfig:
@@ -78,7 +81,10 @@ class TransformerConfig:
                  conv_kernel=3, tie_embeddings=False,
                  router_renorm_eps=1e-20, head_dim=None,
                  num_heads_per_layer=None, sliding_window=None,
-                 attn_gate=None, rope_parameters=None):
+                 attn_gate=None, rope_parameters=None, block_ffn=True,
+                 ssm_heads=None, ssm_head_dim=64, ssm_groups=1,
+                 ssm_state=128, ssm_chunk=128,
+                 ssm_dt_init=(0.001, 0.1, 1e-4)):
         self.vocab_size = vocab_size
         self.num_layers = num_layers
         self.num_heads = num_heads
@@ -107,10 +113,12 @@ class TransformerConfig:
         # "learned" = absolute wpe table (default); "rope" = rotary applied
         # to q/k inside each block — positions flow in explicitly, so
         # sequence-parallel shards (ring/Ulysses) embed their own offsets
-        # and the attention impl itself stays position-agnostic.
-        if pos_encoding not in ("learned", "rope"):
+        # and the attention impl itself stays position-agnostic; "none" =
+        # no positional encoding at all (a hybrid whose recurrent layers
+        # carry the order: Nemotron-H's attention layers).
+        if pos_encoding not in ("learned", "rope", "none"):
             raise ValueError(f"pos_encoding {pos_encoding!r} not in "
-                             "('learned', 'rope')")
+                             "('learned', 'rope', 'none')")
         if pos_encoding == "rope" and (head_dim
                                        or embed_dim // num_heads) % 2:
             raise ValueError(
@@ -119,24 +127,28 @@ class TransformerConfig:
                 f"embed_dim {embed_dim} / num_heads {num_heads})")
         self.pos_encoding = pos_encoding
         self.rope_theta = rope_theta
-        if mlp not in ("gelu", "swiglu"):
-            raise ValueError(f"mlp {mlp!r} not in ('gelu', 'swiglu')")
+        if mlp not in ("gelu", "swiglu", "relu2"):
+            raise ValueError(
+                f"mlp {mlp!r} not in ('gelu', 'swiglu', 'relu2')")
         if num_experts_per_tok < 1 or (
                 num_experts and num_experts_per_tok > num_experts):
             raise ValueError(
                 f"num_experts_per_tok ({num_experts_per_tok}) must lie in "
                 f"1..num_experts ({num_experts})")
-        if num_experts_per_tok > 1 and not (num_experts
-                                            and mlp == "swiglu"):
+        if num_experts_per_tok > 1 and not (
+                num_experts and mlp in ("swiglu", "relu2")):
             raise ValueError(
-                "num_experts_per_tok > 1 without mlp='swiglu' and "
-                "num_experts > 0 is contradictory: only the dropless "
-                "SwiGLU experts route top-k; GELU experts are top-1 Switch")
+                "num_experts_per_tok > 1 without mlp='swiglu' or 'relu2' "
+                "and num_experts > 0 is contradictory: only the dropless "
+                "experts route top-k; GELU experts are top-1 Switch")
         self.mlp = mlp
         # With num_experts > 0 the MLP of every block is a mixture of
         # experts, and ``mlp`` says which: "swiglu" = DroplessMoe (top-k of
         # SwiGLU experts, no capacity, nothing dropped: OLMoE, Moonlight),
-        # "gelu" = SwitchMlp (top-1, static capacity).  ``expert_dim`` is
+        # "relu2" = DroplessMoe of un-gated experts, ``down(relu(up x)^2)``
+        # (Nemotron-H; the dense MLP and a shared expert take the same
+        # form), "gelu" = SwitchMlp (top-1, static capacity).
+        # ``expert_dim`` is
         # the width of ONE expert (None = mlp_ratio * embed_dim);
         # ``norm_topk_prob`` renormalises the k chosen probabilities.
         self.num_experts_per_tok = num_experts_per_tok
@@ -271,16 +283,20 @@ class TransformerConfig:
         self.hyper_res_clamp = tuple(hyper_res_clamp)
         # The token mixer of each block, one entry a layer: "full_attention"
         # (what the other fields describe), "sliding_attention" (the same
-        # attention over the last ``sliding_window`` keys only) or "conv", a
-        # gated short convolution of ``conv_kernel`` taps (``ShortConv``).
-        # None = all full attention.
+        # attention over the last ``sliding_window`` keys only), "conv", a
+        # gated short convolution of ``conv_kernel`` taps (``ShortConv``),
+        # or "mamba", a Mamba-2 mixer on the chunked scan (``Mamba2Mixer``);
+        # the entry "ffn" is a block without a mixer, its feed-forward part
+        # alone.  None = all full attention.  ``block_ffn=False``: no
+        # feed-forward part follows a mixer, so that every block is one part
+        # alone with one norm, ``x <- x + f(RMSNorm(x))`` (Nemotron-H).
         if layer_types is not None:
             layer_types = tuple(layer_types)
-            odd = set(layer_types) - set(MIXERS)
+            odd = set(layer_types) - set(LAYER_KINDS)
             if len(layer_types) != num_layers or odd:
                 raise ValueError(
                     f"layer_types needs num_layers ({num_layers}) entries "
-                    f"of {MIXERS}; got {len(layer_types)}"
+                    f"of {LAYER_KINDS}; got {len(layer_types)}"
                     + (f" with {sorted(odd)}" if odd else ""))
             if "sliding_attention" in layer_types and not (
                     causal and sliding_window and sliding_window >= 1):
@@ -314,6 +330,24 @@ class TransformerConfig:
             raise ValueError(f"conv_kernel must be >= 1; got {conv_kernel}")
         self.layer_types = layer_types
         self.conv_kernel = conv_kernel
+        self.block_ffn = block_ffn
+        # A "mamba" layer: ``ssm_heads`` heads of ``ssm_head_dim`` (the
+        # mixer's inner width is their product), ``ssm_groups`` groups of B
+        # and C with a state of ``ssm_state`` a head value, the scan in
+        # chunks of ``ssm_chunk``, a causal depthwise convolution of
+        # ``conv_kernel`` taps before it; the time steps start log-uniform
+        # in ``ssm_dt_init`` = (min, max, floor), Mamba-2's.
+        if layer_types is not None and "mamba" in layer_types and not (
+                ssm_heads and ssm_heads % ssm_groups == 0 and ssm_chunk >= 1):
+            raise ValueError(
+                f"a 'mamba' layer needs ssm_heads ({ssm_heads}) a multiple "
+                f"of ssm_groups ({ssm_groups}) and ssm_chunk >= 1")
+        self.ssm_heads = ssm_heads
+        self.ssm_head_dim = ssm_head_dim
+        self.ssm_groups = ssm_groups
+        self.ssm_state = ssm_state
+        self.ssm_chunk = ssm_chunk
+        self.ssm_dt_init = tuple(ssm_dt_init)
         # The output head is the transposed embedding: no ``lm_head`` leaf.
         self.tie_embeddings = tie_embeddings
         # What a sigmoid router adds to the sum of the chosen scores before
@@ -392,7 +426,9 @@ class DroplessMoe(nn.Module):
 
     Parameters: ``router/kernel`` (d, E) and the three stacked leaves
     ``gate``, ``up`` (E, d, f) and ``down`` (E, f, d), ``f =
-    cfg.expert_dim``.  Router matmul and softmax run in float32.  Sown into
+    cfg.expert_dim``.  Under ``cfg.mlp == "relu2"`` the experts are
+    un-gated, ``down(relu(up x)^2)``: no ``gate`` and no ``shared_gate``
+    leaf, the same path with the gate left out.  Router matmul and softmax run in float32.  Sown into
     ``intermediates`` (read them with ``moe_stats``): ``moe_load`` (E,)
     int32 assignment counts, ``moe_balance_loss`` and ``moe_z_loss``.
 
@@ -419,7 +455,8 @@ class DroplessMoe(nn.Module):
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         held = getattr(cfg, "experts_held", None)
         n = E if held is None else held
-        gate = self.param("gate", init, (n, d, f))
+        gated = getattr(cfg, "mlp", "swiglu") != "relu2"
+        gate = self.param("gate", init, (n, d, f)) if gated else None
         up = self.param("up", init, (n, d, f))
         down = self.param("down", init, (n, f, d))
         routing = {"scale": getattr(cfg, "routed_scaling_factor", 1.0)}
@@ -450,9 +487,12 @@ class DroplessMoe(nn.Module):
                     dense = functools.partial(nn.Dense, use_bias=False,
                                               dtype=cfg.dtype)
                     xs = xt.astype(cfg.dtype)
-                    y = y + dense(d, name="shared_down")(
-                        nn.silu(dense(shared, name="shared_gate")(xs))
-                        * dense(shared, name="shared_up")(xs))
+                    if gated:
+                        hidden = nn.silu(dense(shared, name="shared_gate")(
+                            xs)) * dense(shared, name="shared_up")(xs)
+                    else:
+                        hidden = relu2(dense(shared, name="shared_up")(xs))
+                    y = y + dense(d, name="shared_down")(hidden)
         self.sow("intermediates", "moe_load", plan.load)
         self.sow("intermediates", "moe_balance_loss", plan.balance_loss)
         self.sow("intermediates", "moe_z_loss", plan.z_loss)
@@ -772,6 +812,24 @@ class HyperConnection(nn.Module):
         return u.astype(x.dtype), mix
 
 
+def relu2(x):
+    """``relu(x)^2``, the un-gated activation of ``mlp="relu2"``."""
+    return jnp.square(nn.relu(x))
+
+
+def causal_taps(u, w):
+    """``c_t = sum_j w[:, j] * u_{t - (L - 1) + j}`` over the ``L`` taps of
+    ``w`` ``(channels, L)``, each channel of ``u`` ``(B, S, channels)`` on
+    its own (depthwise), causal, zeros left of the sequence: ``L`` shifted
+    multiply-adds in plain ``jax.numpy``."""
+    taps, S = w.shape[1], u.shape[1]
+    conv = w[:, taps - 1] * u
+    for back in range(1, taps):
+        conv = conv + w[:, taps - 1 - back] * jnp.pad(
+            u, ((0, 0), (back, 0), (0, 0)))[:, :S]
+    return conv
+
+
 class ShortConv(nn.Module):
     """Gated short convolution (LFM2's ``conv`` layers): the token mixer of
     a block whose ``cfg.layer_types`` entry is ``"conv"``.
@@ -793,7 +851,6 @@ class ShortConv(nn.Module):
     def __call__(self, y):
         cfg = self.cfg
         d, taps = cfg.embed_dim, cfg.conv_kernel
-        S = y.shape[1]
         dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
         with timeline.device_scope("bf.sconv.in"):
             gates = dense(3 * d, name="in")(y)
@@ -803,14 +860,90 @@ class ShortConv(nn.Module):
                        (d, taps)).astype(cfg.dtype)
         with timeline.device_scope("bf.sconv.conv"):
             b, c, x = jnp.split(gates, 3, axis=-1)
-            u = b * x
-            conv = w[:, taps - 1] * u
-            for back in range(1, taps):
-                conv = conv + w[:, taps - 1 - back] * jnp.pad(
-                    u, ((0, 0), (back, 0), (0, 0)))[:, :S]
-            gated = c * conv
+            gated = c * causal_taps(b * x, w)
         with timeline.device_scope("bf.sconv.out"):
             return dense(d, name="out")(gated)
+
+
+class Mamba2Mixer(nn.Module):
+    """Mamba-2's mixer (arXiv:2405.21060, as Nemotron-H lays it out): the
+    token mixer of a block whose ``cfg.layer_types`` entry is ``"mamba"``.
+
+    ``H = cfg.ssm_heads`` heads of ``P = cfg.ssm_head_dim`` (inner width
+    ``I = H P``), ``G = cfg.ssm_groups`` groups with a state of ``N =
+    cfg.ssm_state``.  ``[z | xBC | dt] = y W_in`` with ``I + (I + 2 G N) +
+    H`` columns, no bias; ``xBC <- silu(conv(xBC) + b)``, a causal depthwise
+    convolution of ``cfg.conv_kernel`` taps, zeros left of the sequence;
+    ``xBC`` splits into ``x`` (H heads of P), ``B`` and ``C`` (G groups of
+    N; head ``h`` reads group ``h // (H / G)``); ``dt <- softplus(dt +
+    dt_bias)`` and ``A = -exp(A_log)`` a head, both float32; the recurrence
+    ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``, ``o_t = h_t C_t + D
+    x_t`` runs chunk by chunk (``ops.ssd.ssd_scan`` at ``cfg.ssm_chunk``);
+    then ``o <- RMSNorm(o * silu(z))`` over each of the ``G`` groups of ``I /
+    G`` values with one scale of ``I``, in float32, and ``o W_out``.
+
+    Leaves: ``in/kernel`` ``(d, 2 I + 2 G N + H)``, ``conv_w`` ``(I + 2 G
+    N, taps)``, ``conv_b``, ``dt_bias``, ``A_log``, ``D`` ``(H,)``,
+    ``norm_scale`` ``(I,)`` and ``out/kernel`` ``(I, d)``.  At init ``A`` is
+    uniform in ``-[1, 16]``, ``D`` one and ``softplus(dt_bias)`` log-uniform
+    in ``cfg.ssm_dt_init``.  Training and prefill only: the layer keeps no
+    state for decoding.
+
+    Device scopes: ``bf.ssm.in``, ``bf.ssm.conv`` (taps, bias, SiLU),
+    ``bf.ssm.scan`` (time steps, decays, the chunked scan, the skip),
+    ``bf.ssm.norm`` (gate and grouped norm) and ``bf.ssm.out``."""
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, y):
+        from bluefog_tpu.ops.ssd import ssd_scan
+        cfg = self.cfg
+        H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                      cfg.ssm_state)
+        inner, taps = H * P, cfg.conv_kernel
+        B_, S = y.shape[:2]
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+        with timeline.device_scope("bf.ssm.in"):
+            zxbcdt = dense(2 * inner + 2 * G * N + H, name="in")(y)
+        z = zxbcdt[..., :inner]
+        xbc = zxbcdt[..., inner:2 * inner + 2 * G * N]
+        dt = zxbcdt[..., 2 * inner + 2 * G * N:]
+        # fan-in of one channel: its ``taps`` values
+        w = self.param("conv_w", nn.initializers.lecun_normal(
+            in_axis=1, out_axis=0), (inner + 2 * G * N, taps))
+        b = self.param("conv_b", nn.initializers.zeros, (inner + 2 * G * N,))
+        lo, hi, floor = cfg.ssm_dt_init
+
+        def dt_bias_init(key, shape):
+            step = jnp.maximum(jnp.exp(jax.random.uniform(
+                key, shape, minval=math.log(lo), maxval=math.log(hi))), floor)
+            return step + jnp.log(-jnp.expm1(-step))    # softplus's inverse
+        dt_bias = self.param("dt_bias", dt_bias_init, (H,))
+        a_log = self.param("A_log", lambda key, shape: jnp.log(
+            jax.random.uniform(key, shape, minval=1.0, maxval=16.0)), (H,))
+        skip = self.param("D", nn.initializers.ones, (H,))
+        scale = self.param("norm_scale", nn.initializers.ones, (inner,))
+        with timeline.device_scope("bf.ssm.conv"):
+            xbc = nn.silu(causal_taps(xbc, w.astype(cfg.dtype))
+                          + b.astype(cfg.dtype))
+        with timeline.device_scope("bf.ssm.scan"):
+            o = ssd_scan(
+                xbc[..., :inner].reshape(B_, S, H, P),
+                nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                -jnp.exp(a_log.astype(jnp.float32)),
+                xbc[..., inner:inner + G * N].reshape(B_, S, G, N),
+                xbc[..., inner + G * N:].reshape(B_, S, G, N),
+                chunk=cfg.ssm_chunk, D=skip)
+        with timeline.device_scope("bf.ssm.norm"):
+            gated = (o.reshape(B_, S, inner).astype(jnp.float32)
+                     * nn.silu(z.astype(jnp.float32))).reshape(
+                         B_, S, G, inner // G)
+            gated = gated * jax.lax.rsqrt(
+                jnp.mean(gated * gated, axis=-1, keepdims=True)
+                + cfg.rms_norm_eps)
+            gated = (gated.reshape(B_, S, inner) * scale).astype(cfg.dtype)
+        with timeline.device_scope("bf.ssm.out"):
+            return dense(cfg.embed_dim, name="out")(gated)
 
 
 def block_class(cfg, layer_idx: int = None):
@@ -855,9 +988,13 @@ class Block(nn.Module):
         ``h / kv_h`` (the reason GQA exists).
 
         The mixer is the block's entry of ``cfg.layer_types`` (None: every
-        block attends).  Only plain full attention (MHA / GQA) takes a
-        cache; latent attention, a sliding window and the gated short
-        convolution keep no decode state and raise on one.
+        block attends); the entry ``"ffn"`` is a block with no mixer, and
+        under ``cfg.block_ffn == False`` no feed-forward part follows a
+        mixer: the block is then one part alone on the one residual path,
+        ``x <- x + f(RMSNorm(x))``.  Only plain full attention (MHA / GQA)
+        takes a cache; latent attention, a sliding window, the gated short
+        convolution and the Mamba-2 mixer keep no decode state and raise on
+        one, as does a block without a mixer.
 
         Plain attention reads its sizes by layer: ``cfg.head_dim`` (None:
         ``embed_dim // num_heads``), the block's entry of
@@ -886,23 +1023,36 @@ class Block(nn.Module):
             # standalone Block use (e.g. pipeline stages): local positions
             positions = jnp.arange(x.shape[1])[None, :]
         eps = getattr(cfg, "rms_norm_eps", 1e-6)
+        # the feed-forward part behind the mixer, or nothing
+        ffn = self._ffn if getattr(cfg, "block_ffn", True) \
+            else lambda x, eps: x
+        if kind == "ffn":
+            if cache is not None:
+                raise NotImplementedError(
+                    "a block without a mixer takes no decode cache: "
+                    "generate() fills one K/V entry a block")
+            return self._ffn(x, eps)
         x, join = self._residual(x, "hc_attn")
         y = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype)(x)
         B, S = y.shape[0], y.shape[1]
         conv, sliding = kind == "conv", kind == "sliding_attention"
+        mamba = kind == "mamba"
         latent = getattr(cfg, "kv_lora_rank", None) is not None
-        if cache is not None and (conv or latent or sliding):
+        if cache is not None and (conv or latent or sliding or mamba):
             raise NotImplementedError(
                 "only plain full attention takes a decode cache: latent "
-                "attention, a sliding window and the gated short "
-                "convolution do not")
+                "attention, a sliding window, the gated short convolution "
+                "and the Mamba-2 mixer do not")
         if conv:
             x = join(ShortConv(cfg, name="conv")(y))
-            return self._ffn(x, eps)
+            return ffn(x, eps)
+        if mamba:
+            x = join(Mamba2Mixer(cfg, name="mamba")(y))
+            return ffn(x, eps)
         if latent:
             x = join(LatentAttention(cfg, self.attn_impl, name="mla")(
                 y, positions))
-            return self._ffn(x, eps)
+            return ffn(x, eps)
         scope = "bf.swa" if sliding else "bf.attn"
         with timeline.device_scope(f"{scope}.qkv"):
             if kv_h == h:
@@ -997,7 +1147,7 @@ class Block(nn.Module):
             attn = attn.reshape(B, S, h * d)
             x = join(nn.Dense(cfg.embed_dim, use_bias=False,
                               dtype=cfg.dtype, name="proj")(attn))
-        x = self._ffn(x, eps)
+        x = ffn(x, eps)
         return x if cache is None else (x, cache)
 
     def _residual(self, x, name):
@@ -1020,8 +1170,8 @@ class Block(nn.Module):
                   or cfg.mlp_ratio * cfg.embed_dim)
         if (getattr(cfg, "num_experts", 0) > 0
                 and self.layer_idx >= getattr(cfg, "dense_layers", 0)):
-            moe = (DroplessMoe if getattr(cfg, "mlp", "gelu") == "swiglu"
-                   else SwitchMlp)
+            moe = (DroplessMoe if getattr(cfg, "mlp", "gelu") in (
+                "swiglu", "relu2") else SwitchMlp)
             x = join(moe(cfg, name="moe")(y))
         elif getattr(cfg, "mlp", "gelu") == "swiglu":
             gate = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype,
@@ -1033,7 +1183,8 @@ class Block(nn.Module):
         else:
             y = nn.Dense(hidden, use_bias=False, dtype=cfg.dtype,
                          name="up")(y)
-            y = nn.gelu(y)
+            y = relu2(y) if getattr(cfg, "mlp", "gelu") == "relu2" \
+                else nn.gelu(y)
             x = join(nn.Dense(cfg.embed_dim, use_bias=False, dtype=cfg.dtype,
                               name="down")(y))
         return x
@@ -1060,8 +1211,8 @@ class TransformerLM(nn.Module):
         streams = getattr(cfg, "hyper_streams", 1)
         mixers = getattr(cfg, "layer_types", None) \
             or ("full_attention",) * cfg.num_layers
-        for kind in MIXERS:
-            # what was built last, by kind of mixer; set, not added to: a
+        for kind in LAYER_KINDS:
+            # what was built last, by kind of layer; set, not added to: a
             # model is traced more than once
             telemetry.set_gauge("bf_model_layers_total",
                                 mixers.count(kind), mixer=kind)
@@ -1082,6 +1233,15 @@ class TransformerLM(nn.Module):
                     "KV-cache decoding through a sliding-window layer is "
                     "not supported: its cache of the last sliding_window "
                     "keys is not written")
+            if "mamba" in mixers:
+                raise NotImplementedError(
+                    "KV-cache decoding through a Mamba-2 layer is not "
+                    "supported: the scan hands on no state and the "
+                    "convolution keeps no last taps")
+            if "ffn" in mixers:
+                raise NotImplementedError(
+                    "KV-cache decoding through a block without a mixer is "
+                    "not supported: generate() fills one K/V entry a block")
             if not getattr(cfg, "causal", True):
                 raise ValueError(
                     "KV-cache decoding requires causal=True: the decode "
@@ -1103,8 +1263,9 @@ class TransformerLM(nn.Module):
         x = wte(tokens)
         if positions is None:
             positions = jnp.arange(tokens.shape[1])[None, :]
-        rope = getattr(cfg, "pos_encoding", "learned") == "rope"
-        if not rope:
+        encoding = getattr(cfg, "pos_encoding", "learned")
+        rope = encoding == "rope"
+        if encoding == "learned":
             pos = nn.Embed(cfg.max_seq_len, cfg.embed_dim,
                            dtype=cfg.dtype, name="wpe")(positions)
             x = x + pos

@@ -29,6 +29,9 @@ tpu.megablox``) apply the SwiGLU experts, and the rows return to token
 order weighted by their router probabilities.  All experts live on the
 calling rank; the permutations are gathers in both directions (their
 transposes are written out), so no scatter runs forward or backward.
+Without a gate (``dropless_moe(..., gate=None, ...)``: Nemotron-H's
+``relu2`` experts, ``down(relu(up x)^2)``) the same path runs two grouped
+products a pass.
 
 **A held share** (``dropless_moe(..., held=(first, count))``): the layer is
 told which contiguous range of the ``E`` experts this rank holds, routes over
@@ -444,7 +447,7 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
                  renormalize: bool = False, held: tuple = None,
                  scoring: str = "softmax", bias=None, scale: float = 1.0,
                  renorm_eps: float = 1e-20):
-    """A dropless top-``k`` mixture of SwiGLU experts on this rank.
+    """A dropless top-``k`` mixture of experts on this rank.
 
     ``x``: (T, d) tokens in the compute dtype; ``router_logits``: (T, E);
     ``gate``, ``up``: (E, d, f) and ``down``: (E, f, d), cast to ``x``'s
@@ -454,6 +457,10 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
     the ``Routing`` that holds the per-expert load and the two auxiliary
     losses.  No token is
     dropped at any load: an expert takes as many rows as choose it.
+
+    ``gate=None``: the experts are un-gated, ``down_j(relu(up_j x)^2)``
+    (Nemotron-H's ``relu2``): the same dispatch, window and combine with two
+    grouped products a pass where a gated expert has three (``_activate``).
 
     ``held=(first, count)``: this rank holds the experts ``first .. first +
     count`` of the ``E`` the router scores, and ``gate``, ``up``, ``down``
@@ -484,10 +491,12 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
         first, count = held
         E = router_logits.shape[-1]
         if not (0 <= first and 0 < count and first + count <= E
-                and gate.shape[0] == up.shape[0] == down.shape[0] == count):
+                and all(m.shape[0] == count for m in (gate, up, down)
+                        if m is not None)):
             raise ValueError(
                 f"dropless_moe: held={held} of {E} experts with "
-                f"{gate.shape[0]}, {up.shape[0]}, {down.shape[0]} matrices")
+                f"{[m.shape[0] for m in (gate, up, down) if m is not None]}"
+                " matrices")
     with timeline.device_scope("bf.moe.route"):
         plan = route_topk(router_logits, k, renormalize=renormalize,
                           scoring=scoring, bias=bias, scale=scale,
@@ -508,7 +517,12 @@ def _combine(back, weights, dtype):
     return y.astype(dtype)
 
 
-def _swiglu(g, u):
+def _activate(g, u):
+    """What goes into an expert's down projection: SwiGLU of the gate and
+    up products, or without a gate (``g`` None) the squared ReLU of the up
+    product alone."""
+    if g is None:
+        return jnp.square(jax.nn.relu(u))
     return jax.nn.silu(g) * u
 
 
@@ -522,9 +536,10 @@ def _whole_share(x, weights, gate, up, down, order, inverse, load, k: int,
     with timeline.device_scope("bf.moe.dispatch"):
         rows = _take_rows(x, order, inverse, k)                 # (T*k, d)
     with timeline.device_scope("bf.moe.experts"):
-        g = grouped_matmul(rows, gate, load, first)
+        g = None if gate is None else grouped_matmul(rows, gate, load,
+                                                     first)
         u = grouped_matmul(rows, up, load, first)
-        out = grouped_matmul(_swiglu(g, u), down, load, first)  # (T*k, d)
+        out = grouped_matmul(_activate(g, u), down, load, first)  # (T*k, d)
     with timeline.device_scope("bf.moe.combine"):
         return _combine(_take_rows(out, inverse, order, 1), weights,
                         x.dtype)
@@ -542,7 +557,7 @@ class _Saved(NamedTuple):
     """What a window's transpose needs of its forward, all ``(C, .)``."""
     win: jax.Array      # (C,) int32: its assignments, ``t * k + j``
     rows: jax.Array     # (C, d): their tokens' rows of ``x``
-    g: jax.Array        # (C, f)
+    g: jax.Array        # (C, f); None for un-gated experts
     u: jax.Array        # (C, f)
     out: jax.Array      # (C, d): the experts' result
 
@@ -576,22 +591,25 @@ def _from_window(values, inverse, w: _Window):
 
 
 def _cast(matrices, dtype) -> tuple:
-    """The held matrices in the compute dtype, once for both branches."""
+    """The held matrices in the compute dtype, once for both branches
+    (None stays None: un-gated experts have no gate)."""
     with timeline.device_scope("bf.moe.experts"):
-        return tuple(m.astype(dtype) for m in matrices)
+        return tuple(None if m is None else m.astype(dtype)
+                     for m in matrices)
 
 
 def _sizes(cast, order, load) -> tuple:
     """How many experts are held, the window's rows, and the rows of a
     buffer of whole windows that holds any run."""
-    count = cast[0].shape[0]
+    count = cast[2].shape[0]
     size = held_window(order.shape[0], count, load.shape[0])
     return count, size, -(-order.shape[0] // size) * size
 
 
 def _window_experts(x, cast, order, load, k, first, index=0):
-    """One window's rows through the held experts (``cast``: their three
-    matrices in ``x``'s dtype)."""
+    """One window's rows through the held experts (``cast``: their gate,
+    up and down matrices in ``x``'s dtype, the gate None where they have
+    none)."""
     count, size, _ = _sizes(cast, order, load)
     w = _window(load, first, count, size, index)
     with timeline.device_scope("bf.moe.dispatch"):
@@ -602,9 +620,10 @@ def _window_experts(x, cast, order, load, k, first, index=0):
             (w.start,), (size,))
         rows = x[win // k]                                      # (C, d)
     with timeline.device_scope("bf.moe.experts"):
-        g = _product(rows, cast[0], w.sizes, None)[0]
+        g = None if cast[0] is None else _product(rows, cast[0], w.sizes,
+                                                  None)[0]
         u = _product(rows, cast[1], w.sizes, None)[0]
-        out = _product(_swiglu(g, u), cast[2], w.sizes, None)[0]
+        out = _product(_activate(g, u), cast[2], w.sizes, None)[0]
     return w, _Saved(win, rows, g, u, out)
 
 
@@ -618,15 +637,18 @@ def _window_transpose(saved: _Saved, w: _Window, cast, like, dy, weights, k):
             dy.dtype)
         d_weights = (dyw * saved.out.astype(jnp.float32)).sum(axis=-1)
     with timeline.device_scope("bf.moe.experts"):
-        h, swiglu_t = jax.vjp(_swiglu, saved.g, saved.u)
+        h, activate_t = jax.vjp(_activate, saved.g, saved.u)
         d_h, d_down, _ = _product_transpose(
             None, (h, cast[2], w.sizes, like), d_out)
-        d_g, d_u = swiglu_t(d_h)
-        d_rows_u, d_up, _ = _product_transpose(
+        d_g, d_u = activate_t(d_h)
+        d_rows, d_up, _ = _product_transpose(
             None, (saved.rows, cast[1], w.sizes, like), d_u)
-        d_rows_g, d_gate, _ = _product_transpose(
-            None, (saved.rows, cast[0], w.sizes, like), d_g)
-    return d_rows_u + d_rows_g, d_weights, d_gate, d_up, d_down
+        d_gate = None
+        if d_g is not None:
+            d_rows_g, d_gate, _ = _product_transpose(
+                None, (saved.rows, cast[0], w.sizes, like), d_g)
+            d_rows = d_rows + d_rows_g
+    return d_rows, d_weights, d_gate, d_up, d_down
 
 
 def _to_tokens(d_rows, d_weights, inverse, w: _Window, weights, dtype):
@@ -649,7 +671,7 @@ def _window_fwd(x, weights, cast, order, inverse, load, k, first):
 
 
 def _window_bwd(weights, cast, like, inverse, load, k, first, saved, dy):
-    w = _window(load, first, cast[0].shape[0], saved.win.shape[0], 0)
+    w = _window(load, first, cast[2].shape[0], saved.win.shape[0], 0)
     d_rows, d_weights, *d_matrices = _window_transpose(
         saved, w, cast, like, dy, weights, k)
     return _to_tokens(d_rows, d_weights, inverse, w, weights,
@@ -700,12 +722,14 @@ def _overflow_bwd(x, weights, cast, like, order, inverse, load, k, first,
                                              (index * size, 0)),
                     lax.dynamic_update_slice(d_weights, mine[1],
                                              (index * size,))) + tuple(
-                a + b for a, b in zip(d_matrices, mine[2:]))
+                None if a is None else a + b
+                for a, b in zip(d_matrices, mine[2:]))
     run, (d_rows, d_weights, *d_matrices) = _overflow(
         load, first, count, size, rows, window,
         jnp.zeros((rows, x.shape[1]), dy.dtype),
         jnp.zeros((rows,), jnp.float32),
-        *(jnp.zeros(m.shape, like.dtype) for m in cast))
+        *(None if m is None else jnp.zeros(m.shape, like.dtype)
+          for m in cast))
     return _to_tokens(d_rows, d_weights, inverse, run, weights,
                       dy.dtype) + tuple(d_matrices)
 
@@ -725,7 +749,7 @@ def _held_args(x, weights, gate, up, down, order, inverse, load, k, first):
     """What both branches take, and the window's size."""
     return ((x, weights, _cast((gate, up, down), x.dtype), order, inverse,
              load, k, first),
-            held_window(order.shape[0], gate.shape[0], load.shape[0]))
+            held_window(order.shape[0], down.shape[0], load.shape[0]))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
@@ -738,7 +762,7 @@ def _held_share(x, weights, gate, up, down, order, inverse, load, k: int,
     overflow branch nothing."""
     args, size = _held_args(x, weights, gate, up, down, order, inverse,
                             load, k, first)
-    return _branch(load, first, gate.shape[0], size,
+    return _branch(load, first, down.shape[0], size,
                    lambda: _window_fwd(*args)[0],
                    lambda: _overflow_fwd(*args))
 
@@ -748,17 +772,17 @@ def _held_fwd(x, weights, gate, up, down, order, inverse, load, k, first):
                             load, k, first)
     saved = jax.eval_shape(lambda: _window_fwd(*args)[1])
     y, saved = _branch(
-        load, first, gate.shape[0], size, lambda: _window_fwd(*args),
+        load, first, down.shape[0], size, lambda: _window_fwd(*args),
         lambda: (_overflow_fwd(*args), jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), saved)))
     # the empty array carries the matrices' dtype to the backward pass
-    return y, (args[:6], jnp.zeros((0,), gate.dtype), saved)
+    return y, (args[:6], jnp.zeros((0,), down.dtype), saved)
 
 
 def _held_bwd(k, first, res, dy):
     (x, weights, cast, order, inverse, load), like, saved = res
     return _branch(
-        load, first, cast[0].shape[0], saved.win.shape[0],
+        load, first, cast[2].shape[0], saved.win.shape[0],
         lambda: _window_bwd(weights, cast, like, inverse, load, k, first,
                             saved, dy),
         lambda: _overflow_bwd(x, weights, cast, like, order, inverse, load,

@@ -9,11 +9,13 @@ the cells, traced here on the CPU in child processes.  The readers find the step
 change moves one of them: the ledger's per-layer metrics would read ``null``.
 Their tests are collected here under their own names behind the file's;
 ``test_cells_cpu.py``, ``test_moe_cell_cpu.py`` (three minutes) and the
-``test_twin_*`` cases of ``test_xing_cell_cpu.py``, ``test_lfm2_cell_cpu.py``
-and ``test_laguna_cell_cpu.py`` (whose other cases, the cell's declaration,
-its published widths, its cost functions and its roofline readers, run here;
-of ``test_laguna_cell_cpu.py`` the traced twin too, a minute and a half: the
-cell's checks and every new reader on a CPU trace) stay by hand.
+``test_twin_*`` cases of ``test_xing_cell_cpu.py``, ``test_lfm2_cell_cpu.py``,
+``test_laguna_cell_cpu.py`` and ``test_twotower_cell_cpu.py`` (whose other
+cases, the cell's declaration, its published widths, its cost functions and
+its roofline readers, run here; of ``test_laguna_cell_cpu.py`` and
+``test_twotower_cell_cpu.py`` the traced twin too, a minute and a half and
+under a minute: the cell's checks and every new reader on a CPU trace) stay by
+hand.
 
 Two cases are collected through ``test_setup_readers.py`` and not directly:
 ``test_the_cell_is_declared_with_its_five_metrics`` / ``..._six_metrics``
@@ -23,10 +25,13 @@ metrics to every cell.  ``test_setup_readers.py`` runs both unchanged on
 ``BENCHMARK.json`` less those four entries and then looks for the four.
 The same file's ``test_the_entries_say_what_the_readers_are`` holds those four
 to be the last of ``per_layer`` and ``kernel_stagings`` to list every cell but
-ResNet's; PR 40 appends a cell and five metrics behind them (the contract: new
-entries go last) and may not edit that file either, so the case runs here
-unchanged on ``BENCHMARK.json`` less PR 40's entries, and the five are looked
-for behind the four.
+ResNet's; a ``model_config`` PR appends a cell and its metrics behind them (the
+contract: new entries go last) and may not edit that file either, so the case
+runs here unchanged on ``BENCHMARK.json`` less everything appended after the
+four, cut by position and not by a list of names: the metrics behind the four,
+the cells behind the last one ``kernel_stagings`` lists, the configurations
+behind the last one such a cell names.  PR 40's cell and PR 42's are behind
+that line, and the next appends without touching this file.
 """
 
 import importlib
@@ -37,11 +42,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-# PR 36's own declaration test, run below on the file less PR 40's entries
+# PR 36's own declaration test, run below on the file less what came later
 _BEHIND_THE_FOUR = "test_the_entries_say_what_the_readers_are"
 
 for _file in ("trace_reduce", "program_readers", "setup_readers", "dropin",
-              "xing_cell_cpu", "lfm2_cell_cpu", "laguna_cell_cpu"):
+              "xing_cell_cpu", "lfm2_cell_cpu", "laguna_cell_cpu",
+              "twotower_cell_cpu"):
     _module = importlib.import_module(f"benchmark.selftest.test_{_file}")
     for _name, _obj in vars(_module).items():
         if _name.startswith(("test_twin_", "test_the_cell_is_declared_")) \
@@ -53,28 +59,34 @@ for _file in ("trace_reduce", "program_readers", "setup_readers", "dropin",
             globals()[_name] = _obj     # a fixture its tests ask for by name
 
 
-PR40_METRICS = ["swa_attn_device_ms", "swa_flash_roofline",
-                "gated_attn_device_ms", "small_moe_device_ms",
-                "small_moe_expert_roofline"]
-
-
 def test_setup_readers_the_entries_say_what_the_readers_are(monkeypatch):
     from benchmark import spec
     setup = importlib.import_module("benchmark.selftest.test_setup_readers")
     read_json = spec.read_json
     whole = read_json(os.path.join(ROOT, "BENCHMARK.json"))
     names = [m["name"] for m in whole["per_layer"]]
-    assert names[-9:] == setup.NEW + PR40_METRICS    # appended, in this order
-    assert whole["workloads"][-1]["name"] == "laguna-s8192-1chip"
-    assert whole["configs"][-1]["name"] == "laguna-xs.2"
+    four = names.index(setup.NEW[0]) + len(setup.NEW)
+    assert names[four - len(setup.NEW):four] == setup.NEW   # in this order
+    listed = whole["per_layer"][names.index("kernel_stagings")]["workloads"]
+    cells = [w["name"] for w in whole["workloads"]]
+    last_cell = max(cells.index(name) for name in listed) + 1
+    configs = [c["name"] for c in whole["configs"]]
+    last_config = max(configs.index(w["config"])
+                      for w in whole["workloads"][:last_cell]) + 1
+    # what came later is behind the line and touches nothing before it
+    assert listed == [n for n in cells[:last_cell]
+                      if n != "resnet50-b256-1chip"]
+    for m in whole["per_layer"][four:]:
+        assert set(m["workloads"]) <= set(cells[last_cell:]), m["name"]
+    for w in whole["workloads"][last_cell:]:
+        assert configs.index(w["config"]) >= last_config, w["name"]
 
-    def before_pr40(path):
+    def up_to_the_four(path):
         data = read_json(path)
         if os.path.basename(path) == "BENCHMARK.json":
-            data["per_layer"] = [m for m in data["per_layer"]
-                                 if m["name"] not in PR40_METRICS]
-            data["workloads"] = data["workloads"][:-1]
-            data["configs"] = data["configs"][:-1]
+            data["per_layer"] = data["per_layer"][:four]
+            data["workloads"] = data["workloads"][:last_cell]
+            data["configs"] = data["configs"][:last_config]
         return data
-    monkeypatch.setattr(spec, "read_json", before_pr40)
+    monkeypatch.setattr(spec, "read_json", up_to_the_four)
     getattr(setup, _BEHIND_THE_FOUR)()
