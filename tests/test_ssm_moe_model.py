@@ -27,6 +27,7 @@ from benchmark import spec  # noqa: E402
 from bluefog_tpu import models  # noqa: E402
 from bluefog_tpu.models import transformer as T  # noqa: E402
 from bluefog_tpu.ops.flash_attention import flash_attention_impl  # noqa: E402
+from bluefog_tpu.ops import ssd  # noqa: E402
 from bluefog_tpu.ops.ssd import ssd_scan  # noqa: E402
 from bluefog_tpu.parallel import moe  # noqa: E402
 from bluefog_tpu.utils import telemetry  # noqa: E402
@@ -87,6 +88,11 @@ def _stepwise(ref, x, dt, A, B, C, D):
         jnp.repeat(C, share, axis=2)) + D[:, None] * x
 
 
+def _chunked(chunk):
+    """``ssd_scan`` at ``chunk`` with ``D`` as its sixth argument."""
+    return lambda *a: ssd_scan(*a[:5], chunk=chunk, D=a[5])
+
+
 @pytest.mark.parametrize("seq", [64, 32, 100, 257, 5])
 def test_chunked_scan_against_the_recurrence(toy, seq):
     """Values and every gradient at lengths that are a multiple of the
@@ -94,7 +100,7 @@ def test_chunked_scan_against_the_recurrence(toy, seq):
     shorter than one (5)."""
     ref = toy[2]
     args = _scan_inputs(seq)
-    mine = lambda *a: ssd_scan(*a[:5], chunk=32, D=a[5])  # noqa: E731
+    mine = _chunked(32)
     theirs = functools.partial(_stepwise, ref)
     weight = normal(7, args[0].shape)       # a loss that tells positions apart
     with HIGHEST():
@@ -131,6 +137,119 @@ def test_the_scan_counts_its_chunks_and_checks_its_shapes():
         ssd_scan(x[:, :, :3], dt[:, :, :3], A[:3], B, C)
     with pytest.raises(ValueError, match="ssd_scan"):
         ssd_scan(x, dt[:, :50], A, B, C)
+
+
+def _published_group(ref):
+    """The published shape of a group (8 heads of 64 over a state of 128) in
+    the cell's dtype, 3 chunks of 128 and a tail of 5: values and the six
+    gradients against the float32 recurrence within bfloat16's error."""
+    x, dt, A, B, C, D = _scan_inputs(3 * 128 + 5, b=1, H=8, P=64, G=1, N=128)
+    half = lambda t: t.astype(jnp.bfloat16)  # noqa: E731
+    args = (half(x), 0.1 * dt, A, half(0.3 * B), half(0.3 * C), D)
+    mine = _chunked(128)
+    exact = lambda *a: _stepwise(ref, *[  # noqa: E731
+        t.astype(jnp.float32) for t in a])
+    weight = normal(7, args[0].shape)
+    got, g_mine = value_and_grads(
+        lambda *a: mine(*a).astype(jnp.float32), range(6), weight)(*args)
+    with HIGHEST():
+        want, g_exact = value_and_grads(exact, range(6), weight)(*args)
+    assert got.dtype == jnp.float32 and mine(*args).dtype == jnp.bfloat16
+    assert rel(got, want) < 1e-2
+    for name, a, b in zip("x dt A B C D".split(), g_mine, g_exact):
+        assert rel(a.astype(jnp.float32), b) < 2e-2, name
+
+
+def _two_groups(ref):
+    """A head reads the ``B`` and ``C`` of its own group: one head a group
+    against the recurrence, and a change to the second group's ``B``
+    reaches the second group's heads alone."""
+    x, dt, A, B, C, D = _scan_inputs(70, b=1, H=2, P=16, G=2, N=8)
+    scan = _chunked(32)
+    with HIGHEST():
+        got, grads = value_and_grads(scan, range(6))(x, dt, A, B, C, D)
+        want, g_ref = value_and_grads(
+            functools.partial(_stepwise, ref), range(6))(x, dt, A, B, C, D)
+        moved = scan(x, dt, A, B.at[:, :, 1].multiply(2.0), C, D)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    for name, a, b in zip("x dt A B C D".split(), grads, g_ref):
+        assert rel(a, b) < 1e-5, name
+    np.testing.assert_array_equal(moved[:, :, 0], got[:, :, 0])
+    assert float(jnp.abs(moved[:, :, 1] - got[:, :, 1]).max()) > 0.1
+
+
+def _two_sequences(ref):
+    """The state starts at zero in every sequence: the second row of a
+    batch is what it is alone, values and gradients."""
+    args = _scan_inputs(70)
+    scan = _chunked(32)
+    alone = tuple(a[1:] if a.ndim > 1 else a for a in args)
+    with HIGHEST():
+        both, g_both = value_and_grads(scan, (0, 1, 3, 4))(*args)
+        one, g_one = value_and_grads(scan, (0, 1, 3, 4))(*alone)
+    np.testing.assert_allclose(both[1:], one, rtol=1e-6, atol=1e-6)
+    for a, b in zip(g_both, g_one):
+        np.testing.assert_allclose(a[1:], b, rtol=1e-5, atol=1e-5)
+
+
+def _without_a_skip(ref):
+    """``D=None`` is the recurrence alone."""
+    x, dt, A, B, C, D = _scan_inputs(40)
+    mine = lambda x: ssd_scan(x, dt, A, B, C, chunk=32)  # noqa: E731
+    theirs = lambda x: _stepwise(  # noqa: E731
+        ref, x, dt, A, B, C, jnp.zeros_like(D))
+    with HIGHEST():
+        np.testing.assert_allclose(mine(x), theirs(x), rtol=1e-4, atol=1e-4)
+        assert rel(jax.grad(lambda x: mine(x).sum())(x),
+                   jax.grad(lambda x: theirs(x).sum())(x)) < 1e-5
+
+
+def _staged_once_a_shape(ref):
+    """Forward and backward kernels are staged once for a shape: a second
+    call, and a second program that uses the shape, stage nothing new."""
+    staged = lambda: {  # noqa: E731
+        k: v for k, v in telemetry.snapshot().items()
+        if k.startswith("bf_kernel_stagings_total") and "bf_ssd_" in k}
+    x, dt, A, B, C, D = _scan_inputs(48, b=1, H=2, P=4, G=1, N=4)
+    grad = lambda x: jax.grad(  # noqa: E731
+        lambda x: ssd_scan(x, dt, A, B, C, chunk=16, D=D).sum())(x)
+    before = staged()
+    ssd_scan(x, dt, A, B, C, chunk=16, D=D)
+    grad(x)
+    first = staged()
+    name = 'bf_kernel_stagings_total{kernel="bf_ssd_%s"}'
+    # the plain call and the rule's forward (which also writes the chunks'
+    # entering states) are two programs of the forward kernel
+    assert first[name % "fwd"] - before.get(name % "fwd", 0.0) == 2
+    assert first[name % "bwd"] - before.get(name % "bwd", 0.0) == 1
+    ssd_scan(x, dt, A, B, C, chunk=16, D=D)
+    grad(x)
+    jax.jit(lambda x: ssd_scan(x, dt, A, B, C, chunk=16, D=D) * 2.0)(x)
+    assert staged() == first
+
+
+@pytest.mark.parametrize("case", [
+    _published_group, _two_groups, _two_sequences, _without_a_skip,
+    _staged_once_a_shape], ids=lambda f: f.__name__.strip("_"))
+def test_the_scan_kernels(toy, case):
+    case(toy[2])
+
+
+def test_shapes_a_tpu_cannot_tile_raise_and_the_interpreter_takes_them():
+    """The compiled kernels need the chunk, a group's ``R P`` and ``N`` in
+    whole lane tiles of whole heads; the check needs no TPU, and off the
+    TPU the same shapes run."""
+    ssd.check_tileable(128, 8, 64, 128)         # the published group
+    ssd.check_tileable(256, 2, 128, 256)
+    for shape, named in (((32, 8, 64, 128), "chunk 32"),
+                         ((128, 3, 64, 128), "R P = 192"),
+                         ((128, 8, 64, 16), "a state of 16"),
+                         ((128, 2, 192, 128), "2 heads of 192")):
+        with pytest.raises(ValueError, match=named):
+            ssd.check_tileable(*shape)
+    x, dt, A, B, C, D = _scan_inputs(40, b=1, H=3, P=5, G=1, N=7)
+    out = ssd_scan(x, dt, A, B, C, chunk=12, D=D)
+    assert out.shape == x.shape and bool(jnp.isfinite(out).all())
 
 
 # --- (b) the Mamba-2 mixer around it ---------------------------------------------------
