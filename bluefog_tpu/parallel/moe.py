@@ -44,10 +44,16 @@ bounded by a shape: the share works on a **window** of ``C =
 held_window(T * k, count, E)`` rows from the run's start (twice the even
 share, in whole 256-row tiles).  It gathers ``C`` rows of ``x``, runs the
 three grouped products and the SwiGLU over ``(C, .)`` arrays with the held
-groups' sizes, and each token selects its held assignments' rows from the
-``(C, d)`` result; every other slot adds zero.  A step whose run is longer
+groups' sizes, and sums the ``(C, d)`` result into its tokens: the
+window's rows know their tokens, so they are sorted by assignment (``C``
+keys), gathered into that order and added a tile of tokens at a time by
+the Pallas kernel ``bf_moe_token_sum`` (the transpose of the dispatch's
+gather, and the same function gives ``d x`` from the window's ``d rows``);
+no array has a row for an assignment that is not held, and a token without
+a held assignment gets zero.  A step whose run is longer
 than ``C`` takes, behind one ``lax.cond``, the overflow branch: the same
-work window after window until the run is covered, so nothing is dropped at
+work window after window until the run is covered, the windows' sums added
+in float32, so nothing is dropped at
 any load and no capacity enters the result.  Where ``C`` would be all ``T *
 k`` rows (half the experts held, or all) there is no window and the layer is
 the dropless layer above.  **Sigmoid scoring with a bias**
@@ -67,6 +73,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from bluefog_tpu.utils import telemetry, timeline
 
@@ -467,15 +475,21 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
     are theirs, ``(count, ...)``.  The routing is over all ``E`` (and
     ``routing.load`` counts all ``E``); an assignment to an absent expert
     adds nothing to ``y`` and nothing is computed or stored for it.  The
-    dispatch, the products, the SwiGLU and the combine's transpose work on
-    a window of ``held_window(T * k, count, E)`` rows of the sorted
+    dispatch, the products, the SwiGLU, the combine and their transposes
+    work on a window of ``held_window(T * k, count, E)`` rows of the sorted
     assignments (twice the even share ``T * k * count / E``, in 256-row
-    tiles), which begins at the held experts' first row; the two gathers
-    that produce ``(T, k, d)`` from the token side stay.  When a step
+    tiles), which begins at the held experts' first row: the token side is
+    the window's rows summed into their tokens (``_sum_to_tokens``: a sort
+    of ``C`` keys, a gather of ``C`` rows and the kernel
+    ``bf_moe_token_sum``; float32 weights, a token's rows summed in
+    float32 and rounded once), so no ``(T * k, d)`` array exists and
+    ``routing.inverse`` has no reader.  When a step
     sends the held experts more rows than the window has, the layer covers
     their run window after window (the overflow branch of its one
-    ``lax.cond``): ``y`` and every gradient are then what one window as
-    long as the run would give, the tokens' sums to the bit.  With a window
+    ``lax.cond``), each window summed into its tokens and the windows'
+    sums added in float32: ``y`` and every gradient are then what one
+    window as long as the run would give, but for the order of a token's
+    float32 terms, and nothing is as long as the run.  With a window
     as large as ``T * k`` (``count * 2 >= E``) the layer is the one it is
     with ``held=None`` over the held matrices.  ``None``: all ``E`` are
     held.  ``scoring``, ``bias``, ``scale`` and ``renorm_eps`` go to
@@ -483,7 +497,8 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
 
     Device scopes: ``bf.moe.route``, ``bf.moe.dispatch``, ``bf.moe.experts``
     and ``bf.moe.combine``; the caller wraps the layer (the router matmul
-    included) in ``bf.moe``."""
+    included) in ``bf.moe``.  ``bf_moe_token_sum`` runs under
+    ``bf.moe.combine`` (the combine) and ``bf.moe.dispatch`` (``d x``)."""
     T, d = x.shape
     dt = x.dtype
     first = None
@@ -501,11 +516,11 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
         plan = route_topk(router_logits, k, renormalize=renormalize,
                           scoring=scoring, bias=bias, scale=scale,
                           renorm_eps=renorm_eps)
-    share = _whole_share
     if held is not None and held_window(T * k, count, E) < T * k:
-        share = _held_share
-    return share(x, plan.weights, gate, up, down, plan.order, plan.inverse,
-                 plan.load, k, first), plan
+        return _held_share(x, plan.weights, gate, up, down, plan.order,
+                           plan.load, k, first), plan
+    return _whole_share(x, plan.weights, gate, up, down, plan.order,
+                        plan.inverse, plan.load, k, first), plan
 
 
 def _combine(back, weights, dtype):
@@ -578,16 +593,155 @@ def _window(load, first: int, count: int, size: int, index) -> _Window:
         return _Window(load[:first].sum() + lo, sizes.sum(), sizes)
 
 
-def _from_window(values, inverse, w: _Window):
-    """Each assignment's row of ``values`` (window order, (C, ...)), zeros
-    for the assignments outside the window's held rows: a select and no
-    product, so whatever the kernels left in the rows of the window that
-    belong to no group reaches no sum."""
-    at = inverse - w.start
-    mine = (at >= 0) & (at < w.rows)
-    took = values[jnp.clip(at, 0, values.shape[0] - 1)]
-    return jnp.where(mine.reshape(mine.shape + (1,) * (values.ndim - 1)),
-                     took, jnp.zeros((), values.dtype))
+# Tokens, rows and columns a tile of ``bf_moe_token_sum``.  One v5e chip,
+# 16384 window rows of 2048 into 16384 tokens, weighted (PR 44 trial, a call
+# from the host included): 128 x 128 took 0.37 ms, 128 x 256 0.46, 256 x 256
+# 0.49, 256 x 512 0.74: the (tokens, rows) matrix is built on the vector
+# unit once a visit and a piece, so small tiles win; the width stays whole
+# (3584 columns fit the 16 MiB of scoped VMEM at these tiles).
+_TILES = (128, 128, 4096)
+
+
+def _token_sum_kernel(offsets, groups, tiles, tok, *refs, tt: int, tm: int):
+    """One visit of the grid: the rows of row tile ``tiles[i]`` that belong
+    to the tokens of token tile ``groups[i]``, added to the tile's float32
+    sums by a product with a ``(tt, tm)`` matrix that holds row ``r``'s
+    weight at ``(its token, r)`` and zeros elsewhere.  A weight goes in as
+    three bfloat16 pieces that sum to it exactly, so a product of bfloat16
+    rows is the float32 product; wider rows take one product at the highest
+    precision.  Rows of the tile outside the token tile's run are zeroed
+    and not multiplied by zero: what they hold reaches no sum."""
+    *scale, rows, out, acc = refs
+    i, last = pl.program_id(1), pl.num_programs(1) - 1
+    group = groups[i]
+
+    @pl.when((i == 0) | (groups[jnp.maximum(i - 1, 0)] != group))
+    def _first_visit():
+        acc[...] = jnp.zeros_like(acc)
+
+    lo, hi = offsets[group], offsets[group + 1]
+
+    @pl.when(hi > lo)
+    def _add():
+        at = tiles[i] * tm + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        v = rows[...]
+        v = jnp.where((at >= lo) & (at < hi), v, jnp.zeros((), v.dtype))
+        mine = (tok[...] - group * tt
+                == lax.broadcasted_iota(jnp.int32, (tt, tm), 0))
+        if v.dtype != jnp.bfloat16:
+            weight = scale[0][...] if scale else 1.0
+            acc[...] += jnp.dot(
+                jnp.where(mine, weight, 0.0).astype(jnp.float32),
+                v.astype(jnp.float32), precision=lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            return
+        rest = scale[0][...] if scale else jnp.ones((1, tm), jnp.float32)
+        for _ in range(3 if scale else 1):
+            piece = rest.astype(v.dtype).astype(jnp.float32)
+            acc[...] += jnp.dot(jnp.where(mine, piece, 0.0).astype(v.dtype),
+                                v, preferred_element_type=jnp.float32)
+            rest = rest - piece
+
+    @pl.when((i == last) | (groups[jnp.minimum(i + 1, last)] != group))
+    def _last_visit():
+        out[...] = acc[...].astype(out.dtype)
+
+
+def _visits(tok, groups: int, tt: int, tm: int) -> tuple:
+    """The grid of ``bf_moe_token_sum`` over ``tok`` (ascending tokens of
+    the rows, a whole number of row tiles): ``(offsets, group, tile)`` and
+    how many visits there are.  Token tile ``g`` owns the rows
+    ``offsets[g] .. offsets[g + 1]``; visit ``i`` adds the rows of row tile
+    ``tile[i]`` to token tile ``group[i]``; a token tile's visits follow one
+    another, one for each row tile its rows touch, and a token tile without
+    rows is visited once, to be written as zeros.  (megablox's
+    ``make_group_metadata`` gives the same lists; its histogram and repeats
+    take three times as long to trace and lower as the kernel's body.)"""
+    tiles = tok.shape[0] // tm
+    sizes = jax.nn.one_hot(tok // tt, groups, dtype=jnp.int32).sum(axis=0)
+    ends = lax.cumsum(sizes)
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends])
+    first = jnp.minimum(offsets[:-1] // tm, tiles - 1)
+    last = jnp.where(sizes > 0, (ends - 1) // tm, first)
+    begin = lax.cumsum(last - first + 1) - (last - first + 1)
+    at = jnp.arange(tiles + groups - 1, dtype=jnp.int32)
+    group = jnp.minimum(
+        (begin[None, :] <= at[:, None]).sum(axis=1, dtype=jnp.int32) - 1,
+        groups - 1)
+    tile = jnp.minimum(first[group] + at - begin[group], tiles - 1)
+    return (offsets, group, tile), (last - first + 1).sum()
+
+
+@functools.partial(jax.jit, static_argnames=("tokens", "dtype", "interpret"))
+def _token_sum(tok, scale, rows, *, tokens: int, dtype, interpret: bool):
+    """``out[t] = sum of scale[c] * rows[c] over the c with tok[c] == t``
+    (``scale`` None: of ``rows[c]``) for ``tok`` (C,) ascending, ``rows``
+    (C, d); a ``tok`` of ``tokens`` or more names no token.  (tokens, d) in
+    ``dtype``, summed in float32 and rounded once.  The Pallas kernel
+    ``bf_moe_token_sum``: the grid walks the row tiles a tile of tokens at
+    a time (``_visits``), so its cost is that of ``C`` rows.  Behind
+    ``jax.jit`` for the reason ``_product`` is."""
+    telemetry.inc("bf_kernel_stagings_total", kernel="bf_moe_token_sum")
+    (C, d), T = rows.shape, tokens
+    tt, tm, tn = _TILES
+    tt = min(tt, -(-T // 8) * 8)    # no larger than the tokens need
+    tn = d if d <= tn else _fit(d, tn // 2)
+    G = -(-T // tt)
+    pad = -C % tm
+    tok = jnp.pad(jnp.where(tok < T, tok, G * tt), (0, pad),
+                  constant_values=G * tt)
+    metadata, visits = _visits(tok, G, tt, tm)
+    by_row = pl.BlockSpec((1, tm), lambda n, i, offsets, groups, tiles:
+                          (0, tiles[i]))
+    operands = [tok[None, :]] + ([] if scale is None else [
+        jnp.pad(scale.astype(jnp.float32), (0, pad))[None, :]])
+    out = pl.pallas_call(
+        functools.partial(_token_sum_kernel, tt=tt, tm=tm),
+        name="bf_moe_token_sum",
+        out_shape=jax.ShapeDtypeStruct((G * tt, d), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(d // tn, visits),
+            in_specs=[by_row] * len(operands) + [pl.BlockSpec(
+                (tm, tn), lambda n, i, offsets, groups, tiles:
+                (tiles[i], n))],
+            out_specs=pl.BlockSpec(
+                (tt, tn), lambda n, i, offsets, groups, tiles:
+                (groups[i], n)),
+            scratch_shapes=[pltpu.VMEM((tt, tn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*metadata, *operands, jnp.pad(rows, ((0, pad), (0, 0))))
+    return out[:T]
+
+
+def _held_keys(win, w: _Window, assignments: int):
+    """The window's assignments with its rows past the held run renamed to
+    assignments that do not exist (each its own, all after the last)."""
+    at = jnp.arange(win.shape[0], dtype=jnp.int32)
+    return jnp.where(at < w.rows, win, assignments + at)
+
+
+def _sum_to_tokens(values, win, w: _Window, tokens: int, k: int, dtype,
+                   weights=None):
+    """``out[t] = sum of values[c] over the rows c < w.rows of the window
+    with win[c] // k == t``, the transpose of the dispatch's ``x[win //
+    k]``: ``values`` (C, d) in window order, ``win`` (C,) its assignments,
+    (tokens, d) in ``dtype``.  With ``weights`` (tokens, k) float32, row
+    ``c`` is weighted by its assignment's; a token's rows are weighted and
+    summed in float32 and rounded once.  The window's rows are sorted by
+    assignment (``C`` keys: token order, slot order inside a token),
+    gathered into that order and summed by ``_token_sum``; whatever the
+    kernels left in the rows past ``w.rows`` reaches no sum."""
+    C = values.shape[0]
+    operands = (_held_keys(win, w, tokens * k),
+                jnp.arange(C, dtype=jnp.int32))
+    if weights is not None:
+        operands += (weights.reshape(-1)[win],)
+    key, at, *scale = lax.sort(operands, num_keys=1)
+    return _token_sum(key // k, *(scale or [None]), values[at],
+                      tokens=tokens, dtype=dtype,
+                      interpret=_interpret(values))
 
 
 def _cast(matrices, dtype) -> tuple:
@@ -599,18 +753,16 @@ def _cast(matrices, dtype) -> tuple:
 
 
 def _sizes(cast, order, load) -> tuple:
-    """How many experts are held, the window's rows, and the rows of a
-    buffer of whole windows that holds any run."""
+    """How many experts are held, and the window's rows."""
     count = cast[2].shape[0]
-    size = held_window(order.shape[0], count, load.shape[0])
-    return count, size, -(-order.shape[0] // size) * size
+    return count, held_window(order.shape[0], count, load.shape[0])
 
 
 def _window_experts(x, cast, order, load, k, first, index=0):
     """One window's rows through the held experts (``cast``: their gate,
     up and down matrices in ``x``'s dtype, the gate None where they have
     none)."""
-    count, size, _ = _sizes(cast, order, load)
+    count, size = _sizes(cast, order, load)
     w = _window(load, first, count, size, index)
     with timeline.device_scope("bf.moe.dispatch"):
         # padded, so that a window at the tail of the order is not clamped
@@ -651,142 +803,137 @@ def _window_transpose(saved: _Saved, w: _Window, cast, like, dy, weights, k):
     return d_rows, d_weights, d_gate, d_up, d_down
 
 
-def _to_tokens(d_rows, d_weights, inverse, w: _Window, weights, dtype):
+def _to_tokens(d_rows, d_weights, win, w: _Window, weights, dtype):
     """The gradients of ``x`` and of the weights from those of a window's
-    (or the run's) rows and assignments."""
+    rows and assignments: the rows summed into their tokens, the ``C``
+    scalars placed at their assignments (one each) among zeros."""
     T, k = weights.shape
     with timeline.device_scope("bf.moe.combine"):
-        d_weights = _from_window(d_weights, inverse, w).reshape(T, k)
+        d_weights = jnp.zeros((T * k,), d_weights.dtype).at[
+            _held_keys(win, w, T * k)].set(
+                d_weights, mode="drop", unique_indices=True).reshape(T, k)
     with timeline.device_scope("bf.moe.dispatch"):
-        return _from_window(d_rows, inverse, w).reshape(T, k, -1).sum(
-            axis=1, dtype=dtype), d_weights
+        return _sum_to_tokens(d_rows, win, w, T, k, dtype), d_weights
 
 
-def _window_fwd(x, weights, cast, order, inverse, load, k, first):
+def _window_fwd(x, weights, cast, order, load, k, first):
     """The share of a step whose held run fits the window."""
     w, saved = _window_experts(x, cast, order, load, k, first)
     with timeline.device_scope("bf.moe.combine"):
-        return _combine(_from_window(saved.out, inverse, w), weights,
-                        x.dtype), saved
+        return _sum_to_tokens(saved.out, saved.win, w, x.shape[0], k,
+                              x.dtype, weights), saved
 
 
-def _window_bwd(weights, cast, like, inverse, load, k, first, saved, dy):
+def _window_bwd(weights, cast, like, load, k, first, saved, dy):
     w = _window(load, first, cast[2].shape[0], saved.win.shape[0], 0)
     d_rows, d_weights, *d_matrices = _window_transpose(
         saved, w, cast, like, dy, weights, k)
-    return _to_tokens(d_rows, d_weights, inverse, w, weights,
+    return _to_tokens(d_rows, d_weights, saved.win, w, weights,
                       dy.dtype) + tuple(d_matrices)
 
 
-def _overflow(load, first: int, count: int, size: int, rows: int, window,
-              *carry):
+def _overflow(load, first: int, count: int, size: int, window, *carry):
     """``window(index, *carry)`` over the windows of ``size`` rows that
-    the held run takes, and the run itself as the one window of the
-    ``rows``-long buffers they filled."""
+    the held run takes."""
     with timeline.device_scope("bf.moe.dispatch"):
         windows = (_held_rows(load, first, count) + size - 1) // size
-    carry = lax.fori_loop(0, windows, lambda i, c: window(i, *c), carry)
-    return _window(load, first, count, rows, 0), carry
+    return lax.fori_loop(0, windows, lambda i, c: window(i, *c), carry)
 
 
-def _overflow_fwd(x, weights, cast, order, inverse, load, k, first):
+def _overflow_fwd(x, weights, cast, order, load, k, first):
     """The share of a step whose held run is longer than the window:
-    window after window into one buffer in the run's order, from which
-    every assignment selects its row as it does from the one window; no row
-    is dropped at any load and the tokens' sums are the same."""
-    count, size, rows = _sizes(cast, order, load)
+    window after window, each summed into its tokens as the one window is
+    and the windows' sums added in float32, so no row is dropped at any
+    load, a token's rows are still rounded once, and nothing is as long as
+    the run."""
+    count, size = _sizes(cast, order, load)
 
-    def window(index, out):
-        _, saved = _window_experts(x, cast, order, load, k, first, index)
-        with timeline.device_scope("bf.moe.experts"):
-            return lax.dynamic_update_slice(out, saved.out,
-                                            (index * size, 0)),
-    run, (out,) = _overflow(load, first, count, size, rows, window,
-                            jnp.zeros((rows, x.shape[1]), x.dtype))
-    with timeline.device_scope("bf.moe.combine"):
-        return _combine(_from_window(out, inverse, run), weights, x.dtype)
-
-
-def _overflow_bwd(x, weights, cast, like, order, inverse, load, k, first,
-                  dy):
-    """Its transpose keeps nothing of its forward: each window is run
-    again (the step is the rare one) and the matrices' gradients add
-    up."""
-    count, size, rows = _sizes(cast, order, load)
-
-    def window(index, d_rows, d_weights, *d_matrices):
+    def window(index, y):
         w, saved = _window_experts(x, cast, order, load, k, first, index)
-        mine = _window_transpose(saved, w, cast, like, dy, weights, k)
+        with timeline.device_scope("bf.moe.combine"):
+            return y + _sum_to_tokens(saved.out, saved.win, w, x.shape[0],
+                                      k, jnp.float32, weights),
+    y, = _overflow(load, first, count, size, window,
+                   jnp.zeros(x.shape, jnp.float32))
+    with timeline.device_scope("bf.moe.combine"):
+        return y.astype(x.dtype)
+
+
+def _overflow_bwd(x, weights, cast, like, order, load, k, first, dy):
+    """Its transpose keeps nothing of its forward: each window is run
+    again (the step is the rare one), and the windows' parts of every
+    gradient add up in float32."""
+    count, size = _sizes(cast, order, load)
+
+    def window(index, *sums):
+        w, saved = _window_experts(x, cast, order, load, k, first, index)
+        d_rows, d_weights, *d_matrices = _window_transpose(
+            saved, w, cast, like, dy, weights, k)
+        mine = _to_tokens(d_rows, d_weights, saved.win, w, weights,
+                          jnp.float32) + tuple(d_matrices)
         with timeline.device_scope("bf.moe.experts"):
-            return (lax.dynamic_update_slice(d_rows, mine[0],
-                                             (index * size, 0)),
-                    lax.dynamic_update_slice(d_weights, mine[1],
-                                             (index * size,))) + tuple(
-                None if a is None else a + b
-                for a, b in zip(d_matrices, mine[2:]))
-    run, (d_rows, d_weights, *d_matrices) = _overflow(
-        load, first, count, size, rows, window,
-        jnp.zeros((rows, x.shape[1]), dy.dtype),
-        jnp.zeros((rows,), jnp.float32),
+            return tuple(None if a is None else a + b
+                         for a, b in zip(sums, mine))
+    d_x, *rest = _overflow(
+        load, first, count, size, window, jnp.zeros(x.shape, jnp.float32),
+        jnp.zeros(weights.shape, jnp.float32),
         *(None if m is None else jnp.zeros(m.shape, like.dtype)
           for m in cast))
-    return _to_tokens(d_rows, d_weights, inverse, run, weights,
-                      dy.dtype) + tuple(d_matrices)
+    with timeline.device_scope("bf.moe.dispatch"):
+        return (d_x.astype(dy.dtype),) + tuple(rest)
 
 
 def _branch(load, first, count, size, window, overflow):
     """One ``lax.cond`` on whether the held run fits the window.  The
     barrier keeps the two branches' ends apart: XLA moves a tail that both
-    share out of the conditional, and the window branch then hands over its
-    ``(T, k, d)`` rows (268 MB at 16384 x 4 x 2048) where it hands over
-    their sum."""
+    share out of the conditional, and a branch then hands over the tail's
+    operands where it hands over its result."""
     with timeline.device_scope("bf.moe.dispatch"):
         return lax.cond(_held_rows(load, first, count) <= size, window,
                         lambda: lax.optimization_barrier(overflow()))
 
 
-def _held_args(x, weights, gate, up, down, order, inverse, load, k, first):
+def _held_args(x, weights, gate, up, down, order, load, k, first):
     """What both branches take, and the window's size."""
-    return ((x, weights, _cast((gate, up, down), x.dtype), order, inverse,
-             load, k, first),
+    return ((x, weights, _cast((gate, up, down), x.dtype), order, load, k,
+             first),
             held_window(order.shape[0], down.shape[0], load.shape[0]))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _held_share(x, weights, gate, up, down, order, inverse, load, k: int,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _held_share(x, weights, gate, up, down, order, load, k: int,
                 first: int):
     """The held experts' part of the layer's result over a window of the
     sorted assignments (module docstring).  The transpose is written out,
     because autodiff of a ``lax.cond`` keeps the residuals of both
     branches: here the window branch keeps its ``(C, .)`` arrays and the
     overflow branch nothing."""
-    args, size = _held_args(x, weights, gate, up, down, order, inverse,
-                            load, k, first)
+    args, size = _held_args(x, weights, gate, up, down, order, load, k,
+                            first)
     return _branch(load, first, down.shape[0], size,
                    lambda: _window_fwd(*args)[0],
                    lambda: _overflow_fwd(*args))
 
 
-def _held_fwd(x, weights, gate, up, down, order, inverse, load, k, first):
-    args, size = _held_args(x, weights, gate, up, down, order, inverse,
-                            load, k, first)
+def _held_fwd(x, weights, gate, up, down, order, load, k, first):
+    args, size = _held_args(x, weights, gate, up, down, order, load, k,
+                            first)
     saved = jax.eval_shape(lambda: _window_fwd(*args)[1])
     y, saved = _branch(
         load, first, down.shape[0], size, lambda: _window_fwd(*args),
         lambda: (_overflow_fwd(*args), jax.tree.map(
             lambda s: jnp.zeros(s.shape, s.dtype), saved)))
     # the empty array carries the matrices' dtype to the backward pass
-    return y, (args[:6], jnp.zeros((0,), down.dtype), saved)
+    return y, (args[:5], jnp.zeros((0,), down.dtype), saved)
 
 
 def _held_bwd(k, first, res, dy):
-    (x, weights, cast, order, inverse, load), like, saved = res
+    (x, weights, cast, order, load), like, saved = res
     return _branch(
         load, first, cast[2].shape[0], saved.win.shape[0],
-        lambda: _window_bwd(weights, cast, like, inverse, load, k, first,
-                            saved, dy),
-        lambda: _overflow_bwd(x, weights, cast, like, order, inverse, load,
-                              k, first, dy)) + (None, None, None)
+        lambda: _window_bwd(weights, cast, like, load, k, first, saved, dy),
+        lambda: _overflow_bwd(x, weights, cast, like, order, load, k, first,
+                              dy)) + (None, None)
 
 
 _held_share.defvjp(_held_fwd, _held_bwd)

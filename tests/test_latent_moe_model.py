@@ -341,18 +341,25 @@ WINDOW_CASES = {                # (first, held assignments, compute dtype)
     "exactly-the-window-at-the-tail": (14, 512, jnp.float32),
     "first-zero": (0, 100, jnp.float32),
     "poisoned": (4, 100, jnp.float32),
+    # 60 of the 100 tokens send both choices: 160 rows, a token's two among
+    # them, beside 924 tokens with none
+    "both-slots-held": (4, 100, jnp.float32),
+    "both-slots-held-bfloat16": (4, 100, jnp.bfloat16),
 }
+BOTH = 60
 
 
-def _window_layer(first, n_held, tokens=1024, E=16, d=16, f=24):
+def _window_layer(first, n_held, tokens=1024, E=16, d=16, f=24, both=0):
     """A layer whose first ``n_held`` tokens (of a shuffled order) send
-    their first choice to one of the two held experts and nothing else
-    goes there."""
+    their first choice to one of the two held experts, the first ``both``
+    of them their second choice to the other, and nothing else goes
+    there."""
     x, noise = normal(30, (tokens, d)), normal(31, (tokens, E), 0.1)
     absent = np.array([e for e in range(E) if not first <= e < first + 2])
     t = np.arange(tokens)
     top = np.where(t < n_held, first + t % 2, absent[t % len(absent)])
-    second = absent[(t + 3) % len(absent)]
+    second = np.where(t < both, first + (t + 1) % 2,
+                      absent[(t + 3) % len(absent)])
     logits = noise.at[t, top].add(8.0).at[t, second].add(4.0)
     shuffle = np.random.default_rng(0).permutation(tokens)
     gate, up = normal(32, (2, d, f), 0.3), normal(33, (2, d, f), 0.3)
@@ -360,16 +367,30 @@ def _window_layer(first, n_held, tokens=1024, E=16, d=16, f=24):
             shuffle < n_held)
 
 
+def assert_sums_equal(got, want, k, dtype):
+    """Equal as sums of a token's ``k`` float32 terms are whatever the order
+    they are added in: within ``k`` roundings of float32 before the one
+    rounding to ``dtype``, which is at most one unit in its last place."""
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(
+        got, want, rtol=float(jnp.finfo(dtype).eps),
+        atol=k * 2.0 ** -23 * float(np.abs(want).max(initial=0.0)))
+
+
 @pytest.mark.parametrize("name", sorted(WINDOW_CASES))
 def test_the_window_is_the_whole_path(monkeypatch, name):
     """A held share over its window against the path over all ``T * k``
-    rows (the layer with a window as large as the order, which is also the
-    branch an overflowing step takes): ``y`` and ``d x`` to the last bit,
-    the router's and the matrices' gradients to float32 rounding (their
-    sums run over other tiles); whatever lies in the window's rows past the
-    held ones reaches nothing."""
+    rows (the layer with no window: every row gathered, every assignment
+    back to its token): ``y`` and ``d x`` as a token's float32 sum is in
+    another order of its terms (the window's rows are summed by
+    ``bf_moe_token_sum``, the whole path's in slot order), the router's and
+    the matrices' gradients to float32 rounding (their sums run over other
+    tiles); whatever lies in the window's rows past the held ones reaches
+    nothing."""
     first, n_held, dtype = WINDOW_CASES[name]
-    x, logits, gate, up, down, holds = _window_layer(first, n_held)
+    both = BOTH if name.startswith("both-slots-held") else 0
+    x, logits, gate, up, down, holds = _window_layer(first, n_held,
+                                                     both=both)
     assert moe.held_window(2048, 2, 16) == 512
     if name == "poisoned":      # the tokens whose rows fill the window's end
         x = jnp.where(holds[:, None], x, jnp.nan)
@@ -380,19 +401,96 @@ def test_the_window_is_the_whole_path(monkeypatch, name):
         return (y.astype(jnp.float32) ** 2).sum(), (y, plan.load)
     grad = jax.jit(jax.value_and_grad(run, (0, 1, 2, 3, 4), has_aux=True))
     (_, (y, load)), got = grad(x, logits, gate, up, down)
-    assert int(load[first:first + 2].sum()) == n_held
+    assert int(load[first:first + 2].sum()) == n_held + both
     monkeypatch.setattr(moe, "held_window", lambda n, count, E: n)
     (_, (y_whole, _)), want = jax.jit(jax.value_and_grad(
         run, (0, 1, 2, 3, 4), has_aux=True))(x, logits, gate, up, down)
-    np.testing.assert_array_equal(y, y_whole)
+    assert_sums_equal(y, y_whole, 2, dtype)
     assert np.isfinite(np.asarray(y, np.float32)).all()
     assert bool(jnp.abs(y[holds]).sum() > 0) == (n_held > 0)
-    np.testing.assert_array_equal(got[0][holds], want[0][holds])
+    np.testing.assert_array_equal(y[~holds], 0.0)
+    assert_sums_equal(got[0][holds], want[0][holds], 2, dtype)
     np.testing.assert_array_equal(got[0][~holds], 0.0)
     for a, b in zip(got[1:], want[1:]):
         assert np.isfinite(a).all()
         np.testing.assert_allclose(a, b, rtol=1e-5,
                                    atol=1e-6 * float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize("n_held", [100, 512])
+def test_the_window_branch_is_the_overflow_branch(monkeypatch, n_held):
+    """One load under the window (and one that fills it) through the window
+    branch and through the overflow branch, which covers the same run with
+    its first window: the same rows sorted and summed by the same kernel
+    and added to zeros, so every result is the same to the bit."""
+    x, logits, gate, up, down, holds = _window_layer(4, n_held, both=BOTH)
+
+    def run(x, logits, gate, up, down):
+        y = moe.dropless_moe(x, logits, gate, up, down, k=2, held=(4, 2))[0]
+        return (y ** 2).sum(), y
+    grad = lambda: jax.jit(jax.value_and_grad(  # noqa: E731
+        run, (0, 1, 2, 3, 4), has_aux=True))(x, logits, gate, up, down)
+    taken = []
+    branch = moe._branch
+
+    def spy(load, first, count, size, window, overflow):
+        taken.append(int(size))
+        return branch(load, first, count, size, window, overflow)
+    monkeypatch.setattr(moe, "_branch", spy)
+    (_, y), got = grad()
+    assert taken == [512, 512] and bool(jnp.abs(y[holds]).sum() > 0)
+    monkeypatch.setattr(moe, "_branch", lambda load, first, count, size,
+                        window, overflow: overflow())
+    (_, y_over), want = grad()
+    np.testing.assert_array_equal(y, y_over)
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+
+
+def _walk(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs in its parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub)
+
+
+def test_a_held_share_names_no_row_of_the_order(monkeypatch):
+    """The gradient program of a held share at ``twotower-s8192-1chip``'s
+    shapes (8192 tokens, top-6 of 128 experts, 8 held, 2688 wide, un-gated
+    experts of 1856: a window of 6144 of 49152 rows), both branches: no
+    array of ``T * k`` or more rows as wide as the model, forward or
+    backward, and one sort of ``T * k`` keys, the order's; ``inverse``, the
+    second, has no reader and is dropped.  The whole path has both."""
+    from jax._src.interpreters import partial_eval as pe
+    T_, k, E, d, f = 8192, 6, 128, 2688, 1856
+    assert moe.held_window(T_ * k, 8, E) == 6144
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)  # noqa: E731
+    args = (shape(T_, d), jax.ShapeDtypeStruct((T_, E), jnp.float32),
+            shape(8, d, f), shape(8, f, d))
+
+    def program(held):
+        def loss(x, logits, up, down):
+            y, plan = moe.dropless_moe(x, logits, None, up, down, k=k,
+                                       held=held, scoring="sigmoid")
+            return (y.astype(jnp.float32) ** 2).sum(), plan.load
+        closed = jax.make_jaxpr(jax.value_and_grad(
+            loss, (0, 1, 2, 3), has_aux=True))(*args)
+        jaxpr, _ = pe.dce_jaxpr(closed.jaxpr, [True] * len(closed.out_avals))
+        eqns = list(_walk(jaxpr))
+        rows = [v.aval.shape for e in eqns for v in e.outvars
+                if len(v.aval.shape) >= 2 and v.aval.shape[-1] == d
+                and v.aval.shape[0] * (v.aval.shape[1] if len(v.aval.shape)
+                                       > 2 else 1) >= T_ * k]
+        sorts = [e for e in eqns if e.primitive.name == "sort"
+                 and e.invars[0].aval.shape == (T_ * k,)]
+        return rows, len(sorts), {e.params.get("name") for e in eqns
+                                  if e.primitive.name == "pallas_call"}
+    rows, sorts, kernels = program((0, 8))
+    assert rows == [] and sorts == 1 and "bf_moe_token_sum" in kernels
+    monkeypatch.setattr(moe, "held_window", lambda n, count, E: n)
+    rows, sorts, kernels = program((0, 8))
+    assert rows and sorts == 2 and "bf_moe_token_sum" not in kernels
 
 
 def test_a_held_share_differentiates_one_branch(monkeypatch):

@@ -520,6 +520,124 @@ def test_an_ungated_share_that_overflows_its_window_loses_nothing():
         assert rel(a, b) < 1e-5
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("held_rows", [300, 512, 1024],
+                         ids=["under", "exactly-the-window", "over"])
+def test_an_ungated_window_is_the_whole_path(monkeypatch, held_rows, dtype):
+    """Un-gated experts (``gate=None``) as a share of two of eight on its
+    window of 512 of 1024 rows, under it, filling it and over it (two
+    windows), against the layer with no window: ``y`` and ``d x`` as a
+    token's float32 sum is in another order of its terms, the matrices'
+    and the router's gradients to float32 rounding."""
+    x, _, _, up, down = _layer()
+    t = np.arange(512)
+    held = np.where(t % 2 == 0, 2, 3)
+    absent = np.array([0, 1, 4, 5, 6, 7])
+    # the first tokens send both choices to the held pair, the others none
+    first = np.where(t < held_rows // 2, held, absent[t % 6])
+    second = np.where(t < held_rows // 2, 5 - held, absent[(t + 1) % 6])
+    logits = (normal(47, (512, 8), 0.1).at[t, first].add(8.0)
+              .at[t, second].add(6.0))
+
+    def run(x, logits, up, down):
+        y, plan = moe.dropless_moe(x.astype(dtype), logits, None, up, down,
+                                   k=2, held=(2, 2))
+        return (y.astype(jnp.float32) ** 2).sum(), (y, plan.load)
+    grad = lambda: jax.jit(jax.value_and_grad(  # noqa: E731
+        run, (0, 1, 2, 3), has_aux=True))(x, logits, up[2:4], down[2:4])
+    (_, (y, load)), got = grad()
+    assert int(load[2:4].sum()) == held_rows // 2 * 2
+    assert moe.held_window(1024, 2, 8) == 512
+    monkeypatch.setattr(moe, "held_window", lambda n, count, E: n)
+    (_, (y_whole, _)), want = grad()
+    eps = float(jnp.finfo(dtype).eps)
+    for a, b in ((y, y_whole), (got[0], want[0])):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(b).max() > 0
+        np.testing.assert_allclose(a, b, rtol=eps,
+                                   atol=2 ** -22 * np.abs(b).max())
+    for a, b in zip(got[1:], want[1:]):
+        assert rel(a, b) < 1e-5
+
+
+# --- (d') a window's rows summed into their tokens: ``bf_moe_token_sum`` ------------
+
+SUM_CASES = {       # tokens, k, window rows C, rows the run covers, width
+    "random": (1000, 3, 300, 217, 40),
+    "full-window": (64, 4, 256, 256, 16),
+    "empty-window": (1024, 2, 512, 0, 16),
+    "one-row": (1024, 2, 512, 1, 16),
+    "several-token-tiles": (700, 2, 640, 600, 136),
+}
+
+
+def _window_rows(name, dtype):
+    T_, k, C, rows, d = SUM_CASES[name]
+    rng = np.random.default_rng(7)
+    win = rng.permutation(T_ * k)[:C].astype(np.int32)
+    values = normal(60, (C, d)).astype(dtype)
+    # what the kernels left in the rows past the run: it reaches no sum
+    values = values.at[rows:].set(jnp.nan)
+    w = moe._Window(jnp.int32(0), jnp.int32(rows), None)
+    return T_, k, rows, jnp.asarray(win), values, w
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["plain", "weighted"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("name", sorted(SUM_CASES))
+def test_a_windows_rows_are_summed_into_their_tokens(name, dtype, weighted):
+    """``_sum_to_tokens`` alone against ``jax.ops.segment_sum`` in float32
+    on a random window: rows of ``dtype``, float32 weights, a float32
+    result to float32 rounding of a token's at most ``k`` terms; NaN in
+    the rows past ``w.rows`` and a token without rows gives zero."""
+    T_, k, rows, win, values, w = _window_rows(name, dtype)
+    weights = 0.5 + jax.random.uniform(KEY, (T_, k)) if weighted else None
+    got = jax.jit(lambda v, win, w, weights: moe._sum_to_tokens(
+        v, win, w, T_, k, jnp.float32, weights))(values, win, w, weights)
+    terms = values[:rows].astype(jnp.float32)
+    if weighted:
+        terms = terms * weights.reshape(-1)[win[:rows]][:, None]
+    want = jax.ops.segment_sum(terms, win[:rows] // k, num_segments=T_)
+    assert got.shape == want.shape and got.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=2 ** -22,
+                               atol=k * 2 ** -23 * float(
+                                   jnp.abs(want).max(initial=0.0)))
+    touched = np.zeros(T_, bool)
+    touched[np.asarray(win[:rows]) // k] = True
+    np.testing.assert_array_equal(got[~touched], 0.0)
+
+
+def test_all_rows_of_a_window_to_one_token():
+    """Every row of the run is one token's (more rows than any ``k``: the
+    kernel asks nothing of how many rows a token has), the result rounded
+    once to bfloat16 from the float32 sum."""
+    T_, C, d = 256, 256, 24
+    values = normal(61, (C, d)).astype(jnp.bfloat16)
+    win = jnp.full((C,), 77 * 2, jnp.int32).at[1::2].add(1)   # token 77
+    w = moe._Window(jnp.int32(0), jnp.int32(200), None)
+    got = moe._sum_to_tokens(values, win, w, T_, 2, jnp.bfloat16)
+    want = values[:200].astype(jnp.float32).sum(axis=0)
+    np.testing.assert_allclose(got[77].astype(jnp.float32), want,
+                               rtol=2 ** -7, atol=1e-6)
+    np.testing.assert_array_equal(jnp.delete(got, 77, axis=0), 0.0)
+
+
+def test_the_token_sum_is_staged_once_a_shape():
+    """``bf_kernel_stagings_total{kernel="bf_moe_token_sum"}`` counts a
+    staging a shape: the kernel sits behind a ``jax.jit`` of its own."""
+    name = 'bf_kernel_stagings_total{kernel="bf_moe_token_sum"}'
+    T_, k, rows, win, values, w = _window_rows("one-row", jnp.float32)
+    values = values[:, :8]                      # a shape of this test's own
+    call = lambda: moe._sum_to_tokens(values, win, w, T_, k,  # noqa: E731
+                                      jnp.float32)
+    before = telemetry.snapshot().get(name, 0)
+    call(), call()
+    jax.jit(lambda: call() + call())()
+    assert telemetry.snapshot()[name] - before == 1
+
+
 @pytest.mark.parametrize("count", [2, 4], ids=["window", "whole"])
 def test_the_shares_add_up_to_the_uncut_layer_of_the_reference(toy, count):
     """Eight un-gated experts in shares of ``count`` (two: each on its
