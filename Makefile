@@ -7,8 +7,8 @@ PYTEST = python -m pytest -q
 
 .PHONY: test test-fast test-slow test-all chip-smoke bench-comm \
         bench-comm-smoke native telemetry-smoke prof-smoke transport-smoke \
-        stripe-smoke tracerec-smoke async-smoke ffi-smoke fused-smoke \
-        probe-smoke placement-smoke synth-smoke hier-smoke sharded-smoke \
+        stripe-smoke tracerec-smoke async-smoke ffi-smoke \
+        placement-smoke synth-smoke hier-smoke sharded-smoke \
         chaos-smoke chaos links-smoke tune-smoke metrics-lint
 
 # Fast gate: ~3 min on the CPU mesh (in-process virtual-mesh tests only;
@@ -19,8 +19,8 @@ PYTEST = python -m pytest -q
 # window-transport hot path is fresh (graceful skip without a toolchain —
 # every native consumer has a Python fallback).
 test: native test-fast bench-comm-smoke prof-smoke transport-smoke \
-      stripe-smoke tracerec-smoke async-smoke ffi-smoke fused-smoke \
-      probe-smoke placement-smoke synth-smoke hier-smoke sharded-smoke \
+      stripe-smoke tracerec-smoke async-smoke ffi-smoke \
+      placement-smoke synth-smoke hier-smoke sharded-smoke \
       chaos-smoke links-smoke tune-smoke metrics-lint
 test-fast:
 	$(PYTEST) tests/ -m "not slow"
@@ -163,32 +163,6 @@ async-smoke:
 # win over the PR-9 native put path for rows >= 4 KiB.
 ffi-smoke:
 	env JAX_PLATFORMS=cpu python bench_comm.py --ffi-smoke
-
-# Whole-step compilation CI gate (BLUEFOG_TPU_FUSED_STEP): the gossip
-# training step lowered into one XLA program with per-bucket FFI puts
-# issued by data dependence.  Structural assertions on the loopback
-# transport rig, no timing: every step takes the fused path
-# (bf_fused_step_active = 1, in-program puts counted), the fused
-# trajectory is bitwise identical to the eager oracle over the same
-# gradient stream, BLUEFOG_TPU_FUSED_STEP=0 builds nothing and registers
-# nothing, and a fused=True optimizer without the native XLA put handler
-# falls back to eager with exactly one warning.  Graceful skip when the
-# native bf_xla_win_put_pass symbols are absent.  The >= 1.5x end-to-end
-# step-time win is gated by `python bench_comm.py --fused` full runs.
-fused-smoke:
-	env JAX_PLATFORMS=cpu python bench_comm.py --fused-smoke
-
-# In-program probe CI gate (BLUEFOG_TPU_PROBE, utils/probes.py): run the
-# fused loopback rig with probes on and assert the whole reconcile loop —
-# every step served fused, a measured bf_fused_overlap_ratio in (0, 1],
-# probe events drained (bf_probe_events_total > 0), one
-# bf_fused_bucket_issue_seconds series per fusion bucket, a finite
-# measured-vs-modeled divergence, and trace-merge'd timeline output
-# carrying the fused-probe lanes.  Graceful skip when the native core
-# lacks the bf_probe_* / bf_xla_probe symbols (the feature then degrades
-# to the labeled-but-unattributed fused-step phase, tested in tier 1).
-probe-smoke:
-	env JAX_PLATFORMS=cpu python bench_comm.py --probe-smoke
 
 # Churn-controller CI gate: a real 4-process `bfrun --chaos` gang on the
 # CPU backend, one rank SIGKILLed mid-gossip — asserts the survivors reach

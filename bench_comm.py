@@ -64,7 +64,6 @@ import argparse
 import json
 import os
 import time
-from typing import Optional
 
 
 def _parse_args():
@@ -105,32 +104,6 @@ def _parse_args():
                         "asserts the FFI path engaged + zero staging-copy "
                         "bytes, no timing assertion; graceful skip when "
                         "jax.ffi or the native bf_xla symbols are absent")
-    p.add_argument("--fused", action="store_true",
-                   help="run the whole-step compilation bench "
-                        "(BLUEFOG_TPU_FUSED_STEP): eager vs fused "
-                        "end-to-end step time on the loopback transport "
-                        "rig plus the structural gates; asserts the "
-                        ">= 1.5x step-time win and <= 1e-6 trajectory "
-                        "equivalence over 50 steps")
-    p.add_argument("--fused-smoke", action="store_true",
-                   help="CI variant of --fused (`make fused-smoke`): "
-                        "asserts fused engagement (bf_fused_step_active, "
-                        "in-program puts counted), trajectory equivalence "
-                        "vs eager, FUSED_STEP=0 bitwise inertness and the "
-                        "graceful one-warning fallback; no timing "
-                        "assertion, graceful skip without the native "
-                        "bf_xla_win_put_pass handler")
-    p.add_argument("--probe-smoke", action="store_true",
-                   help="CI gate of the in-program probes "
-                        "(`make probe-smoke`): a fused loopback run with "
-                        "BLUEFOG_TPU_PROBE on (the default) asserts the "
-                        "probe surfaces land — bf_fused_overlap_ratio in "
-                        "(0, 1], per-bucket issue histograms, "
-                        "bf_probe_events_total, a finite measured-vs-"
-                        "modeled divergence — and that trace-merge emits "
-                        "valid JSON carrying the fused-probe lanes; "
-                        "graceful skip when the native core lacks "
-                        "bf_xla_probe")
     p.add_argument("--async-smoke", action="store_true",
                    help="structural CI gate of the barrier-free async "
                         "gossip mode (`make async-smoke`): a loopback "
@@ -667,32 +640,6 @@ def transport_main(args) -> int:
                 os.environ["BLUEFOG_TPU_LINK_OBS"] = prev_obs
             config.reload()
 
-    # Whole-step compilation leg (full runs only — it needs jax + the
-    # native XLA put handler): the eager-vs-fused end-to-end step-time
-    # cell, folded into this report's detail next to the links leg.
-    # Capability-gated with a graceful skip, like the ffi leg above.
-    fused_detail = None
-    if not smoke and native_ok:
-        from bluefog_tpu import native as _native
-        if (_native.has_win_xla() and _native.has_xla_handler()
-                and os.environ.get("BLUEFOG_TPU_FUSED_STEP") != "0"):
-            prev_fused = _fused_env_setup()
-            try:
-                from bluefog_tpu.ops import xlaffi as _xlaffi
-                from bluefog_tpu.utils import config as _fconfig
-                _fconfig.reload()
-                _xlaffi._reset_for_tests()
-                if _xlaffi.armed() and _xlaffi.has_passthrough():
-                    fused_detail = _fused_timing_cell()
-                else:
-                    fused_detail = {"skipped": _xlaffi.disarm_reason()
-                                    or "no passthrough put handler"}
-            finally:
-                _fused_env_restore(prev_fused)
-        else:
-            fused_detail = {"skipped": "bf_xla symbols absent or "
-                                       "FUSED_STEP pinned off"}
-
     rc = 0
     for f in failures:
         print(f"bench_comm --transport: {f}", file=sys.stderr)
@@ -720,7 +667,6 @@ def transport_main(args) -> int:
             "ffi": ffi_detail,
             "tracing": tracing_detail,
             "links": links_detail,
-            "fused_step": fused_detail,
         },
     }))
     return rc
@@ -1064,495 +1010,6 @@ def async_main(args) -> int:
         },
     }))
     return rc
-
-
-def _fused_env_setup():
-    """Arm the whole-step rig's environment (idempotent; call BEFORE the
-    first jax import): CPU backend, 8 virtual devices, native window
-    transport + XLA put path.  Returns the saved env for restore."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8")
-    prev = {v: os.environ.get(v) for v in (
-        "BLUEFOG_TPU_WIN_NATIVE", "BLUEFOG_TPU_WIN_XLA",
-        "BLUEFOG_TPU_WIN_COALESCE", "BLUEFOG_TPU_WIN_COALESCE_LINGER_MS",
-        "BLUEFOG_TPU_WIN_COMPRESSION", "BLUEFOG_TPU_FUSED_STEP",
-        "BLUEFOG_TPU_TELEMETRY")}
-    os.environ.update({
-        "BLUEFOG_TPU_WIN_NATIVE": "1",
-        "BLUEFOG_TPU_WIN_XLA": "1",
-        "BLUEFOG_TPU_WIN_COALESCE": "1",
-        "BLUEFOG_TPU_WIN_COALESCE_LINGER_MS": "500",
-        "BLUEFOG_TPU_WIN_COMPRESSION": "none",
-        "BLUEFOG_TPU_TELEMETRY": "1",
-    })
-    os.environ.pop("BLUEFOG_TPU_FUSED_STEP", None)
-    return prev
-
-
-def _fused_env_restore(prev):
-    from bluefog_tpu.ops import xlaffi
-    from bluefog_tpu.utils import config as _config
-    for var, val in prev.items():
-        if val is None:
-            os.environ.pop(var, None)
-        else:
-            os.environ[var] = val
-    _config.reload()
-    xlaffi._reset_for_tests()
-
-
-def _fused_rig(fused, leaves, cols, buckets, steps, warm=0, synced=False,
-               lr=0.5, armed=True):
-    """One loopback leg of the whole-step rig: a ``leaves x (8, cols)``
-    f32 tree stepped through a put-family optimizer against the
-    two-transport loopback store pair (the test_win_xla rig — the
-    windows predate the directory install, so one store serves both wire
-    ends and every remote put really crosses TCP).
-
-    ``synced=True`` gates each drain on every remote frame of the step
-    having been applied (the loopback twin of a quiescent wire) — the
-    determinism mode the trajectory-equivalence legs need; timing legs
-    run ungated.  Returns (times_ms, final_params, fused_steps)."""
-    import threading
-
-    import numpy as np
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    import bluefog_tpu as bf
-    from bluefog_tpu import topology as topo
-    from bluefog_tpu.ops import transport as T
-    from bluefog_tpu.ops import window as W
-    from bluefog_tpu.ops import xlaffi
-
-    from bluefog_tpu.optim import window_optimizers as WO
-
-    W.win_free()
-    bf.init(lambda: topo.RingGraph(8))
-    rs = np.random.RandomState(7)
-    params = {f"l{i:02d}": jnp.asarray(rs.randn(8, cols)
-                                       .astype(np.float32))
-              for i in range(leaves)}
-    opt = WO.DistributedWinPutOptimizer(optax.sgd(lr), fused=fused,
-                                        fusion_buckets=buckets)
-    st = opt.init(params)
-
-    applied = [0]
-    cv = threading.Condition()
-
-    def bump(k):
-        with cv:
-            applied[0] += k
-            cv.notify_all()
-
-    def apply(op, name, src, dst, weight, p_weight, payload):
-        W._apply_inbound(op, name, src, dst, weight, p_weight, payload)
-        bump(1)
-
-    def apply_batch(msgs):
-        W._apply_inbound_batch(msgs)
-        bump(len(msgs))
-
-    def apply_items(items):
-        W._apply_inbound_items(items)
-        bump(sum((p[5] + p[6]) if k else 1 for k, p in items))
-
-    server = T.WindowTransport(apply, apply_batch=apply_batch,
-                               apply_items=apply_items)
-    client = T.WindowTransport(lambda *a: None)
-    saved = W._store.distrib
-    orig_update = W.win_update
-    expect = [0]
-
-    def synced_update(name, **kw):
-        with cv:
-            assert cv.wait_for(lambda: applied[0] >= expect[0],
-                               timeout=60), (applied[0], expect[0])
-        return orig_update(name, **kw)
-
-    try:
-        assert client.native_path, "native transport sender required"
-        for name, spl in zip(opt._names, opt._bucket_splits):
-            server.register_window(name, int(spl[-1]))
-        W._store.distrib = W._Distrib(
-            client, rank_owner={r: r % 2 for r in range(8)},
-            proc_addr={0: ("127.0.0.1", 1),
-                       1: ("127.0.0.1", server.port)},
-            my_proc=0)
-        if armed:
-            assert xlaffi.armed(), xlaffi.disarm_reason()
-        if synced:
-            W.win_update = synced_update
-        p = params
-        rng = np.random.RandomState(42)
-        times = []
-        n_windows = len(opt._names)
-        for i in range(steps + warm):
-            g = jax.tree.map(lambda x: x * 0.01 + jnp.asarray(
-                rng.randn(*x.shape).astype(np.float32)) * 1e-3, p)
-            # The bidirectional ring's out-edges from owned (even) srcs
-            # all target odd dsts: 8 remote edges per op per window.
-            expect[0] += 8 * n_windows
-            t0 = time.perf_counter()
-            p, st = opt.step(p, g, st, require_mutex=False)
-            jax.block_until_ready(p)
-            if i >= warm:
-                times.append((time.perf_counter() - t0) * 1e3)
-        fused_steps = (opt._fused_impl.fused_steps
-                       if opt._fused_impl is not None else 0)
-        return times, {k: np.asarray(v) for k, v in p.items()}, fused_steps
-    finally:
-        W.win_update = orig_update
-        W._store.distrib = saved
-        opt.free()
-        client.stop()
-        server.stop()
-
-
-def _probe_overlap_cell(buckets: int, steps: int) -> Optional[dict]:
-    """Measured-overlap summary over the fused leg's last ``steps``
-    probe reconciles: the MEASURED ``bf_fused_overlap_ratio`` median
-    (replacing the static model as the headline number), per-bucket
-    p50/p99 put-issue latencies, and the modeled mean kept solely for
-    the divergence ratio (the link-observatory x3 pattern)."""
-    import numpy as np
-
-    from bluefog_tpu.utils import probes
-    rows = probes.recent_summaries(steps)
-    if not rows:
-        return None
-    meas = [r["measured_overlap"] for r in rows]
-    modeled = rows[-1].get("modeled_overlap")
-    measured = float(np.median(meas))
-    cell = {
-        "measured_overlap": round(measured, 4),
-        "modeled_overlap": modeled,
-        "overlap_divergence": (round(measured / modeled, 3)
-                               if modeled else None),
-        "reconciled_steps": len(rows),
-        "bucket_issue_us": {},
-    }
-    for bi in range(buckets):
-        vals = [r["bucket_issue_seconds"][bi] * 1e6 for r in rows
-                if bi in r["bucket_issue_seconds"]]
-        if vals:
-            cell["bucket_issue_us"][str(bi)] = {
-                "p50": round(float(np.percentile(vals, 50)), 1),
-                "p99": round(float(np.percentile(vals, 99)), 1),
-            }
-    return cell
-
-
-def _fused_timing_cell(steps=40, warm=6):
-    """The acceptance cell: eager vs fused end-to-end step time on the
-    ungated loopback rig at the window-heavy configuration (32 leaves x
-    (8, 128) over 8 fusion buckets = 8 in-program puts per step).
-
-    When the native core carries the in-program probes the cell reports
-    MEASURED overlap (median over the timed steps) with per-bucket
-    p50/p99 issue latencies; the static model stays only as the
-    denominator of the divergence ratio."""
-    import numpy as np
-
-    from bluefog_tpu.utils import probes, telemetry
-    leaves, cols, buckets = 32, 128, 8
-    te, _, _ = _fused_rig(False, leaves, cols, buckets, steps, warm)
-    telemetry.reset()
-    probes._reset_for_tests()
-    tf, _, fsteps = _fused_rig(True, leaves, cols, buckets, steps, warm)
-    snap = telemetry.snapshot()
-    compile_s = snap.get("bf_fused_step_compile_seconds_sum", 0.0)
-    e50, e99 = np.percentile(te, 50), np.percentile(te, 99)
-    f50, f99 = np.percentile(tf, 50), np.percentile(tf, 99)
-    cell = {
-        "leaves": leaves, "cols": cols, "fusion_buckets": buckets,
-        "steps": steps,
-        "eager_ms_p50": round(float(e50), 3),
-        "eager_ms_p99": round(float(e99), 3),
-        "fused_ms_p50": round(float(f50), 3),
-        "fused_ms_p99": round(float(f99), 3),
-        "speedup": round(float(e50 / max(f50, 1e-9)), 3),
-        "compile_seconds": round(float(compile_s), 3),
-        "fused_steps": fsteps,
-    }
-    overlap = _probe_overlap_cell(buckets, steps)
-    if overlap is not None:
-        cell["overlap"] = overlap
-    return cell
-
-
-def _fused_report(smoke: bool):
-    """The whole-step compilation gate: returns (speedup|None, detail,
-    failures).  Callers set the env (``_fused_env_setup``) first.
-
-    Structural legs (both modes):
-      1. engagement — a gated loopback run where every step takes the
-         fused path (``bf_fused_step_active`` 1, in-program puts
-         counted), with the trajectory-equivalence assert (<= 1e-6 vs
-         eager over the same gradient stream; bitwise expected — the
-         2^-1 learning rate keeps the update multiply exact, so XLA's
-         FMA contraction and eager's separate mul+add round the same);
-      2. ``BLUEFOG_TPU_FUSED_STEP=0`` — bitwise identical to the eager
-         leg AND inert (no program built, no bf_fused_step_* series);
-      3. graceful fallback — with the XLA put path disarmed a
-         ``fused=True`` optimizer warns ONCE, keeps stepping on the
-         eager path, and reports inactive.
-    Full runs add the timing cell and assert the >= 1.5x end-to-end
-    step-time win."""
-    import numpy as np
-
-    from bluefog_tpu import native
-    from bluefog_tpu.ops import xlaffi
-    from bluefog_tpu.utils import config as _config
-    from bluefog_tpu.utils import logging as bflog
-    from bluefog_tpu.utils import telemetry
-    _config.reload()
-    xlaffi._reset_for_tests()
-
-    if not (native.available() and native.has_win_xla()
-            and native.has_xla_handler() and xlaffi.has_passthrough()):
-        reason = ("native core lacks bf_xla_win_put_pass"
-                  if native.available() else "native core unavailable")
-        return None, {"skipped": reason}, []
-
-    failures = []
-    detail = {"smoke": smoke}
-    steps = 10 if smoke else 50
-
-    # -- leg 1: engagement + trajectory equivalence (gated loopback) --------
-    telemetry.reset()
-    _, pe, _ = _fused_rig(False, 2, 48, 2, steps, synced=True)
-    _, pf, fsteps = _fused_rig(True, 2, 48, 2, steps, synced=True)
-    snap = telemetry.snapshot()
-    max_diff = max(float(np.abs(pe[k] - pf[k]).max()) for k in pe)
-    bitwise = all(np.array_equal(pe[k], pf[k]) for k in pe)
-    if fsteps != steps:
-        failures.append(f"only {fsteps}/{steps} steps took the fused "
-                        "path on the engagement leg")
-    if snap.get("bf_fused_step_active") != 1.0:
-        failures.append("bf_fused_step_active != 1 after a fused run")
-    if not snap.get("bf_fused_step_puts_total"):
-        failures.append("no in-program puts counted "
-                        "(bf_fused_step_puts_total)")
-    if max_diff > 1e-6:
-        failures.append(f"fused-vs-eager trajectory diverged: max |d| = "
-                        f"{max_diff} > 1e-6 over {steps} steps")
-    detail["trajectory"] = {
-        "steps": steps, "max_abs_diff": max_diff, "bitwise": bitwise,
-        "puts_total": snap.get("bf_fused_step_puts_total", 0.0),
-    }
-
-    # -- leg 2: BLUEFOG_TPU_FUSED_STEP=0 is inert and bitwise eager ---------
-    os.environ["BLUEFOG_TPU_FUSED_STEP"] = "0"
-    _config.reload()
-    telemetry.reset()
-    try:
-        _, p0, _ = _fused_rig(None, 2, 48, 2, steps, synced=True)
-    finally:
-        os.environ.pop("BLUEFOG_TPU_FUSED_STEP", None)
-        _config.reload()
-    snap0 = telemetry.snapshot()
-    off_bitwise = all(np.array_equal(pe[k], p0[k]) for k in pe)
-    if not off_bitwise:
-        failures.append("FUSED_STEP=0 leg is not bitwise identical to "
-                        "the eager oracle")
-    leaked = [k for k in snap0 if k.startswith("bf_fused_step")]
-    if leaked:
-        failures.append(f"FUSED_STEP=0 leg registered {leaked[:3]}")
-    detail["env_off"] = {"bitwise": off_bitwise, "inert": not leaked}
-
-    # -- leg 3: graceful fallback when the XLA put path is disarmed ---------
-    # Same loopback rig (distrib installed — the eligibility check only
-    # applies to a live wire), XLA put path pinned off: the fused=True
-    # optimizer must warn ONCE, keep stepping eager, report inactive,
-    # and land the SAME trajectory.
-    os.environ["BLUEFOG_TPU_WIN_XLA"] = "0"
-    _config.reload()
-    xlaffi._reset_for_tests()
-    telemetry.reset()
-    warns = []
-    logger = bflog.get_logger()
-    orig_warning = logger.warning
-    logger.warning = lambda msg, *a, **kw: (
-        warns.append(msg % a if a else msg), orig_warning(msg, *a, **kw))
-    try:
-        _, pfb, fb_steps = _fused_rig(True, 2, 48, 2, steps, synced=True,
-                                      armed=False)
-    finally:
-        logger.warning = orig_warning
-        os.environ["BLUEFOG_TPU_WIN_XLA"] = "1"
-        _config.reload()
-        xlaffi._reset_for_tests()
-    fb_warns = [m for m in warns if "falling back to the eager path" in m]
-    if len(fb_warns) != 1:
-        failures.append(f"fallback leg warned {len(fb_warns)} times "
-                        "(want exactly 1)")
-    if fb_steps != 0:
-        failures.append(f"fallback leg still took {fb_steps} fused steps "
-                        "with the XLA put path disarmed")
-    if telemetry.snapshot().get("bf_fused_step_active") != 0.0:
-        failures.append("fallback leg did not report "
-                        "bf_fused_step_active = 0")
-    fb_bitwise = all(np.array_equal(pe[k], pfb[k]) for k in pe)
-    if not fb_bitwise:
-        failures.append("fallback leg's eager trajectory is not bitwise "
-                        "identical to the eager oracle")
-    detail["fallback"] = {"warnings": len(fb_warns),
-                          "bitwise_eager": fb_bitwise}
-
-    # -- timing cell (full runs only: shared CI boxes jitter) ---------------
-    speedup = None
-    if not smoke:
-        cell = _fused_timing_cell()
-        detail["timing"] = cell
-        speedup = cell["speedup"]
-        if speedup < 1.5:
-            failures.append(f"fused end-to-end step speedup {speedup}x "
-                            "< 1.5x on the transport rig")
-    return speedup, detail, failures
-
-
-def fused_main(args) -> int:
-    """`make fused-smoke` / `--fused`: the whole-step compilation gate.
-
-    Smoke: structural only — fused engagement (every step through the
-    single XLA program, in-program puts counted), trajectory equivalence
-    vs eager, FUSED_STEP=0 bitwise inertness, graceful one-warning
-    fallback without the native XLA handler.  Full adds the eager-vs-
-    fused timing cell and asserts the >= 1.5x end-to-end win."""
-    import sys
-
-    smoke = bool(args.fused_smoke and not args.fused)
-    prev = _fused_env_setup()
-    try:
-        value, detail, failures = _fused_report(smoke)
-    finally:
-        _fused_env_restore(prev)
-    rc = 0
-    for f in failures:
-        print(f"bench_comm --fused: {f}", file=sys.stderr)
-        rc = 1
-    print(json.dumps({
-        "metric": "fused_step_speedup",
-        "value": value,
-        "unit": "x",
-        "detail": detail,
-    }))
-    return rc
-
-
-def probe_main(args) -> int:
-    """`make probe-smoke`: the in-program probe CI gate.
-
-    One fused loopback run (probes on by default) must land every probe
-    surface: the measured ``bf_fused_overlap_ratio`` gauge in (0, 1],
-    per-bucket ``bf_fused_bucket_issue_seconds`` histograms,
-    ``bf_probe_events_total``, a finite measured-vs-modeled divergence
-    ratio, and — with a timeline armed — trace-merge output that is
-    valid JSON carrying the ``fused-probe`` lanes.  Structural only (no
-    timing assertion); graceful skip when the native core predates
-    ``bf_xla_probe``."""
-    import sys
-    import tempfile
-
-    prev = _fused_env_setup()
-    prev["BLUEFOG_TPU_PYTHON_TIMELINE"] = os.environ.get(
-        "BLUEFOG_TPU_PYTHON_TIMELINE")
-    # Probe lanes need the args-capable Python writer for lane naming,
-    # and the in-band clock anchor keeps trace-merge alignment exact.
-    os.environ["BLUEFOG_TPU_PYTHON_TIMELINE"] = "1"
-    try:
-        from bluefog_tpu import native, tools
-        from bluefog_tpu.ops import xlaffi
-        from bluefog_tpu.utils import config as _config
-        from bluefog_tpu.utils import probes, telemetry, timeline
-        _config.reload()
-        xlaffi._reset_for_tests()
-        if not (native.available() and native.has_win_xla()
-                and native.has_xla_handler() and xlaffi.has_passthrough()
-                and native.has_probe()):
-            reason = ("native core lacks bf_xla_probe"
-                      if native.available() else "native core unavailable")
-            print(json.dumps({
-                "metric": "probe_overlap_measured",
-                "value": None, "unit": "ratio", "status": "no_probe",
-                "detail": {"reason": reason}}))
-            return 0
-
-        failures = []
-        buckets, steps = 2, 8
-        tmpdir = tempfile.mkdtemp(prefix="bf-probe-smoke-")
-        prefix = os.path.join(tmpdir, "tl_")
-        telemetry.reset()
-        probes._reset_for_tests()
-        timeline.start_timeline(f"{prefix}0.json")
-        try:
-            _, _, fsteps = _fused_rig(True, 4, 64, buckets, steps)
-        finally:
-            timeline.stop_timeline()
-
-        if fsteps != steps:
-            failures.append(f"only {fsteps}/{steps} steps took the "
-                            "fused path")
-        snap = telemetry.snapshot()
-        ratio = snap.get("bf_fused_overlap_ratio")
-        if ratio is None or not (0.0 < ratio <= 1.0):
-            failures.append(f"bf_fused_overlap_ratio {ratio!r} not in "
-                            "(0, 1]")
-        if not snap.get("bf_probe_events_total"):
-            failures.append("bf_probe_events_total missing or zero")
-        issue_counts = [k for k in snap
-                        if k.startswith("bf_fused_bucket_issue_seconds"
-                                        "_count")]
-        if len(issue_counts) < buckets:
-            failures.append("per-bucket issue histograms missing: "
-                            f"{issue_counts}")
-        div = snap.get("bf_fused_overlap_divergence_ratio")
-        if div is None or not (div > 0):
-            failures.append(f"divergence ratio {div!r} not finite/positive")
-
-        summary = probes.last_summary()
-        if summary is None:
-            failures.append("probes.last_summary() is None after a "
-                            "fused run")
-
-        merged = tools.trace_merge(prefix)
-        try:
-            with open(merged) as f:
-                events = json.load(f)  # must be VALID json
-        except ValueError as e:
-            events, failures = [], failures + [f"trace-merge output is "
-                                               f"not valid JSON: {e}"]
-        lanes = {e.get("tid") for e in events
-                 if e.get("cat") == "fused-probe"}
-        if not lanes:
-            failures.append("no fused-probe lanes in the merged trace")
-
-        rc = 0
-        for f in failures:
-            print(f"bench_comm --probe-smoke: {f}", file=sys.stderr)
-            rc = 1
-        print(json.dumps({
-            "metric": "probe_overlap_measured",
-            "value": ratio,
-            "unit": "ratio",
-            "detail": {
-                "fused_steps": fsteps,
-                "overlap": _probe_overlap_cell(buckets, steps),
-                "probe_events": snap.get("bf_probe_events_total"),
-                "divergence": div,
-                "probe_lanes": sorted(int(t) for t in lanes
-                                      if t is not None),
-                "merged_events": len(events),
-            },
-        }))
-        return rc
-    finally:
-        _fused_env_restore(prev)
 
 
 def tracerec_main(args) -> int:
@@ -2792,10 +2249,6 @@ def main():
     args = _parse_args()
     if args.ffi or args.ffi_smoke:
         return ffi_main(args)
-    if args.fused or args.fused_smoke:
-        return fused_main(args)
-    if args.probe_smoke:
-        return probe_main(args)
     if args.async_smoke:
         return async_main(args)
     if args.tracerec_smoke:

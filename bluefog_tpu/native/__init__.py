@@ -89,15 +89,6 @@ class RecEvent(ctypes.Structure):
     ]
 
 
-class ProbeEvent(ctypes.Structure):
-    """Mirror of ``bf_probe_event_t`` (one in-program probe ring slot)."""
-    _fields_ = [
-        ("t_ns", ctypes.c_int64),
-        ("probe_id", ctypes.c_int32),
-        ("seq", ctypes.c_uint32),
-    ]
-
-
 class WinRxStats(ctypes.Structure):
     """Mirror of ``bf_winrx_stats_t`` (cumulative native-drain counters)."""
     _fields_ = [
@@ -286,26 +277,6 @@ def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
         lib.bf_xla_has_handler.argtypes = []
     except AttributeError:
         pass
-    # In-program probe ring (xlacall.cc, this PR's symbols) — own try so
-    # an older .so missing them degrades to the Python stamp fallback
-    # (has_probe() reports the capability).
-    try:
-        lib.bf_probe_enable.restype = i64
-        lib.bf_probe_enable.argtypes = [i64]
-        lib.bf_probe_is_enabled.restype = i32
-        lib.bf_probe_is_enabled.argtypes = []
-        lib.bf_probe_note.restype = None
-        lib.bf_probe_note.argtypes = [i32]
-        lib.bf_probe_total.restype = i64
-        lib.bf_probe_total.argtypes = []
-        lib.bf_probe_drain.restype = i64
-        lib.bf_probe_drain.argtypes = [ptr(ProbeEvent), i64]
-        lib.bf_probe_reset.restype = None
-        lib.bf_probe_reset.argtypes = []
-        lib.bf_xla_has_probe.restype = i32
-        lib.bf_xla_has_probe.argtypes = []
-    except AttributeError:
-        pass
     return lib
 
 
@@ -458,19 +429,6 @@ def has_xla_handler() -> bool:
     handle = lib()
     return (has_win_xla() and hasattr(handle, "bf_xla_has_handler")
             and bool(handle.bf_xla_has_handler()))
-
-
-def has_probe() -> bool:
-    """True when the build carries the in-program probe surface: the
-    ``bf_probe_*`` ring AND the ``bf_xla_probe`` FFI handler (compiled
-    against the jaxlib FFI headers, like :func:`has_xla_handler`), and is
-    not stale.  False means ``utils/probes.py`` stays on its Python
-    stamp fallback."""
-    handle = lib()
-    return (handle is not None and not _stale
-            and hasattr(handle, "bf_probe_drain")
-            and hasattr(handle, "bf_xla_has_probe")
-            and bool(handle.bf_xla_has_probe()))
 
 
 _FASTCALL_ABI = 2

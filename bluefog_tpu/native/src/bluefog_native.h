@@ -439,49 +439,6 @@ void bf_rec_note(int32_t etype, int32_t op, int32_t stripe, int32_t src,
 int64_t bf_rec_snapshot(bf_rec_event_t* out, int64_t cap);
 void bf_rec_reset(void);
 
-/* -------- xlacall.cc: in-program probes (BLUEFOG_TPU_PROBE) --------
- *
- * Timestamp instrumentation that lives INSIDE a compiled XLA program: the
- * `bf_xla_probe` FFI handler (passthrough, like bf_xla_win_put_pass) is
- * threaded through the fused step program at its semantic seams, and each
- * execution records one (probe_id, steady-clock ns, claim counter) event
- * into a process-wide lock-free ring — the flight-recorder design
- * (bf_rec_*) with a 16-byte event and a drain cursor.  Recording is one
- * relaxed fetch_add + a 16-byte store (~ns, no GIL, no allocation); when
- * the ring is not armed every site is a single atomic pointer load, so
- * BLUEFOG_TPU_PROBE=0 (which also compiles no probe ops) is bitwise
- * inert.  The clock is CLOCK_MONOTONIC, the same epoch as Python's
- * time.monotonic_ns() and the timeline writer's event clock, so ring
- * events align with host timestamps and the chrome timeline with no
- * extra anchor. */
-
-typedef struct {
-  int64_t t_ns;     /* std::chrono::steady_clock (CLOCK_MONOTONIC) ns */
-  int32_t probe_id; /* caller-defined seam id (utils/probes.py names them) */
-  uint32_t seq;     /* low 32 bits of the global claim counter (wraps) */
-} bf_probe_event_t;
-
-/* Allocate + arm the ring (idempotent; capacity <= 0 = 8192).  Returns
- * the live capacity. */
-int64_t bf_probe_enable(int64_t capacity);
-int32_t bf_probe_is_enabled(void);
-/* Record one probe event (the FFI handler calls this from inside the
- * program; Python calls it over ctypes for the host-side seams). */
-void bf_probe_note(int32_t probe_id);
-/* Total events ever recorded (monotonic; drain loss = total - drained). */
-int64_t bf_probe_total(void);
-/* Copy the events recorded since the last drain into out (oldest-first,
- * at most cap; events overwritten before the drain are lost — ring
- * semantics) and advance the cursor.  Returns the count copied, 0 when
- * nothing new, -1 when the ring is off. */
-int64_t bf_probe_drain(bf_probe_event_t* out, int64_t cap);
-void bf_probe_reset(void);
-
-/* 1 when this build carries the `bf_xla_probe` XLA FFI handler, else 0
- * (FFI headers absent at compile time — same gate as
- * bf_xla_has_handler). */
-int32_t bf_xla_has_probe(void);
-
 #ifdef __cplusplus
 }
 #endif

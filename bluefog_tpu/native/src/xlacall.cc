@@ -49,7 +49,6 @@
 
 #include <algorithm>
 #include <atomic>
-#include <chrono>
 #include <map>
 #include <memory>
 #include <mutex>
@@ -337,105 +336,6 @@ void bf_xla_drop_residuals(const char* name) {
 }  // extern "C"
 
 // ---------------------------------------------------------------------------
-// In-program probe ring (BLUEFOG_TPU_PROBE) — the flight-recorder design
-// (winsvc.cc RecRing) with a 16-byte event and a drain cursor.  The ring
-// is process-wide and lock-free on the record path: arming swaps an atomic
-// pointer under a mutex, recording is an acquire pointer load + a relaxed
-// fetch_add slot claim + a 16-byte store.  Off state = one pointer load,
-// zero mutation (the BLUEFOG_TPU_PROBE=0 inertness contract).  Only the
-// drain takes the mutex (once per training step, from Python).
-// ---------------------------------------------------------------------------
-
-namespace {
-
-struct ProbeRing {
-  std::vector<bf_probe_event_t> ev;
-  std::atomic<uint64_t> idx{0};
-};
-
-std::atomic<ProbeRing*> g_probe{nullptr};
-std::mutex g_probe_m;  // serializes enable/reset/drain, never the record
-uint64_t g_probe_read = 0;  // drain cursor (total events already drained)
-
-inline int64_t SteadyNs() {
-  return std::chrono::duration_cast<std::chrono::nanoseconds>(
-             std::chrono::steady_clock::now().time_since_epoch())
-      .count();
-}
-
-}  // namespace
-
-extern "C" {
-
-int64_t bf_probe_enable(int64_t capacity) {
-  std::lock_guard<std::mutex> lk(g_probe_m);
-  ProbeRing* r = g_probe.load(std::memory_order_acquire);
-  if (r != nullptr) return (int64_t)r->ev.size();
-  if (capacity <= 0) capacity = 8192;
-  auto* ring = new ProbeRing();
-  ring->ev.assign((size_t)capacity, bf_probe_event_t{0, 0, 0});
-  g_probe_read = 0;
-  g_probe.store(ring, std::memory_order_release);
-  return capacity;
-}
-
-int32_t bf_probe_is_enabled(void) {
-  return g_probe.load(std::memory_order_acquire) != nullptr;
-}
-
-void bf_probe_note(int32_t probe_id) {
-  ProbeRing* r = g_probe.load(std::memory_order_acquire);
-  if (r == nullptr) return;
-  uint64_t i = r->idx.fetch_add(1, std::memory_order_relaxed);
-  bf_probe_event_t& e = r->ev[(size_t)(i % r->ev.size())];
-  e.t_ns = SteadyNs();
-  e.probe_id = probe_id;
-  e.seq = (uint32_t)i;
-}
-
-int64_t bf_probe_total(void) {
-  ProbeRing* r = g_probe.load(std::memory_order_acquire);
-  return r == nullptr ? 0 : (int64_t)r->idx.load(std::memory_order_relaxed);
-}
-
-int64_t bf_probe_drain(bf_probe_event_t* out, int64_t cap) {
-  std::lock_guard<std::mutex> lk(g_probe_m);
-  ProbeRing* r = g_probe.load(std::memory_order_acquire);
-  if (r == nullptr) return -1;
-  uint64_t total = r->idx.load(std::memory_order_acquire);
-  uint64_t size = (uint64_t)r->ev.size();
-  uint64_t first = g_probe_read;
-  if (total - first > size) first = total - size;  // overwritten: lost
-  uint64_t n = total - first;
-  if (out != nullptr && (int64_t)n > cap) {
-    first = total - (uint64_t)cap;  // keep the newest cap events
-    n = (uint64_t)cap;
-  }
-  if (out != nullptr) {
-    for (uint64_t k = 0; k < n; ++k)
-      out[k] = r->ev[(size_t)((first + k) % size)];
-  }
-  g_probe_read = total;
-  return (int64_t)n;
-}
-
-void bf_probe_reset(void) {
-  std::lock_guard<std::mutex> lk(g_probe_m);
-  ProbeRing* r = g_probe.load(std::memory_order_acquire);
-  if (r == nullptr) return;
-  // Disarm first so no recorder claims a slot mid-clear, then re-arm the
-  // same storage (the ring stays allocated for the process lifetime —
-  // same leak-by-design as the flight recorder's reset).
-  g_probe.store(nullptr, std::memory_order_release);
-  r->idx.store(0, std::memory_order_release);
-  std::fill(r->ev.begin(), r->ev.end(), bf_probe_event_t{0, 0, 0});
-  g_probe_read = 0;
-  g_probe.store(r, std::memory_order_release);
-}
-
-}  // extern "C"
-
-// ---------------------------------------------------------------------------
 // XLA FFI handler (compiled only when the jaxlib FFI headers are present)
 // ---------------------------------------------------------------------------
 
@@ -476,77 +376,10 @@ XLA_FFI_DEFINE_HANDLER_SYMBOL(bf_xla_win_put, BfXlaWinPutImpl,
                                   .Attr<int64_t>("plan_id")
                                   .Attr<int64_t>("tx"));
 
-// Donated-buffer passthrough variant: same plan executor, but the input
-// buffer flows THROUGH the call as the first output (the Python side
-// declares input_output_aliases={0: 0}, so XLA donates the buffer and
-// x_out IS x — no copy).  Downstream program stages consume x_out, which
-// makes the put a real data dependence inside the fused step program:
-// XLA cannot sink it past the consumers, and each bucket's put issues
-// exactly when that bucket's bytes are materialized.
-static bffi::Error BfXlaWinPutPassImpl(bffi::AnyBuffer x,
-                                       bffi::Result<bffi::AnyBuffer> x_out,
-                                       bffi::Result<bffi::AnyBuffer> status,
-                                       int64_t plan_id, int64_t tx) {
-  if (status->element_count() < 1)
-    return bffi::Error(bffi::ErrorCode::kInvalidArgument,
-                       "bf_xla_win_put_pass needs an i32[1] status output");
-  auto* out = reinterpret_cast<int32_t*>(status->untyped_data());
-  if (x.element_type() != bffi::DataType::F32) {
-    out[0] = -12;  // non-f32 buffer: the Python side falls back
-    return bffi::Error::Success();
-  }
-  out[0] = PlanRun(plan_id, (const void*)(uintptr_t)tx,
-                   reinterpret_cast<const float*>(x.untyped_data()),
-                   (uint64_t)x.element_count());
-  // Defensive: honor the passthrough contract even if the runtime did
-  // not alias (donation can be declined when the buffer is still live).
-  if (x_out->untyped_data() != x.untyped_data())
-    std::memcpy(x_out->untyped_data(), x.untyped_data(),
-                x.element_count() * sizeof(float));
-  return bffi::Error::Success();
-}
-
-XLA_FFI_DEFINE_HANDLER_SYMBOL(bf_xla_win_put_pass, BfXlaWinPutPassImpl,
-                              bffi::Ffi::Bind()
-                                  .Arg<bffi::AnyBuffer>()
-                                  .Ret<bffi::AnyBuffer>()
-                                  .Ret<bffi::AnyBuffer>()
-                                  .Attr<int64_t>("plan_id")
-                                  .Attr<int64_t>("tx"));
-
-// In-program probe: the passthrough trick again, minus the plan executor.
-// The input buffer flows through as the output (input_output_aliases=
-// {0: 0} on the Python side, so XLA donates and no bytes move) and the
-// handler's only work is one bf_probe_note — a timestamped marker pinned
-// into the program's dataflow.  Because downstream stages consume x_out,
-// XLA can neither sink the probe past the work that produced x nor hoist
-// the consumers above it: the recorded instant genuinely separates the
-// program phases it sits between.  Element type is irrelevant (the bytes
-// are never read), so any dtype threads through.
-static bffi::Error BfXlaProbeImpl(bffi::AnyBuffer x,
-                                  bffi::Result<bffi::AnyBuffer> x_out,
-                                  int64_t probe_id) {
-  bf_probe_note((int32_t)probe_id);
-  // Defensive: honor the passthrough contract even if donation was
-  // declined (the buffer is still live elsewhere in the program).
-  if (x_out->untyped_data() != x.untyped_data())
-    std::memcpy(x_out->untyped_data(), x.untyped_data(),
-                x.size_bytes());
-  return bffi::Error::Success();
-}
-
-XLA_FFI_DEFINE_HANDLER_SYMBOL(bf_xla_probe, BfXlaProbeImpl,
-                              bffi::Ffi::Bind()
-                                  .Arg<bffi::AnyBuffer>()
-                                  .Ret<bffi::AnyBuffer>()
-                                  .Attr<int64_t>("probe_id"));
-
 extern "C" int32_t bf_xla_has_handler(void) { return 1; }
-extern "C" int32_t bf_xla_has_probe(void) { return 1; }
 
 #else  // !BF_HAVE_XLA_FFI
 
 extern "C" int32_t bf_xla_has_handler(void) { return 0; }
-extern "C" int32_t bf_xla_has_probe(void) { return 0; }
 
 #endif  // BF_HAVE_XLA_FFI

@@ -124,7 +124,7 @@ class ShardPlan:
 
     @cached_property
     def signature(self) -> Tuple:
-        """Hashable token for schedule caches and fused-program keys."""
+        """Hashable token for schedule and step-program caches."""
         return (self.n, self.n_shards, self.groups, self.mask, self.dims)
 
     def summary(self) -> Dict[str, object]:
@@ -407,7 +407,7 @@ def induced_window_weights(plan: ShardPlan, topo: nx.DiGraph):
 
 
 # ---------------------------------------------------------------------------
-# Host-side slice helpers (window payloads / fused-step host put)
+# Host-side slice helpers (window payloads)
 # ---------------------------------------------------------------------------
 
 def own_shard_rows(leaf: np.ndarray, dim: int, coords: Sequence[int],

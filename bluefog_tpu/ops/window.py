@@ -2079,51 +2079,6 @@ def _publish_self(win, tensor, self_weight) -> None:
                 win.p_main[r] *= sw_vec[r]
 
 
-def _fused_host_finish(name: str, payload, edges: Dict[tuple, float], *,
-                       accumulate: bool, self_weight=None,
-                       require_mutex: bool = False, remote_procs=None,
-                       since=None, flush: bool = True) -> None:
-    """Host half of one fused-program put (``ops/fused_step.py``).
-
-    The fused step program runs the REMOTE plan dispatch inside XLA
-    (``bf_xla_win_put_pass``); everything ``_do_put`` performs around
-    that dispatch still needs the host — the local-edge staging writes,
-    the scoped transport flush (the op boundary: every remote edge
-    enqueued by the program reaches TCP before the step reports its put
-    complete) and the post-send self-publish — in exactly the eager
-    order, so the window state a fused step leaves behind is the state
-    the eager oracle would have left.
-
-    ``flush=False`` skips the per-window flush so a multi-bucket caller
-    can issue ONE scoped flush after every bucket's finish (the flush is
-    a wire boundary, not a state mutation — final window state is
-    unchanged, only the sends-in-flight point moves)."""
-    from bluefog_tpu.utils.timeline import op_span
-    try:
-        win = _store.get(name)
-    except KeyError:
-        return  # window freed after dispatch
-    op = OP_ACCUMULATE if accumulate else OP_PUT
-    kind = "win_accumulate" if accumulate else "win_put"
-    host_t = None
-    local = [((src, dst), w) for (src, dst), w in edges.items()
-             if _owns(src) and _owns(dst)]
-    if local:
-        host_t = payload if isinstance(payload, np.ndarray) \
-            else xlaffi.host_view(payload)
-        for (src, dst), w in local:
-            with op_span(f"{kind}.{name}.{src}->{dst}", "COMMUNICATE"):
-                _do_put_edge(win, name, host_t, win.row_of[src], src, dst,
-                             w, op, accumulate, require_mutex)
-    if remote_procs and flush:
-        _flush_transport(remote_procs, since=since)
-    if self_weight is not None:
-        if host_t is None:
-            host_t = payload if isinstance(payload, np.ndarray) \
-                else xlaffi.host_view(payload)
-        _publish_self(win, host_t, self_weight)
-
-
 def win_put_nonblocking(tensor, name: str, *, self_weight=None,
                         dst_weights=None, require_mutex: bool = False) -> int:
     """Scaled overwrite of each destination's buffer-for-me (async).
@@ -2306,8 +2261,7 @@ def _default_update_weights(win: _Window):
 
 
 def win_update(name: str, *, self_weight=None, neighbor_weights=None,
-               reset_weights: bool = False, require_mutex: bool = False,
-               commit: bool = True):
+               reset_weights: bool = False, require_mutex: bool = False):
     """Combine self memory with in-neighbor staging buffers, in place.
 
     ``out_i = sw_i * main_i + sum_src w[dst=i,src] * staging[i,src]``; writes
@@ -2490,18 +2444,14 @@ def win_update(name: str, *, self_weight=None, neighbor_weights=None,
             # re-enters jax as a zero-copy view where the runtime allows
             # (CPU backend aliases; else dlpack) instead of a host→device
             # re-upload — a verified copy counts into
-            # bf_win_host_copy_bytes_total{path="commit"}.  ``commit=False``
-            # hands back the raw host array for callers already running on
-            # the host side of an ``io_callback`` (the fused drain), where
-            # a jax re-entry would be immediately unwrapped again.
-            return xlaffi.commit_to_jax(ret) if commit else ret
+            # bf_win_host_copy_bytes_total{path="commit"}.
+            return xlaffi.commit_to_jax(ret)
     finally:
         for m in acquired:
             m.release()
 
 
-def win_update_then_collect(name: str, *, require_mutex: bool = True,
-                            commit: bool = True):
+def win_update_then_collect(name: str, *, require_mutex: bool = True):
     """Sum self memory with all received contributions and zero the staging
     buffers — the push-sum collect step (``torch/mpi_ops.py:1206-1260``)."""
     win = _store.get(name)
@@ -2511,8 +2461,7 @@ def win_update_then_collect(name: str, *, require_mutex: bool = True,
     all_edges = {(dst, src): 1.0
                  for dst in win.owned for src in win.in_nbrs[dst]}
     return win_update(name, self_weight=1.0, neighbor_weights=all_edges,
-                      reset_weights=True, require_mutex=require_mutex,
-                      commit=commit)
+                      reset_weights=True, require_mutex=require_mutex)
 
 
 # ---------------------------------------------------------------------------

@@ -530,70 +530,8 @@ def _ensure_registered() -> bool:
         mod.register_ffi_target("bf_xla_win_put",
                                 mod.pycapsule(lib.bf_xla_win_put),
                                 platform="cpu")
-        # Donated-buffer passthrough variant (fused step programs);
-        # absent from prebuilt cores that predate it — the plain target
-        # still registers and the fused path degrades gracefully.
-        if hasattr(lib, "bf_xla_win_put_pass"):
-            mod.register_ffi_target(
-                "bf_xla_win_put_pass",
-                mod.pycapsule(lib.bf_xla_win_put_pass),
-                platform="cpu")
-        # In-program probe (BLUEFOG_TPU_PROBE): the timestamp custom call
-        # the fused step threads through its semantic seams.  Same
-        # degradation contract as the pass variant.
-        if hasattr(lib, "bf_xla_probe"):
-            mod.register_ffi_target("bf_xla_probe",
-                                    mod.pycapsule(lib.bf_xla_probe),
-                                    platform="cpu")
         _registered[0] = True
     return True
-
-
-def has_passthrough() -> bool:
-    """True when the donated-buffer passthrough FFI target is available
-    (native core carries ``bf_xla_win_put_pass`` and jax has an FFI
-    module)."""
-    if not _ensure_registered():
-        return False
-    try:
-        return hasattr(native.lib(), "bf_xla_win_put_pass")
-    except Exception:  # noqa: BLE001 — treat load failure as absent
-        return False
-
-
-def has_probe() -> bool:
-    """True when the in-program probe FFI target is registered (native
-    core carries ``bf_xla_probe`` + the ring symbols and jax has an FFI
-    module)."""
-    if not _ensure_registered():
-        return False
-    return native.has_probe()
-
-
-def xla_probe_program(probe_id: int):
-    """A timestamp probe lowered INTO a compiled program: returns
-    ``f(x) -> x`` where the output IS the input buffer
-    (``input_output_aliases={0: 0}`` — XLA donates it, no copy) and the
-    custom call records ``(probe_id, steady-clock ns, counter)`` into the
-    native probe ring as a side effect.  Because the caller rethreads its
-    value through the probe, the recorded instant is pinned into the
-    program's dataflow: XLA cannot hoist the probe above the work that
-    produced ``x`` or sink it below the stages that consume the output.
-    None when the probe handler is unavailable (the Python stamp fallback
-    still works)."""
-    if not has_probe():
-        return None
-    import jax
-    from jax import ffi as mod
-
-    def run(x):
-        call = mod.ffi_call(
-            "bf_xla_probe",
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
-            has_side_effect=True,
-            input_output_aliases={0: 0})
-        return call(x, probe_id=np.int64(probe_id))
-    return run
 
 
 def xla_put_program(plan_id: int, tx: int):
@@ -613,52 +551,6 @@ def xla_put_program(plan_id: int, tx: int):
 
     def run(x):
         return call(x, plan_id=np.int64(plan_id), tx=np.int64(tx))
-    return run
-
-
-def xla_put_program_pass(plan_id: int, tx: int):
-    """Donated-buffer passthrough form of :func:`xla_put_program`:
-    returns ``f(x) -> (x, i32[1] status)`` where the first output IS the
-    input buffer (``input_output_aliases={0: 0}`` — XLA donates it, no
-    copy).  Downstream stages consume the passthrough output, which makes
-    the put a real data dependence inside a fused step program: each
-    bucket's put issues exactly when XLA materializes that bucket, and
-    the program's remaining math keeps executing around it.  None when
-    the handler (or the pass variant of it) is unavailable."""
-    if not has_passthrough():
-        return None
-    import jax
-    import jax.numpy as jnp
-    from jax import ffi as mod
-
-    def run(x):
-        call = mod.ffi_call(
-            "bf_xla_win_put_pass",
-            (jax.ShapeDtypeStruct(x.shape, x.dtype),
-             jax.ShapeDtypeStruct((1,), jnp.int32)),
-            has_side_effect=True,
-            input_output_aliases={0: 0})
-        return call(x, plan_id=np.int64(plan_id), tx=np.int64(tx))
-    return run
-
-
-def drain_to_device(fn, result_avals, *, ordered: bool = True):
-    """Embed a host-side window drain INTO a compiled program: wraps
-    ``fn`` (a host callback performing ``win_update``/collect and
-    returning numpy/jax arrays matching ``result_avals``) as an ordered
-    ``io_callback`` so the drain can run mid-program, its results
-    re-entering the program as device buffers (on the CPU backend the
-    ``commit_to_jax`` views inside ``fn`` stay zero-copy end to end).
-    Returns a callable taking arbitrary token arguments (pass the put
-    statuses so the drain data-depends on the puts), or None when this
-    jax has no ``io_callback``."""
-    try:
-        from jax.experimental import io_callback
-    except Exception:  # noqa: BLE001 — older jax: host-side drain instead
-        return None
-
-    def run(*tokens):
-        return io_callback(fn, result_avals, *tokens, ordered=ordered)
     return run
 
 

@@ -75,7 +75,7 @@ import optax
 
 from bluefog_tpu import basics
 from bluefog_tpu.ops import window as W
-from bluefog_tpu.optim.functional import DistOptState, _leaf_bytes
+from bluefog_tpu.optim.functional import DistOptState
 
 __all__ = [
     "DistributedWinPutOptimizer",
@@ -89,34 +89,12 @@ def _leaf_names(tree, prefix: str):
     return [f"{prefix}.{jax.tree_util.keystr(p)}" for p, _ in paths]
 
 
-def _bucket_groups(leaves, k: int):
-    """Partition flatten-order leaf indices into at most ``k`` contiguous,
-    byte-balanced buckets (one window each).  Deterministic: every rank
-    must build identical windows."""
-    nbytes = [_leaf_bytes(l) for l in leaves]
-    total = sum(nbytes)
-    k = max(1, min(int(k), len(leaves)))
-    # Close bucket b once the running total crosses b/k of the bytes:
-    # balanced without look-ahead, never more than k buckets.
-    groups, cur, cum, b = [], [], 0, 1
-    for i, nb in enumerate(nbytes):
-        cur.append(i)
-        cum += nb
-        if cum * k >= b * total and b < k:
-            groups.append(cur)
-            cur, b = [], b + 1
-    if cur:
-        groups.append(cur)
-    return groups
-
-
 class _WindowOptimizerBase:
     """Shared plumbing: fused (or per-leaf) windows + vmapped local update."""
 
     def __init__(self, base: optax.GradientTransformation, *,
                  window_prefix: str, num_steps_per_communication: int = 1,
-                 fuse: bool = True, layout: str = "auto",
-                 fused=None, fusion_buckets=None):
+                 fuse: bool = True, layout: str = "auto"):
         if layout not in ("auto", "rank", "owned"):
             raise ValueError(
                 f"layout must be 'auto', 'rank' or 'owned', got {layout!r}")
@@ -124,33 +102,20 @@ class _WindowOptimizerBase:
         self.window_prefix = window_prefix
         self.num_steps_per_communication = int(num_steps_per_communication)
         self.fuse = bool(fuse)
-        # Whole-step compilation (ops/fused_step.py): fused=True forces
-        # the compiled step, False pins eager, None defers to
-        # BLUEFOG_TPU_FUSED_STEP.  Distinct from fuse= (window fusion):
-        # fuse= decides how many windows carry the tree, fused= decides
-        # whether (update x concat x put) lowers into one XLA program.
-        self.fused = fused
-        # fusion_buckets=k partitions the fused tree over k windows
-        # (contiguous, byte-balanced — _bucket_groups)
-        # so the fused program can issue one put per bucket as XLA
-        # materializes it.  None keeps today's single window.
-        self.fusion_buckets = fusion_buckets
         self.layout = layout
         self._layout = None   # resolved at init(): "rank" or "owned"
         self._names: List[str] = None
         self._update_fn = None
-        self._fused_impl = None  # lazily-built ops.fused_step.FusedStep
         self._n = 0
         self._rows = 0        # leading dim of caller trees (n or len(owned))
         self._owned: List[int] = []
         self._shapes = None   # per-leaf (rows, *rest) shapes, fused mode
         self._dtypes = None   # per-leaf dtypes (concatenate promotes; cast back)
-        self._splits = None   # np.cumsum of per-leaf flat sizes, fused mode
-        self._buckets = None        # per-window leaf-index lists, fused mode
-        self._bucket_splits = None  # per-window np.cumsum of leaf sizes
+        self._fused_idx = None  # leaf indices the fused window carries
+        self._splits = None   # np.cumsum of those leaves' flat sizes
         # Sharded-aware gossip (ops/sharded.py): subclasses that support
         # it set shard_specs/shard_groups/num_shards; init() resolves the
-        # plan.  With an active plan the fused buckets cover REPLICATED
+        # plan.  With an active plan the fused window covers REPLICATED
         # leaves only and one extra "<prefix>.sharded" window carries each
         # rank's own-shard slices, put/updated over in-group edges only.
         self.shard_specs = None
@@ -179,24 +144,21 @@ class _WindowOptimizerBase:
         instead of after a whole-tree host materialization.  Bitwise
         equivalent to the host path (same f32 rows, same wire frames);
         any other configuration takes the legacy numpy path."""
-        # Pre-init callers (probes, tests) see the single-bucket layout;
-        # init() installs the real partition before any window exists.
-        n_leaves = len(jax.tree_util.tree_leaves(tree))
-        buckets = (self._buckets if self._buckets is not None
-                   else [list(range(n_leaves))])
         if self._device_payloads_ok(tree):
             leaves = jax.tree_util.tree_leaves(tree)
             if not self.fuse:
                 return list(leaves)
             return [jnp.concatenate(
-                [jnp.reshape(leaves[i], (self._rows, -1)) for i in idxs],
-                axis=1) for idxs in buckets]
+                [jnp.reshape(x, (self._rows, -1)) for x in leaves], axis=1)]
         leaves = [np.asarray(x) for x in jax.tree_util.tree_leaves(tree)]
         if not self.fuse:
             return leaves
+        # Pre-init callers (tests) see every leaf in the one window.
+        idxs = (self._fused_idx if self._fused_idx is not None
+                else range(len(leaves)))
         out = [np.concatenate(
-            [leaves[i].reshape(self._rows, -1) for i in idxs], axis=1)
-            for idxs in buckets]
+            [leaves[i].reshape(self._rows, -1) for i in idxs],
+            axis=1)] if idxs else []
         if self._shard_plan is not None:
             out.append(self._shard_payload(leaves))
         return out
@@ -241,14 +203,13 @@ class _WindowOptimizerBase:
         treedef = jax.tree_util.tree_structure(like)
         if self.fuse:
             leaves = [None] * len(self._shapes)
-            for arr, idxs, splits in zip(arrays, self._buckets,
-                                         self._bucket_splits):
-                flat = np.asarray(arr)
-                parts = np.split(flat, splits[:-1], axis=1)
+            if self._fused_idx:
+                parts = np.split(np.asarray(arrays[0]), self._splits[:-1],
+                                 axis=1)
                 # Cast back to each leaf's own dtype: the fused
                 # concatenate promoted mixed-precision trees to a common
                 # wire dtype.
-                for p, i in zip(parts, idxs):
+                for p, i in zip(parts, self._fused_idx):
                     leaves[i] = p.reshape(self._shapes[i]).astype(
                         self._dtypes[i])
             if self._shard_plan is not None:
@@ -365,24 +326,11 @@ class _WindowOptimizerBase:
             self._shapes = [x.shape for x in leaves]
             self._dtypes = [x.dtype for x in leaves]
             sizes = [int(np.prod(s[1:])) for s in self._shapes]
-            self._splits = np.cumsum(sizes)
-            rep_idx = (list(range(len(leaves))) if plan is None else
-                       [i for i, m in enumerate(plan.mask) if not m])
-            if self.fusion_buckets is not None \
-                    and int(self.fusion_buckets) > 1 and rep_idx:
-                rel = _bucket_groups([leaves[i] for i in rep_idx],
-                                     int(self.fusion_buckets))
-                self._buckets = [[rep_idx[j] for j in grp] for grp in rel]
-            else:
-                self._buckets = [rep_idx] if rep_idx else []
-            self._bucket_splits = [
-                np.cumsum([sizes[i] for i in idxs])
-                for idxs in self._buckets]
-            if len(self._buckets) == 1:
-                self._names = [f"{self.window_prefix}.fused"]
-            else:
-                self._names = [f"{self.window_prefix}.fusedb{i}"
-                               for i in range(len(self._buckets))]
+            self._fused_idx = (list(range(len(leaves))) if plan is None else
+                               [i for i, m in enumerate(plan.mask) if not m])
+            self._splits = np.cumsum([sizes[i] for i in self._fused_idx])
+            self._names = ([f"{self.window_prefix}.fused"]
+                           if self._fused_idx else [])
             if plan is not None:
                 self._sharded_name = f"{self.window_prefix}.sharded"
                 self._names.append(self._sharded_name)
@@ -453,37 +401,6 @@ class _WindowOptimizerBase:
         updates, base_state = self._update_fn(grads, state.base, params)
         new_params = jax.tree.map(lambda p, u: p + u, params, updates)
         return new_params, base_state
-
-    # -- whole-step compilation (ops/fused_step.py) ------------------------
-    def _fused_wanted(self) -> bool:
-        """Does this step even attempt the fused lowering?  One config
-        check when the constructor deferred (``fused=None``) — with
-        ``BLUEFOG_TPU_FUSED_STEP=0`` nothing fused is ever imported,
-        built or registered (the inertness contract)."""
-        if self.fused is False:
-            return False
-        if self.fused is True:
-            return True
-        from bluefog_tpu.utils import config
-        return bool(config.get().fused_step)
-
-    def _fused_try_step(self, params, grads, state: DistOptState, *,
-                        family: str, dst_weights=None, self_weight=None,
-                        require_mutex: bool = False, pre_drain=None):
-        """Run one step through the compiled fused program, or return
-        None (after one logged warning per reason) when this
-        configuration cannot take the fused path — the caller then runs
-        the eager step, which stays the bitwise oracle."""
-        from bluefog_tpu.ops import fused_step as fused_mod
-        if self._fused_impl is None:
-            self._fused_impl = fused_mod.FusedStep(self)
-        try:
-            return self._fused_impl.step(
-                params, grads, state, family=family,
-                dst_weights=dst_weights, self_weight=self_weight,
-                require_mutex=require_mutex, pre_drain=pre_drain)
-        except fused_mod.FusedFallback:
-            return None
 
     # Latest committed membership change observed by _maybe_churn_step
     # (None until the gang churns); `evicted` mirrors the supervisor's
@@ -709,12 +626,10 @@ class DistributedWinPutOptimizer(_WindowOptimizerBase):
     def __init__(self, base, *, window_prefix: str = "winput",
                  num_steps_per_communication: int = 1, fuse: bool = True,
                  overlap: bool = False, layout: str = "auto",
-                 fused=None, fusion_buckets=None,
                  shard_specs=None, shard_groups=None, num_shards=None):
         super().__init__(base, window_prefix=window_prefix,
                          num_steps_per_communication=num_steps_per_communication,
-                         fuse=fuse, layout=layout, fused=fused,
-                         fusion_buckets=fusion_buckets)
+                         fuse=fuse, layout=layout)
         self.overlap = bool(overlap)
         # Sharded-aware gossip (ops/sharded.py, same contract as the
         # collective family's DistributedOptimizer kwargs): sharded
@@ -732,13 +647,6 @@ class DistributedWinPutOptimizer(_WindowOptimizerBase):
         self._async_step_begin(int(state.step))
         t = int(state.step)
         comm = (t + 1) % self.num_steps_per_communication == 0
-        if comm and self._fused_wanted():
-            out = self._fused_try_step(params, grads, state, family="put",
-                                       dst_weights=dst_weights,
-                                       require_mutex=require_mutex)
-            if out is not None:
-                self._record_step_time(t0, t)
-                return out
         new_params, base_state = self._local_adapt(params, grads, state)
         if comm:
             # Ordering: the previous overlapped put must complete before a
@@ -863,12 +771,10 @@ class DistributedPushSumOptimizer(_WindowOptimizerBase):
 
     def __init__(self, base, *, window_prefix: str = "pushsum",
                  num_steps_per_communication: int = 1, fuse: bool = True,
-                 layout: str = "auto", auto_collect_rounds: int = 8,
-                 fused=None, fusion_buckets=None):
+                 layout: str = "auto", auto_collect_rounds: int = 8):
         super().__init__(base, window_prefix=window_prefix,
                          num_steps_per_communication=num_steps_per_communication,
-                         fuse=fuse, layout=layout, fused=fused,
-                         fusion_buckets=fusion_buckets)
+                         fuse=fuse, layout=layout)
         self.auto_collect_rounds = int(auto_collect_rounds)
 
     def init(self, params) -> DistOptState:
@@ -904,28 +810,6 @@ class DistributedPushSumOptimizer(_WindowOptimizerBase):
             dst_weights = self._outgoing_weights()
         self_share = self._self_share()
         t = int(state.step)
-        if self._fused_wanted():
-            fence_due = (not self._async_on
-                         and self.auto_collect_rounds > 0
-                         and W._store.distrib is not None
-                         and (t + 1) % self.auto_collect_rounds == 0)
-            backstop_due = self._async_collect_due(t)
-
-            def _pre_drain():
-                if fence_due or backstop_due:
-                    W.win_fence()
-                    if backstop_due:
-                        for name in self._names:
-                            W.win_fold_stale_residuals(name)
-            out = self._fused_try_step(params, grads, state,
-                                       family="pushsum",
-                                       dst_weights=dst_weights,
-                                       self_weight=self_share,
-                                       require_mutex=require_mutex,
-                                       pre_drain=_pre_drain)
-            if out is not None:
-                self._record_step_time(t0, t)
-                return out
         new_params, base_state = self._local_adapt(params, grads, state)
         # Flow control, lockstep mode: every ``auto_collect_rounds``
         # communication rounds the step fences the transport before

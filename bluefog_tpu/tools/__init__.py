@@ -289,9 +289,7 @@ def schedule_dump(topology: str, n: int, torus: str, *, slices: int = 1,
                   budget: float = 2.0, optimize_placement: bool = False,
                   show_rounds: bool = False, hier: bool = False,
                   hier_outer_every: int = 1,
-                  hier_compression: str = "none",
-                  lowering: str = "ppermute", fusion_buckets: int = 4,
-                  payload_mb: float = 64.0, sharded: bool = False,
+                  hier_compression: str = "none", sharded: bool = False,
                   replicated_frac: float = 0.5,
                   num_shards: int = 4) -> str:
     """Text report of the schedule pipeline for one topology x torus.
@@ -345,11 +343,6 @@ def schedule_dump(topology: str, n: int, torus: str, *, slices: int = 1,
     stages = [("naive", naive), ("konig", konig), ("congestion", packed)]
     if chosen is not packed:
         stages.append((S.schedule_provenance(chosen), chosen))
-    if lowering == "fused":
-        # The fused-step consumer re-tags the dispatched artifact, same
-        # as ops/fused_step.compile_fused_schedule does before reading
-        # window_plan() back off it.
-        chosen = S.as_compiled(chosen, lowering="fused")
     lines = [
         f"schedule-dump: {topology} over {n} ranks on {model.name} "
         f"({slices} slice(s)), placement={placement_note}, "
@@ -373,21 +366,6 @@ def schedule_dump(topology: str, n: int, torus: str, *, slices: int = 1,
         f"synth improvement={ratio:.3f}x"
         + ("" if ratio > 1.0 else " (packed retained — tie or no win)"),
     ]
-    if lowering == "fused":
-        from bluefog_tpu.ops import fused_step as FS
-        total = int(payload_mb * (1 << 20))
-        k = max(1, int(fusion_buckets))
-        per = [total // k + (1 if i < total % k else 0) for i in range(k)]
-        lines += [
-            "",
-            f"fused lowering preview ({k} bucket(s) over "
-            f"{payload_mb:g} MB — whole-step compilation pipelines each "
-            "bucket's put against the remaining update compute):",
-            f"{'bucket':>6} {'bytes':>12} {'ready_at':>9} {'overlap':>8}",
-        ]
-        for r in FS.modeled_overlap(per):
-            lines.append(f"{r['bucket']:>6} {r['bytes']:>12} "
-                         f"{r['ready_at']:>9.2f} {r['overlap']:>8.2f}")
     if show_rounds:
         lines.append("")
         node = np.asarray(model.device_node, np.int64)
@@ -764,18 +742,6 @@ def main(argv=None) -> int:
     pd.add_argument("--hier-compression", default="none",
                     help="--hier: outer codec none / bf16 / sparse:<frac> "
                          "(default none)")
-    pd.add_argument("--lowering", default="ppermute",
-                    choices=["ppermute", "fused"],
-                    help="dispatch target to preview: 'fused' re-tags the "
-                         "chosen schedule for the whole-step compiler "
-                         "(BLUEFOG_TPU_FUSED_STEP) and appends the "
-                         "modeled per-bucket put/compute overlap table")
-    pd.add_argument("--fusion-buckets", type=int, default=4,
-                    help="--lowering fused: bucket count for the overlap "
-                         "preview (default 4)")
-    pd.add_argument("--payload-mb", type=float, default=64.0,
-                    help="--lowering fused: modeled per-step payload in "
-                         "MB split across the buckets (default 64)")
     pd.add_argument("--sharded", action="store_true",
                     help="append the sharding-aware gossip table "
                          "(BLUEFOG_TPU_SHARDED_GOSSIP): per-replica-"
@@ -797,8 +763,7 @@ def main(argv=None) -> int:
             show_rounds=args.rounds, hier=args.hier,
             hier_outer_every=args.hier_outer_every,
             hier_compression=args.hier_compression,
-            lowering=args.lowering, fusion_buckets=args.fusion_buckets,
-            payload_mb=args.payload_mb, sharded=args.sharded,
+            sharded=args.sharded,
             replicated_frac=args.replicated_frac,
             num_shards=args.num_shards))
         return 0

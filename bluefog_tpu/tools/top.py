@@ -6,9 +6,7 @@ served by ``utils/telemetry.start_http_server`` /
 ``BLUEFOG_TPU_TELEMETRY_PORT``) and renders, in one terminal frame,
 
   * per-rank health: status, step clock / async lag, deepest tx queue,
-    straggler score, measured fused-step overlap (``!``-flagged when
-    the measured-vs-modeled divergence crosses the link observatory's
-    x3 alert threshold), SLO breaches;
+    straggler score, SLO breaches;
   * the cluster link matrix: per-edge measured one-way delay, jitter and
     measured-vs-modeled divergence (the link observatory's
     ``bf_link_*`` gauges, MAX-merged across ranks exactly as the
@@ -124,7 +122,7 @@ def render_frame(polls: Dict[str, Tuple[Optional[Dict[str, float]],
     # -- per-rank table ----------------------------------------------------
     lines.append(f"{'endpoint':<22} {'status':<9} {'step':>7} "
                  f"{'lag':>5} {'queue':>6} {'straggler':>10} "
-                 f"{'ovlp':>7} {'tune':<14} {'slo':<20}")
+                 f"{'tune':<14} {'slo':<20}")
     lines.append("-" * width)
     for ep in sorted(polls):
         metrics, health = polls[ep]
@@ -141,17 +139,6 @@ def render_frame(polls: Dict[str, Tuple[Optional[Dict[str, float]],
         if q is None:
             q = _gauge(metrics, "bf_win_tx_queue_depth")
         sc = (health or {}).get("straggler", {}).get("straggler_score")
-        # Measured fused-step overlap (the in-program probes' gauge);
-        # flagged, link-observatory style, when measurement and the
-        # static model disagree past the x3 alert threshold in either
-        # direction — a rank whose puts are NOT hiding where the
-        # schedule preview says they should.
-        ovlp = _gauge(metrics, "bf_fused_overlap_ratio")
-        odiv = _gauge(metrics, "bf_fused_overlap_divergence_ratio")
-        ovlp_txt = f"{ovlp:.2f}" if ovlp is not None else "-"
-        if odiv is not None and \
-                max(odiv, 1.0 / max(odiv, 1e-9)) > linkobs.DIVERGENCE_ALERT:
-            ovlp_txt += "!"
         # Self-tuning control plane: "<epoch>:<last knob>", "!"-flagged
         # while a revert-on-regression probation window is open ("-" when
         # the tuner is off: no block, no column content).
@@ -176,7 +163,6 @@ def render_frame(polls: Dict[str, Tuple[Optional[Dict[str, float]],
             f"{f'{lag:g}' if lag is not None else '-':>5} "
             f"{f'{q:g}' if q is not None else '-':>6} "
             f"{f'{sc:.2f}' if sc is not None else '-':>10} "
-            f"{ovlp_txt:>7} "
             f"{tune_txt[:14]:<14} "
             f"{slo_txt[:20]:<20}")
     # -- link matrix (gauge-MAX merge: each edge lives on its receiver) ----

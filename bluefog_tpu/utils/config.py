@@ -19,7 +19,6 @@ for the TPU rebuild.  Values are read lazily on first access and cached; call
 | BLUEFOG_TPU_WIN_COALESCE      | 1     | 0: legacy per-message transport sends |
 | BLUEFOG_TPU_WIN_NATIVE        | 1     | 0: keep the transport hot loop (batch/drain/fold) in Python; 1 auto-falls back when the native core is missing/stale |
 | BLUEFOG_TPU_WIN_XLA           | 1     | 0: pin the host-staged put path (the bitwise oracle); 1 auto-disarms (one warning) without jax.ffi, the bf_xla native symbols, or host-addressable device buffers |
-| BLUEFOG_TPU_FUSED_STEP        | 0     | whole-step compilation (ops/fused_step.py): optimizer math + per-bucket window puts lower into one jitted XLA program; 0 pins the eager step (the bitwise oracle); 1 auto-falls back to eager (one warning) when the XLA put path is disarmed |
 | BLUEFOG_TPU_SHARDED_GOSSIP    | 1     | sharding-aware gossip (ops/sharded.py): with explicit shard specs, replicated leaves gossip over the full topology while sharded leaves gossip per replica group only — DCN bytes scale with the replicated fraction; 0 forces replicated-only gossip; fully replicated trees are bitwise identical either way |
 | BLUEFOG_TPU_WIN_COALESCE_LINGER_MS | 1.0 | sender-worker linger before flushing a partial batch |
 | BLUEFOG_TPU_WIN_COALESCE_BYTES | 1 MiB | queued bytes that force an immediate batch flush |
@@ -54,7 +53,6 @@ for the TPU rebuild.  Values are read lazily on first access and cached; call
 | BLUEFOG_TPU_TELEMETRY_CONSENSUS_EVERY | 10 | consensus-distance sample period (0=off) |
 | BLUEFOG_TPU_PROFILE           | 0     | 1: enable the step profiler's periodic sampling |
 | BLUEFOG_TPU_PROFILE_EVERY     | 50    | straggler-gather / synced-sample period (steps) |
-| BLUEFOG_TPU_PROBE             | 1     | in-program probes (utils/probes.py): native timestamp custom calls threaded through the fused step program — measured overlap, fused-path phase attribution, per-bucket timeline lanes; 0 compiles no probe ops and is bitwise inert |
 | BLUEFOG_TPU_SCHEDULE_OPT      | 1     | 0: skip the min-round schedule repack |
 | BLUEFOG_TPU_SCHEDULE_SYNTH    | 1     | 0: skip sketch-guided schedule synthesis (PR 5 congestion-repack path exactly) |
 | BLUEFOG_TPU_SCHEDULE_SYNTH_SKETCH | auto | synthesis sketch: auto / ring-within-slice / hierarchical / chunked-pipelined |
@@ -301,16 +299,6 @@ class Config:
     # (non-CPU backends, pending the TPU lowering); 0 pins the host-staged
     # PR-9 path unconditionally — the bitwise equivalence oracle.
     win_xla: bool
-    # Whole-step compilation (ops/fused_step.py): the distributed window
-    # optimizers lower (optimizer update x bucket concat x per-bucket
-    # window put) into one jitted XLA program; bucket puts issue as XLA
-    # materializes each bucket, pipelining against the remaining update
-    # math by data dependence instead of the hand-rolled _pending list.
-    # OFF by default — with fused_step=0 no program is built anywhere and
-    # every step is bit-identical to the eager path.  1 auto-falls back
-    # to eager (one logged warning) whenever the XLA put path is
-    # disarmed (no jax.ffi / native symbols / non-CPU backend).
-    fused_step: bool
     # Sharded-aware gossip (ops/sharded.py): optimizers given per-leaf
     # PartitionSpecs neighbor-average only the replicated (data-parallel)
     # leaves over the full topology, while sharded (expert/stage/tensor)
@@ -489,16 +477,6 @@ class Config:
     # overrides both.  bf.step_profile() works regardless of this flag.
     profile: bool
     profile_every: int
-    # In-program probes (utils/probes.py + native xlacall.cc): the fused
-    # step program threads bf_xla_probe timestamp custom calls through its
-    # semantic seams (per-bucket put issue, step end) and a post-step
-    # reconciler maps the ring events into measured overlap, fused-path
-    # phase attribution and per-bucket timeline lanes.  ON by default —
-    # one probe is a relaxed atomic claim + a 16-byte store (~ns).  0
-    # compiles NO probe ops into the program and never arms the ring:
-    # bitwise inert.  Structurally inert anyway while fused_step is off
-    # (the eager path carries no probes).
-    probe: bool
 
     @staticmethod
     def from_env() -> "Config":
@@ -527,7 +505,6 @@ class Config:
                 "BLUEFOG_TPU_WIN_DECODE_THREADS", floor=0),
             win_native=_flag("BLUEFOG_TPU_WIN_NATIVE", default=True),
             win_xla=_flag("BLUEFOG_TPU_WIN_XLA", default=True),
-            fused_step=_flag("BLUEFOG_TPU_FUSED_STEP"),
             sharded_gossip=_flag("BLUEFOG_TPU_SHARDED_GOSSIP",
                                  default=True),
             win_retries=int(os.environ.get(
@@ -603,7 +580,6 @@ class Config:
             profile=_flag("BLUEFOG_TPU_PROFILE"),
             profile_every=int(
                 os.environ.get("BLUEFOG_TPU_PROFILE_EVERY", "50")),
-            probe=_flag("BLUEFOG_TPU_PROBE", default=True),
         )
 
 

@@ -50,7 +50,6 @@ __all__ = [
     "StepProfiler",
     "step_profile",
     "active",
-    "attribution_degraded",
     "profile_period",
     "record_synced_step",
     "straggler_report",
@@ -63,12 +62,6 @@ __all__ = [
 # see from inside its comm entry points).
 PHASES = ("grad-compute", "gossip-communicate", "optimizer-update",
           "host-sync")
-
-# Under BLUEFOG_TPU_FUSED_STEP the whole program is one host-opaque
-# interval; when the in-program probes (utils/probes.py) cannot attribute
-# it, the remainder is labeled with this phase instead of grad-compute —
-# an honest "one compiled program, composition unknown" bucket.
-FUSED_PHASE = "fused-step"
 
 
 def _classify_span(op_name: str, span_phase: str) -> str:
@@ -98,21 +91,11 @@ _active: Optional["StepProfiler"] = None
 _state_lock = threading.Lock()
 _step_count = 0          # profiled steps seen (straggler-gather period base)
 _last_report: Optional[dict] = None
-_degraded = False        # a fused step ran without probe attribution
 
 
 def active() -> Optional["StepProfiler"]:
     """The StepProfiler currently wrapping a step, or None."""
     return _active
-
-
-def attribution_degraded() -> bool:
-    """True once a profiled fused step ran WITHOUT in-program probe
-    attribution (native core predates ``bf_xla_probe`` or
-    ``BLUEFOG_TPU_PROBE=0``): phase histograms carry an opaque
-    ``fused-step`` bucket instead of real phases, and ``/healthz``
-    flags the straggler report accordingly."""
-    return _degraded
 
 
 def last_straggler_report() -> Optional[dict]:
@@ -123,11 +106,10 @@ def last_straggler_report() -> Optional[dict]:
 
 
 def _reset_for_tests() -> None:
-    global _active, _step_count, _last_report, _degraded
+    global _active, _step_count, _last_report
     _active = None
     _step_count = 0
     _last_report = None
-    _degraded = False
     _uninstall_hook()
 
 
@@ -203,21 +185,6 @@ class StepProfiler:
         self._t0: Optional[float] = None
         self._enabled = False
         self._prev: Optional[StepProfiler] = None
-        self._fused = False
-        self._fused_attributed = False
-
-    def note_fused(self, attributed: bool) -> None:
-        """The fused step served this step; ``attributed`` says whether
-        the in-program probes reconciled real phases into it.  Without
-        attribution the exit remainder is labeled ``fused-step`` (the
-        program is host-opaque — calling it grad-compute would be a lie)
-        and the module-wide degraded flag trips for ``/healthz``."""
-        global _degraded
-        self._fused = True
-        if attributed:
-            self._fused_attributed = True
-        else:
-            _degraded = True
 
     def attribute(self, phase: str, seconds: float) -> None:
         """Add ``seconds`` of this step's wall time to ``phase``."""
@@ -274,14 +241,7 @@ class StepProfiler:
         attributed = sum(self.phases().values())
         if total > attributed:
             # The step's own compute: everything no framework span claimed.
-            # When a fused program served the step WITHOUT probe
-            # attribution, the remainder is the whole opaque program —
-            # update math and puts included — so it gets the honest
-            # ``fused-step`` label instead of grad-compute.
-            remainder = (FUSED_PHASE
-                         if self._fused and not self._fused_attributed
-                         else "grad-compute")
-            self.attribute(remainder, total - attributed)
+            self.attribute("grad-compute", total - attributed)
         for ph, dt in sorted(self.phases().items()):
             telemetry.observe("bf_step_phase_seconds", dt, phase=ph)
         telemetry.observe("bf_step_seconds", total)

@@ -497,15 +497,6 @@ def health() -> dict:
     from bluefog_tpu.utils import profiler
     straggler = profiler.last_straggler_report()
     if straggler is not None:
-        if profiler.attribution_degraded():
-            # A fused step ran without in-program probe attribution
-            # (BLUEFOG_TPU_PROBE=0 or a pre-probe native core): phase
-            # histograms carry an opaque "fused-step" bucket, so per-
-            # phase straggler diagnosis is not available.
-            straggler["attribution"] = (
-                "degraded: fused steps unattributed (no in-program "
-                "probes); phase histograms carry an opaque fused-step "
-                "bucket")
         body["straggler"] = straggler
     # Transport-coalescing health (tentpole PR 4): sub-messages per native
     # send (1.0 = nothing coalescing) and the deepest per-peer tx backlog

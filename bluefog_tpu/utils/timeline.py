@@ -129,8 +129,7 @@ def _make_writer(path: str):
 
 _writer = None
 _active: Dict[str, object] = {}
-# The synthetic lane of the ``bf.build.<stage>`` spans, beside the probe
-# reconciler's 998, 999 and 1000+.
+# The synthetic lane of the ``bf.build.<stage>`` spans.
 _BUILD_LANE = 997
 _lock = threading.Lock()
 
@@ -319,13 +318,12 @@ def timeline_context(tensor_name: str, activity_name: str = "USER"):
 
 
 def probe_span(name: str, ts_us: int, dur_us: int, tid: int,
-               cat: str = "fused-probe") -> None:
-    """Emit one complete ("X") span on a synthetic lane — the in-program
-    probe reconciler (``utils/probes.py``) renders fused-step seams with
-    these.  ``ts_us`` is on the same monotonic microsecond clock as every
-    other event here, so trace-merge's clock anchors align probe lanes
-    cross-rank for free.  Works on both writers (the native wire format
-    carries ``dur``)."""
+               cat: str) -> None:
+    """Emit one complete ("X") span on a synthetic lane — the
+    ``bf.build.<stage>`` spans are these.  ``ts_us`` is on the same
+    monotonic microsecond clock as every other event here, so
+    trace-merge's clock anchors align the lanes cross-rank for free.
+    Works on both writers (the native wire format carries ``dur``)."""
     w = _writer
     if w is None:
         return

@@ -44,6 +44,18 @@ def _reset_bluefog_state():
         pass
 
 
+def pytest_generate_tests(metafunc):
+    """A case of ``tests/twins.py`` runs for those of the twins its file
+    names whose row has what the case needs."""
+    names = getattr(metafunc.module, "TWINS", None)
+    if names is not None and "twin" in metafunc.fixturenames:
+        import twins
+        need = getattr(metafunc.function, "needs", None)
+        metafunc.parametrize("twin", [
+            name for name in names
+            if need is None or need in twins.TWINS[name]])
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: long-running multi-process integration test")

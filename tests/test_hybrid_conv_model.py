@@ -4,15 +4,12 @@ against the configuration's plain reference
 ``bluefog_tpu``) or a hand-written line of it: the gated short convolution,
 a per-layer choice of token mixer, the per-head QK norm, flash attention at
 heads of 64 under grouped queries, the router's renormalisation epsilon, a
-tied head, the whole toy model's loss and gradients, and the optimizer step
-on its tree.  float32 to 1e-5; bfloat16 inside the toy's bounds;
-float8-rounded matrices outside them."""
+tied head; the whole toy model's cases are those of ``tests/twins.py``, and
+the digests of every twin's gradient program are held here.  float32 to
+1e-5."""
 
-import copy
 import functools
-import hashlib
 import os
-import re
 import sys
 
 import jax
@@ -24,38 +21,22 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import spec  # noqa: E402
 from bluefog_tpu import models  # noqa: E402
 from bluefog_tpu.models import transformer as T  # noqa: E402
 from bluefog_tpu.ops.flash_attention import flash_attention  # noqa: E402
 from bluefog_tpu.parallel import moe  # noqa: E402
+import twins  # noqa: E402
+from twins import (  # noqa: E402,F401
+    HIGHEST, rel, toy,
+    test_atc_on_four_devices_is_w_times_the_handwritten_update,
+    test_float8_rounded_matrices_fail_the_bounds,
+    test_the_shares_add_up_to_the_uncut_layer,
+    test_toy_model_in_bfloat16_is_inside_the_twin_bounds,
+    test_toy_model_loss_and_every_gradient_leaf_in_float32)
 
-HIGHEST = functools.partial(jax.default_matmul_precision, "highest")
+TWINS = ("tiny-lfm2",)
 KEY = jax.random.PRNGKey(34)
-
-
-def normal(i, shape, scale=1.0):
-    return scale * jax.random.normal(jax.random.fold_in(KEY, i), shape)
-
-
-@pytest.fixture(scope="module")
-def toy():
-    """The tiny twin's configuration, its task and the reference."""
-    config = spec.read_json(os.path.join(
-        spec.HERE, "selftest", "configs", "tiny-lfm2.json"))
-    return (config, spec.load_module("tasks/hybrid_moe_causal_lm.py"),
-            spec.load_module("reference/lfm2-24b-a2b.py"))
-
-
-def with_dtype(config, dtype):
-    config = copy.deepcopy(config)
-    config["model"]["args"]["dtype"] = dtype
-    return config
-
-
-def rel(a, b):
-    return float(jnp.linalg.norm((a - b).ravel())
-                 / jnp.linalg.norm(b.ravel()))
+normal = functools.partial(twins.normal, KEY)
 
 
 # --- (a) the gated short convolution ------------------------------------------
@@ -302,156 +283,10 @@ def test_the_tied_head_is_one_leaf_and_its_gradient_the_sum_of_both_uses():
     assert float(jnp.abs(parts["lm_head"]["kernel"]).max()) > 0
 
 
-# --- (g) the whole toy model ------------------------------------------------------------
-
-def _model_case(toy, dtype, seq=64):
-    config, task, ref = toy
-    config = with_dtype(config, dtype)
-    model = task.make_model(config)
-    batch = {"sequences": 2, "seq_len": seq}
-    params, aux = task.init(model, KEY, config, batch)
-    params = jax.tree.map(lambda p: p + 0.02 * jax.random.uniform(
-        jax.random.fold_in(KEY, p.size), p.shape, minval=-1.0, maxval=1.0),
-        params)
-    aux = dict(aux, bias=normal(30, aux["bias"].shape, 0.05))
-    tokens, = task.make_batch(jax.random.fold_in(KEY, 31), config, batch)
-    program = jax.jit(jax.value_and_grad(task.loss_fn(model, config),
-                                         has_aux=True))
-    reference = jax.jit(jax.value_and_grad(
-        functools.partial(ref.loss, cfg=config), has_aux=True))
-    return config, params, aux, tokens, program, reference
-
-
-def test_toy_model_loss_and_every_gradient_leaf_in_float32(toy):
-    config, params, aux, tokens, program, reference = _model_case(
-        toy, "float32", seq=72)
-    with HIGHEST():
-        (loss, new), grads = program(params, aux, tokens)
-        (want, ref_new), ref_grads = reference(params, aux, tokens)
-    assert "lm_head" not in params and "conv" in params["block_0"]
-    assert "moe" not in params["block_0"] and "moe" in params["block_1"]
-    assert not any(k.startswith("shared") for k in params["block_1"]["moe"])
-    assert params["block_1"]["moe"]["gate"].shape == (4, 64, 32)
-    assert params["block_1"]["moe"]["router"]["kernel"].shape == (64, 8)
-    assert abs(float(loss) - float(want)) / float(want) < 1e-5
-    np.testing.assert_array_equal(new["load"], ref_new["load"])
-    assert new["load"].shape == (4, 8)
-    assert int(new["load"][0].sum()) == 2 * 72 * 2      # all eight counted
-    np.testing.assert_allclose(new["bias"], ref_new["bias"], atol=1e-7)
-    assert float(jnp.abs(new["bias"] - aux["bias"]).max()) == pytest.approx(
-        config["router_bias_update_rate"], rel=1e-3)
-    errs = jax.tree.map(rel, grads, ref_grads)
-    worst = max(jax.tree_util.tree_leaves_with_path(errs),
-                key=lambda kv: kv[1])
-    assert worst[1] < 1e-4, jax.tree_util.keystr(worst[0])
-    assert float(np.median(jax.tree.leaves(errs))) < 1e-5
-
-
-def _sampled(errs, bound, draws=50):
-    """How many of ``draws`` samples of 8 leaves the check would pass."""
-    rng = np.random.default_rng(0)
-    errs = np.asarray(errs)
-    return sum(errs[rng.choice(len(errs), 8, replace=False)].max() <= bound
-               for _ in range(draws))
-
-
-def test_toy_model_in_bfloat16_is_inside_the_twin_bounds(toy):
-    config, params, aux, tokens, program, reference = _model_case(
-        toy, "bfloat16", seq=256)
-    (loss, _), grads = program(params, aux, tokens)
-    with HIGHEST():
-        (want, _), ref_grads = reference(params, aux, tokens)
-    bounds = config["model_check"]
-    assert abs(float(loss) - float(want)) / float(want) < bounds["loss_rtol"]
-    errs = jax.tree.leaves(jax.tree.map(rel, grads, ref_grads))
-    assert max(errs) < bounds["grad_rtol"]
-    assert float(np.median(errs)) < bounds["grad_rtol"] / 2
-
-
-def test_float8_rounded_matrices_fail_the_bounds(toy):
-    """The nearest precision below: the float32 reference with nothing but
-    its matrices rounded to float8_e4m3fn, against itself unrounded, is
-    outside the twin's gradient bound in so many leaves that hardly a sample
-    of 8 passes; the cell's own bound was read on the chip
-    (``model_check.why`` of ``lfm2-24b-a2b.json``)."""
-    config, params, aux, tokens, _, reference = _model_case(
-        toy, "float32", seq=256)
-    bound = config["model_check"]["grad_rtol"]
-    rounded = jax.tree.map(
-        lambda p: p.astype(jnp.float8_e4m3fn).astype(p.dtype)
-        if p.ndim >= 2 else p, params)
-    with HIGHEST():
-        (want, _), ref_grads = reference(params, aux, tokens)
-        (loss, _), grads = reference(rounded, aux, tokens)
-    errs = jax.tree.leaves(jax.tree.map(rel, grads, ref_grads))
-    assert float(np.median(errs)) > bound
-    assert sum(e > bound for e in errs) > 0.5 * len(errs)
-    assert _sampled(errs, bound) <= 1
-
-
 # --- (h) the older configurations' gradient programs ------------------------------------
 
-# sha256 of ``str(jax.make_jaxpr(value_and_grad(loss)))`` (addresses cut) of
-# the tiny twins, taken at the parent of PR 34 (commit 739c0c9) with
-# ``benchmark.spec``'s own task and configuration files; the cells' own
-# programs were compared at their full shapes the same way (``CHANGES.md``).
-# A PR that means to change one of these programs replaces its digest: PR 37
-# replaced the three that run the flash kernels (their tile bodies and the
-# ``jax.jit`` around each call), PR 41 the four that run ``apply_rope`` (a
-# product with a constant half-swap and a written transpose where two
-# half-width slices and a concatenate were; ``tests/test_rope.py`` holds the
-# values to the bit).  ``tiny-resnet`` is as at 739c0c9.
-PARENT_JAXPR = {
-    ("tiny-lm", "causal_lm"): "a4de682606a8a8cd",
-    ("tiny-olmoe", "moe_causal_lm"): "88f1130aabf52fc8",
-    ("tiny-xing", "latent_moe_causal_lm"): "8b4108d454904332",
-    ("tiny-resnet", "image_classification"): "cd85047ddb144986",
-    ("tiny-lfm2", "hybrid_moe_causal_lm"): "c593f86020260dce",
-}
-
-
-def grad_jaxpr_digest(config_name: str) -> str:
-    config = spec.read_json(os.path.join(
-        spec.HERE, "selftest", "configs", config_name + ".json"))
-    task = spec.load_module(os.path.join("tasks", config["task"] + ".py"))
-    model = task.make_model(config)
-    batch = ({"images": 2} if config["task"] == "image_classification"
-             else {"sequences": 2, "seq_len": 128})
-
-    def shapes(key):
-        params, aux = task.init(model, key, config, batch)
-        return params, aux, task.make_batch(key, config, batch)
-    params, aux, one = jax.eval_shape(shapes, KEY)
-    text = str(jax.make_jaxpr(jax.value_and_grad(
-        task.loss_fn(model, config), has_aux=True))(params, aux, *one))
-    return hashlib.sha256(
-        re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest()[:16]
-
-
-@pytest.mark.parametrize("config_name,task", sorted(PARENT_JAXPR))
+@pytest.mark.parametrize("config_name,task", sorted(twins.PARENT_JAXPR))
 def test_an_older_configuration_traces_to_the_parents_jaxpr(config_name,
                                                             task):
-    assert grad_jaxpr_digest(config_name) == PARENT_JAXPR[config_name, task]
-
-
-# --- (i) the optimizer step on the toy's tree --------------------------------------------
-
-def test_atc_adamw_on_four_devices_is_w_times_the_handwritten_update(devices):
-    """``bf.init`` + ``bf.rank_map`` + ``DistributedAdaptThenCombineOptimizer``
-    over AdamW on four CPU devices, two steps on the toy's tree (its tied
-    embedding, the taps, the held experts) from seeded values that differ by
-    rank, against ``W_t @`` the update written out in
-    ``reference/optim_adamw.py``: the benchmark's own ``step`` check."""
-    from benchmark import checks
-    from benchmark.build import Job
-    from benchmark.selftest.test_lfm2_cell_cpu import twin_cell
-    cell = twin_cell()
-    job = Job(cell, spec.task_module(cell), devices[:4], 34)
-    assert job.n == 4 and "lm_head" not in job.params
-    report = checks.step(job, spec.optimizer_reference(cell),
-                         spec.mixing_reference(cell))
-    assert report["leaves"] == len(jax.tree.leaves(job.params))
-    assert report["worst_share_of_update"] <= checks.STEP_TOL
-    loss, grads = job.grad(job.next_batch())
-    assert np.asarray(loss).shape == (4,) and np.isfinite(loss).all()
-    assert jax.tree.structure(grads) == jax.tree.structure(job.params)
+    assert twins.grad_jaxpr_digest(config_name) \
+        == twins.PARENT_JAXPR[config_name, task]

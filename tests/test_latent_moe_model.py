@@ -4,9 +4,9 @@ against the configuration's plain reference
 ``bluefog_tpu``) or a hand-written line of it: flash attention with a value
 dim and a scale of its own, the latent-attention sub-layer with YaRN
 frequencies, the sigmoid router with its bias, the held share of the experts
-(the shares add up), the Sinkhorn-normalised residual maps, and the whole toy
-model's loss and gradients.  float32 to 1e-5; bfloat16 inside the toy's
-bounds; float8-rounded matrices outside them."""
+(the shares add up), the Sinkhorn-normalised residual maps, and the toy
+model on a window; the whole toy model's other cases are those of
+``tests/twins.py``, run from ``tests/test_twins.py``.  float32 to 1e-5."""
 
 import copy
 import functools
@@ -29,33 +29,13 @@ from bluefog_tpu.models import transformer as T  # noqa: E402
 from bluefog_tpu.ops.flash_attention import (  # noqa: E402
     flash_attention, flash_attention_impl)
 from bluefog_tpu.parallel import moe  # noqa: E402
+import twins  # noqa: E402
+from twins import HIGHEST, rel, toy, with_dtype  # noqa: E402,F401
 
-HIGHEST = functools.partial(jax.default_matmul_precision, "highest")
+# the twin of ``toy``; its whole-model cases run from tests/test_twins.py
+TWINS = ("tiny-xing",)
 KEY = jax.random.PRNGKey(31)
-
-
-def normal(i, shape, scale=1.0):
-    return scale * jax.random.normal(jax.random.fold_in(KEY, i), shape)
-
-
-@pytest.fixture(scope="module")
-def toy():
-    """The tiny twin's configuration, its task and the reference."""
-    config = spec.read_json(os.path.join(
-        spec.HERE, "selftest", "configs", "tiny-xing.json"))
-    return (config, spec.load_module("tasks/latent_moe_causal_lm.py"),
-            spec.load_module("reference/xing4.0-29b-a4b.py"))
-
-
-def with_dtype(config, dtype):
-    config = copy.deepcopy(config)
-    config["model"]["args"]["dtype"] = dtype
-    return config
-
-
-def rel(a, b):
-    return float(jnp.linalg.norm((a - b).ravel())
-                 / jnp.linalg.norm(b.ravel()))
+normal = functools.partial(twins.normal, KEY)
 
 
 # --- (a) flash attention: value dim and scale -------------------------------
@@ -591,48 +571,6 @@ def test_a_fresh_block_is_the_plain_block_on_the_mean_stream():
 
 # --- (g), (h) the whole toy model ---------------------------------------------------
 
-def _model_case(toy, dtype, seq=64):
-    config, task, ref = toy
-    config = with_dtype(config, dtype)
-    model = task.make_model(config)
-    batch = {"sequences": 2, "seq_len": seq}
-    params, aux = task.init(model, KEY, config, batch)
-    params = jax.tree.map(lambda p: p + 0.02 * jax.random.uniform(
-        jax.random.fold_in(KEY, p.size), p.shape, minval=-1.0, maxval=1.0),
-        params)
-    aux = dict(aux, bias=normal(30, aux["bias"].shape, 0.05))
-    tokens, = task.make_batch(jax.random.fold_in(KEY, 31), config, batch)
-    program = jax.jit(jax.value_and_grad(task.loss_fn(model, config),
-                                         has_aux=True))
-    reference = jax.jit(jax.value_and_grad(
-        functools.partial(ref.loss, cfg=config), has_aux=True))
-    return config, params, aux, tokens, program, reference
-
-
-def test_toy_model_loss_and_every_gradient_leaf_in_float32(toy):
-    config, params, aux, tokens, program, reference = _model_case(
-        toy, "float32")
-    with HIGHEST():
-        (loss, new), grads = program(params, aux, tokens)
-        (want, ref_new), ref_grads = reference(params, aux, tokens)
-    assert set(params["block_0"]) >= {"mla", "hc_attn", "hc_ffn", "gate"}
-    assert "moe" not in params["block_0"] and "moe" in params["block_1"]
-    assert params["block_1"]["moe"]["gate"].shape == (4, 64, 32)
-    assert params["block_1"]["moe"]["router"]["kernel"].shape == (64, 8)
-    assert abs(float(loss) - float(want)) / float(want) < 1e-5
-    np.testing.assert_array_equal(new["load"], ref_new["load"])
-    assert new["load"].shape == (2, 8)
-    assert int(new["load"][0].sum()) == 2 * 64 * 2      # all eight counted
-    np.testing.assert_allclose(new["bias"], ref_new["bias"], atol=1e-7)
-    assert float(jnp.abs(new["bias"] - aux["bias"]).max()) == pytest.approx(
-        config["router_bias_update_rate"], rel=1e-3)
-    errs = jax.tree.map(rel, grads, ref_grads)
-    worst = max(jax.tree_util.tree_leaves_with_path(errs),
-                key=lambda kv: kv[1])
-    assert worst[1] < 1e-3, jax.tree_util.keystr(worst[0])
-    assert float(np.median(jax.tree.leaves(errs))) < 1e-5
-
-
 def test_toy_model_with_a_window_against_the_reference(toy):
     """One expert of eight held, two rows of 128 tokens: the expert layers
     (under remat, their statistics sown) work on a window of 256 of 512
@@ -642,11 +580,12 @@ def test_toy_model_with_a_window_against_the_reference(toy):
     held_one = dict(copy.deepcopy(config), n_routed_experts=1,
                     experts_first=2)
     assert moe.held_window(2 * 128 * 2, 1, 8) == 256
-    _, params, aux, tokens, program, reference = _model_case(
-        (held_one, task, ref), "float32", seq=128)
+    _, params, aux, batch, program, reference = twins.model_case(
+        (held_one, task, ref), KEY, "float32",
+        {"sequences": 2, "seq_len": 128})
     with HIGHEST():
-        (loss, new), grads = program(params, aux, tokens)
-        (want, ref_new), ref_grads = reference(params, aux, tokens)
+        (loss, new), grads = program(params, aux, *batch)
+        (want, ref_new), ref_grads = reference(params, aux, *batch)
     assert params["block_1"]["moe"]["gate"].shape == (1, 64, 32)
     assert abs(float(loss) - float(want)) / float(want) < 1e-5
     np.testing.assert_array_equal(new["load"], ref_new["load"])
@@ -654,45 +593,3 @@ def test_toy_model_with_a_window_against_the_reference(toy):
     worst = max(jax.tree_util.tree_leaves_with_path(errs),
                 key=lambda kv: kv[1])
     assert worst[1] < 1e-3, jax.tree_util.keystr(worst[0])
-
-
-def _sampled(errs, bound, draws=50):
-    """How many of ``draws`` samples of 8 leaves the check would pass."""
-    rng = np.random.default_rng(0)
-    errs = np.asarray(errs)
-    return sum(errs[rng.choice(len(errs), 8, replace=False)].max() <= bound
-               for _ in range(draws))
-
-
-def test_toy_model_in_bfloat16_is_inside_the_twin_bounds(toy):
-    config, params, aux, tokens, program, reference = _model_case(
-        toy, "bfloat16", seq=256)
-    (loss, _), grads = program(params, aux, tokens)
-    with HIGHEST():
-        (want, _), ref_grads = reference(params, aux, tokens)
-    bounds = config["model_check"]
-    assert abs(float(loss) - float(want)) / float(want) < bounds["loss_rtol"]
-    errs = jax.tree.leaves(jax.tree.map(rel, grads, ref_grads))
-    assert max(errs) < bounds["grad_rtol"]
-    assert float(np.median(errs)) < bounds["grad_rtol"] / 2
-
-
-def test_float8_rounded_matrices_fail_the_bounds(toy):
-    """The nearest precision below: the float32 reference with nothing but
-    its matrices rounded to float8_e4m3fn, against itself unrounded, is
-    outside the twin's gradient bound in most leaves, so that no sample of
-    8 leaves passes; the cell's own bound (0.8 against 87 to 90% in the
-    median at the published widths) was read on the chip."""
-    config, params, aux, tokens, _, reference = _model_case(
-        toy, "float32", seq=256)
-    bound = config["model_check"]["grad_rtol"]
-    rounded = jax.tree.map(
-        lambda p: p.astype(jnp.float8_e4m3fn).astype(p.dtype)
-        if p.ndim >= 2 else p, params)
-    with HIGHEST():
-        (want, _), ref_grads = reference(params, aux, tokens)
-        (loss, _), grads = reference(rounded, aux, tokens)
-    errs = jax.tree.leaves(jax.tree.map(rel, grads, ref_grads))
-    assert float(np.median(errs)) > bound
-    assert sum(e > bound for e in errs) > 0.7 * len(errs)
-    assert _sampled(errs, bound) == 0

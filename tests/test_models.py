@@ -6,6 +6,14 @@ import numpy as np
 import pytest
 
 from bluefog_tpu import models
+from twins import (  # noqa: F401
+    test_atc_on_four_devices_is_w_times_the_handwritten_update,
+    test_float8_rounded_matrices_fail_the_bounds,
+    test_toy_model_in_bfloat16_is_inside_the_twin_bounds,
+    test_toy_model_loss_and_every_gradient_leaf_in_float32)
+
+# the twins whose whole-model cases (``tests/twins.py``) run in this file
+TWINS = ("tiny-lm", "tiny-resnet")
 
 
 def test_lenet_forward():

@@ -682,21 +682,6 @@ def test_one_rank_cuts_and_transfers_nothing(monkeypatch):
     assert snap["bf_optim_exchange_transfers"] == 0
 
 
-def test_bucket_groups_partitioning():
-    """Unit contract of the window family's bucket partitioner:
-    contiguous, exhaustive, byte-balanced."""
-    from bluefog_tpu.optim.window_optimizers import _bucket_groups
-    leaves = [np.zeros(s, np.float32) for s in (100, 50, 200, 10, 40)]
-    g2 = _bucket_groups(leaves, 2)
-    assert [i for grp in g2 for i in grp] == [0, 1, 2, 3, 4]
-    assert len(g2) == 2
-    # more buckets than leaves clamps to one leaf per bucket
-    g9 = _bucket_groups(leaves, 9)
-    assert len(g9) <= 5 and [i for g in g9 for i in g] == [0, 1, 2, 3, 4]
-    # fusion_buckets=1 is exactly the single window
-    assert _bucket_groups(leaves, 1) == [[0, 1, 2, 3, 4]]
-
-
 @pytest.mark.parametrize("factory,kind", [
     (lambda b: bf.optim.DistributedNeighborAllreduceOptimizer(
         b, compression="bf16"), "neighbor"),

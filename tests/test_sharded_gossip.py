@@ -3,9 +3,8 @@
 Planner unit tests (partition-spec -> gossip mask, per-group schedule
 compilation, slice row extract/scatter, induced window weights), the
 eager collective and window paths against dense / per-group oracles,
-the bit-identity hatches (knob off, fully replicated tree), the
-per-shard telemetry split, and the fused-step composition (put-plan
-skip + fused-vs-eager oracle).  The slow bfrun leg drives a simulated
+the bit-identity hatches (knob off, fully replicated tree) and the
+per-shard telemetry split.  The slow bfrun leg drives a simulated
 MoE tree across real processes and asserts replicated consensus with
 experts mixing inside their replica group only.
 """
@@ -308,82 +307,6 @@ def test_window_fully_replicated_bitwise():
     for k in p1:
         np.testing.assert_array_equal(np.asarray(p1[k]),
                                       np.asarray(p2[k]), err_msg=k)
-
-
-# ---------------------------------------------------------------------------
-# Fused-step composition
-# ---------------------------------------------------------------------------
-
-def _drive_fused(monkeypatch, fused, prefix, specs, num_shards, steps=3):
-    monkeypatch.setenv("BLUEFOG_TPU_FUSED_STEP", "1" if fused else "0")
-    config.reload()
-    params = _tree(seed=2)
-    grads = _tree(seed=3)
-    opt = bf.optim.DistributedWinPutOptimizer(
-        optax.sgd(0.0), window_prefix=prefix,
-        shard_specs=specs, num_shards=num_shards)
-    state = opt.init(params)
-    p = params
-    for _ in range(steps):
-        p, state = opt.step(p, grads, state)
-    fi = opt._fused_impl
-    stats = (fi.fused_steps, fi.builds) if fi is not None else (0, 0)
-    prog = (next(iter(fi._programs.values()))
-            if fi is not None and fi._programs else None)
-    opt.free()
-    return p, stats, prog
-
-
-def test_fused_step_skips_sharded_put_plans(monkeypatch):
-    bf.init(lambda: topo.ExponentialTwoGraph(N))
-    try:
-        p_f, st, prog = _drive_fused(monkeypatch, True, "wf", SPECS, 2)
-        assert st == (3, 1)
-        assert prog is not None
-        # The program covers the replicated bucket windows only — the
-        # put-plan builder skipped the sharded window at compile time.
-        assert prog.shard_name == "wf.sharded"
-        assert all(not nm.endswith(".sharded") for nm in prog.names)
-        assert len(prog.plans) == len(prog.names)
-        p_e, st_e, _ = _drive_fused(monkeypatch, False, "we", SPECS, 2)
-        assert st_e == (0, 0)
-        for k in p_f:
-            np.testing.assert_array_equal(
-                np.asarray(p_f[k]), np.asarray(p_e[k]),
-                err_msg=f"{k}: fused-vs-eager oracle (sharded tree)")
-    finally:
-        monkeypatch.delenv("BLUEFOG_TPU_FUSED_STEP")
-        config.reload()
-
-
-def test_fused_step_replicated_tree_has_no_shard_window(monkeypatch):
-    bf.init(lambda: topo.ExponentialTwoGraph(N))
-    try:
-        p_r, _st, prog_r = _drive_fused(
-            monkeypatch, True, "wr", {"a": P(), "b": P()}, 2)
-        p_n, _st2, prog_n = _drive_fused(monkeypatch, True, "wn",
-                                         None, None)
-        assert prog_r is not None and prog_r.shard_name is None
-        assert prog_n is not None and prog_n.shard_name is None
-        for k in p_r:
-            np.testing.assert_array_equal(
-                np.asarray(p_r[k]), np.asarray(p_n[k]), err_msg=k)
-    finally:
-        monkeypatch.delenv("BLUEFOG_TPU_FUSED_STEP")
-        config.reload()
-
-
-def test_fused_key_carries_plan_signature(monkeypatch):
-    """Same tree with and without specs must compile DIFFERENT programs."""
-    bf.init(lambda: topo.ExponentialTwoGraph(N))
-    try:
-        _p, _st, prog_a = _drive_fused(monkeypatch, True, "ka", SPECS, 2)
-        _p2, _st2, prog_b = _drive_fused(monkeypatch, True, "ka",
-                                         None, None)
-        assert prog_a.key != prog_b.key
-    finally:
-        monkeypatch.delenv("BLUEFOG_TPU_FUSED_STEP")
-        config.reload()
 
 
 # ---------------------------------------------------------------------------

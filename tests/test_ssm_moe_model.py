@@ -5,11 +5,10 @@ of ``bluefog_tpu`` and walks the recurrence step by step) or a hand-written
 line of it: the chunked state-space scan, the Mamba-2 mixer around it,
 blocks that are one part alone, un-gated ``relu2`` experts through the one
 expert path (whole and as a held share on its window), grouped-query
-attention of 32 over 2 style without positions, the whole toy model's loss
-and gradients.  float32 to 1e-5; bfloat16 inside the toy's bounds;
-float8-rounded matrices outside them."""
+attention of 32 over 2 style without positions; the whole toy model's cases
+are those of ``tests/twins.py``, run from ``tests/test_twins.py``.  float32 to
+1e-5."""
 
-import copy
 import functools
 import os
 import sys
@@ -31,33 +30,14 @@ from bluefog_tpu.ops import ssd  # noqa: E402
 from bluefog_tpu.ops.ssd import ssd_scan  # noqa: E402
 from bluefog_tpu.parallel import moe  # noqa: E402
 from bluefog_tpu.utils import telemetry  # noqa: E402
+import twins  # noqa: E402
+from twins import HIGHEST, rel, toy, with_dtype  # noqa: E402,F401
 
-HIGHEST = functools.partial(jax.default_matmul_precision, "highest")
+# the twin of ``toy``; its whole-model cases run from tests/test_twins.py
+TWINS = ("tiny-twotower",)
 KEY = jax.random.PRNGKey(42)
+normal = functools.partial(twins.normal, KEY)
 
-
-def normal(i, shape, scale=1.0):
-    return scale * jax.random.normal(jax.random.fold_in(KEY, i), shape)
-
-
-@pytest.fixture(scope="module")
-def toy():
-    """The tiny twin's configuration, its task and the reference."""
-    config = spec.read_json(os.path.join(
-        spec.HERE, "selftest", "configs", "tiny-twotower.json"))
-    return (config, spec.load_module("tasks/ssm_moe_causal_lm.py"),
-            spec.load_module("reference/nemotron-twotower-30b-a3b.py"))
-
-
-def with_dtype(config, dtype, remat=True):
-    config = copy.deepcopy(config)
-    config["model"]["args"].update(dtype=dtype, remat=remat)
-    return config
-
-
-def rel(a, b):
-    return float(jnp.linalg.norm((a - b).ravel())
-                 / jnp.linalg.norm(b.ravel()))
 
 
 def value_and_grads(fn, argnums, weight=None):
@@ -706,57 +686,7 @@ def test_grouped_attention_without_positions_against_the_reference(toy):
         assert rel(a, b) < 1e-4
 
 
-# --- (f) the whole toy model ------------------------------------------------------------
-
-def _model_case(toy, dtype, seq=64, remat=True):
-    config, task, ref = toy
-    config = with_dtype(config, dtype, remat)
-    model = task.make_model(config)
-    batch = {"sequences": 2, "seq_len": seq}
-    params, aux = task.init(model, KEY, config, batch)
-    params = jax.tree.map(lambda p: p + 0.02 * jax.random.uniform(
-        jax.random.fold_in(KEY, p.size), p.shape, minval=-1.0, maxval=1.0),
-        params)
-    aux = dict(aux, bias=normal(30, aux["bias"].shape, 0.05))
-    tokens, = task.make_batch(jax.random.fold_in(KEY, 31), config, batch)
-    program = jax.jit(jax.value_and_grad(task.loss_fn(model, config),
-                                         has_aux=True))
-    reference = jax.jit(jax.value_and_grad(
-        functools.partial(ref.loss, cfg=config), has_aux=True))
-    return config, params, aux, tokens, program, reference
-
-
-def test_toy_model_loss_and_every_gradient_leaf_in_float32(toy):
-    """Nine blocks ``MEMEM*EME`` of one part each, 4 of 16 experts held from
-    the fourth on, at a length that is no multiple of the chunk: the loss,
-    the load, the moved bias and every gradient leaf."""
-    config, params, aux, tokens, program, reference = _model_case(
-        toy, "float32", seq=200, remat=False)   # the same numbers, half the
-    #                                             program to compile
-    with HIGHEST():
-        (loss, new), grads = program(params, aux, tokens)
-        (want, ref_new), ref_grads = reference(params, aux, tokens)
-    assert "wpe" not in params and set(params["block_0"]) == {"RMSNorm_0",
-                                                              "mamba"}
-    assert set(params["block_1"]) == {"RMSNorm_0", "moe"}
-    assert set(params["block_5"]) == {"RMSNorm_0", "q", "kv", "proj"}
-    assert "gate" not in params["block_1"]["moe"]
-    assert params["block_1"]["moe"]["up"].shape == (4, 64, 24)
-    assert params["block_1"]["moe"]["router"]["kernel"].shape == (64, 16)
-    assert abs(float(loss) - float(want)) / float(want) < 1e-5
-    np.testing.assert_array_equal(new["load"], ref_new["load"])
-    assert new["load"].shape == (4, 16)
-    assert int(new["load"][0].sum()) == 2 * 200 * 3     # all sixteen counted
-    np.testing.assert_allclose(new["bias"], ref_new["bias"], atol=1e-7)
-    assert float(jnp.abs(new["bias"] - aux["bias"]).max()) == pytest.approx(
-        config["router_bias_update_rate"], rel=1e-3)
-    errs = jax.tree.map(rel, grads, ref_grads)
-    assert len(jax.tree.leaves(errs)) == 67
-    worst = max(jax.tree_util.tree_leaves_with_path(errs),
-                key=lambda kv: kv[1])
-    assert worst[1] < 1e-4, jax.tree_util.keystr(worst[0])
-    assert float(np.median(jax.tree.leaves(errs))) < 1e-5
-
+# --- (f) the published widths ----------------------------------------------------
 
 def test_the_published_widths_count_667_million_parameters():
     """``jax.eval_shape`` of the cell's own model: no array is made."""
@@ -782,46 +712,3 @@ def test_the_published_widths_count_667_million_parameters():
     assert params["block_5"]["kv"]["kernel"].shape == (2688, 512)
     assert aux["bias"].shape == (4, 128)
     assert all(p.dtype == jnp.float32 for p in jax.tree.leaves(params))
-
-
-def _sampled(errs, bound, draws=50):
-    """How many of ``draws`` samples of 8 leaves the check would pass."""
-    rng = np.random.default_rng(0)
-    errs = np.asarray(errs)
-    return sum(errs[rng.choice(len(errs), 8, replace=False)].max() <= bound
-               for _ in range(draws))
-
-
-def test_toy_model_in_bfloat16_is_inside_the_twin_bounds(toy):
-    config, params, aux, tokens, program, reference = _model_case(
-        toy, "bfloat16", seq=256)
-    (loss, _), grads = program(params, aux, tokens)
-    with HIGHEST():
-        (want, _), ref_grads = reference(params, aux, tokens)
-    bounds = config["model_check"]
-    assert abs(float(loss) - float(want)) / float(want) < bounds["loss_rtol"]
-    errs = jax.tree.leaves(jax.tree.map(rel, grads, ref_grads))
-    assert max(errs) < bounds["grad_rtol"]
-    assert float(np.median(errs)) < bounds["grad_rtol"] / 2
-
-
-def test_float8_rounded_matrices_fail_the_bounds(toy):
-    """The nearest precision below: the float32 reference with nothing but
-    its matrices rounded to float8_e4m3fn, against itself unrounded, is
-    outside the twin's gradient bound in so many leaves that hardly a sample
-    of 8 passes; the cell's own bound was read on the chip
-    (``model_check.why`` of ``nemotron-twotower-30b-a3b.json``)."""
-    config, params, aux, tokens, _, reference = _model_case(
-        toy, "float32", seq=256)
-    bound = config["model_check"]["grad_rtol"]
-    rounded = jax.tree.map(
-        lambda p: p.astype(jnp.float8_e4m3fn).astype(p.dtype)
-        if p.ndim >= 2 else p, params)
-    with HIGHEST():
-        (want, _), ref_grads = reference(params, aux, tokens)
-        (loss, _), grads = reference(rounded, aux, tokens)
-    errs = jax.tree.leaves(jax.tree.map(rel, grads, ref_grads))
-    print(sorted(errs))
-    assert float(np.median(errs)) > bound
-    assert sum(e > bound for e in errs) > 0.5 * len(errs)
-    assert _sampled(errs, bound) <= 1

@@ -5,9 +5,8 @@ against the configuration's plain reference
 the layer's own, a sliding window handed to the attention, a rotary scheme by
 layer type (YaRN over half of a head, plain over the whole), the per-head
 output gate, softmax routing with a scaling factor over a held share with a
-shared expert, the shares' sum, the whole toy model's loss and gradients, and
-the optimizer step on its tree.  float32 to 1e-5; bfloat16 inside the toy's
-bounds; float8-rounded matrices outside them."""
+shared expert; the shares' sum and the whole toy model's cases are those of
+``tests/twins.py``.  float32 to 1e-5."""
 
 import copy
 import functools
@@ -23,39 +22,24 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import spec  # noqa: E402
 from bluefog_tpu import models  # noqa: E402
 from bluefog_tpu.models import transformer as T  # noqa: E402
 from bluefog_tpu.ops.flash_attention import flash_attention_impl  # noqa: E402
 from bluefog_tpu.parallel import moe  # noqa: E402
 from bluefog_tpu.utils import telemetry  # noqa: E402
+import twins  # noqa: E402
+from twins import (  # noqa: E402,F401
+    HIGHEST, rel, toy, with_dtype,
+    test_atc_on_four_devices_is_w_times_the_handwritten_update,
+    test_float8_rounded_matrices_fail_the_bounds,
+    test_the_shares_add_up_to_the_uncut_layer,
+    test_toy_model_in_bfloat16_is_inside_the_twin_bounds,
+    test_toy_model_loss_and_every_gradient_leaf_in_float32)
 
-HIGHEST = functools.partial(jax.default_matmul_precision, "highest")
+TWINS = ("tiny-laguna",)
 KEY = jax.random.PRNGKey(40)
+normal = functools.partial(twins.normal, KEY)
 
-
-def normal(i, shape, scale=1.0):
-    return scale * jax.random.normal(jax.random.fold_in(KEY, i), shape)
-
-
-@pytest.fixture(scope="module")
-def toy():
-    """The tiny twin's configuration, its task and the reference."""
-    config = spec.read_json(os.path.join(
-        spec.HERE, "selftest", "configs", "tiny-laguna.json"))
-    return (config, spec.load_module("tasks/window_moe_causal_lm.py"),
-            spec.load_module("reference/laguna-xs.2.py"))
-
-
-def with_dtype(config, dtype):
-    config = copy.deepcopy(config)
-    config["model"]["args"]["dtype"] = dtype
-    return config
-
-
-def rel(a, b):
-    return float(jnp.linalg.norm((a - b).ravel())
-                 / jnp.linalg.norm(b.ravel()))
 
 
 def lm_config(**kw):
@@ -414,149 +398,3 @@ def test_a_held_share_with_softmax_scores_and_the_shared_expert(toy):
     worst = max(jax.tree_util.tree_leaves_with_path(errs),
                 key=lambda kv: kv[1])
     assert worst[1] < 1e-5, jax.tree_util.keystr(worst[0])
-
-
-def test_the_shares_add_up_to_the_uncut_layer(toy):
-    """Four shares of 4 of the 16 experts, the shared expert counted once,
-    give the reference's layer with all 16 experts held."""
-    config, task, ref = toy
-    whole = _moe_layer(config, task, 16, 0)
-    y = normal(22, (2, 40, 64))
-    params = whole.init(KEY, y)["params"]
-    params = jax.tree.map(lambda p: p + 0.1 * normal(p.size, p.shape),
-                          params)
-    with HIGHEST():
-        want = jax.jit(lambda p, y: ref._experts(
-            y, p, dict(config, num_experts=16))[0])(params, y)
-        shared = ref._swiglu(y, params["shared_gate"]["kernel"],
-                             params["shared_up"]["kernel"],
-                             params["shared_down"]["kernel"])
-        parts = []
-        for first in range(0, 16, 4):
-            share = dict(params, **{k: params[k][first:first + 4]
-                                    for k in ("gate", "up", "down")})
-            layer = _moe_layer(config, task, 4, first)
-            parts.append(jax.jit(lambda p, y: layer.apply(  # noqa: B023
-                {"params": p}, y, mutable=["intermediates"])[0])(share, y))
-            np.testing.assert_allclose(
-                parts[-1], jax.jit(lambda p, y: ref._experts(  # noqa: B023
-                    y, p, dict(config, experts_first=first))[0])(share, y),
-                rtol=1e-5, atol=1e-5)
-    total = sum(p - shared for p in parts) + shared
-    np.testing.assert_allclose(total, want, rtol=1e-5, atol=2e-5)
-    assert float(jnp.abs(parts[0] - parts[1]).max()) > 1e-3
-
-
-# --- (e) the whole toy model ---------------------------------------------------------
-
-def _model_case(toy, dtype, seq):
-    config, task, ref = toy
-    config = with_dtype(config, dtype)
-    model = task.make_model(config)
-    batch = {"sequences": 2, "seq_len": seq}
-    params, aux = task.init(model, KEY, config, batch)
-    params = jax.tree.map(lambda p: p + 0.02 * jax.random.uniform(
-        jax.random.fold_in(KEY, p.size), p.shape, minval=-1.0, maxval=1.0),
-        params)
-    tokens, = task.make_batch(jax.random.fold_in(KEY, 31), config, batch)
-    program = jax.jit(jax.value_and_grad(task.loss_fn(model, config),
-                                         has_aux=True))
-    reference = jax.jit(jax.value_and_grad(
-        functools.partial(ref.loss, cfg=config), has_aux=True))
-    return config, params, aux, tokens, program, reference
-
-
-def test_toy_model_loss_and_every_gradient_leaf_in_float32(toy):
-    """Both layer types, 6 / 8 heads of 16 over 2 K/V heads, the gate, both
-    rotary schemes, 4 of 16 experts held with the shared expert, through
-    the flash kernels (a window of 48 over 192 positions in blocks of
-    64)."""
-    config, params, aux, tokens, program, reference = _model_case(
-        toy, "float32", seq=192)
-    with HIGHEST():
-        (loss, new), grads = program(params, aux, tokens)
-        (want, ref_new), ref_grads = reference(params, aux, tokens)
-    assert params["block_0"]["q"]["kernel"].shape == (64, 6 * 16)
-    assert params["block_1"]["q"]["kernel"].shape == (64, 8 * 16)
-    assert "moe" not in params["block_0"] and "moe" in params["block_4"]
-    assert params["block_1"]["moe"]["gate"].shape == (4, 64, 16)
-    assert params["block_1"]["moe"]["router"]["kernel"].shape == (64, 16)
-    assert params["lm_head"]["kernel"].shape == (64, 512)
-    assert abs(float(loss) - float(want)) / float(want) < 1e-6
-    np.testing.assert_array_equal(new["load"], ref_new["load"])
-    assert new["load"].shape == (4, 16)
-    assert int(new["load"][0].sum()) == 2 * 192 * 4     # all sixteen counted
-    for term in ("balance_loss", "z_loss"):
-        assert float(new[term]) == pytest.approx(float(ref_new[term]),
-                                                 rel=1e-5)
-    errs = jax.tree.map(rel, grads, ref_grads)
-    assert len(jax.tree.leaves(errs)) == len(jax.tree.leaves(params)) == 64
-    worst = max(jax.tree_util.tree_leaves_with_path(errs),
-                key=lambda kv: kv[1])
-    assert worst[1] < 1e-5, jax.tree_util.keystr(worst[0])
-
-
-def _sampled(errs, bound, draws=50):
-    """How many of ``draws`` samples of 8 leaves the check would pass."""
-    rng = np.random.default_rng(0)
-    errs = np.asarray(errs)
-    return sum(errs[rng.choice(len(errs), 8, replace=False)].max() <= bound
-               for _ in range(draws))
-
-
-def test_toy_model_in_bfloat16_is_inside_the_twin_bounds(toy):
-    config, params, aux, tokens, program, reference = _model_case(
-        toy, "bfloat16", seq=256)
-    (loss, _), grads = program(params, aux, tokens)
-    with HIGHEST():
-        (want, _), ref_grads = reference(params, aux, tokens)
-    bounds = config["model_check"]
-    assert abs(float(loss) - float(want)) / float(want) < bounds["loss_rtol"]
-    errs = jax.tree.leaves(jax.tree.map(rel, grads, ref_grads))
-    assert max(errs) < bounds["grad_rtol"]
-    assert float(np.median(errs)) < bounds["grad_rtol"] / 2
-
-
-def test_float8_rounded_matrices_fail_the_bounds(toy):
-    """The nearest precision below: the float32 reference with nothing but
-    its matrices rounded to float8_e4m3fn, against itself unrounded, is
-    outside the twin's gradient bound in so many leaves that hardly a sample
-    of 8 passes; the cell's own bound was read on the chip
-    (``model_check.why`` of ``laguna-xs.2.json``)."""
-    config, params, aux, tokens, _, reference = _model_case(
-        toy, "float32", seq=256)
-    bound = config["model_check"]["grad_rtol"]
-    rounded = jax.tree.map(
-        lambda p: p.astype(jnp.float8_e4m3fn).astype(p.dtype)
-        if p.ndim >= 2 else p, params)
-    with HIGHEST():
-        _, ref_grads = reference(params, aux, tokens)
-        _, grads = reference(rounded, aux, tokens)
-    errs = jax.tree.leaves(jax.tree.map(rel, grads, ref_grads))
-    assert float(np.median(errs)) > bound
-    assert sum(e > bound for e in errs) > 0.5 * len(errs)
-    assert _sampled(errs, bound) <= 1
-
-
-# --- (f) the optimizer step on the toy's tree -------------------------------------------
-
-def test_atc_adamw_on_four_devices_is_w_times_the_handwritten_update(devices):
-    """``bf.init`` + ``bf.rank_map`` + ``DistributedAdaptThenCombineOptimizer``
-    over AdamW on four CPU devices, two steps on the toy's tree (its gates,
-    the wider window projections, the held experts and the shared one) from
-    seeded values that differ by rank, against ``W_t @`` the update written
-    out in ``reference/optim_adamw.py``: the benchmark's own ``step``
-    check."""
-    from benchmark import checks
-    from benchmark.build import Job
-    from benchmark.selftest.test_laguna_cell_cpu import twin_cell
-    cell = twin_cell()
-    job = Job(cell, spec.task_module(cell), devices[:4], 40)
-    assert job.n == 4 and "attn_gate" in job.params["block_1"]
-    report = checks.step(job, spec.optimizer_reference(cell),
-                         spec.mixing_reference(cell))
-    assert report["leaves"] == len(jax.tree.leaves(job.params))
-    assert report["worst_share_of_update"] <= checks.STEP_TOL
-    loss, grads = job.grad(job.next_batch())
-    assert np.asarray(loss).shape == (4,) and np.isfinite(loss).all()
-    assert jax.tree.structure(grads) == jax.tree.structure(job.params)
