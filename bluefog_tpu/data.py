@@ -15,6 +15,11 @@ framework needs its own feed.  Two pieces:
   pmap-era and GPU-gated; this one targets ``NamedSharding`` over the rank
   mesh and works on any backend.)
 
+* :func:`pack_documents` — documents of any length in, rows of ``seq_len``
+  tokens out with the ids and positions that keep them apart
+  (``models.TransformerLM(..., positions=, segment_ids=)``); stack its rows
+  and hand them to either of the above as any other array.
+
 Batches are **rank-major**: leading dim ``bf.size()``, row ``r`` is rank
 ``r``'s per-device batch — the same convention as every eager op
 (``docs/ops.md``).
@@ -30,9 +35,60 @@ from typing import Callable, Iterable, Iterator, Optional, Sequence
 import jax
 import numpy as np
 
+from bluefog_tpu.utils import telemetry
 from bluefog_tpu.utils.timeline import op_span
 
-__all__ = ["DistributedSampler", "ShardedLoader", "prefetch_to_device"]
+__all__ = ["DistributedSampler", "ShardedLoader", "prefetch_to_device",
+           "document_layout", "pack_documents"]
+
+
+def document_layout(lengths: Sequence[int]) -> tuple:
+    """``(segment_ids, positions)`` of a row that holds documents of these
+    ``lengths`` one after the other, each ``(sum(lengths),)`` int32: token
+    ``t`` lies in document ``segment_ids[t]`` (0, 1, ... in the row's order,
+    so the ids never decrease: what ``ops.flash_attention`` asks) at
+    ``positions[t]``, counted from its document's first token."""
+    lengths = np.asarray(lengths, np.int64)
+    segment_ids = np.repeat(np.arange(len(lengths)), lengths)
+    starts = np.repeat(np.cumsum(lengths) - lengths, lengths)
+    return (segment_ids.astype(np.int32),
+            (np.arange(lengths.sum()) - starts).astype(np.int32))
+
+
+def pack_documents(documents: Iterable, seq_len: int) -> Iterator[tuple]:
+    """Pack ``documents`` (an iterable of 1-D token arrays of any lengths)
+    into rows of exactly ``seq_len`` tokens: yields ``(tokens, segment_ids,
+    positions)``, each ``(seq_len,)``, the latter two int32 as
+    :func:`document_layout` gives them.
+
+    Concatenate and chunk: documents fill a row in arrival order; the one
+    that reaches the row's end is cut there and its rest opens the next row
+    as a document of its own (id 0, positions from 0: the row cannot see
+    the part it lacks).  Nothing is padded, so the tokens left when
+    ``documents`` ends, fewer than a row, are not yielded.  Empty documents
+    are skipped.  Stack rows into ``(N, seq_len)`` arrays for
+    :class:`ShardedLoader`, or into rank-major batches for
+    :func:`prefetch_to_device`.
+
+    Span ``bf.data.pack`` around each row's assembly; counters
+    ``bf_pack_documents_total`` (documents and parts of documents laid into
+    yielded rows) and ``bf_pack_tokens_total``."""
+    parts, held = [], 0      # what the open row holds so far
+    for doc in documents:
+        doc = np.asarray(doc).reshape(-1)
+        while doc.size:
+            take = doc[:seq_len - held]
+            parts.append(take)
+            held += take.size
+            doc = doc[take.size:]
+            if held == seq_len:
+                with op_span("data", "pack", documents=len(parts)):
+                    row = (np.concatenate(parts),) + document_layout(
+                        [p.size for p in parts])
+                telemetry.inc("bf_pack_documents_total", len(parts))
+                telemetry.inc("bf_pack_tokens_total", seq_len)
+                parts, held = [], 0
+                yield row
 
 
 class DistributedSampler:

@@ -31,11 +31,12 @@ __all__ = ["TransformerLM", "TransformerConfig", "local_attention",
 
 
 def local_attention(q, k, v, *, causal: bool = True, scale: float = None,
-                    window: int = None):
+                    window: int = None, segment_ids=None):
     """Plain single-device attention: ``(B, S, H, D)`` inputs (``v`` may
     have a last dim of its own); ``scale=None`` means ``1 / sqrt(D)``.
     ``window=W`` (with ``causal``): a query sees its own key and the ``W -
-    1`` before it."""
+    1`` before it.  ``segment_ids`` ``(B, S)``: a query sees the keys of its
+    own document only (a packed row, ``data.pack_documents``)."""
     dt = q.dtype
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
@@ -50,6 +51,9 @@ def local_attention(q, k, v, *, causal: bool = True, scale: float = None,
             mask &= ~jnp.tril(jnp.ones((s_q, s_k), bool),
                               s_k - s_q - window)
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
+    if segment_ids is not None:
+        same = segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
+        logits = jnp.where(same, logits, jnp.finfo(jnp.float32).min)
     probs = nn.softmax(logits.astype(jnp.float32), axis=-1).astype(dt)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
@@ -676,6 +680,9 @@ class LatentAttention(nn.Module):
     ``attn_impl`` gets q and k heads of ``nope + rope`` and v heads of
     ``v_head_dim`` (``ops.flash_attention`` takes both) and the scale.
 
+    ``segment_ids`` (the documents of a packed row) go to ``attn_impl``
+    where there are any; ``positions`` then restart at each document.
+
     Device scopes: ``bf.mla.q``, ``bf.mla.kv``, ``bf.mla.rope``,
     ``bf.mla.attend`` (the key's assembly and the attention itself) and
     ``bf.mla.out``."""
@@ -683,7 +690,7 @@ class LatentAttention(nn.Module):
     attn_impl: Callable
 
     @nn.compact
-    def __call__(self, y, positions):
+    def __call__(self, y, positions, segment_ids=None):
         cfg = self.cfg
         B, S, _ = y.shape
         h, nope, rope = (cfg.num_heads, cfg.qk_nope_head_dim,
@@ -729,7 +736,7 @@ class LatentAttention(nn.Module):
                 [kv[..., :nope], jnp.broadcast_to(k_rope, (B, S, h, rope))],
                 axis=-1)
             attn = self.attn_impl(q, k, kv[..., nope:], causal=cfg.causal,
-                                  scale=scale)
+                                  scale=scale, **_documents(segment_ids))
         with timeline.device_scope("bf.mla.out"):
             return dense(cfg.embed_dim, name="proj")(
                 attn.reshape(B, S, h * dv))
@@ -946,6 +953,13 @@ class Mamba2Mixer(nn.Module):
             return dense(cfg.embed_dim, name="out")(gated)
 
 
+def _documents(segment_ids) -> dict:
+    """What an ``attn_impl`` is handed beside q, k and v for a packed row:
+    nothing without ids, so that one that knows none serves every model
+    that packs nothing."""
+    return {} if segment_ids is None else {"segment_ids": segment_ids}
+
+
 def block_class(cfg, layer_idx: int = None):
     """The (possibly remat-wrapped) Block class for a config — shared by
     ``TransformerLM`` and ``models.vit.ViT`` so ``remat_policy`` behaves
@@ -979,7 +993,7 @@ class Block(nn.Module):
                             # cfg.dense_layers keep the dense MLP
 
     @nn.compact
-    def __call__(self, x, positions=None, cache=None):
+    def __call__(self, x, positions=None, cache=None, segment_ids=None):
         """Training/prefill path when ``cache is None``; with ``cache =
         (k_cache, v_cache)`` (shapes ``(B, L, kv_h, d)``) the input is ONE
         new token per sequence (S == 1) written at position ``positions``
@@ -995,6 +1009,13 @@ class Block(nn.Module):
         takes a cache; latent attention, a sliding window, the gated short
         convolution and the Mamba-2 mixer keep no decode state and raise on
         one, as does a block without a mixer.
+
+        ``segment_ids`` ``(B, S)``: the documents of a packed row
+        (``data.pack_documents``; ``positions`` restart with them).  Full
+        attention, plain or latent, hands them to ``attn_impl``; a block
+        that cannot keep the documents apart raises (a convolution's taps
+        and a scan's state would have to be reset at a boundary, and a
+        window's kernels know no document mask), as does a decode cache.
 
         Plain attention reads its sizes by layer: ``cfg.head_dim`` (None:
         ``embed_dim // num_heads``), the block's entry of
@@ -1043,6 +1064,16 @@ class Block(nn.Module):
                 "only plain full attention takes a decode cache: latent "
                 "attention, a sliding window, the gated short convolution "
                 "and the Mamba-2 mixer do not")
+        if segment_ids is not None and (conv or mamba or sliding
+                                         or cache is not None):
+            raise NotImplementedError(
+                f"segment_ids reached a {kind!r} block"
+                + (" with a decode cache" if cache is not None else "")
+                + ": a packed row needs the gated short convolution's taps "
+                "and the Mamba-2 scan's state reset at every boundary, a "
+                "window's kernels masked by document and a cache written "
+                "by document; only full attention (plain or latent) keeps "
+                "documents apart")
         if conv:
             x = join(ShortConv(cfg, name="conv")(y))
             return ffn(x, eps)
@@ -1051,7 +1082,7 @@ class Block(nn.Module):
             return ffn(x, eps)
         if latent:
             x = join(LatentAttention(cfg, self.attn_impl, name="mla")(
-                y, positions))
+                y, positions, **_documents(segment_ids)))
             return ffn(x, eps)
         scope = "bf.swa" if sliding else "bf.attn"
         with timeline.device_scope(f"{scope}.qkv"):
@@ -1116,7 +1147,7 @@ class Block(nn.Module):
                 reach = {"window": cfg.sliding_window} if sliding else {}
                 attn = self.attn_impl(
                     q, k, v, causal=getattr(self.cfg, "causal", True),
-                    **reach)
+                    **reach, **_documents(segment_ids))
         else:
             ck, cv = cache
             idx = positions[0, 0]  # decode positions are batch-uniform
@@ -1196,9 +1227,14 @@ class TransformerLM(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, train: bool = True, positions=None,
-                 return_hidden: bool = False, cache=None):
+                 return_hidden: bool = False, cache=None, segment_ids=None):
         """``positions``: optional (B, S) global position ids — required when
         the sequence axis is sharded (each shard must embed its own offset).
+        ``segment_ids``: optional (B, S) document ids of packed rows, not
+        decreasing along a row (``data.pack_documents`` yields them with
+        the ``positions`` that restart at each document): every block's
+        attention then keeps to a token's own document, and a block that
+        cannot raises.
         ``return_hidden``: skip the lm-head and return the final normalized
         activations (B, S, E) — pair with
         ``ops.chunked_loss.chunked_softmax_cross_entropy`` so very long
@@ -1217,6 +1253,10 @@ class TransformerLM(nn.Module):
             telemetry.set_gauge("bf_model_layers_total",
                                 mixers.count(kind), mixer=kind)
         if cache is not None:
+            if segment_ids is not None:
+                raise NotImplementedError(
+                    "KV-cache decoding of a packed row is not supported: "
+                    "the cache is written by position, not by document")
             if getattr(cfg, "num_experts", 0) > 0:
                 raise NotImplementedError(
                     "KV-cache decoding with MoE blocks is not supported")
@@ -1282,6 +1322,8 @@ class TransformerLM(nn.Module):
             if cache is not None:
                 x, blk_cache = blk(x, positions, cache[i])
                 new_cache.append(blk_cache)
+            elif segment_ids is not None:
+                x = blk(x, positions, None, segment_ids)
             elif rope:
                 x = blk(x, positions)
             else:

@@ -48,6 +48,23 @@ step is handed the band's key blocks as pieces and each row chunk runs one
 softmax over its visible range (``_fwd_band_kernel``), since under a narrow
 window a step of the running state cost more than the products it served.
 
+A document mask (``segment_ids``, with ``causal``: query ``i`` sees the keys
+``j <= i`` of its own document; packed rows) runs as ``bf_flash_seg_fwd / dq
+/ dkv``.  Where the visible pairs end is data, so the kernels read it ahead
+of the grid: the wrapper turns the ids into every position's *bound* (a
+query's: its document's first position; a key's, in ``bf_flash_seg_dkv``:
+its document's last), and the bounds of the first and last position of
+every 256 are scalars in SMEM (``_Docs``).  The grid walks the tiles at or
+under the diagonal and no others (36 steps a head at S 8192 where the causal
+grid has 64); a query block's steps count from the key block where its first
+query's document begins, so a tile whose keys all lie in earlier documents
+runs no product and moves no block: the steps it leaves over, behind the
+diagonal's, repeat a block and run nothing.  A tile inside one document runs the causal bodies above
+as they are.  A tile that a boundary crosses works in chunks of 256 own
+rows, each on its visible range of the other dimension rounded out to whole
+256 (a body for each length, the start traced), one step of the softmax
+state a chunk.
+
 What bounds a tile (one v5e chip, PR 37, ``PERF.md``): the backward runs its
 products at 85 to 91% of the array's peak; the forward spends 1.1 to 1.4 us
 of every 4.2 us tile on the softmax state (two reductions along the lanes,
@@ -76,7 +93,7 @@ from jax.experimental.pallas import tpu as pltpu
 from bluefog_tpu.utils import telemetry
 
 __all__ = ["flash_attention", "flash_attention_lse",
-           "flash_attention_impl", "platform_in_use"]
+           "flash_attention_impl", "platform_in_use", "segment_tiles"]
 
 _NEG_INF = -1e30
 
@@ -154,6 +171,146 @@ class _Band(NamedTuple):
         past the last block it reaches is not live."""
         first, last = self.reach(np_, i)
         return first + step, first + step <= last
+
+
+# Of a tile that a document boundary crosses: the own rows of one chunk, and
+# the unit its visible range of the other dimension is rounded to (one v5e
+# chip, PR 47: PERF.md).
+_DOC_ROWS = 256
+_DOC_KEYS = 256
+
+
+def _doc_chunk(block: int, want: int) -> int:
+    return want if block % want == 0 else block
+
+
+class _Docs(NamedTuple):
+    """What a kernel of a packed call (``segment_ids``) knows of the
+    documents: of every position of its *own* dimension (the queries; the
+    keys in ``bf_flash_dkv``, ``by_keys``) the ``bound`` of what it sees of
+    the other under the causal mask: a query ``i`` the keys from its
+    document's first position to ``i``, a key ``j`` the queries from ``j``
+    to its document's last position.  ``first`` and ``last`` (SMEM, ``B *
+    seq / granule``): the bound of the first and of the last position of
+    every ``granule`` own positions, a row of the batch after the other;
+    ``bounds`` (VMEM, ``(own block, 1)``): of every own position of the
+    tile.  Documents are contiguous, so the bounds do not decrease: the
+    first own position's bound and the last's say, without a look at the
+    rest, where a block's visible range begins and ends, whether a tile
+    lies whole outside it (no product, no block moved) and whether it holds
+    one document."""
+    first: object
+    last: object
+    own_of: object  # SMEM: of every step of a head's grid its own block
+    step_of: object     # and which of that block's steps it is
+    bounds: object
+    by_keys: bool
+    granule: int
+    seq: int
+    row: object     # of the batch: the grid's first index over the heads
+
+    @classmethod
+    def of(cls, packed, refs) -> tuple:
+        """``(docs, the kernel's other refs)`` from ``packed = (heads,
+        by_keys, granule, seq)``; ``(None, refs)`` for a call that is not
+        packed."""
+        if packed is None:
+            return None, refs
+        return cls(*refs[:5], *packed[1:],
+                   row=pl.program_id(0) // packed[0]), refs[5:]
+
+    def tile(self, block_q: int, block_k: int) -> tuple:
+        """``(own block, step, the block's last step)`` of this grid step:
+        a packed grid walks the tiles at or under the diagonal one after
+        the other (``_under_diagonal``) and has no steps above it."""
+        own = self.own_of[pl.program_id(1)]
+        return (own, self.step_of[pl.program_id(1)],
+                _last_step(own, block_q, block_k, self.seq, self.by_keys))
+
+    def reach(self, at, n: int) -> tuple:
+        """``(lo, hi)``: the positions of the other dimension that the ``n``
+        own positions from ``at`` on (whole granules) see between them."""
+        base = self.row * (self.seq // self.granule)
+        if self.by_keys:
+            return at, self.last[base + (at + n) // self.granule - 1]
+        return self.first[base + at // self.granule], at + n - 1
+
+    def one_document(self, at, n: int, other_at, m: int):
+        """Whether the ``n`` own positions from ``at`` on see no position of
+        another document among the ``m`` others from ``other_at`` on."""
+        base = self.row * (self.seq // self.granule)
+        if self.by_keys:    # the queries end before the first key's document
+            return other_at + m - 1 <= self.first[base + at // self.granule]
+        return other_at >= self.last[base + (at + n) // self.granule - 1]
+
+    def on_tiles(self, tile, rows, qi, kb, live, block_q: int, block_k: int):
+        """Run the tile ``(qi, kb)`` of a packed causal grid as it needs.
+        Nothing where it is not ``live`` (a step past the block's reach).
+        ``tile`` as ``_on_tiles`` runs it where the tile holds one document.
+        Where a boundary crosses it: chunk by chunk of own rows, ``rows`` on
+        the chunk's visible range of the other dimension, rounded out to
+        whole ``_DOC_KEYS`` (a body for each length, the start traced), the
+        scores masked by the rows' bounds and by the diagonal; a chunk that
+        sees nothing of the tile runs nothing."""
+        q_at, k_at = qi * block_q, kb * block_k
+        own_at, other_at, block, other = (
+            (k_at, q_at, block_k, block_q) if self.by_keys
+            else (q_at, k_at, block_q, block_k))
+        inside = self.one_document(own_at, block, other_at, other)
+        _on_tiles(tile, qi, kb, causal=True, block_q=block_q,
+                  block_k=block_k, live=live & inside)
+        chunk = _doc_chunk(block, _DOC_ROWS)
+        unit = _doc_chunk(other, _DOC_KEYS)
+
+        @pl.when(live & jnp.logical_not(inside))
+        def _crossed():
+            for r0 in range(0, block, chunk):
+                lo, hi = self.reach(own_at + r0, chunk)
+                lo = jnp.maximum(lo - other_at, 0) // unit
+                count = jnp.minimum(hi - other_at, other - 1) // unit + 1 - lo
+                start = pl.multiple_of(lo * unit, unit)
+                own = slice(r0, r0 + chunk)
+                see = functools.partial(
+                    self.visible, own=own, own_at=own_at + r0,
+                    other_at=other_at + start)
+                for units in range(1, other // unit + 1):
+                    pl.when(count == units)(functools.partial(
+                        rows, own, pl.ds(start, units * unit), see))
+
+    def visible(self, s, *, own, own_at, other_at):
+        """``s`` (own positions along the rows, from ``own_at``; the others
+        along the lanes, from ``other_at``: both traced) with the pairs of
+        two documents and those whose key is past their query at
+        ``-1e30``."""
+        along = lambda axis, first: first + jax.lax.broadcasted_iota(
+            jnp.int32, tuple(n if a == axis else 1
+                             for a, n in enumerate(s.shape)), axis)
+        rows, others, bounds = along(0, own_at), along(1, other_at), \
+            self.bounds[own, :]
+        seen = ((others <= bounds) & (others >= rows) if self.by_keys
+                else (others >= bounds) & (others <= rows))
+        return jnp.where(seen, s, _NEG_INF)
+
+
+def _last_step(own, block_q: int, block_k: int, seq: int, by_keys: bool):
+    """The last step of an own block of a causal grid whose steps count
+    from the block's first tile at or under the diagonal: a query block's
+    are the key blocks up to its diagonal, a key block's the query blocks
+    from its frontier on."""
+    if by_keys:
+        return seq // block_q - 1 - (own * block_k) // block_q
+    return ((own + 1) * block_q - 1) // block_k
+
+
+def _under_diagonal(block_q: int, block_k: int, seq: int, by_keys: bool
+                    ) -> tuple:
+    """``(own_of, step_of)``: the tiles at or under the diagonal of one
+    head, own block after own block, each block's steps in order."""
+    n_own = seq // (block_k if by_keys else block_q)
+    steps = [int(_last_step(i, block_q, block_k, seq, by_keys)) + 1
+             for i in range(n_own)]
+    return (np.repeat(np.arange(n_own, dtype=np.int32), steps),
+            np.concatenate([np.arange(n, dtype=np.int32) for n in steps]))
 
 
 def _step_of(band, i, step) -> tuple:
@@ -251,17 +408,25 @@ def _visible(s, *, key0, query0, keys_axis: int, window: int = None,
     return jnp.where(functools.reduce(jnp.logical_and, edges), s, _NEG_INF)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale: float, causal: bool, block_q: int, block_k: int,
-                band: _Band = None):
+def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
+                block_k: int, band: _Band = None, packed: tuple = None):
     """Grid (bh, q-block, k-block): online-softmax recurrence with the
     running (acc, m, l) state in f32 VMEM scratch across the sequential
     innermost k dimension.  Every operand is a block — VMEM stays O(block),
     so sequence length is bounded by HBM, not VMEM.  In a windowed grid
     (``band``) the innermost dimension counts from the first key block the
-    query block reaches."""
-    qi, step = pl.program_id(1), pl.program_id(2)
-    kb, live, window = _step_of(band, qi, step)
+    query block reaches; in a ``packed`` one (``_Docs``) from the first key
+    block that holds a document of the query block's."""
+    docs, (q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
+           l_ref) = _Docs.of(packed, refs)
+    if docs is None:
+        qi, step = pl.program_id(1), pl.program_id(2)
+        kb, live, window = _step_of(band, qi, step)
+    else:
+        qi, step, last = docs.tile(block_q, block_k)
+        kb, window = docs.reach(qi * block_q, block_q)[0] // block_k + step, \
+            None
+        live = kb <= last       # the diagonal's block
 
     @pl.when(step == 0)
     def _init():
@@ -269,34 +434,43 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
+    def rows(own, keys, see=None):
+        """One step of the state of the queries ``own`` over ``keys``;
+        ``see`` masks the scores."""
+        q = q_ref[own, :].astype(jnp.float32)          # (rows, D)
+        k = k_ref[keys, :].astype(jnp.float32)         # (keys, D)
+        v = v_ref[keys, :].astype(jnp.float32)         # (keys, Dv)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        if see is not None:
+            s = see(s)
+        m_prev = m_ref[own, :]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[own, :] = l_ref[own, :] * corr + p.sum(
+            axis=-1, keepdims=True)
+        acc_ref[own, :] = acc_ref[own, :] * corr + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_ref[own, :] = m_new
+
     def tile(offset):
-        rows = _chunk_rows(offset, block_q, _FWD_CHUNK)
-        for q0 in range(0, block_q, rows):
-            lo, hi, masked = _keys_of(offset, q0, rows, block_k, window)
+        chunk = _chunk_rows(offset, block_q, _FWD_CHUNK)
+        for q0 in range(0, block_q, chunk):
+            lo, hi, masked = _keys_of(offset, q0, chunk, block_k, window)
             if hi == lo:
                 continue
-            own = slice(q0, q0 + rows)
-            q = q_ref[own, :].astype(jnp.float32)          # (rows, D)
-            k = k_ref[lo:hi, :].astype(jnp.float32)        # (hi - lo, D)
-            v = v_ref[lo:hi, :].astype(jnp.float32)        # (hi - lo, Dv)
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-            if masked:
-                s = _visible(s, key0=lo, query0=q0 + offset, keys_axis=1,
-                             window=window)
-            m_prev = m_ref[own, :]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[own, :] = l_ref[own, :] * corr + p.sum(
-                axis=-1, keepdims=True)
-            acc_ref[own, :] = acc_ref[own, :] * corr + jnp.dot(
-                p, v, preferred_element_type=jnp.float32)
-            m_ref[own, :] = m_new
+            rows(slice(q0, q0 + chunk), slice(lo, hi),
+                 functools.partial(_visible, key0=lo, query0=q0 + offset,
+                                   keys_axis=1, window=window)
+                 if masked else None)
 
-    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k,
-              window=window, live=live)
+    if docs is None:
+        _on_tiles(tile, qi, kb, causal=causal, block_q=block_q,
+                  block_k=block_k, window=window, live=live)
+    else:
+        docs.on_tiles(tile, rows, qi, kb, live, block_q, block_k)
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    @pl.when(step == (pl.num_programs(2) - 1 if docs is None else last))
     def _store():
         l = jnp.maximum(l_ref[:], 1e-30)
         o_ref[:] = (acc_ref[:] / l).astype(o_ref.dtype)
@@ -418,25 +592,34 @@ def _grid_offsets(n_qb: int, n_kb: int, block_q: int, block_k: int,
     return offsets
 
 
-def _kernel_name(kind: str, band) -> str:
-    """``bf_flash_<kind>``, or ``bf_flash_win_<kind>`` for a windowed call:
-    the device trace tells a model's window layers from its full ones."""
-    return f"bf_flash_{'win_' if band is not None else ''}{kind}"
+def _kernel_name(kind: str, band, packed: bool = False) -> str:
+    """``bf_flash_<kind>``, ``bf_flash_win_<kind>`` for a windowed call or
+    ``bf_flash_seg_<kind>`` for a packed one: the device trace tells a
+    model's window layers from its full ones, and packed rows from whole."""
+    return (f"bf_flash_{'seg_' if packed else ''}"
+            f"{'win_' if band is not None else ''}{kind}")
 
 
 def _staged(kind: str, heads: int, seq: int, block_q: int, block_k: int,
-            causal: bool, band: _Band = None):
+            causal: bool, band: _Band = None, packed: bool = False):
     """Count one staging of the kernel ``kind`` and the tiles of its call
     by kind (``heads`` grids of ``_grid_offsets``; a non-causal call's are
-    all interior, a windowed grid's dead steps are skipped).  A wrapper's
-    Python runs at trace time: once a shape behind ``jax.jit``, once a call
-    for a bare kernel, and each staging is a Mosaic lowering."""
-    kernel = _kernel_name(kind, band)
+    all interior, a windowed grid's dead steps are skipped; a ``packed``
+    call's grid is the tiles at or under the diagonal, all ``by_data``: the
+    documents decide on the device which of them run).  A wrapper's Python runs at
+    trace time: once a shape behind ``jax.jit``, once a call for a bare
+    kernel, and each staging is a Mosaic lowering."""
+    kernel = _kernel_name(kind, band, packed)
     telemetry.inc("bf_kernel_stagings_total", kernel=kernel)
     offsets = _grid_offsets(seq // block_q, seq // block_k, block_q, block_k,
                             band)
     last_interior = math.inf if band is None else band.window - block_q
     skipped = sum(o is None or (causal and o <= -block_q) for o in offsets)
+    if packed:      # its grid has no tile past the diagonal
+        telemetry.inc("bf_flash_tiles_total",
+                      heads * (len(offsets) - skipped), kernel=kernel,
+                      kind="by_data")
+        return
     interior = sum(o is not None and (
         not causal or block_k - 1 <= o <= last_interior) for o in offsets)
     for tiles, n in (("skipped", skipped), ("interior", interior),
@@ -525,7 +708,7 @@ def _fwd_call(qf, kf, vf, *, scale, causal, block_q, block_k, interpret,
 
 
 def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
-         scale=None, window=None):
+         scale=None, window=None, ids=None):
     B, S, H, D = q.shape
     Dv = v.shape[-1]
     if scale is None:
@@ -536,105 +719,142 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
     block_q = _fit_block(block_q, S)
     block_k = _fit_block(_window_block(block_k, window), S)
     _staged("fwd", bh, S, block_q, block_k, causal,
-            _Band.pair(window, block_q, block_k, S)[0])
-    o, lse = _fwd_call(qf, kf, vf, scale=float(scale), causal=causal,
-                       block_q=block_q, block_k=block_k, interpret=interpret,
-                       vma=vma, window=window)
+            _Band.pair(window, block_q, block_k, S)[0], ids is not None)
+    call = _fwd_call if ids is None else functools.partial(
+        _packed_fwd_call, ids=ids)
+    o, lse = call(qf, kf, vf, scale=float(scale), causal=causal,
+                  block_q=block_q, block_k=block_k, interpret=interpret,
+                  vma=vma, window=window)
     lse = lse[..., 0]
     unfold = lambda t: t.reshape(B, H, S, Dv).transpose(0, 2, 1, 3)
     return unfold(o), (qf, kf, vf, o, lse, (B, S, H, D, scale, causal))
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, scale: float, causal: bool, block_q: int,
-               block_k: int, band: _Band = None):
+def _dq_kernel(*refs, scale: float, causal: bool, block_q: int,
+               block_k: int, band: _Band = None, packed: tuple = None):
     """Grid (bh, q-block, k-block): recompute P from the saved logsumexp and
     accumulate ds @ K into a f32 VMEM scratch across the (sequential,
     innermost) k dimension; one cast-and-store to the output block on the
     last step.  Every operand is a block — VMEM stays O(block), never
     O(S)."""
-    qi, step = pl.program_id(1), pl.program_id(2)
-    kb, live, window = _step_of(band, qi, step)
+    docs, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+           acc_ref) = _Docs.of(packed, refs)
+    if docs is None:
+        qi, step = pl.program_id(1), pl.program_id(2)
+        kb, live, window = _step_of(band, qi, step)
+    else:
+        qi, step, last = docs.tile(block_q, block_k)
+        kb, window = docs.reach(qi * block_q, block_q)[0] // block_k + step, \
+            None
+        live = kb <= last       # the diagonal's block
 
     @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
+    def rows(own, keys, see=None):
+        q = q_ref[own, :].astype(jnp.float32)          # (rows, D)
+        k = k_ref[keys, :].astype(jnp.float32)         # (keys, D)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        if see is not None:
+            s = see(s)
+        p = jnp.exp(s - lse_ref[own, :])               # masked -> 0
+        dp = jnp.dot(do_ref[own, :].astype(jnp.float32),
+                     v_ref[keys, :].astype(jnp.float32).T,
+                     preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[own, :]) * scale
+        acc_ref[own, :] += jnp.dot(ds, k,
+                                   preferred_element_type=jnp.float32)
+
     def tile(offset):
-        rows = _chunk_rows(offset, block_q, _BWD_CHUNK)
-        for q0 in range(0, block_q, rows):
-            lo, hi, masked = _keys_of(offset, q0, rows, block_k, window)
+        chunk = _chunk_rows(offset, block_q, _BWD_CHUNK)
+        for q0 in range(0, block_q, chunk):
+            lo, hi, masked = _keys_of(offset, q0, chunk, block_k, window)
             if hi == lo:
                 continue
-            own = slice(q0, q0 + rows)
-            q = q_ref[own, :].astype(jnp.float32)          # (rows, D)
-            k = k_ref[lo:hi, :].astype(jnp.float32)        # (hi - lo, D)
-            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-            if masked:
-                s = _visible(s, key0=lo, query0=q0 + offset, keys_axis=1,
-                             window=window)
-            p = jnp.exp(s - lse_ref[own, :])               # masked -> 0
-            dp = jnp.dot(do_ref[own, :].astype(jnp.float32),
-                         v_ref[lo:hi, :].astype(jnp.float32).T,
-                         preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_ref[own, :]) * scale
-            acc_ref[own, :] += jnp.dot(ds, k,
-                                       preferred_element_type=jnp.float32)
+            rows(slice(q0, q0 + chunk), slice(lo, hi),
+                 functools.partial(_visible, key0=lo, query0=q0 + offset,
+                                   keys_axis=1, window=window)
+                 if masked else None)
 
-    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k,
-              window=window, live=live)
+    if docs is None:
+        _on_tiles(tile, qi, kb, causal=causal, block_q=block_q,
+                  block_k=block_k, window=window, live=live)
+    else:
+        docs.on_tiles(tile, rows, qi, kb, live, block_q, block_k)
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    @pl.when(step == (pl.num_programs(2) - 1 if docs is None else last))
     def _store():
         dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                causal: bool, block_q: int, block_k: int,
-                band: _Band = None):
+def _dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
+                block_k: int, band: _Band = None, packed: tuple = None):
     """Grid (bh, k-block, q-block): accumulate P.T @ dO and ds.T @ Q into f32
     VMEM scratches across the (sequential, innermost) q dimension.  The
     scores are computed as ``k q^T``, (BK, BQ) with the keys along the rows
     as the accumulators have them, so no (BQ, BK) tile is transposed; the
     per-query ``lse`` and ``delta`` columns are turned to rows once a
-    tile."""
-    kb, step = pl.program_id(1), pl.program_id(2)
-    qi, live, window = _step_of(band, kb, step)
+    tile.  In a ``packed`` grid the steps stop at the last query block that
+    holds a document of the key block's."""
+    docs, (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+           dk_acc, dv_acc) = _Docs.of(packed, refs)
+    if docs is None:
+        kb, step = pl.program_id(1), pl.program_id(2)
+        qi, live, window = _step_of(band, kb, step)
+    else:
+        kb, step, last = docs.tile(block_q, block_k)
+        qi, window = (kb * block_k) // block_q + step, None
+        live = qi <= docs.reach(kb * block_k, block_k)[1] // block_q
 
     @pl.when(step == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    def rows(own, queries, see=None, turned=None):
+        """The keys ``own`` against ``queries``; ``turned``: the tile's
+        ``lse`` and ``delta`` as rows ``(1, BQ)`` where the caller has
+        turned them, else the queries' own are turned here."""
+        if turned is None:
+            lse, delta, per_query = lse_ref, delta_ref, \
+                lambda ref: ref[queries, :].T
+        else:
+            (lse, delta), per_query = turned, lambda row: row[:, queries]
+        q = q_ref[queries, :].astype(jnp.float32)      # (queries, D)
+        do = do_ref[queries, :].astype(jnp.float32)
+        st = jnp.dot(k_ref[own, :].astype(jnp.float32), q.T,
+                     preferred_element_type=jnp.float32) * scale
+        if see is not None:
+            st = see(st)
+        pt = jnp.exp(st - per_query(lse))              # masked -> 0
+        dpt = jnp.dot(v_ref[own, :].astype(jnp.float32), do.T,
+                      preferred_element_type=jnp.float32)
+        dst = pt * (dpt - per_query(delta)) * scale
+        dv_acc[own, :] += jnp.dot(pt, do,
+                                  preferred_element_type=jnp.float32)
+        dk_acc[own, :] += jnp.dot(dst, q,
+                                  preferred_element_type=jnp.float32)
+
     def tile(offset):
         lse, delta = lse_ref[:].T, delta_ref[:].T          # (1, BQ)
-        rows = _chunk_rows(offset, block_k, _BWD_CHUNK)
-        for k0 in range(0, block_k, rows):
-            lo, hi, masked = _queries_of(offset, k0, rows, block_q, window)
+        chunk = _chunk_rows(offset, block_k, _BWD_CHUNK)
+        for k0 in range(0, block_k, chunk):
+            lo, hi, masked = _queries_of(offset, k0, chunk, block_q, window)
             if hi == lo:
                 continue
-            own = slice(k0, k0 + rows)
-            q = q_ref[lo:hi, :].astype(jnp.float32)        # (hi - lo, D)
-            do = do_ref[lo:hi, :].astype(jnp.float32)
-            st = jnp.dot(k_ref[own, :].astype(jnp.float32), q.T,
-                         preferred_element_type=jnp.float32) * scale
-            if masked:
-                st = _visible(st, key0=k0, query0=lo + offset, keys_axis=0,
-                              window=window)
-            pt = jnp.exp(st - lse[:, lo:hi])               # masked -> 0
-            dpt = jnp.dot(v_ref[own, :].astype(jnp.float32), do.T,
-                          preferred_element_type=jnp.float32)
-            dst = pt * (dpt - delta[:, lo:hi]) * scale
-            dv_acc[own, :] += jnp.dot(pt, do,
-                                      preferred_element_type=jnp.float32)
-            dk_acc[own, :] += jnp.dot(dst, q,
-                                      preferred_element_type=jnp.float32)
+            rows(slice(k0, k0 + chunk), slice(lo, hi),
+                 functools.partial(_visible, key0=k0, query0=lo + offset,
+                                   keys_axis=0, window=window)
+                 if masked else None, (lse, delta))
 
-    _on_tiles(tile, qi, kb, causal=causal, block_q=block_q, block_k=block_k,
-              window=window, live=live)
+    if docs is None:
+        _on_tiles(tile, qi, kb, causal=causal, block_q=block_q,
+                  block_k=block_k, window=window, live=live)
+    else:
+        docs.on_tiles(tile, rows, qi, kb, live, block_q, block_k)
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    @pl.when(step == (pl.num_programs(2) - 1 if docs is None else last))
     def _store():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -700,7 +920,8 @@ def _bwd_calls(qf, kf, vf, dof, lse3, delta, *, scale, causal, block_q,
     return dq, dk, dv
 
 
-def _bwd(block_q, block_k, interpret, vma, window, res, cotangents):
+def _bwd(block_q, block_k, interpret, vma, window, res, cotangents,
+         ids=None):
     """Flash backward as two Pallas kernels (dq accumulating over k-blocks;
     dk/dv accumulating over q-blocks) — O(block) VMEM, O(S) HBM, and no
     S x S materialization anywhere.
@@ -731,14 +952,163 @@ def _bwd(block_q, block_k, interpret, vma, window, res, cotangents):
         block_k = _fit_block(512, S)
     for kind, band in zip(("dq", "dkv"),
                           _Band.pair(window, block_q, block_k, S)):
-        _staged(kind, bh, S, block_q, block_k, causal, band)
+        _staged(kind, bh, S, block_q, block_k, causal, band, ids is not None)
+    calls = _bwd_calls if ids is None else functools.partial(
+        _packed_bwd_calls, ids=ids)
     # (the residuals' Python scalars come back as jax literals: not hashable)
-    dq, dk, dv = _bwd_calls(qf, kf, vf, dof, lse[..., None], delta,
-                            scale=float(scale), causal=bool(causal),
-                            block_q=block_q, block_k=block_k,
-                            interpret=interpret, vma=vma, window=window)
+    dq, dk, dv = calls(qf, kf, vf, dof, lse[..., None], delta,
+                       scale=float(scale), causal=bool(causal),
+                       block_q=block_q, block_k=block_k,
+                       interpret=interpret, vma=vma, window=window)
     unfold = lambda t: t.reshape(B, H, S, t.shape[-1]).transpose(0, 2, 1, 3)
     return unfold(dq), unfold(dk), unfold(dv)
+
+
+def _bounds(ids, block: int, by_keys: bool) -> tuple:
+    """What a packed grid knows of the documents ``ids`` ``(B, S)``, as
+    ``_Docs`` has it: ``((first, last), bounds, granule)``.  ``bounds`` ``(B,
+    S, 1)``: of every position the first position of its document (the
+    last, ``by_keys``), found from where the ids change along a row;
+    ``first`` and ``last``, read ahead of the grid: the bounds of the first
+    and of the last position of every ``granule`` positions, flat; the
+    granule is a chunk of the own ``block``."""
+    B, S = ids.shape
+    at = jnp.arange(S, dtype=jnp.int32)
+    changes = ids[:, 1:] != ids[:, :-1]
+    edge = jnp.ones((B, 1), bool)
+    if by_keys:
+        bounds = jax.lax.cummin(jnp.where(jnp.concatenate(
+            [changes, edge], axis=1), at, S - 1), axis=1, reverse=True)
+    else:
+        bounds = jax.lax.cummax(jnp.where(jnp.concatenate(
+            [edge, changes], axis=1), at, 0), axis=1)
+    granule = _doc_chunk(block, _DOC_ROWS)
+    return ((bounds[:, ::granule].reshape(-1),
+             bounds[:, granule - 1::granule].reshape(-1)),
+            bounds[:, :, None], granule)
+
+
+def _packed_specs(heads: int) -> tuple:
+    """Block specs of a packed grid's operands: ``at(block, dim)(sel)`` as
+    in ``_bwd_calls``, ``sel`` handed the own block and the step of the
+    grid's step (``_under_diagonal``) and the bounds' scalars behind them,
+    and ``column(block, sel)`` for the bounds ``(B, S, 1)``, one row of the
+    batch for its heads."""
+    at = lambda block, dim: lambda sel: pl.BlockSpec(
+        (None, block, dim), lambda b, t, first, last, own, step: (
+            b, sel(own[t], step[t], b, first, last), 0))
+    column = lambda block, sel: pl.BlockSpec(
+        (None, block, 1), lambda b, t, first, last, own, step: (
+            b // heads, sel(own[t], step[t], b, first, last), 0))
+    return at, column
+
+
+def _reach_by_queries(heads: int, seq: int, block_q: int, block_k: int,
+                      granule: int):
+    """``(i, j, b, first, last) ->`` the key block of step ``j`` of query
+    block ``i``: from the block where the first query's document begins to
+    the diagonal (a step past it repeats the diagonal's block: no DMA)."""
+    def red(i, j, b, first, last):
+        lo = first[b // heads * (seq // granule) + i * (block_q // granule)]
+        return jnp.minimum(lo // block_k + j,
+                           ((i + 1) * block_q - 1) // block_k)
+    return red
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _packed_fwd_call(qf, kf, vf, *, ids, scale, causal, block_q, block_k,
+                     interpret, vma, window=None):
+    """``bf_flash_seg_fwd`` on folded operands and ``ids`` ``(B, S)``: ``o``
+    and the per-row logsumexp ``(B*H, S, 1)``."""
+    bh, S, D = qf.shape
+    Dv, heads = vf.shape[-1], bh // ids.shape[0]
+    scalars, bounds, granule = _bounds(ids, block_q, False)
+    tiles = _under_diagonal(block_q, block_k, S, False)
+    own = lambda i, j, *_: i
+    red = _reach_by_queries(heads, S, block_q, block_k, granule)
+    at, column = _packed_specs(heads)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, causal=True,
+                          block_q=block_q, block_k=block_k,
+                          packed=(heads, False, granule, S)),
+        name=_kernel_name("fwd", None, True),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(bh, len(tiles[0])),
+            in_specs=[column(block_q, own), at(block_q, D)(own),
+                      at(block_k, D)(red), at(block_k, Dv)(red)],
+            out_specs=[at(block_q, Dv)(own), at(block_q, 1)(own)],
+            scratch_shapes=[pltpu.VMEM((block_q, Dv), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32),
+                            pltpu.VMEM((block_q, 1), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((bh, S, Dv), qf.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((bh, S, 1), jnp.float32, vma=vma)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*scalars, *tiles, bounds, qf, kf, vf)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _packed_bwd_calls(qf, kf, vf, dof, lse3, delta, *, ids, scale, causal,
+                      block_q, block_k, interpret, vma, window=None):
+    """``bf_flash_seg_dq`` and ``bf_flash_seg_dkv``: ``dq, dk, dv``."""
+    bh, S, D = qf.shape
+    Dv, heads = vf.shape[-1], bh // ids.shape[0]
+    own = lambda i, j, *_: i
+    params = dict(
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret)
+    kernel = dict(scale=scale, causal=True, block_q=block_q, block_k=block_k)
+    at, column = _packed_specs(heads)
+    q_at, k_at = at(block_q, D), at(block_k, D)
+    do_at, v_at, r_at = at(block_q, Dv), at(block_k, Dv), at(block_q, 1)
+
+    scalars, bounds, granule = _bounds(ids, block_q, False)
+    tiles = _under_diagonal(block_q, block_k, S, False)
+    red_dq = _reach_by_queries(heads, S, block_q, block_k, granule)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, **kernel,
+                          packed=(heads, False, granule, S)),
+        name=_kernel_name("dq", None, True),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(bh, len(tiles[0])),
+            in_specs=[column(block_q, own), q_at(own), k_at(red_dq),
+                      v_at(red_dq), do_at(own), r_at(own), r_at(own)],
+            out_specs=q_at(own),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((bh, S, D), qf.dtype, vma=vma),
+        **params,
+    )(*scalars, *tiles, bounds, qf, kf, vf, dof, lse3, delta)
+
+    scalars, bounds, granule = _bounds(ids, block_k, True)
+    tiles = _under_diagonal(block_q, block_k, S, True)
+
+    def red_kv(i, j, b, first, last):
+        """From the key block's frontier to the query block where its last
+        key's document ends."""
+        hi = last[b // heads * (S // granule)
+                  + (i + 1) * (block_k // granule) - 1]
+        return jnp.minimum((i * block_k) // block_q + j, hi // block_q)
+
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, **kernel,
+                          packed=(heads, True, granule, S)),
+        name=_kernel_name("dkv", None, True),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(bh, len(tiles[0])),
+            in_specs=[column(block_k, own), q_at(red_kv), k_at(own),
+                      v_at(own), do_at(red_kv), r_at(red_kv), r_at(red_kv)],
+            out_specs=[k_at(own), v_at(own)],
+            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
+                            pltpu.VMEM((block_k, Dv), jnp.float32)]),
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, S, D), kf.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, S, Dv), vf.dtype, vma=vma),
+        ],
+        **params,
+    )(*scalars, *tiles, bounds, qf, kf, vf, dof, lse3, delta)
+    return dq, dk, dv
 
 
 def _lse_bsh(lse, B, S, H):
@@ -772,6 +1142,36 @@ def _flash_bwd(causal, block_q, block_k, interpret, vma, scale, window, res,
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_packed(q, k, v, ids, block_q, block_k, interpret, vma=None,
+                  scale=None):
+    """``_flash`` under the causal mask and the documents ``ids`` ``(B,
+    S)``: a rule of its own, so that a call without ids stages what it
+    staged before there were any."""
+    return _flash_packed_fwd(q, k, v, ids, block_q, block_k, interpret, vma,
+                             scale)[0]
+
+
+def _flash_packed_fwd(q, k, v, ids, block_q, block_k, interpret, vma=None,
+                      scale=None):
+    out, res = _fwd(q, k, v, causal=True, block_q=block_q, block_k=block_k,
+                    interpret=interpret, vma=vma, scale=scale, ids=ids)
+    B, S, H = res[5][0], res[5][1], res[5][2]
+    return (out, _lse_bsh(res[4], B, S, H)), res + (ids,)
+
+
+def _flash_packed_bwd(block_q, block_k, interpret, vma, scale, res,
+                      cotangents):
+    del scale       # the residuals carry the one the forward used
+    *res, ids = res
+    # integer ids have no tangent space but float0's
+    return _bwd(block_q, block_k, interpret, vma, None, tuple(res),
+                cotangents, ids) + (np.zeros(ids.shape, jax.dtypes.float0),)
+
+
+_flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
+
+
 def platform_in_use(x) -> str:
     """Platform of the devices a computation on ``x`` will run on: a
     concrete array's own devices; under tracing (no devices to read), those
@@ -787,7 +1187,8 @@ def platform_in_use(x) -> str:
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
                     block_k: int = 1024, interpret: bool = None, vma=None,
-                    scale: float = None, window: int = None):
+                    scale: float = None, window: int = None,
+                    segment_ids=None):
     """Memory-O(S) exact attention; ``q`` and ``k`` ``(B, S, H, D)``, ``v``
     ``(B, S, H, Dv)`` (``Dv`` is ``D`` unless the values have a head dim of
     their own), result ``(B, S, H, Dv)``.  ``scale`` multiplies the scores
@@ -799,6 +1200,23 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
     W``; the forward of a band of at most 2048 keys a query block takes it
     in pieces of ``W`` rounded up to a power of two, one softmax pass a row
     chunk.  ``W >= S`` is the causal kernel.
+
+    ``segment_ids`` (with ``causal``): ``(B, S)`` integers, the document of
+    every token of a packed row; query ``i`` sees the keys ``j <= i`` of its
+    own document.  **The ids must not decrease along a sequence** (documents
+    are contiguous, as ``data.pack_documents`` lays them); the kernels do
+    not check data, and an id that comes back after another gives a
+    document of its own or wrong tiles, not an error.
+    The kernels are then ``bf_flash_seg_fwd / dq / dkv``.  Where each
+    block's documents begin and end is read ahead of the grid, so a tile
+    whose keys all lie in
+    earlier documents than all its queries runs no product and moves no
+    block; a tile inside one document runs what the causal kernel runs; a
+    tile that a boundary crosses works in chunks of 256 own rows, each on
+    its visible range of keys rounded out to whole 256 in one step of the
+    softmax state, masked by document beside the diagonal
+    (``segment_tiles`` counts the three kinds on the host).  Together with
+    ``window``, or without ``causal``, it raises.
 
     ``interpret=None`` compiles the Mosaic kernel when the devices in use
     (:func:`platform_in_use`) are TPUs and runs the Pallas interpreter
@@ -815,12 +1233,14 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
     values of 128, the backward at 1024 x 512, 2.22 / 3.29 / 3.43)."""
     return flash_attention_lse(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret,
-                               vma=vma, scale=scale, window=window)[0]
+                               vma=vma, scale=scale, window=window,
+                               segment_ids=segment_ids)[0]
 
 
 def flash_attention_lse(q, k, v, *, causal: bool = True, block_q: int = 1024,
                         block_k: int = 1024, interpret: bool = None,
-                        vma=None, scale: float = None, window: int = None):
+                        vma=None, scale: float = None, window: int = None,
+                        segment_ids=None):
     """Like :func:`flash_attention` but also returns the per-row logsumexp
     ``(B, S, H)`` — the merge weight sequence-parallel consumers need
     (``parallel.ring_attention`` combines per-hop partials with it).
@@ -841,15 +1261,52 @@ def flash_attention_lse(q, k, v, *, causal: bool = True, block_q: int = 1024,
         interpret = platform_in_use(q) != "tpu"
     if vma is None:
         vma = frozenset().union(*(jax.typeof(t).vma for t in (q, k, v)))
-    return _flash(q, k, v, causal, block_q, block_k, interpret, vma,
-                  None if scale is None else float(scale), window)
+    scale = None if scale is None else float(scale)
+    if segment_ids is None:
+        return _flash(q, k, v, causal, block_q, block_k, interpret, vma,
+                      scale, window)
+    if not causal or window is not None:
+        raise NotImplementedError(
+            "flash_attention: segment_ids run under the causal mask alone: "
+            "a packed grid starts each query block at the first key block "
+            "of its documents and ends it at the diagonal, and has neither "
+            "the tiles past the diagonal (causal=False) nor a band's two "
+            f"edges (window={window})")
+    if segment_ids.shape != q.shape[:2]:
+        raise ValueError(
+            f"flash_attention: segment_ids {segment_ids.shape} are not one "
+            f"id a token of q {q.shape}: (B, S)")
+    return _flash_packed(q, k, v, segment_ids.astype(jnp.int32), block_q,
+                         block_k, interpret, vma, scale)
+
+
+def segment_tiles(segment_ids, block_q: int = 1024, block_k: int = 1024
+                  ) -> dict:
+    """Of the tiles at or under the diagonal that ``(block_q, block_k)``
+    blocks cut the packed rows ``segment_ids`` ``(B, S)`` into (one head's;
+    blocks fitted to ``S`` as the kernels fit them), how many are ``dead``
+    (every key in an earlier document than every query: no product, no
+    block moved), ``crossed`` by a document boundary (chunks masked by id)
+    and ``inside`` one document (the causal kernel's work).  Computed on the
+    host with numpy: for logging, tests and the benchmark's readers."""
+    ids = np.asarray(segment_ids)
+    S = ids.shape[1]
+    block_q, block_k = _fit_block(block_q, S), _fit_block(block_k, S)
+    q_first, q_last = ids[:, ::block_q], ids[:, block_q - 1::block_q]
+    k_first, k_last = ids[:, ::block_k], ids[:, block_k - 1::block_k]
+    qi, kb = np.meshgrid(np.arange(S // block_q), np.arange(S // block_k),
+                         indexing="ij")
+    under = kb * block_k <= (qi + 1) * block_q - 1
+    dead = k_last[:, None, :] < q_first[:, :, None]
+    inside = k_first[:, None, :] == q_last[:, :, None]
+    count = lambda hit: int((hit & under).sum())
+    return {"dead": count(dead), "crossed": count(~dead & ~inside),
+            "inside": count(inside)}
 
 
 def flash_attention_impl(block_q: int = 1024, block_k: int = 1024,
                          interpret: bool = None):
-    """``attn_impl`` for ``models.TransformerLM`` / ``parallel.ulysses``."""
-    def impl(q, k, v, *, causal=True, scale=None, window=None):
-        return flash_attention(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k, interpret=interpret,
-                               scale=scale, window=window)
-    return impl
+    """``attn_impl`` for ``models.TransformerLM`` / ``parallel.ulysses``:
+    ``flash_attention`` at these blocks, with whatever else it takes."""
+    return functools.partial(flash_attention, block_q=block_q,
+                             block_k=block_k, interpret=interpret)

@@ -61,14 +61,22 @@ def _merge(o, lse, o_h, lse_h):
 
 
 def ring_attention(q, k, v, *, axis_name: str, causal: bool = True,
-                   window: int = None):
+                   window: int = None, segment_ids=None):
     """Exact attention with K/V rotating around the ``axis_name`` ring.
 
     Per-device blocks ``(B, S_local, H, D)``; the global sequence is the
     concatenation of blocks in axis-index order.  Returns the local output
     block, bit-for-bit a blockwise-stable evaluation of full attention.
-    A ``window`` (a model's sliding-attention layer) is not supported.
+    A ``window`` (a model's sliding-attention layer) is not supported, nor
+    are ``segment_ids`` (a packed row's documents).
     """
+    if segment_ids is not None:
+        raise NotImplementedError(
+            "ring_attention: segment_ids are not supported: a hop's K/V "
+            "block would have to travel with its document ids, and a hop "
+            "whose documents all end before the local queries' begin be "
+            "skipped; run packed rows with ops.flash_attention on one "
+            "device")
     if window is not None:
         raise NotImplementedError(
             "ring_attention: a sliding window is not supported: a hop's "

@@ -22,9 +22,11 @@ __all__ = ["ulysses_attention", "ulysses_attention_impl"]
 
 
 def ulysses_attention(q, k, v, *, axis_name: str, causal: bool = True,
-                      inner_attention=None, window: int = None):
+                      inner_attention=None, window: int = None,
+                      segment_ids=None):
     """All-to-all head-parallel attention over ``axis_name``.  A ``window``
-    (a model's sliding-attention layer) is not supported.
+    (a model's sliding-attention layer) is not supported, nor are
+    ``segment_ids`` (a packed row's documents).
 
     ``inner_attention(q, k, v, causal=...)`` runs on the gathered-sequence /
     sharded-head layout.  Default: the compiled flash kernel when the
@@ -32,6 +34,13 @@ def ulysses_attention(q, k, v, *, axis_name: str, causal: bool = True,
     memory matters), dense ``local_attention`` elsewhere (the Pallas
     interpreter would dominate CPU-mesh test time).
     """
+    if segment_ids is not None:
+        raise NotImplementedError(
+            "ulysses_attention: segment_ids are not supported: the document "
+            "ids are sharded over the sequence like q and would have to be "
+            "gathered to the whole sequence before the inner attention, "
+            "which is called without them; run packed rows with "
+            "ops.flash_attention on one device")
     if window is not None:
         raise NotImplementedError(
             "ulysses_attention: a sliding window is not supported: the "

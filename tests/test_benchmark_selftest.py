@@ -10,11 +10,12 @@ change moves one of them: the ledger's per-layer metrics would read ``null``.
 Their tests are collected here under their own names behind the file's;
 ``test_cells_cpu.py``, ``test_moe_cell_cpu.py`` (three minutes) and the
 ``test_twin_*`` cases of ``test_xing_cell_cpu.py``, ``test_lfm2_cell_cpu.py``,
-``test_laguna_cell_cpu.py`` and ``test_twotower_cell_cpu.py`` (whose other
-cases, the cell's declaration, its published widths, its cost functions and
-its roofline readers, run here; of ``test_laguna_cell_cpu.py`` and
-``test_twotower_cell_cpu.py`` the traced twin too, a minute and a half and
-under a minute: the cell's checks and every new reader on a CPU trace) stay by
+``test_laguna_cell_cpu.py``, ``test_twotower_cell_cpu.py`` and
+``test_kanana_cell_cpu.py`` (whose other cases, the cell's declaration, its
+published widths, its cost functions and its roofline readers, run here; of
+``test_laguna_cell_cpu.py``, ``test_twotower_cell_cpu.py`` and
+``test_kanana_cell_cpu.py`` the traced twin too, a minute and a half and under
+a minute twice: the cell's checks and every new reader on a CPU trace) stay by
 hand.
 
 Two cases are collected through ``test_setup_readers.py`` and not directly:
@@ -31,7 +32,11 @@ runs here unchanged on ``BENCHMARK.json`` less everything appended after the
 four, cut by position and not by a list of names: the metrics behind the four,
 the cells behind the last one ``kernel_stagings`` lists, the configurations
 behind the last one such a cell names.  PR 40's cell and PR 42's are behind
-that line, and the next appends without touching this file.
+that line, and PR 47's cell, and the next appends without touching this
+file but for its tuple of files.  One more case runs on a cut file:
+``test_twotower_cell_cpu.py`` holds PR 42's entries to be the last of their
+lists, so it runs here on ``BENCHMARK.json`` as that PR left it
+(``_WHEN_LAST``); PR 47's own declaration case counts by position instead.
 """
 
 import importlib
@@ -44,14 +49,19 @@ if ROOT not in sys.path:
 
 # PR 36's own declaration test, run below on the file less what came later
 _BEHIND_THE_FOUR = "test_the_entries_say_what_the_readers_are"
+# PR 42's declaration test holds its cell, configuration and five metrics to
+# be the LAST of their lists (a file of the benchmark that a later PR may not
+# edit): run below on the file cut behind them
+_WHEN_LAST = ("twotower_cell_cpu",
+              "test_declared_with_its_five_metrics_and_no_other_cells")
 
 for _file in ("trace_reduce", "program_readers", "setup_readers", "dropin",
               "xing_cell_cpu", "lfm2_cell_cpu", "laguna_cell_cpu",
-              "twotower_cell_cpu"):
+              "twotower_cell_cpu", "kanana_cell_cpu"):
     _module = importlib.import_module(f"benchmark.selftest.test_{_file}")
     for _name, _obj in vars(_module).items():
         if _name.startswith(("test_twin_", "test_the_cell_is_declared_")) \
-                or _name == _BEHIND_THE_FOUR:
+                or _name == _BEHIND_THE_FOUR or (_file, _name) == _WHEN_LAST:
             continue    # two minutes, by hand; through test_setup_readers
         if _name.startswith("test_"):
             globals()[f"test_{_file}_{_name[len('test_'):]}"] = _obj
@@ -90,3 +100,35 @@ def test_setup_readers_the_entries_say_what_the_readers_are(monkeypatch):
         return data
     monkeypatch.setattr(spec, "read_json", up_to_the_four)
     getattr(setup, _BEHIND_THE_FOUR)()
+
+
+def test_twotower_cell_cpu_declared_with_its_five_metrics_and_no_other_cells(
+        monkeypatch):
+    """PR 42's case unchanged, on ``BENCHMARK.json`` cut behind that PR's
+    entries by position: behind its cell, its configuration and the last
+    metric that lists its cell alone."""
+    from benchmark import spec
+    module = importlib.import_module(
+        f"benchmark.selftest.test_{_WHEN_LAST[0]}")
+    read_json = spec.read_json
+    whole = read_json(os.path.join(ROOT, "BENCHMARK.json"))
+    cell = [w["name"] for w in whole["workloads"]].index(
+        module.STANDS_FOR) + 1
+    config = [c["name"] for c in whole["configs"]].index(
+        whole["workloads"][cell - 1]["config"]) + 1
+    metric = max(i for i, m in enumerate(whole["per_layer"])
+                 if m.get("workloads") == [module.STANDS_FOR]) + 1
+    # what came later is behind the line and lists none of what is before
+    for m in whole["per_layer"][metric:]:
+        assert module.STANDS_FOR not in m["workloads"], m["name"]
+
+    def as_pr_42_left_it(path):
+        data = read_json(path)
+        if os.path.basename(path) == "BENCHMARK.json":
+            data["per_layer"] = data["per_layer"][:metric]
+            data["workloads"] = data["workloads"][:cell]
+            data["configs"] = data["configs"][:config]
+        return data
+    monkeypatch.setattr(spec, "read_json", as_pr_42_left_it)
+    getattr(module, _WHEN_LAST[1])()
+

@@ -163,3 +163,86 @@ def test_prefetch_abandoned_consumer_releases_producer():
     assert not any(t.name == "bf-data-prefetch" and t.is_alive()
                    for t in threading.enumerate()), "producer thread leaked"
     assert len(produced) < 100  # it stopped early, not after exhausting gen
+
+
+# --- packing documents into rows ------------------------------------------------
+
+def _documents(lengths, seed=0):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(0, 1000, size=n) for n in lengths]
+
+
+def test_document_layout_restarts_at_each_document():
+    from bluefog_tpu.data import document_layout
+    segment_ids, positions = document_layout([3, 1, 4])
+    assert segment_ids.tolist() == [0, 0, 0, 1, 2, 2, 2, 2]
+    assert positions.tolist() == [0, 1, 2, 0, 0, 1, 2, 3]
+    assert segment_ids.dtype == positions.dtype == np.int32
+    assert all(len(a) == 0 for a in document_layout([]))
+
+
+@pytest.mark.parametrize("lengths, seq_len", [
+    ([5, 9, 3, 20, 2, 1], 8),          # a document over two and three rows
+    ([8, 8, 8], 8),                    # boundaries on the rows' ends
+    ([3, 0, 2, 7, 30, 4, 11, 7], 16),  # an empty document is skipped
+    ([100], 32),                       # one document, cut and cut again
+])
+def test_pack_documents_cuts_and_continues_and_pads_nothing(lengths, seq_len):
+    from bluefog_tpu.data import pack_documents
+    docs = _documents(lengths)
+    rows = list(pack_documents(iter(docs), seq_len))
+    stream = np.concatenate(docs)
+    assert len(rows) == len(stream) // seq_len      # the tail is no row
+    for tokens, segment_ids, positions in rows:
+        assert tokens.shape == segment_ids.shape == positions.shape \
+            == (seq_len,)
+        # contiguous ids from 0 that never decrease; positions restart at
+        # each id and count on inside it
+        assert segment_ids[0] == 0 and positions[0] == 0
+        steps = np.diff(segment_ids)
+        assert set(steps.tolist()) <= {0, 1}
+        assert (positions[1:][steps == 1] == 0).all()
+        assert (np.diff(positions)[steps == 0] == 1).all()
+    # nothing lost, nothing added, nothing out of order
+    packed = np.concatenate([r[0] for r in rows]) if rows else stream[:0]
+    np.testing.assert_array_equal(packed, stream[:len(packed)])
+    # a document cut at a row's end opens the next row as a new document
+    ends = np.cumsum([n for n in lengths if n])
+    for i, (_, segment_ids, positions) in enumerate(rows[1:], start=1):
+        cut = i * seq_len not in ends
+        first = int((segment_ids == 0).sum())
+        if cut:     # the rest of the cut document, or a row's worth of it
+            left = ends[np.searchsorted(ends, i * seq_len)] - i * seq_len
+            assert first == min(left, seq_len)
+
+
+def test_pack_documents_counts_and_composes_with_the_loaders():
+    from bluefog_tpu.data import pack_documents
+    from bluefog_tpu.utils import telemetry
+
+    def counted():
+        snap = telemetry.snapshot()
+        return (snap.get("bf_pack_documents_total", 0),
+                snap.get("bf_pack_tokens_total", 0))
+
+    before = counted()
+    rows = list(pack_documents(_documents([40, 7, 30, 60, 9, 50]), 16))
+    after = counted()
+    assert len(rows) == 12
+    assert after[1] - before[1] == 12 * 16
+    assert after[0] - before[0] == sum(
+        int(segment_ids[-1]) + 1 for _, segment_ids, _ in rows)
+    # stacked, the rows are arrays like any other: ShardedLoader batches
+    # them rank-major, prefetch_to_device passes batches through
+    tokens, segment_ids, positions = (np.stack(c) for c in zip(*rows))
+    loader = ShardedLoader((tokens, segment_ids, positions), batch_size=3,
+                           num_ranks=2, shuffle=False, sharding=False)
+    batches = list(loader)
+    assert len(batches) == 2
+    for batch in batches:
+        assert [b.shape for b in batch] == [(2, 3, 16)] * 3
+    np.testing.assert_array_equal(batches[0][1][0], segment_ids[:3])
+    fed = list(prefetch_to_device(
+        ({"tokens": t[None], "segment_ids": s[None], "positions": p[None]}
+         for t, s, p in rows), sharding=False))
+    assert len(fed) == 12 and fed[3]["positions"].shape == (1, 16)

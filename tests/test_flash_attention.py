@@ -685,3 +685,199 @@ def test_a_wide_bands_forward_goes_through_the_grid(window_qkv, window,
         np.testing.assert_allclose(got[1][1], want_lse, rtol=2e-5, atol=2e-5)
     for a, b in zip(banded[0], gridded[0]):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# A document mask (``segment_ids``): query i sees the keys j <= i of its own
+# document.  A tile is dead, crossed by a boundary or inside one document by
+# the ids, which are data.
+# ---------------------------------------------------------------------------
+
+def _ids(lengths, batch):
+    """``(batch, sum(lengths))`` document ids; row ``b``'s documents are the
+    lengths rotated by ``b``, so the rows' boundaries differ."""
+    rows = [np.repeat(np.arange(len(lengths)),
+                      lengths[b % len(lengths):] + lengths[:b % len(lengths)])
+            for b in range(batch)]
+    return jnp.asarray(np.stack(rows), jnp.int32)
+
+
+def _dense_documents(q, k, v, ids, scale=None):
+    """Float32 attention over a full ``(S, S)`` score matrix with the
+    causal and the document mask written out, and its logsumexp."""
+    q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    scale = 1 / np.sqrt(q.shape[-1]) if scale is None else scale
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    seq = logits.shape[-1]
+    seen = jnp.tril(jnp.ones((seq, seq), bool))[None] & (
+        ids[:, :, None] == ids[:, None, :])
+    logits = jnp.where(seen[:, None], logits, -jnp.inf)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(logits - lse[..., None]), v)
+    return out, lse.transpose(0, 2, 1)
+
+
+# (S, lengths, blocks): boundaries off every block edge; a document shorter
+# than a block and than a chunk; blocks that differ (the backward at heads
+# over 128 runs 1024 x 512); dead tiles (the last documents' keys start
+# past the first key blocks); a sequence that is one block
+PACKED = [
+    (512, [130, 200, 60, 122], (128, 128)),
+    (512, [300, 212], (256, 128)),
+    (1024, [333, 5, 274, 412], (512, 256)),
+    (1024, [1, 700, 323], (256, 512)),
+    (200, [77, 123], (1024, 1024)),
+    (768, [250, 250, 268], (1024, 1024)),
+    # tiles of several chunks of 256 rows, each with a range of its own
+    (2048, [700, 1, 333, 600, 414], (1024, 1024)),
+    (2048, [1300, 748], (1024, 512)),
+    (2048, [300, 300, 300, 300, 300, 300, 248], (512, 1024)),
+]
+
+
+@pytest.fixture(scope="module")
+def packed_qkv():
+    rng = np.random.RandomState(7)
+
+    def make(seq, d, dv):
+        mk = lambda dim: jnp.asarray(rng.randn(2, seq, 2, dim), jnp.float32)
+        return mk(d), mk(d), mk(dv)
+    return make
+
+
+@pytest.mark.parametrize("dims", [(192, 128), (128, 128)])
+@pytest.mark.parametrize("seq, lengths, blocks", PACKED)
+def test_documents_match_a_masked_softmax(packed_qkv, seq, lengths, blocks,
+                                          dims):
+    """Value, logsumexp and all three gradients against the plain masked
+    softmax, at the latent heads (``D`` 192, ``Dv`` 128) and at 128 / 128."""
+    from bluefog_tpu.ops.flash_attention import flash_attention_lse
+    q, k, v = packed_qkv(seq, *dims)
+    ids = _ids(lengths, 2)
+    block_q, block_k = blocks
+
+    def loss(fn):
+        def f(q, k, v):
+            out, lse = fn(q, k, v)
+            return jnp.sum(out ** 2) + jnp.sum(lse), (out, lse)
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        got, (out, lse) = loss(lambda q, k, v: flash_attention_lse(
+            q, k, v, segment_ids=ids, block_q=block_q, block_k=block_k))
+        want, (ref, ref_lse) = loss(
+            lambda q, k, v: _dense_documents(q, k, v, ids))
+    np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(lse, ref_lse, rtol=2e-5, atol=2e-5)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_one_document_is_the_causal_kernel(packed_qkv, dtype):
+    """Ids that name one document give what no ids give, to rounding: every
+    tile is inside the document and runs the causal kernel's bodies."""
+    q, k, v = (t.astype(dtype) for t in packed_qkv(512, 64, 64))
+
+    def grads(**kw):
+        def f(q, k, v):
+            return jnp.sum(flash_attention(
+                q, k, v, block_q=128, block_k=128, **kw).astype(
+                    jnp.float32) ** 2)
+        return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    (a, ga), (b, gb) = grads(), grads(
+        segment_ids=jnp.full((2, 512), 3, jnp.int32))
+    assert float(a) == float(b)
+    for x, y in zip(ga, gb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_local_attention_takes_the_documents(packed_qkv):
+    q, k, v = packed_qkv(200, 16, 16)
+    ids = _ids([77, 123], 2)
+    want, _ = _dense_documents(q, k, v, ids)
+    with jax.default_matmul_precision("highest"):
+        got = local_attention(q, k, v, segment_ids=ids)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+def test_documents_raise_where_they_cannot_run(packed_qkv):
+    from bluefog_tpu.parallel import ring_attention, ulysses_attention
+    q, k, v = packed_qkv(200, 16, 16)
+    ids = _ids([77, 123], 2)
+    with pytest.raises(NotImplementedError, match="causal mask alone"):
+        flash_attention(q, k, v, causal=False, segment_ids=ids)
+    with pytest.raises(NotImplementedError, match="window=64"):
+        flash_attention(q, k, v, window=64, segment_ids=ids)
+    with pytest.raises(ValueError, match=r"\(B, S\)"):
+        flash_attention(q, k, v, segment_ids=ids[:, :100])
+    with pytest.raises(NotImplementedError, match="document"):
+        ring_attention(q, k, v, axis_name="sp", segment_ids=ids)
+    with pytest.raises(NotImplementedError, match="document"):
+        ulysses_attention(q, k, v, axis_name="sp", segment_ids=ids)
+
+
+# the cell's row (kanana2-packed-s8192-1chip) and a head's tiles by hand:
+# forward 1024 x 1024: 36 at or under the diagonal; 17 hold a visible pair
+CELL_DOCUMENTS = [2961, 1734, 1207, 811, 562, 377, 243, 161, 89, 47]
+
+
+def test_segment_tiles_counts_the_cells_row_by_hand():
+    from bluefog_tpu.ops.flash_attention import segment_tiles
+    ids = np.repeat(np.arange(10), CELL_DOCUMENTS)[None]
+    tiles = segment_tiles(ids, 1024, 1024)
+    assert sum(tiles.values()) == 36 and tiles["dead"] == 19
+    # query blocks 0, 1 and 3 lie inside documents 0, 0 and 1: their tiles
+    # over key blocks of the same document are the causal kernel's: (0, 0),
+    # (1, 0), (1, 1) and (3, 3)
+    assert tiles == {"dead": 19, "crossed": 13, "inside": 4}
+    # the backward's 1024 x 512: 72 at or under the diagonal
+    assert sum(segment_tiles(ids, 1024, 512).values()) == 72
+    # two rows count twice; one document is all inside
+    assert segment_tiles(np.concatenate([ids, ids]), 1024, 1024)[
+        "dead"] == 38
+    assert segment_tiles(np.zeros((1, 8192), int), 1024, 1024) == {
+        "dead": 0, "crossed": 0, "inside": 36}
+
+
+def test_packed_calls_have_names_grids_and_by_data_tiles_of_their_own():
+    """Traced, nothing runs: the masked kernels' names at the cell's shapes,
+    grids of the tiles at or under the diagonal and no others (36 a head at
+    1024 x 1024, 72 at 1024 x 512, where the causal kernels' have 64 and
+    128 steps), four scalar arrays read ahead of the grid (the bounds'
+    first and last, the tiles' own block and step), the documents' bounds
+    beside the operands, and ``bf_flash_tiles_total``'s ``by_data``."""
+    from bluefog_tpu.utils import telemetry
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((1, 8192), jnp.int32)
+
+    def loss(q, k, v, ids):
+        return flash_attention(q, k, v, segment_ids=ids).astype(
+            jnp.float32).sum()
+
+    def tiles():
+        return {key: n for key, n in telemetry.snapshot().items()
+                if key.startswith("bf_flash_tiles_total")}
+
+    before = tiles()
+    calls = _pallas_calls(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1, 2)))(q, q, v, ids).jaxpr, [])
+    after = tiles()
+    assert [c.params["name"] for c in calls] == [
+        "bf_flash_seg_fwd", "bf_flash_seg_dq", "bf_flash_seg_dkv"]
+    for call, grid, operands, under in zip(
+            calls, ((32, 36), (32, 72), (32, 72)), (6, 8, 9),
+            (36, 72, 72)):
+        mapping = call.params["grid_mapping"]
+        assert mapping.grid == grid
+        assert mapping.num_index_operands == 4
+        assert len(mapping.block_mappings) == operands
+        name = call.params["name"]
+        counted = {kind: after.get(key, 0) - before.get(key, 0)
+                   for kind in ("skipped", "by_data", "crossed", "interior")
+                   for key in [f'bf_flash_tiles_total{{kernel="{name}",'
+                               f'kind="{kind}"}}']}
+        assert counted == {"skipped": 0, "by_data": 32 * under,
+                           "crossed": 0, "interior": 0}

@@ -1052,3 +1052,34 @@ def test_compression_string_validated_even_for_empty_communication():
             F.compress_combiner(ident, bad)
     for ok in ("bf16", "sparse:0.25", "none"):
         assert F.compress_combiner(ident, ok).identity, ok
+
+
+def test_step_returns_when_the_step_before_is_over(monkeypatch):
+    """``step()`` launches its program and waits for the step BEFORE it:
+    the host runs one step ahead of the device and no further (so that a
+    device near its memory's end is never asked for a third tree of
+    gradients).  Held by what the call waits on: nothing at the first step,
+    then a leaf of the parameters the previous call returned, inside the
+    span ``bf.optim.wait``."""
+    from bluefog_tpu.utils import timeline
+    bf.init()
+    opt = bf.optim.DistributedAdaptThenCombineOptimizer(optax.sgd(0.1))
+    params = {"w": jnp.ones((N, DIM, 1)), "b": jnp.zeros((N, 1))}
+    state = opt.init(params)
+    waited, spans = [], []
+    ready = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: waited.append(x) or ready(x))
+    timeline.set_op_span_hook(lambda op, phase, s: spans.append((op, phase)))
+    try:
+        returned = []
+        for _ in range(3):
+            params, state = opt.step(params, params, state)
+            returned.append(params)
+    finally:
+        timeline.set_op_span_hook(None)
+    # the smallest leaf of the step before: ready when its program is
+    assert len(waited) == 2
+    assert waited[0] is returned[0]["b"] and waited[1] is returned[1]["b"]
+    assert spans.count(("optim", "wait")) == 2
+    assert spans.count(("optim", "step")) == 3

@@ -76,6 +76,8 @@ def _without_bias(ref, y, params, bias, cfg):
 # traffic: the twin's own optimizer (``benchmark/selftest/traffic``).
 # held, experts: the key that says how many experts are held, and the
 #   reference's expert layer.
+# documents: of a row of ``seq`` tokens, where the twin's task packs its
+#   rows (no boundary on a multiple of a block).
 TWINS = {
     "tiny-lm": dict(
         seed=45, f32=dict(seq=200, loss=1e-5, worst=1e-4, median=1e-5),
@@ -133,6 +135,17 @@ TWINS = {
         load=(4, 16), leaves=67, f8=(0.5, 1),
         traffic="tiny-tokens-1row-adamw",
         held="n_routed_experts", experts=_with_bias),
+    "tiny-kanana2": dict(
+        seed=47, f32=dict(seq=200, loss=1e-5, worst=1e-4, median=1e-5),
+        has={"block_0/mla/q/kernel": (64, 4 * 24), "block_0/gate": None,
+             "block_1/moe/gate": (2, 64, 32),
+             "block_1/moe/shared_gate/kernel": (64, 64),
+             "block_1/moe/router/kernel": (64, 16)},
+        lacks=("block_0/moe", "block_0/mla/q_a", "block_0/hc_attn", "wpe"),
+        load=(2, 16), f8=(0.5, 1), traffic="tiny-tokens-packed-adamw",
+        held="n_routed_experts", experts=_with_bias,
+        documents=lambda seq: [seq - seq // 3 - seq // 5 - seq // 9,
+                               seq // 3, seq // 5, seq // 9]),
 }
 
 
@@ -185,6 +198,8 @@ def _twin_case(twin, dtype, seq, remat=None):
     row = TWINS[twin]
     batch = ({"images": row["images"]} if "images" in row
              else {"sequences": 2, "seq_len": seq})
+    if "documents" in row:
+        batch["documents"] = row["documents"](seq)
     return model_case(load(twin), jax.random.PRNGKey(row["seed"]), dtype,
                       batch, remat)
 
