@@ -50,20 +50,34 @@ window a step of the running state cost more than the products it served.
 
 A document mask (``segment_ids``, with ``causal``: query ``i`` sees the keys
 ``j <= i`` of its own document; packed rows) runs as ``bf_flash_seg_fwd / dq
-/ dkv``.  Where the visible pairs end is data, so the kernels read it ahead
-of the grid: the wrapper turns the ids into every position's *bound* (a
+/ dkv``.  Where the visible pairs end is data, so a packed call runs a
+*list of live work made from the ids* on the device, inside the call's own
+``jax.jit`` (``_doc_work``).  The ids give every position's *bound* (a
 query's: its document's first position; a key's, in ``bf_flash_seg_dkv``:
-its document's last), and the bounds of the first and last position of
-every 256 are scalars in SMEM (``_Docs``).  The grid walks the tiles at or
-under the diagonal and no others (36 steps a head at S 8192 where the causal
-grid has 64); a query block's steps count from the key block where its first
-query's document begins, so a tile whose keys all lie in earlier documents
-runs no product and moves no block: the steps it leaves over, behind the
-diagonal's, repeat a block and run nothing.  A tile inside one document runs the causal bodies above
-as they are.  A tile that a boundary crosses works in chunks of 256 own
-rows, each on its visible range of the other dimension rounded out to whole
-256 (a body for each length, the start traced), one step of the softmax
-state a chunk.
+its document's last).  Documents are contiguous, so the blocks of the other
+dimension that hold a pair visible to a block of own positions are a range
+without a hole: a query block's from the key block where its first query's
+document begins to the diagonal's, a key block's from its frontier to the
+query block where its last key's document ends.  The ranges are compacted,
+own block after own block, into the list ``(own block, other block, kind,
+the crossed chunks' ranges)`` in SMEM, and the grid's second dimension is
+the list's length, a traced scalar: **no grid step is dead** (the cell's
+row fills 33 of the 72 steps a head's list can hold; the rows of a batch
+pad to the longest list of the call, and a padded step repeats its row's
+last tile and runs nothing).  A packed call's query blocks are fitted to
+512 at most and its key blocks stay what the caller asked for (query blocks
+of 1024 held too few tiles inside one document, key blocks under 1024 paid
+a step of the softmax state too often).  A tile inside one document runs
+the causal bodies above as they are.  A tile that a boundary crosses works
+in chunks of 256 own rows, each on its visible range of the other dimension
+rounded out to whole 256 (a body for each length, the start traced), one
+step of the softmax state a chunk.  The kernels alone on the cell's ten
+documents, 32 heads of 192 / 128 at S 8192 (one v5e chip, PR 48, forward /
+dq / dkv in ms by the device's clock): 2.65 / 2.88 / 3.54, where the static
+grid of every tile under the diagonal at 1024 x 1024 and 1024 x 512 took
+2.91 / 4.12 / 4.71; one document of 8192: 6.36 / 8.55 / 10.11 for 5.81 /
+8.99 / 10.49; ten documents of 819: 2.10 / 1.95 / 2.50 for 2.41 / 3.37 /
+3.46.
 
 What bounds a tile (one v5e chip, PR 37, ``PERF.md``): the backward runs its
 products at 85 to 91% of the array's peak; the forward spends 1.1 to 1.4 us
@@ -93,7 +107,8 @@ from jax.experimental.pallas import tpu as pltpu
 from bluefog_tpu.utils import telemetry
 
 __all__ = ["flash_attention", "flash_attention_lse",
-           "flash_attention_impl", "platform_in_use", "segment_tiles"]
+           "flash_attention_impl", "platform_in_use", "segment_steps",
+           "segment_tiles"]
 
 _NEG_INF = -1e30
 
@@ -173,109 +188,128 @@ class _Band(NamedTuple):
         return first + step, first + step <= last
 
 
-# Of a tile that a document boundary crosses: the own rows of one chunk, and
-# the unit its visible range of the other dimension is rounded to (one v5e
-# chip, PR 47: PERF.md).
+# A packed call's query blocks are fitted to ``_DOC_BLOCK`` positions at
+# most; its key blocks stay what the caller asked for.  Of a tile that a
+# document boundary crosses: the own rows of one chunk, and the unit its
+# visible range of the other dimension is rounded to (one v5e chip, PR 48:
+# PERF.md).
+_DOC_BLOCK = 512
 _DOC_ROWS = 256
 _DOC_KEYS = 256
+
+# ``_Docs.kind_of``'s bits
+_FIRST, _LAST, _INSIDE = 1, 2, 4
 
 
 def _doc_chunk(block: int, want: int) -> int:
     return want if block % want == 0 else block
 
 
+def _doc_bounds(np_, ids, by_keys: bool):
+    """Of every position of the packed rows ``ids`` ``(B, S)`` the *bound* of
+    what it sees of the other dimension under the causal mask: a query its
+    document's first position (it sees the keys from there to itself), a
+    key, ``by_keys``, its document's last (the queries from itself to
+    there); found from where the ids change along a row.  ``np_`` is
+    ``jax.numpy`` inside a call and ``numpy`` on the host."""
+    B, S = ids.shape
+    at = np_.arange(S, dtype=np_.int32)
+    changes = ids[:, 1:] != ids[:, :-1]
+    edge = np_.ones((B, 1), bool)
+    if by_keys:
+        ends = np_.where(np_.concatenate([changes, edge], axis=1), at, S - 1)
+        if np_ is np:
+            return np.minimum.accumulate(ends[:, ::-1], axis=1)[:, ::-1]
+        return jax.lax.cummin(ends, axis=1, reverse=True)
+    starts = np_.where(np_.concatenate([edge, changes], axis=1), at, 0)
+    if np_ is np:
+        return np.maximum.accumulate(starts, axis=1)
+    return jax.lax.cummax(starts, axis=1)
+
+
+def _doc_reach(np_, bounds, own: int, other: int, by_keys: bool) -> tuple:
+    """``(lo, hi)`` ``(B, S / own)``: the blocks of ``other`` positions of
+    the other dimension that hold a pair visible to each block of ``own``
+    positions.  A query block's run from the block where its first query's
+    document begins to the diagonal's, a key block's from its frontier to
+    the block where its last key's document ends.  Documents are contiguous,
+    so the bounds do not decrease and the range has no hole."""
+    at = np_.arange(bounds.shape[1] // own, dtype=np_.int32) * own
+    if by_keys:
+        hi = bounds[:, own - 1::own] // other
+        return np_.broadcast_to(at // other, hi.shape), hi
+    lo = bounds[:, ::own] // other
+    return lo, np_.broadcast_to((at + own - 1) // other, lo.shape)
+
+
 class _Docs(NamedTuple):
     """What a kernel of a packed call (``segment_ids``) knows of the
-    documents: of every position of its *own* dimension (the queries; the
-    keys in ``bf_flash_dkv``, ``by_keys``) the ``bound`` of what it sees of
-    the other under the causal mask: a query ``i`` the keys from its
-    document's first position to ``i``, a key ``j`` the queries from ``j``
-    to its document's last position.  ``first`` and ``last`` (SMEM, ``B *
-    seq / granule``): the bound of the first and of the last position of
-    every ``granule`` own positions, a row of the batch after the other;
-    ``bounds`` (VMEM, ``(own block, 1)``): of every own position of the
-    tile.  Documents are contiguous, so the bounds do not decrease: the
-    first own position's bound and the last's say, without a look at the
-    rest, where a block's visible range begins and ends, whether a tile
-    lies whole outside it (no product, no block moved) and whether it holds
-    one document."""
-    first: object
-    last: object
-    own_of: object  # SMEM: of every step of a head's grid its own block
-    step_of: object     # and which of that block's steps it is
+    documents: the list of live work of its row of the batch, made from the
+    ids ahead of the grid (``_doc_work``), and its own positions' bounds.
+    The *own* dimension is the queries, and the keys in ``bf_flash_dkv``
+    (``by_keys``).  In SMEM, ``capacity`` steps a row of the batch, a row
+    after the other: ``own_of`` and ``other_of``, the tile of every step;
+    ``kind_of`` (read once: ``kind``), whether the step is its own block's
+    ``_FIRST`` (the accumulators start), its ``_LAST`` (the results are
+    stored), and whether the tile lies ``_INSIDE`` one document;
+    ``range_of``, of every chunk of the own rows of a tile that a boundary
+    crosses, the chunk's visible range of the tile's other dimension in
+    units of ``_DOC_KEYS``: ``start * (units + 1) + count``.  ``bounds``
+    (VMEM, ``(own block, 1)``): of every own position of the tile
+    (``_doc_bounds``)."""
+    own_of: object
+    other_of: object
+    range_of: object
     bounds: object
     by_keys: bool
-    granule: int
-    seq: int
-    row: object     # of the batch: the grid's first index over the heads
+    at: object      # this step's place in ``own_of`` and ``other_of``
+    kind: object
 
     @classmethod
     def of(cls, packed, refs) -> tuple:
         """``(docs, the kernel's other refs)`` from ``packed = (heads,
-        by_keys, granule, seq)``; ``(None, refs)`` for a call that is not
+        by_keys, capacity)``; ``(None, refs)`` for a call that is not
         packed."""
         if packed is None:
             return None, refs
-        return cls(*refs[:5], *packed[1:],
-                   row=pl.program_id(0) // packed[0]), refs[5:]
+        heads, by_keys, capacity = packed
+        own_of, other_of, kind_of, range_of, bounds = refs[:5]
+        at = pl.program_id(0) // heads * capacity + pl.program_id(1)
+        return cls(own_of, other_of, range_of, bounds, by_keys, at,
+                   kind_of[at]), refs[5:]
 
-    def tile(self, block_q: int, block_k: int) -> tuple:
-        """``(own block, step, the block's last step)`` of this grid step:
-        a packed grid walks the tiles at or under the diagonal one after
-        the other (``_under_diagonal``) and has no steps above it."""
-        own = self.own_of[pl.program_id(1)]
-        return (own, self.step_of[pl.program_id(1)],
-                _last_step(own, block_q, block_k, self.seq, self.by_keys))
+    def tile(self) -> tuple:
+        """``(own block, other block, first, last)`` of this grid step."""
+        return (self.own_of[self.at], self.other_of[self.at],
+                (self.kind & _FIRST) != 0, (self.kind & _LAST) != 0)
 
-    def reach(self, at, n: int) -> tuple:
-        """``(lo, hi)``: the positions of the other dimension that the ``n``
-        own positions from ``at`` on (whole granules) see between them."""
-        base = self.row * (self.seq // self.granule)
-        if self.by_keys:
-            return at, self.last[base + (at + n) // self.granule - 1]
-        return self.first[base + at // self.granule], at + n - 1
-
-    def one_document(self, at, n: int, other_at, m: int):
-        """Whether the ``n`` own positions from ``at`` on see no position of
-        another document among the ``m`` others from ``other_at`` on."""
-        base = self.row * (self.seq // self.granule)
-        if self.by_keys:    # the queries end before the first key's document
-            return other_at + m - 1 <= self.first[base + at // self.granule]
-        return other_at >= self.last[base + (at + n) // self.granule - 1]
-
-    def on_tiles(self, tile, rows, qi, kb, live, block_q: int, block_k: int):
-        """Run the tile ``(qi, kb)`` of a packed causal grid as it needs.
-        Nothing where it is not ``live`` (a step past the block's reach).
-        ``tile`` as ``_on_tiles`` runs it where the tile holds one document.
-        Where a boundary crosses it: chunk by chunk of own rows, ``rows`` on
-        the chunk's visible range of the other dimension, rounded out to
-        whole ``_DOC_KEYS`` (a body for each length, the start traced), the
-        scores masked by the rows' bounds and by the diagonal; a chunk that
-        sees nothing of the tile runs nothing."""
+    def on_tiles(self, tile, rows, qi, kb, block_q: int, block_k: int):
+        """Run the tile ``(qi, kb)`` of a packed grid as it needs.  ``tile``
+        as ``_on_tiles`` runs it where the tile holds one document.  Where a
+        boundary crosses it: ``rows`` on each chunk of own rows and the
+        chunk's visible range of the other dimension, rounded out to whole
+        ``_DOC_KEYS`` (a body for each length, the start traced), the
+        scores masked by the rows' bounds and by the diagonal.  Nothing on a
+        step past the row's list."""
         q_at, k_at = qi * block_q, kb * block_k
         own_at, other_at, block, other = (
             (k_at, q_at, block_k, block_q) if self.by_keys
             else (q_at, k_at, block_q, block_k))
-        inside = self.one_document(own_at, block, other_at, other)
         _on_tiles(tile, qi, kb, causal=True, block_q=block_q,
-                  block_k=block_k, live=live & inside)
+                  block_k=block_k, live=(self.kind & _INSIDE) != 0)
         chunk = _doc_chunk(block, _DOC_ROWS)
         unit = _doc_chunk(other, _DOC_KEYS)
-
-        @pl.when(live & jnp.logical_not(inside))
-        def _crossed():
-            for r0 in range(0, block, chunk):
-                lo, hi = self.reach(own_at + r0, chunk)
-                lo = jnp.maximum(lo - other_at, 0) // unit
-                count = jnp.minimum(hi - other_at, other - 1) // unit + 1 - lo
-                start = pl.multiple_of(lo * unit, unit)
-                own = slice(r0, r0 + chunk)
-                see = functools.partial(
-                    self.visible, own=own, own_at=own_at + r0,
-                    other_at=other_at + start)
-                for units in range(1, other // unit + 1):
-                    pl.when(count == units)(functools.partial(
-                        rows, own, pl.ds(start, units * unit), see))
+        units = other // unit
+        for c in range(block // chunk):
+            reach = self.range_of[self.at * (block // chunk) + c]
+            start = pl.multiple_of(reach // (units + 1) * unit, unit)
+            own = slice(c * chunk, (c + 1) * chunk)
+            see = functools.partial(
+                self.visible, own=own, own_at=own_at + c * chunk,
+                other_at=other_at + start)
+            for n in range(1, units + 1):
+                pl.when(reach % (units + 1) == n)(functools.partial(
+                    rows, own, pl.ds(start, n * unit), see))
 
     def visible(self, s, *, own, own_at, other_at):
         """``s`` (own positions along the rows, from ``own_at``; the others
@@ -292,25 +326,53 @@ class _Docs(NamedTuple):
         return jnp.where(seen, s, _NEG_INF)
 
 
-def _last_step(own, block_q: int, block_k: int, seq: int, by_keys: bool):
-    """The last step of an own block of a causal grid whose steps count
-    from the block's first tile at or under the diagonal: a query block's
-    are the key blocks up to its diagonal, a key block's the query blocks
-    from its frontier on."""
-    if by_keys:
-        return seq // block_q - 1 - (own * block_k) // block_q
-    return ((own + 1) * block_q - 1) // block_k
+def _doc_capacity(block_q: int, block_k: int, seq: int) -> int:
+    """The steps a head's list holds at most: the tiles at or under the
+    diagonal (one document fills it), by query blocks or by key blocks."""
+    return sum(((i + 1) * block_q - 1) // block_k + 1
+               for i in range(seq // block_q))
 
 
-def _under_diagonal(block_q: int, block_k: int, seq: int, by_keys: bool
-                    ) -> tuple:
-    """``(own_of, step_of)``: the tiles at or under the diagonal of one
-    head, own block after own block, each block's steps in order."""
-    n_own = seq // (block_k if by_keys else block_q)
-    steps = [int(_last_step(i, block_q, block_k, seq, by_keys)) + 1
-             for i in range(n_own)]
-    return (np.repeat(np.arange(n_own, dtype=np.int32), steps),
-            np.concatenate([np.arange(n, dtype=np.int32) for n in steps]))
+def _doc_work(ids, block_q: int, block_k: int, by_keys: bool) -> tuple:
+    """The live work of a packed call, made from the ids ``(B, S)`` on the
+    device: ``(steps, (own_of, other_of, kind_of, range_of), bounds)`` as
+    ``_Docs`` has them.  Own block after own block, each block's range of
+    ``_doc_reach`` in order, and nothing else: ``steps``, the grid's second
+    dimension, is the longest row's list, and a shorter row's steps past its
+    end repeat its last tile (no block moves) with no bit in ``kind_of`` and
+    no range in ``range_of``: they run nothing."""
+    own, other = (block_k, block_q) if by_keys else (block_q, block_k)
+    bounds = _doc_bounds(jnp, ids, by_keys)
+    lo, hi = _doc_reach(jnp, bounds, own, other, by_keys)
+    ends = jnp.cumsum(hi - lo + 1, axis=1)                  # (B, S / own)
+    t = jnp.arange(_doc_capacity(block_q, block_k, ids.shape[1]),
+                   dtype=jnp.int32)[None]
+    own_of = jnp.minimum((t[:, :, None] >= ends[:, None, :]).sum(
+        axis=-1, dtype=jnp.int32), ends.shape[1] - 1)       # (B, capacity)
+    of_own = lambda x: jnp.take_along_axis(x, own_of, axis=1)
+    other_of = jnp.minimum(of_own(hi) - (of_own(ends) - 1 - t), of_own(hi))
+    live = t < ends[:, -1:]
+    bound_at = lambda at: jnp.take_along_axis(bounds, at, axis=1)
+    own_at, other_at = own_of * own, other_of * other
+    inside = (other_at + other - 1 <= bound_at(own_at) if by_keys
+              else other_at >= bound_at(own_at + own - 1))
+    kind_of = (_FIRST * (live & (other_of == of_own(lo)))
+               + _LAST * (live & (other_of == of_own(hi)))
+               + _INSIDE * (live & inside))
+    chunk, unit = _doc_chunk(own, _DOC_ROWS), _doc_chunk(other, _DOC_KEYS)
+    ranges = []
+    for r0 in range(0, own, chunk):
+        first, last = own_at + r0, own_at + r0 + chunk - 1
+        see_lo, see_hi = ((first, bound_at(last)) if by_keys
+                          else (bound_at(first), last))
+        start = jnp.maximum(see_lo - other_at, 0) // unit
+        count = jnp.minimum(see_hi - other_at, other - 1) // unit + 1 - start
+        ranges.append(jnp.where(live & ~inside & (count > 0),
+                                start * (other // unit + 1) + count, 0))
+    flat = lambda x: x.astype(jnp.int32).reshape(-1)
+    return (ends[:, -1].max(), (flat(own_of), flat(other_of), flat(kind_of),
+                                flat(jnp.stack(ranges, axis=-1))),
+            bounds[:, :, None])
 
 
 def _step_of(band, i, step) -> tuple:
@@ -423,12 +485,9 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
         qi, step = pl.program_id(1), pl.program_id(2)
         kb, live, window = _step_of(band, qi, step)
     else:
-        qi, step, last = docs.tile(block_q, block_k)
-        kb, window = docs.reach(qi * block_q, block_q)[0] // block_k + step, \
-            None
-        live = kb <= last       # the diagonal's block
+        (qi, kb, first, last), window = docs.tile(), None
 
-    @pl.when(step == 0)
+    @pl.when(step == 0 if docs is None else first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -468,9 +527,9 @@ def _fwd_kernel(*refs, scale: float, causal: bool, block_q: int,
         _on_tiles(tile, qi, kb, causal=causal, block_q=block_q,
                   block_k=block_k, window=window, live=live)
     else:
-        docs.on_tiles(tile, rows, qi, kb, live, block_q, block_k)
+        docs.on_tiles(tile, rows, qi, kb, block_q, block_k)
 
-    @pl.when(step == (pl.num_programs(2) - 1 if docs is None else last))
+    @pl.when(step == pl.num_programs(2) - 1 if docs is None else last)
     def _store():
         l = jnp.maximum(l_ref[:], 1e-30)
         o_ref[:] = (acc_ref[:] / l).astype(o_ref.dtype)
@@ -605,9 +664,10 @@ def _staged(kind: str, heads: int, seq: int, block_q: int, block_k: int,
     """Count one staging of the kernel ``kind`` and the tiles of its call
     by kind (``heads`` grids of ``_grid_offsets``; a non-causal call's are
     all interior, a windowed grid's dead steps are skipped; a ``packed``
-    call's grid is the tiles at or under the diagonal, all ``by_data``: the
-    documents decide on the device which of them run).  A wrapper's Python runs at
-    trace time: once a shape behind ``jax.jit``, once a call for a bare
+    call counts the steps a head's list can hold at most, the tiles at or
+    under the diagonal, as ``by_data``: the documents decide on the device
+    how many of them the list holds).  A wrapper's Python runs at trace
+    time: once a shape behind ``jax.jit``, once a call for a bare
     kernel, and each staging is a Mosaic lowering."""
     kernel = _kernel_name(kind, band, packed)
     telemetry.inc("bf_kernel_stagings_total", kernel=kernel)
@@ -716,6 +776,8 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, vma=None,
     bh = B * H
     fold = lambda t: t.transpose(0, 2, 1, 3).reshape(bh, S, t.shape[-1])
     qf, kf, vf = fold(q), fold(k), fold(v)
+    if ids is not None:
+        block_q = min(block_q, _DOC_BLOCK)
     block_q = _fit_block(block_q, S)
     block_k = _fit_block(_window_block(block_k, window), S)
     _staged("fwd", bh, S, block_q, block_k, causal,
@@ -743,12 +805,9 @@ def _dq_kernel(*refs, scale: float, causal: bool, block_q: int,
         qi, step = pl.program_id(1), pl.program_id(2)
         kb, live, window = _step_of(band, qi, step)
     else:
-        qi, step, last = docs.tile(block_q, block_k)
-        kb, window = docs.reach(qi * block_q, block_q)[0] // block_k + step, \
-            None
-        live = kb <= last       # the diagonal's block
+        (qi, kb, first, last), window = docs.tile(), None
 
-    @pl.when(step == 0)
+    @pl.when(step == 0 if docs is None else first)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
@@ -781,9 +840,9 @@ def _dq_kernel(*refs, scale: float, causal: bool, block_q: int,
         _on_tiles(tile, qi, kb, causal=causal, block_q=block_q,
                   block_k=block_k, window=window, live=live)
     else:
-        docs.on_tiles(tile, rows, qi, kb, live, block_q, block_k)
+        docs.on_tiles(tile, rows, qi, kb, block_q, block_k)
 
-    @pl.when(step == (pl.num_programs(2) - 1 if docs is None else last))
+    @pl.when(step == pl.num_programs(2) - 1 if docs is None else last)
     def _store():
         dq_ref[:] = acc_ref[:].astype(dq_ref.dtype)
 
@@ -803,11 +862,9 @@ def _dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
         kb, step = pl.program_id(1), pl.program_id(2)
         qi, live, window = _step_of(band, kb, step)
     else:
-        kb, step, last = docs.tile(block_q, block_k)
-        qi, window = (kb * block_k) // block_q + step, None
-        live = qi <= docs.reach(kb * block_k, block_k)[1] // block_q
+        (kb, qi, first, last), window = docs.tile(), None
 
-    @pl.when(step == 0)
+    @pl.when(step == 0 if docs is None else first)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
@@ -852,9 +909,9 @@ def _dkv_kernel(*refs, scale: float, causal: bool, block_q: int,
         _on_tiles(tile, qi, kb, causal=causal, block_q=block_q,
                   block_k=block_k, window=window, live=live)
     else:
-        docs.on_tiles(tile, rows, qi, kb, live, block_q, block_k)
+        docs.on_tiles(tile, rows, qi, kb, block_q, block_k)
 
-    @pl.when(step == (pl.num_programs(2) - 1 if docs is None else last))
+    @pl.when(step == pl.num_programs(2) - 1 if docs is None else last)
     def _store():
         dk_ref[:] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_acc[:].astype(dv_ref.dtype)
@@ -941,6 +998,8 @@ def _bwd(block_q, block_k, interpret, vma, window, res, cotangents,
     delta = delta - dlse.astype(jnp.float32).transpose(0, 2, 1) \
         .reshape(bh, S)[..., None]
 
+    if ids is not None:
+        block_q = min(block_q, _DOC_BLOCK)
     block_q = _fit_block(block_q, S)
     block_k = _fit_block(block_k, S)
     if D > 128 and block_q * block_k > 512 * 1024:
@@ -964,55 +1023,25 @@ def _bwd(block_q, block_k, interpret, vma, window, res, cotangents,
     return unfold(dq), unfold(dk), unfold(dv)
 
 
-def _bounds(ids, block: int, by_keys: bool) -> tuple:
-    """What a packed grid knows of the documents ``ids`` ``(B, S)``, as
-    ``_Docs`` has it: ``((first, last), bounds, granule)``.  ``bounds`` ``(B,
-    S, 1)``: of every position the first position of its document (the
-    last, ``by_keys``), found from where the ids change along a row;
-    ``first`` and ``last``, read ahead of the grid: the bounds of the first
-    and of the last position of every ``granule`` positions, flat; the
-    granule is a chunk of the own ``block``."""
-    B, S = ids.shape
-    at = jnp.arange(S, dtype=jnp.int32)
-    changes = ids[:, 1:] != ids[:, :-1]
-    edge = jnp.ones((B, 1), bool)
-    if by_keys:
-        bounds = jax.lax.cummin(jnp.where(jnp.concatenate(
-            [changes, edge], axis=1), at, S - 1), axis=1, reverse=True)
-    else:
-        bounds = jax.lax.cummax(jnp.where(jnp.concatenate(
-            [edge, changes], axis=1), at, 0), axis=1)
-    granule = _doc_chunk(block, _DOC_ROWS)
-    return ((bounds[:, ::granule].reshape(-1),
-             bounds[:, granule - 1::granule].reshape(-1)),
-            bounds[:, :, None], granule)
+# which of a step's two blocks an operand of a packed grid moves with
+# (``_packed_specs``)
+_OWN, _OTHER = 0, 1
 
 
-def _packed_specs(heads: int) -> tuple:
-    """Block specs of a packed grid's operands: ``at(block, dim)(sel)`` as
-    in ``_bwd_calls``, ``sel`` handed the own block and the step of the
-    grid's step (``_under_diagonal``) and the bounds' scalars behind them,
-    and ``column(block, sel)`` for the bounds ``(B, S, 1)``, one row of the
-    batch for its heads."""
-    at = lambda block, dim: lambda sel: pl.BlockSpec(
-        (None, block, dim), lambda b, t, first, last, own, step: (
-            b, sel(own[t], step[t], b, first, last), 0))
-    column = lambda block, sel: pl.BlockSpec(
-        (None, block, 1), lambda b, t, first, last, own, step: (
-            b // heads, sel(own[t], step[t], b, first, last), 0))
+def _packed_specs(heads: int, capacity: int) -> tuple:
+    """Block specs of a packed grid's operands: ``at(block, dim)(of)`` as
+    in ``_bwd_calls``, ``of`` ``_OWN`` for the own block of the grid's step
+    and ``_OTHER`` for the other (``_Docs.own_of`` and ``other_of``, the list's scalars
+    behind the grid's indices), and ``column(block)`` for the own block of
+    the bounds ``(B, S, 1)``, one row of the batch for its heads."""
+    step = lambda b, t: b // heads * capacity + t
+    at = lambda block, dim: lambda of: pl.BlockSpec(
+        (None, block, dim), lambda b, t, *lists: (
+            b, lists[of][step(b, t)], 0))
+    column = lambda block: pl.BlockSpec(
+        (None, block, 1), lambda b, t, own_of, *_: (
+            b // heads, own_of[step(b, t)], 0))
     return at, column
-
-
-def _reach_by_queries(heads: int, seq: int, block_q: int, block_k: int,
-                      granule: int):
-    """``(i, j, b, first, last) ->`` the key block of step ``j`` of query
-    block ``i``: from the block where the first query's document begins to
-    the diagonal (a step past it repeats the diagonal's block: no DMA)."""
-    def red(i, j, b, first, last):
-        lo = first[b // heads * (seq // granule) + i * (block_q // granule)]
-        return jnp.minimum(lo // block_k + j,
-                           ((i + 1) * block_q - 1) // block_k)
-    return red
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -1022,21 +1051,19 @@ def _packed_fwd_call(qf, kf, vf, *, ids, scale, causal, block_q, block_k,
     and the per-row logsumexp ``(B*H, S, 1)``."""
     bh, S, D = qf.shape
     Dv, heads = vf.shape[-1], bh // ids.shape[0]
-    scalars, bounds, granule = _bounds(ids, block_q, False)
-    tiles = _under_diagonal(block_q, block_k, S, False)
-    own = lambda i, j, *_: i
-    red = _reach_by_queries(heads, S, block_q, block_k, granule)
-    at, column = _packed_specs(heads)
+    steps, lists, bounds = _doc_work(ids, block_q, block_k, False)
+    capacity = _doc_capacity(block_q, block_k, S)
+    at, column = _packed_specs(heads, capacity)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=True,
                           block_q=block_q, block_k=block_k,
-                          packed=(heads, False, granule, S)),
+                          packed=(heads, False, capacity)),
         name=_kernel_name("fwd", None, True),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(bh, len(tiles[0])),
-            in_specs=[column(block_q, own), at(block_q, D)(own),
-                      at(block_k, D)(red), at(block_k, Dv)(red)],
-            out_specs=[at(block_q, Dv)(own), at(block_q, 1)(own)],
+            num_scalar_prefetch=4, grid=(bh, steps),
+            in_specs=[column(block_q), at(block_q, D)(_OWN),
+                      at(block_k, D)(_OTHER), at(block_k, Dv)(_OTHER)],
+            out_specs=[at(block_q, Dv)(_OWN), at(block_q, 1)(_OWN)],
             scratch_shapes=[pltpu.VMEM((block_q, Dv), jnp.float32),
                             pltpu.VMEM((block_q, 1), jnp.float32),
                             pltpu.VMEM((block_q, 1), jnp.float32)]),
@@ -1045,7 +1072,7 @@ def _packed_fwd_call(qf, kf, vf, *, ids, scale, causal, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*scalars, *tiles, bounds, qf, kf, vf)
+    )(*lists, bounds, qf, kf, vf)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -1054,52 +1081,41 @@ def _packed_bwd_calls(qf, kf, vf, dof, lse3, delta, *, ids, scale, causal,
     """``bf_flash_seg_dq`` and ``bf_flash_seg_dkv``: ``dq, dk, dv``."""
     bh, S, D = qf.shape
     Dv, heads = vf.shape[-1], bh // ids.shape[0]
-    own = lambda i, j, *_: i
     params = dict(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret)
     kernel = dict(scale=scale, causal=True, block_q=block_q, block_k=block_k)
-    at, column = _packed_specs(heads)
+    capacity = _doc_capacity(block_q, block_k, S)
+    at, column = _packed_specs(heads, capacity)
     q_at, k_at = at(block_q, D), at(block_k, D)
     do_at, v_at, r_at = at(block_q, Dv), at(block_k, Dv), at(block_q, 1)
 
-    scalars, bounds, granule = _bounds(ids, block_q, False)
-    tiles = _under_diagonal(block_q, block_k, S, False)
-    red_dq = _reach_by_queries(heads, S, block_q, block_k, granule)
+    steps, lists, bounds = _doc_work(ids, block_q, block_k, False)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kernel,
-                          packed=(heads, False, granule, S)),
+                          packed=(heads, False, capacity)),
         name=_kernel_name("dq", None, True),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(bh, len(tiles[0])),
-            in_specs=[column(block_q, own), q_at(own), k_at(red_dq),
-                      v_at(red_dq), do_at(own), r_at(own), r_at(own)],
-            out_specs=q_at(own),
+            num_scalar_prefetch=4, grid=(bh, steps),
+            in_specs=[column(block_q), q_at(_OWN), k_at(_OTHER), v_at(_OTHER),
+                      do_at(_OWN), r_at(_OWN), r_at(_OWN)],
+            out_specs=q_at(_OWN),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((bh, S, D), qf.dtype, vma=vma),
         **params,
-    )(*scalars, *tiles, bounds, qf, kf, vf, dof, lse3, delta)
+    )(*lists, bounds, qf, kf, vf, dof, lse3, delta)
 
-    scalars, bounds, granule = _bounds(ids, block_k, True)
-    tiles = _under_diagonal(block_q, block_k, S, True)
-
-    def red_kv(i, j, b, first, last):
-        """From the key block's frontier to the query block where its last
-        key's document ends."""
-        hi = last[b // heads * (S // granule)
-                  + (i + 1) * (block_k // granule) - 1]
-        return jnp.minimum((i * block_k) // block_q + j, hi // block_q)
-
+    steps, lists, bounds = _doc_work(ids, block_q, block_k, True)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kernel,
-                          packed=(heads, True, granule, S)),
+                          packed=(heads, True, capacity)),
         name=_kernel_name("dkv", None, True),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(bh, len(tiles[0])),
-            in_specs=[column(block_k, own), q_at(red_kv), k_at(own),
-                      v_at(own), do_at(red_kv), r_at(red_kv), r_at(red_kv)],
-            out_specs=[k_at(own), v_at(own)],
+            num_scalar_prefetch=4, grid=(bh, steps),
+            in_specs=[column(block_k), q_at(_OTHER), k_at(_OWN), v_at(_OWN),
+                      do_at(_OTHER), r_at(_OTHER), r_at(_OTHER)],
+            out_specs=[k_at(_OWN), v_at(_OWN)],
             scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                             pltpu.VMEM((block_k, Dv), jnp.float32)]),
         out_shape=[
@@ -1107,7 +1123,7 @@ def _packed_bwd_calls(qf, kf, vf, dof, lse3, delta, *, ids, scale, causal,
             jax.ShapeDtypeStruct((bh, S, Dv), vf.dtype, vma=vma),
         ],
         **params,
-    )(*scalars, *tiles, bounds, qf, kf, vf, dof, lse3, delta)
+    )(*lists, bounds, qf, kf, vf, dof, lse3, delta)
     return dq, dk, dv
 
 
@@ -1207,16 +1223,21 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
     are contiguous, as ``data.pack_documents`` lays them); the kernels do
     not check data, and an id that comes back after another gives a
     document of its own or wrong tiles, not an error.
-    The kernels are then ``bf_flash_seg_fwd / dq / dkv``.  Where each
-    block's documents begin and end is read ahead of the grid, so a tile
-    whose keys all lie in
-    earlier documents than all its queries runs no product and moves no
-    block; a tile inside one document runs what the causal kernel runs; a
-    tile that a boundary crosses works in chunks of 256 own rows, each on
-    its visible range of keys rounded out to whole 256 in one step of the
-    softmax state, masked by document beside the diagonal
-    (``segment_tiles`` counts the three kinds on the host).  Together with
-    ``window``, or without ``causal``, it raises.
+    The kernels are then ``bf_flash_seg_fwd / dq / dkv``, on query blocks of
+    at most 512 (``block_q`` fitted down) and the key blocks asked for.
+    They run a list of live work that the call makes from the ids on the
+    device: for every block of own positions the range of blocks of the
+    other dimension that hold a visible pair, one after the other; the
+    grid's second dimension is that list's length, so a tile whose keys all
+    lie in earlier documents than all its queries is no step at all, and
+    the rows of a batch pad to the longest list.  A tile inside one
+    document runs what the causal kernel runs; a tile that a boundary
+    crosses works in chunks of 256 own rows, each on its visible range of
+    keys rounded out to whole 256 in one step of the softmax state, masked
+    by document beside the diagonal (``segment_steps`` counts on the host
+    how much of its capacity a layout's list fills, ``segment_tiles`` the
+    tiles of the three kinds).  Together with ``window``, or without
+    ``causal``, it raises.
 
     ``interpret=None`` compiles the Mosaic kernel when the devices in use
     (:func:`platform_in_use`) are TPUs and runs the Pallas interpreter
@@ -1230,7 +1251,10 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 1024,
     PR 37, the kernels alone on causal bfloat16 ``(B*H, S, D)``; forward /
     dq / dkv in ms: (64, 8192, 64) 11.1 / 13.7 / 15.7; (16, 16384, 128) 9.0 /
     10.9 / 13.2; (32, 4096, 128) 1.44 / 1.79 / 2.03; (32, 4096, 192) with
-    values of 128, the backward at 1024 x 512, 2.22 / 3.29 / 3.43)."""
+    values of 128, the backward at 1024 x 512, 2.22 / 3.29 / 3.43; PR 48,
+    (32, 8192, 192) with values of 128 packed from ten documents of 2961
+    to 47, at 512 x 1024: 2.65 / 2.88 / 3.54, and 2.44 forward with
+    ``block_k=2048``)."""
     return flash_attention_lse(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret,
                                vma=vma, scale=scale, window=window,
@@ -1302,6 +1326,26 @@ def segment_tiles(segment_ids, block_q: int = 1024, block_k: int = 1024
     count = lambda hit: int((hit & under).sum())
     return {"dead": count(dead), "crossed": count(~dead & ~inside),
             "inside": count(inside)}
+
+
+def segment_steps(segment_ids, block_q: int = _DOC_BLOCK,
+                  block_k: int = 1024) -> dict:
+    """Of the steps a head's list can hold at ``(block_q, block_k)`` blocks
+    (``capacity``: the tiles at or under the diagonal, what
+    ``bf_flash_tiles_total{kind="by_data"}`` counts a head at staging), how
+    many the packed rows ``segment_ids`` ``(B, S)`` fill (``listed``: what
+    ``segment_tiles`` counts as ``crossed`` or ``inside``), by the rule the
+    device applies (``_doc_reach``), summed over the rows; the grid's
+    second dimension is the longest row's.  The blocks are fitted to ``S``
+    and no further: a packed call's query blocks are ``_DOC_BLOCK`` at
+    most, the default.  Computed on the host with numpy: for logging and tests."""
+    ids = np.asarray(segment_ids)
+    S = ids.shape[1]
+    block_q, block_k = _fit_block(block_q, S), _fit_block(block_k, S)
+    lo, hi = _doc_reach(np, _doc_bounds(np, ids, False), block_q, block_k,
+                        False)
+    return {"listed": int((hi - lo + 1).sum()),
+            "capacity": len(ids) * _doc_capacity(block_q, block_k, S)}
 
 
 def flash_attention_impl(block_q: int = 1024, block_k: int = 1024,

@@ -695,11 +695,15 @@ def test_a_wide_bands_forward_goes_through_the_grid(window_qkv, window,
 
 def _ids(lengths, batch):
     """``(batch, sum(lengths))`` document ids; row ``b``'s documents are the
-    lengths rotated by ``b``, so the rows' boundaries differ."""
-    rows = [np.repeat(np.arange(len(lengths)),
-                      lengths[b % len(lengths):] + lengths[:b % len(lengths)])
-            for b in range(batch)]
-    return jnp.asarray(np.stack(rows), jnp.int32)
+    lengths rotated by ``b``, so the rows' boundaries differ, or, where
+    ``lengths`` is a tuple of lists, row ``b``'s are its ``b``-th list."""
+    if isinstance(lengths, tuple):
+        rows = [lengths[b % len(lengths)] for b in range(batch)]
+    else:
+        rows = [lengths[b % len(lengths):] + lengths[:b % len(lengths)]
+                for b in range(batch)]
+    return jnp.asarray(np.stack([np.repeat(np.arange(len(row)), row)
+                                 for row in rows]), jnp.int32)
 
 
 def _dense_documents(q, k, v, ids, scale=None):
@@ -717,6 +721,11 @@ def _dense_documents(q, k, v, ids, scale=None):
     return out, lse.transpose(0, 2, 1)
 
 
+# the cell's row (kanana2-packed-s8192-1chip) and a head's tiles by hand:
+# forward 1024 x 1024: 36 at or under the diagonal; 17 hold a visible pair
+CELL_DOCUMENTS = [2961, 1734, 1207, 811, 562, 377, 243, 161, 89, 47]
+CELL_DOCUMENTS_SHORT = [370, 217, 151, 101, 70, 47, 30, 20, 11, 7]
+
 # (S, lengths, blocks): boundaries off every block edge; a document shorter
 # than a block and than a chunk; blocks that differ (the backward at heads
 # over 128 runs 1024 x 512); dead tiles (the last documents' keys start
@@ -732,6 +741,19 @@ PACKED = [
     (2048, [700, 1, 333, 600, 414], (1024, 1024)),
     (2048, [1300, 748], (1024, 512)),
     (2048, [300, 300, 300, 300, 300, 300, 248], (512, 1024)),
+    # boundaries on block edges: no tile is crossed, most are dead
+    (512, [128, 256, 128], (128, 128)),
+    # every document shorter than a block: every live tile is crossed
+    (512, [100, 90, 110, 80, 70, 62], (128, 128)),
+    # one document: the list is the whole triangle
+    (512, [512], (128, 128)),
+    # the cell's ten lengths over eight: a long head and a tail of short
+    # documents in the last block, at small blocks and at the default ones
+    (1024, CELL_DOCUMENTS_SHORT, (128, 128)),
+    (1024, CELL_DOCUMENTS_SHORT, (1024, 1024)),
+    # two rows whose lists differ in length: the shorter one is padded
+    (512, ([512], [60, 70, 80, 90, 100, 112]), (128, 128)),
+    (1024, ([1000, 24], CELL_DOCUMENTS_SHORT), (256, 128)),
 ]
 
 
@@ -771,6 +793,38 @@ def test_documents_match_a_masked_softmax(packed_qkv, seq, lengths, blocks,
     np.testing.assert_allclose(lse, ref_lse, rtol=2e-5, atol=2e-5)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("seq, lengths, blocks", [
+    (512, [130, 200, 60, 122], (128, 128)),
+    (1024, [333, 5, 274, 412], (512, 256)),
+    (2048, [700, 1, 333, 600, 414], (1024, 1024)),
+    (512, [100, 90, 110, 80, 70, 62], (128, 128)),
+    (1024, CELL_DOCUMENTS_SHORT, (1024, 1024)),
+    (1024, ([1000, 24], CELL_DOCUMENTS_SHORT), (256, 128))])
+def test_documents_match_under_checkpoint(packed_qkv, seq, lengths, blocks,
+                                          dtype):
+    """The forward and the gradients of a rematerialised packed call (the
+    list is made again with the recomputed forward) against the plain
+    masked softmax of the same operands in float32."""
+    q, k, v = (t.astype(dtype) for t in packed_qkv(seq, 192, 128))
+    ids = _ids(lengths, 2)
+
+    def grads(fn):
+        def f(q, k, v):
+            out = fn(q, k, v).astype(jnp.float32)
+            return jnp.sum(out ** 2), out
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    got, out = grads(jax.checkpoint(lambda q, k, v: flash_attention(
+        q, k, v, segment_ids=ids, block_q=blocks[0], block_k=blocks[1])))
+    want, ref = grads(lambda q, k, v: _dense_documents(q, k, v, ids)[0])
+    tol = 2e-3 if dtype == jnp.float32 else 5e-2
+    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a.astype(jnp.float32), b, rtol=tol,
+                                   atol=tol * float(jnp.abs(b).max()))
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -818,11 +872,6 @@ def test_documents_raise_where_they_cannot_run(packed_qkv):
         ulysses_attention(q, k, v, axis_name="sp", segment_ids=ids)
 
 
-# the cell's row (kanana2-packed-s8192-1chip) and a head's tiles by hand:
-# forward 1024 x 1024: 36 at or under the diagonal; 17 hold a visible pair
-CELL_DOCUMENTS = [2961, 1734, 1207, 811, 562, 377, 243, 161, 89, 47]
-
-
 def test_segment_tiles_counts_the_cells_row_by_hand():
     from bluefog_tpu.ops.flash_attention import segment_tiles
     ids = np.repeat(np.arange(10), CELL_DOCUMENTS)[None]
@@ -841,13 +890,84 @@ def test_segment_tiles_counts_the_cells_row_by_hand():
         "dead": 0, "crossed": 0, "inside": 36}
 
 
+def test_segment_steps_counts_the_cells_row_by_hand():
+    from bluefog_tpu.ops.flash_attention import segment_steps, segment_tiles
+    ids = np.repeat(np.arange(10), CELL_DOCUMENTS)[None]
+    # what the parent's grid walked, 36 steps a head, holds 17 live tiles
+    assert segment_steps(ids, 1024, 1024) == {"listed": 17, "capacity": 36}
+    assert segment_steps(ids, 512, 512) == {"listed": 49, "capacity": 136}
+    # a packed call's own blocks: query blocks of 512 on key blocks of 1024.
+    # A query block's steps run from the key block where its first query's
+    # document begins to the diagonal's.  The documents begin at 0, 2961,
+    # 4695, 5902, 6713, 7275, 7652, ...: query blocks 0 to 5 begin in the
+    # first (key block 0: 1 + 1 + 2 + 2 + 3 + 3 steps), 6 to 9 in the second
+    # (key block 2: 2 + 2 + 3 + 3), 10 and 11 in the third (key block 4: 2 +
+    # 2), 12 and 13 in the fourth (key block 5: 2 + 2), 14 in the fifth
+    # (key block 6: 2) and 15 in the seventh (key block 7: 1)
+    assert segment_steps(ids) == {
+        "listed": 1 + 1 + 2 + 2 + 3 + 3 + 2 + 2 + 3 + 3 + 2 + 2 + 2 + 2 + 2
+        + 1, "capacity": 72}
+    # the listed steps are the tiles that are not dead, at any blocks
+    for blocks in ((1024, 1024), (512, 1024), (512, 512), (256, 512)):
+        tiles = segment_tiles(ids, *blocks)
+        assert segment_steps(ids, *blocks) == {
+            "listed": tiles["crossed"] + tiles["inside"],
+            "capacity": sum(tiles.values())}
+    # two rows count twice; one document fills the list
+    assert segment_steps(np.concatenate([ids, ids]), 512, 512) == {
+        "listed": 98, "capacity": 272}
+    assert segment_steps(np.zeros((1, 8192), int)) == {
+        "listed": 72, "capacity": 72}
+
+
+def test_the_devices_list_is_the_hosts_count():
+    """``_doc_work`` (the list a packed call makes on the device) against
+    ``segment_steps`` and a walk over the tiles by hand: every live tile
+    once, own block after own block, the first and the last step of each
+    marked, the steps past a shorter row's list with no bit and no range."""
+    from bluefog_tpu.ops import flash_attention as fa
+    ids = _ids(([1000, 24], CELL_DOCUMENTS_SHORT), 2)
+    for by_keys in (False, True):
+        steps, (own_of, other_of, kind_of, range_of), _ = fa._doc_work(
+            ids, 128, 256, by_keys)
+        capacity = fa._doc_capacity(128, 256, 1024)
+        listed = [fa.segment_steps(np.asarray(ids[b:b + 1]), 128, 256)
+                  for b in range(2)]
+        assert capacity == listed[0]["capacity"] == 2 * (1 + 2 + 3 + 4)
+        assert int(steps) == max(row["listed"] for row in listed)
+        for b in range(2):
+            own, other, kind, reach = (
+                np.asarray(x).reshape(2, capacity, -1)[b].max(axis=-1)
+                for x in (own_of, other_of, kind_of, range_of))
+            n = listed[b]["listed"]
+            # a listed tile lies inside a document or has a range to run
+            assert (((kind[:n] & fa._INSIDE) != 0) != (reach[:n] != 0)).all()
+            assert (kind[n:] == 0).all() and (reach[n:] == 0).all()
+            qi, kb = (other, own) if by_keys else (own, other)
+            row = np.asarray(ids[b])
+            live = {(i, j) for i in range(8) for j in range(4)
+                    if j * 256 <= i * 128 + 127
+                    and row[j * 256 + 255] >= row[i * 128]}
+            assert set(zip(qi[:n], kb[:n])) == live and len(live) == n
+            assert (np.diff(own[:n]) >= 0).all()
+            first, last = (kind & fa._FIRST) != 0, (kind & fa._LAST) != 0
+            blocks = len(set(own[:n]))
+            assert first.sum() == last.sum() == blocks == (4 if by_keys
+                                                            else 8)
+            assert (first[:n] == np.r_[True, np.diff(own[:n]) > 0]).all()
+            assert (last[:n] == np.r_[np.diff(own[:n]) > 0, True]).all()
+
+
 def test_packed_calls_have_names_grids_and_by_data_tiles_of_their_own():
-    """Traced, nothing runs: the masked kernels' names at the cell's shapes,
-    grids of the tiles at or under the diagonal and no others (36 a head at
-    1024 x 1024, 72 at 1024 x 512, where the causal kernels' have 64 and
-    128 steps), four scalar arrays read ahead of the grid (the bounds'
-    first and last, the tiles' own block and step), the documents' bounds
-    beside the operands, and ``bf_flash_tiles_total``'s ``by_data``."""
+    """Traced, nothing runs: the masked kernels' names at the cell's shapes;
+    grids whose second dimension is data (the length of the list of live
+    work) where the causal kernels' have 64 and 128 static steps; query
+    blocks of 512 on the key blocks the caller asked for; four scalar
+    arrays read ahead of the grid (the list: own block, other block, kind
+    and the crossed chunks' ranges), each with room for the tiles at or
+    under the diagonal, 72 a head; the documents' bounds beside the
+    operands; and ``bf_flash_tiles_total``'s ``by_data``, that capacity."""
+    from jax._src.pallas import core as pallas_core
     from bluefog_tpu.utils import telemetry
     q = jax.ShapeDtypeStruct((1, 8192, 32, 192), jnp.bfloat16)
     v = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16)
@@ -867,17 +987,34 @@ def test_packed_calls_have_names_grids_and_by_data_tiles_of_their_own():
     after = tiles()
     assert [c.params["name"] for c in calls] == [
         "bf_flash_seg_fwd", "bf_flash_seg_dq", "bf_flash_seg_dkv"]
-    for call, grid, operands, under in zip(
-            calls, ((32, 36), (32, 72), (32, 72)), (6, 8, 9),
-            (36, 72, 72)):
-        mapping = call.params["grid_mapping"]
-        assert mapping.grid == grid
+    bq, bk, own_chunks = 512, 1024, {"bf_flash_seg_fwd": 2,
+                                     "bf_flash_seg_dq": 2,
+                                     "bf_flash_seg_dkv": 4}
+    rows = (None, bq, 1)
+    expected = {
+        "bf_flash_seg_fwd": [rows, (None, bq, 192), (None, bk, 192),
+                             (None, bk, 128), (None, bq, 128), rows],
+        "bf_flash_seg_dq": [rows, (None, bq, 192), (None, bk, 192),
+                            (None, bk, 128), (None, bq, 128), rows, rows,
+                            (None, bq, 192)],
+        "bf_flash_seg_dkv": [(None, bk, 1), (None, bq, 192), (None, bk, 192),
+                             (None, bk, 128), (None, bq, 128), rows, rows,
+                             (None, bk, 192), (None, bk, 128)],
+    }
+    for call in calls:
+        mapping, name = call.params["grid_mapping"], call.params["name"]
+        assert mapping.grid == (32, pallas_core.dynamic_grid_dim)
+        assert mapping.num_dynamic_grid_bounds == 1
         assert mapping.num_index_operands == 4
-        assert len(mapping.block_mappings) == operands
-        name = call.params["name"]
+        steps, *lists = call.invars[:5]
+        assert steps.aval.shape == () and [x.aval.shape for x in lists] == [
+            (72,), (72,), (72,), (72 * own_chunks[name],)]
+        assert [tuple(getattr(dim, "block_size", None)
+                      for dim in m.block_shape)
+                for m in mapping.block_mappings] == expected[name]
         counted = {kind: after.get(key, 0) - before.get(key, 0)
                    for kind in ("skipped", "by_data", "crossed", "interior")
                    for key in [f'bf_flash_tiles_total{{kernel="{name}",'
                                f'kind="{kind}"}}']}
-        assert counted == {"skipped": 0, "by_data": 32 * under,
+        assert counted == {"skipped": 0, "by_data": 32 * 72,
                            "crossed": 0, "interior": 0}
