@@ -887,7 +887,8 @@ class Mamba2Mixer(nn.Module):
     ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``, ``o_t = h_t C_t + D
     x_t`` runs chunk by chunk (``ops.ssd.ssd_scan`` at ``cfg.ssm_chunk``);
     then ``o <- RMSNorm(o * silu(z))`` over each of the ``G`` groups of ``I /
-    G`` values with one scale of ``I``, in float32, and ``o W_out``.
+    G`` values with one scale of ``I``, in float32
+    (``ops.gated_norm.gated_rms_norm``), and ``o W_out``.
 
     Leaves: ``in/kernel`` ``(d, 2 I + 2 G N + H)``, ``conv_w`` ``(I + 2 G
     N, taps)``, ``conv_b``, ``dt_bias``, ``A_log``, ``D`` ``(H,)``,
@@ -898,11 +899,20 @@ class Mamba2Mixer(nn.Module):
 
     Device scopes: ``bf.ssm.in``, ``bf.ssm.conv`` (taps, bias, SiLU),
     ``bf.ssm.scan`` (time steps, decays, the chunked scan, the skip),
-    ``bf.ssm.norm`` (gate and grouped norm) and ``bf.ssm.out``."""
+    ``bf.ssm.norm`` (gate and grouped norm: the Pallas kernels
+    ``bf_gated_norm_fwd`` and ``bf_gated_norm_bwd``, forward, remat
+    recompute and transpose, beside the cut of ``z`` out of the
+    in-projection's result and the sum for ``norm_scale``'s gradient) and
+    ``bf.ssm.out``.  On a TPU a group ``I / G`` that is no multiple of 128
+    raises (``gated_norm.check_tileable``), as the scan's shapes do
+    (``ssd.check_tileable``); ``bf_kernel_stagings_total{kernel=
+    "bf_gated_norm_fwd" | "bf_gated_norm_bwd"}`` counts the shapes the norm's
+    kernels were staged for."""
     cfg: Any
 
     @nn.compact
     def __call__(self, y):
+        from bluefog_tpu.ops.gated_norm import gated_rms_norm
         from bluefog_tpu.ops.ssd import ssd_scan
         cfg = self.cfg
         H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
@@ -942,13 +952,8 @@ class Mamba2Mixer(nn.Module):
                 xbc[..., inner + G * N:].reshape(B_, S, G, N),
                 chunk=cfg.ssm_chunk, D=skip)
         with timeline.device_scope("bf.ssm.norm"):
-            gated = (o.reshape(B_, S, inner).astype(jnp.float32)
-                     * nn.silu(z.astype(jnp.float32))).reshape(
-                         B_, S, G, inner // G)
-            gated = gated * jax.lax.rsqrt(
-                jnp.mean(gated * gated, axis=-1, keepdims=True)
-                + cfg.rms_norm_eps)
-            gated = (gated.reshape(B_, S, inner) * scale).astype(cfg.dtype)
+            gated = gated_rms_norm(o.reshape(B_, S, inner), z, scale,
+                                   groups=G, eps=cfg.rms_norm_eps)
         with timeline.device_scope("bf.ssm.out"):
             return dense(cfg.embed_dim, name="out")(gated)
 
