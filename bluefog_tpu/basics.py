@@ -462,7 +462,27 @@ def rank_map(fn):
     is per-rank by construction, so there is no replication for
     ``check_vma`` to track, and it is off: ``fn`` is ordinary model code (a
     ``lax.scan`` with a constant initial carry does not type-check under
-    it).  The result also has ``.lower(*args)``, like a jitted function."""
+    it).  The result also has ``.lower(*args)``, like a jitted function.
+
+    **A launch the runtime holds.**  A call returns once its program is
+    enqueued, arguments still being computed: that is how the host runs
+    ahead.  Where the chip cannot hold the program's results beside what is
+    in flight, the runtime holds the call until memory is free, and where
+    that is only when the arguments are ready (the step before is over),
+    running ahead buys nothing: the allocator hands out what holes it finds
+    while it waits and now and then pays for them with tens of milliseconds
+    before the program starts.  So after ``_HELD_LAUNCHES`` calls in a row
+    that each took ``_HELD_SECONDS`` or more and returned with every
+    argument ready, the calls wait for their arguments first (span
+    ``bf.rank_map.wait``, ``bf_rank_map_waits_total``) and are enqueued on
+    a chip at rest: the same wait, outside the allocator (one v5e chip, 767M
+    parameters under AdamW, PR 50: launches of 362 ms of which one in eight
+    took 405, groups of five steps 1.917 to 1.961 s; waiting first, launches
+    of 8 ms and groups of 1.922 to 1.931 s).  A launch that is quick, or
+    returns before an argument is ready, starts the count again: the host
+    is ahead there and stays ahead."""
+    import time
+
     from bluefog_tpu.utils import telemetry
     from bluefog_tpu.utils.timeline import op_span
 
@@ -484,12 +504,37 @@ def rank_map(fn):
                     out_specs=P(RANK_AXIS), check_vma=False))
         return compiled[mesh]
 
+    held = collections.Counter()  # per program: held launches in a row
+
     def mapped(*args):
         call = program()
+        if held[call] >= _HELD_LAUNCHES:
+            telemetry.inc("bf_rank_map_waits_total")
+            with op_span("rank_map", "wait"):
+                jax.block_until_ready(args)
+        t0 = time.perf_counter()
         with op_span("rank_map", "launch"):
-            return call(*args)
+            out = call(*args)
+        if held[call] < _HELD_LAUNCHES:
+            was_held = (time.perf_counter() - t0 >= _HELD_SECONDS
+                        and all(getattr(x, "is_ready", lambda: True)()
+                                for x in jax.tree.leaves(args)))
+            held[call] = held[call] + 1 if was_held else 0
+        return out
     mapped.lower = lambda *args: program().lower(*args)
     return mapped
+
+
+# A launch that took this long was held by the runtime, and this many in a
+# row that ended with their arguments ready make ``rank_map`` wait for them
+# first.  What the wait can cost is a launch on a chip at rest (5 to 8 ms
+# with a tree of 104 leaves) less what the allocator took after it let go:
+# 2% of a step at most under a hold this long.  Shorter holds are left to
+# the allocator (759.5M parameters on the same chip: holds of 240 ms in some
+# runs, which return 1 ms behind their arguments and would lose 4 to the
+# wait).
+_HELD_SECONDS = 0.3
+_HELD_LAUNCHES = 3
 
 
 def _name_program(run, name: str):

@@ -27,7 +27,7 @@ from bluefog_tpu.utils import telemetry, timeline
 
 __all__ = ["TransformerLM", "TransformerConfig", "local_attention",
            "init_cache", "generate", "DroplessMoe", "moe_stats", "ShortConv",
-           "Mamba2Mixer", "head_matrix"]
+           "Mamba2Mixer", "KimiDeltaMixer", "head_matrix"]
 
 
 def local_attention(q, k, v, *, causal: bool = True, scale: float = None,
@@ -58,7 +58,7 @@ def local_attention(q, k, v, *, causal: bool = True, scale: float = None,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-MIXERS = ("conv", "full_attention", "sliding_attention", "mamba")
+MIXERS = ("conv", "full_attention", "sliding_attention", "mamba", "kda")
 # what an entry of ``layer_types`` may say: a mixer, or "ffn" for a block
 # that is its feed-forward part alone
 LAYER_KINDS = MIXERS + ("ffn",)
@@ -88,7 +88,9 @@ class TransformerConfig:
                  attn_gate=None, rope_parameters=None, block_ffn=True,
                  ssm_heads=None, ssm_head_dim=64, ssm_groups=1,
                  ssm_state=128, ssm_chunk=128,
-                 ssm_dt_init=(0.001, 0.1, 1e-4)):
+                 ssm_dt_init=(0.001, 0.1, 1e-4), kda_chunk=64,
+                 kda_lower_bound=-5.0, router_groups=1,
+                 router_groups_kept=1):
         self.vocab_size = vocab_size
         self.num_layers = num_layers
         self.num_heads = num_heads
@@ -240,6 +242,26 @@ class TransformerConfig:
                 f"({experts_first}) must lie within num_experts "
                 f"({num_experts})")
         self.num_shared_experts = num_shared_experts
+        # ``router_groups`` > 1 (sigmoid scoring only; DeepSeek-V3's
+        # ``n_group`` / ``topk_group``): the experts lie in that many
+        # consecutive groups, a token's ``router_groups_kept`` best groups
+        # (by the sum of a group's two largest score + bias) stay and the
+        # top-k is taken among their experts alone.
+        if router_groups < 1 or not 1 <= router_groups_kept <= router_groups \
+                or (router_groups > 1 and (
+                    router_scoring != "sigmoid"
+                    or num_experts % router_groups
+                    or num_experts // router_groups < 2
+                    or router_groups_kept * (num_experts // router_groups)
+                    < num_experts_per_tok)):
+            raise ValueError(
+                f"router_groups ({router_groups}) must divide num_experts "
+                f"({num_experts}) into groups of two experts or more under "
+                f"router_scoring='sigmoid' (got {router_scoring!r}), and the "
+                f"router_groups_kept ({router_groups_kept}) of them hold "
+                f"num_experts_per_tok ({num_experts_per_tok}) experts")
+        self.router_groups = router_groups
+        self.router_groups_kept = router_groups_kept
         self.router_scoring = router_scoring
         self.routed_scaling_factor = routed_scaling_factor
         self.experts_held = experts_held
@@ -289,11 +311,13 @@ class TransformerConfig:
         # (what the other fields describe), "sliding_attention" (the same
         # attention over the last ``sliding_window`` keys only), "conv", a
         # gated short convolution of ``conv_kernel`` taps (``ShortConv``),
-        # or "mamba", a Mamba-2 mixer on the chunked scan (``Mamba2Mixer``);
-        # the entry "ffn" is a block without a mixer, its feed-forward part
-        # alone.  None = all full attention.  ``block_ffn=False``: no
-        # feed-forward part follows a mixer, so that every block is one part
-        # alone with one norm, ``x <- x + f(RMSNorm(x))`` (Nemotron-H).
+        # "mamba", a Mamba-2 mixer on the chunked scan (``Mamba2Mixer``), or
+        # "kda", Kimi Delta Attention on the chunked delta rule
+        # (``KimiDeltaMixer``); the entry "ffn" is a block without a mixer,
+        # its feed-forward part alone.  None = all full attention.
+        # ``block_ffn=False``: no feed-forward part follows a mixer, so that
+        # every block is one part alone with one norm, ``x <- x +
+        # f(RMSNorm(x))`` (Nemotron-H).
         if layer_types is not None:
             layer_types = tuple(layer_types)
             odd = set(layer_types) - set(LAYER_KINDS)
@@ -310,7 +334,8 @@ class TransformerConfig:
         self.sliding_window = sliding_window
         # "head": each head's attention output is multiplied by the sigmoid
         # of one value, a linear map of the block's normed input, before
-        # the output projection (arXiv:2505.06708's head-wise gate).
+        # the output projection (arXiv:2505.06708's head-wise gate), in
+        # plain and in latent attention alike.
         if attn_gate not in (None, "head"):
             raise ValueError(f"attn_gate {attn_gate!r} not in (None, 'head')")
         self.attn_gate = attn_gate
@@ -352,6 +377,22 @@ class TransformerConfig:
         self.ssm_state = ssm_state
         self.ssm_chunk = ssm_chunk
         self.ssm_dt_init = tuple(ssm_dt_init)
+        # A "kda" layer: ``num_heads`` heads of ``head_dim`` (None:
+        # ``embed_dim // num_heads``) for keys and values alike, a causal
+        # depthwise convolution of ``conv_kernel`` taps on each of q, k and
+        # v, log decays a step in ``(kda_lower_bound, 0)`` and the rule in
+        # chunks of ``kda_chunk`` (``ops.kda.kda_chunked`` says which chunks
+        # and which bounds it takes); ``dt_bias`` starts as a "mamba"
+        # layer's, from ``ssm_dt_init``.
+        if layer_types is not None and "kda" in layer_types and not (
+                kda_chunk >= 1 and -80.0 / 15 <= kda_lower_bound < 0):
+            raise ValueError(
+                f"a 'kda' layer needs kda_chunk ({kda_chunk}) >= 1 and a "
+                f"kda_lower_bound ({kda_lower_bound}) in [-80 / 15, 0): "
+                "the chunked rule keeps a sub-block's 15 steps of decay "
+                "inside float32")
+        self.kda_chunk = kda_chunk
+        self.kda_lower_bound = kda_lower_bound
         # The output head is the transposed embedding: no ``lm_head`` leaf.
         self.tie_embeddings = tie_embeddings
         # What a sigmoid router adds to the sum of the chosen scores before
@@ -472,6 +513,9 @@ class DroplessMoe(nn.Module):
                 renorm_eps=getattr(cfg, "router_renorm_eps", 1e-20),
                 bias=self.variable("router_state", "bias", jnp.zeros, (E,),
                                    jnp.float32).value)
+        if getattr(cfg, "router_groups", 1) > 1:
+            routing.update(n_group=cfg.router_groups,
+                           topk_group=cfg.router_groups_kept)
         with timeline.device_scope("bf.moe"):
             xt = x.reshape(B * S, d)
             with timeline.device_scope("bf.moe.route"):
@@ -683,9 +727,13 @@ class LatentAttention(nn.Module):
     ``segment_ids`` (the documents of a packed row) go to ``attn_impl``
     where there are any; ``positions`` then restart at each document.
 
+    Under ``cfg.attn_gate == "head"`` the leaf ``attn_gate`` ``(embed_dim,
+    heads)`` gates each head's result before the output projection, ``o_h
+    <- sigmoid(y W_g)_h o_h``, as in ``Block``'s plain branch.
+
     Device scopes: ``bf.mla.q``, ``bf.mla.kv``, ``bf.mla.rope``,
-    ``bf.mla.attend`` (the key's assembly and the attention itself) and
-    ``bf.mla.out``."""
+    ``bf.mla.attend`` (the key's assembly and the attention itself),
+    ``bf.mla.gate`` (where there is a gate) and ``bf.mla.out``."""
     cfg: Any
     attn_impl: Callable
 
@@ -737,6 +785,10 @@ class LatentAttention(nn.Module):
                 axis=-1)
             attn = self.attn_impl(q, k, kv[..., nope:], causal=cfg.causal,
                                   scale=scale, **_documents(segment_ids))
+        if getattr(cfg, "attn_gate", None) == "head":
+            with timeline.device_scope("bf.mla.gate"):
+                attn = attn * nn.sigmoid(dense(h, name="attn_gate")(
+                    y).astype(jnp.float32)).astype(cfg.dtype)[..., None]
         with timeline.device_scope("bf.mla.out"):
             return dense(cfg.embed_dim, name="proj")(
                 attn.reshape(B, S, h * dv))
@@ -872,6 +924,22 @@ class ShortConv(nn.Module):
             return dense(d, name="out")(gated)
 
 
+def _dt_bias_init(lo: float, hi: float, floor: float):
+    """An initialiser whose ``softplus`` is log-uniform in ``(lo, hi)``, no
+    smaller than ``floor`` (Mamba-2's time steps; Kimi Delta Attention's
+    ``dt_bias`` starts the same way)."""
+    def init(key, shape):
+        step = jnp.maximum(jnp.exp(jax.random.uniform(
+            key, shape, minval=math.log(lo), maxval=math.log(hi))), floor)
+        return step + jnp.log(-jnp.expm1(-step))    # softplus's inverse
+    return init
+
+
+def _a_log_init(key, shape):
+    """``log`` of uniform ``[1, 16]``: where ``A_log`` starts."""
+    return jnp.log(jax.random.uniform(key, shape, minval=1.0, maxval=16.0))
+
+
 class Mamba2Mixer(nn.Module):
     """Mamba-2's mixer (arXiv:2405.21060, as Nemotron-H lays it out): the
     token mixer of a block whose ``cfg.layer_types`` entry is ``"mamba"``.
@@ -929,15 +997,9 @@ class Mamba2Mixer(nn.Module):
         w = self.param("conv_w", nn.initializers.lecun_normal(
             in_axis=1, out_axis=0), (inner + 2 * G * N, taps))
         b = self.param("conv_b", nn.initializers.zeros, (inner + 2 * G * N,))
-        lo, hi, floor = cfg.ssm_dt_init
-
-        def dt_bias_init(key, shape):
-            step = jnp.maximum(jnp.exp(jax.random.uniform(
-                key, shape, minval=math.log(lo), maxval=math.log(hi))), floor)
-            return step + jnp.log(-jnp.expm1(-step))    # softplus's inverse
-        dt_bias = self.param("dt_bias", dt_bias_init, (H,))
-        a_log = self.param("A_log", lambda key, shape: jnp.log(
-            jax.random.uniform(key, shape, minval=1.0, maxval=16.0)), (H,))
+        dt_bias = self.param("dt_bias", _dt_bias_init(*cfg.ssm_dt_init),
+                             (H,))
+        a_log = self.param("A_log", _a_log_init, (H,))
         skip = self.param("D", nn.initializers.ones, (H,))
         scale = self.param("norm_scale", nn.initializers.ones, (inner,))
         with timeline.device_scope("bf.ssm.conv"):
@@ -956,6 +1018,93 @@ class Mamba2Mixer(nn.Module):
                                    groups=G, eps=cfg.rms_norm_eps)
         with timeline.device_scope("bf.ssm.out"):
             return dense(cfg.embed_dim, name="out")(gated)
+
+
+class KimiDeltaMixer(nn.Module):
+    """Kimi Delta Attention (arXiv:2510.26692, section 3, as Ling-3.0 lays
+    it out): the token mixer of a block whose ``cfg.layer_types`` entry is
+    ``"kda"``.  A linear-attention layer whose state is corrected by what it
+    already predicts for the current key, under a decay per channel.
+
+    ``H = cfg.num_heads`` heads of ``D = cfg.head_dim`` (None: ``embed_dim //
+    num_heads``; inner width ``I = H D``), keys and values alike; no bias
+    and no positional encoding.  ``[q | k | v] = silu(conv(y W_qkv))``, a
+    causal depthwise convolution of ``cfg.conv_kernel`` taps on each of the
+    ``3 I`` channels, zeros left of the sequence; ``q`` and ``k`` are
+    divided by their length over each head, ``x / sqrt(sum x^2 + 1e-6)``,
+    and ``q`` is scaled by ``D^-0.5``.  The log decays a channel of the key,
+    float32: ``g = cfg.kda_lower_bound * sigmoid(exp(A_log_h) * (y W_f +
+    dt_bias))``, each in ``(kda_lower_bound, 0)`` (``W_f`` one matrix; its
+    product leaves the array in float32); ``beta = sigmoid(y W_beta)``, one
+    value a head.  The rule, a state ``S`` of ``D x D`` a head in float32,
+    zero before the first token: ``S' = Diag(exp(g_t)) S_{t-1}``, ``S_t = S'
+    + beta_t k_t (v_t - S'^T k_t)^T``, ``o_t = S_t^T q_t``, run chunk by
+    chunk (``ops.kda.kda_chunked`` at ``cfg.kda_chunk``).  Then ``o <-
+    RMSNorm(o) * sigmoid(y W_g)`` over each head's ``D`` values in float32,
+    one learned scale of ``D`` that the heads share, and ``o W_out``.
+
+    Leaves: ``qkv/kernel`` ``(d, 3 I)``, ``conv_w`` ``(3 I, taps)``,
+    ``f/kernel`` ``(d, I)``, ``dt_bias`` ``(I,)``, ``A_log`` ``(H,)``,
+    ``beta/kernel`` ``(d, H)``, ``gate/kernel`` ``(d, I)``, ``norm_scale``
+    ``(D,)`` and ``out/kernel`` ``(I, d)``.  At init ``exp(A_log)`` is
+    uniform in ``[1, 16]`` and ``softplus(dt_bias)`` log-uniform in
+    ``cfg.ssm_dt_init``, as flash-linear-attention starts them.  Training
+    and prefill only: the rule hands on no state and the convolution keeps
+    no last taps.
+
+    Device scopes: ``bf.kda.qkv``, ``bf.kda.conv`` (taps, SiLU, the lengths),
+    ``bf.kda.gate`` (``W_f``, the decays, ``beta``), ``bf.kda.chunk`` (the
+    chunked rule), ``bf.kda.norm`` (``W_g``, the norm, the gate) and
+    ``bf.kda.out``."""
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, y):
+        from bluefog_tpu.ops.kda import kda_chunked
+        cfg = self.cfg
+        H = cfg.num_heads
+        D = getattr(cfg, "head_dim", None) or cfg.embed_dim // H
+        inner, taps, f32 = H * D, cfg.conv_kernel, jnp.float32
+        B_, S = y.shape[:2]
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=cfg.dtype)
+        with timeline.device_scope("bf.kda.qkv"):
+            qkv = dense(3 * inner, name="qkv")(y)
+        # fan-in of one channel: its ``taps`` values
+        w = self.param("conv_w", nn.initializers.lecun_normal(
+            in_axis=1, out_axis=0), (3 * inner, taps))
+        dt_bias = self.param("dt_bias", _dt_bias_init(*cfg.ssm_dt_init),
+                             (inner,))
+        a_log = self.param("A_log", _a_log_init, (H,))
+        scale = self.param("norm_scale", nn.initializers.ones, (D,))
+        with timeline.device_scope("bf.kda.conv"):
+            qkv = nn.silu(causal_taps(qkv, w.astype(cfg.dtype))).reshape(
+                B_, S, 3, H, D)
+
+            def unit(x):
+                x = x.astype(f32)
+                return x * jax.lax.rsqrt(
+                    jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+            q = (unit(qkv[:, :, 0]) * D ** -0.5).astype(cfg.dtype)
+            k = unit(qkv[:, :, 1]).astype(cfg.dtype)
+            v = qkv[:, :, 2]
+        with timeline.device_scope("bf.kda.gate"):
+            # float32 out of the array: the decays' sums run over a chunk
+            f = dense(inner, name="f", dot_general=functools.partial(
+                jax.lax.dot_general, preferred_element_type=f32))(y)
+            g = cfg.kda_lower_bound * nn.sigmoid(
+                jnp.exp(a_log.astype(f32))[:, None]
+                * (f + dt_bias).reshape(B_, S, H, D))
+            beta = nn.sigmoid(dense(H, name="beta")(y).astype(f32))
+        with timeline.device_scope("bf.kda.chunk"):
+            o = kda_chunked(q, k, v, g, beta, chunk=cfg.kda_chunk)
+        with timeline.device_scope("bf.kda.norm"):
+            o = o.astype(f32)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                                  + cfg.rms_norm_eps) * scale
+            gate = dense(inner, name="gate")(y).reshape(B_, S, H, D)
+            o = (o * nn.sigmoid(gate.astype(f32))).astype(cfg.dtype)
+        with timeline.device_scope("bf.kda.out"):
+            return dense(cfg.embed_dim, name="out")(o.reshape(B_, S, inner))
 
 
 def _documents(segment_ids) -> dict:
@@ -1012,15 +1161,16 @@ class Block(nn.Module):
         mixer: the block is then one part alone on the one residual path,
         ``x <- x + f(RMSNorm(x))``.  Only plain full attention (MHA / GQA)
         takes a cache; latent attention, a sliding window, the gated short
-        convolution and the Mamba-2 mixer keep no decode state and raise on
-        one, as does a block without a mixer.
+        convolution, the Mamba-2 mixer and Kimi Delta Attention keep no
+        decode state and raise on one, as does a block without a mixer.
 
         ``segment_ids`` ``(B, S)``: the documents of a packed row
         (``data.pack_documents``; ``positions`` restart with them).  Full
         attention, plain or latent, hands them to ``attn_impl``; a block
         that cannot keep the documents apart raises (a convolution's taps
-        and a scan's state would have to be reset at a boundary, and a
-        window's kernels know no document mask), as does a decode cache.
+        and a scan's or a delta rule's state would have to be reset at a
+        boundary, and a window's kernels know no document mask), as does a
+        decode cache.
 
         Plain attention reads its sizes by layer: ``cfg.head_dim`` (None:
         ``embed_dim // num_heads``), the block's entry of
@@ -1035,7 +1185,11 @@ class Block(nn.Module):
         ``bf.attn.qkv``, ``bf.attn.norm``, ``bf.attn.rope``,
         ``bf.attn.attend`` (the K/V fan-out and the attention itself),
         ``bf.attn.gate`` and ``bf.attn.out``; a sliding layer's are the
-        family ``bf.swa.*`` of the same names."""
+        family ``bf.swa.*`` of the same names.  The other mixers carry
+        their own: ``bf.mla.*`` (``LatentAttention``), ``bf.sconv.*``
+        (``ShortConv``), ``bf.ssm.*`` (``Mamba2Mixer``) and ``bf.kda.qkv``,
+        ``bf.kda.conv``, ``bf.kda.gate``, ``bf.kda.chunk``, ``bf.kda.norm``,
+        ``bf.kda.out`` (``KimiDeltaMixer``)."""
         cfg = self.cfg
         layer_types = getattr(cfg, "layer_types", None)
         kind = (layer_types[self.layer_idx] if layer_types is not None
@@ -1062,28 +1216,33 @@ class Block(nn.Module):
         y = nn.RMSNorm(epsilon=eps, dtype=cfg.dtype)(x)
         B, S = y.shape[0], y.shape[1]
         conv, sliding = kind == "conv", kind == "sliding_attention"
-        mamba = kind == "mamba"
+        mamba, kda = kind == "mamba", kind == "kda"
         latent = getattr(cfg, "kv_lora_rank", None) is not None
-        if cache is not None and (conv or latent or sliding or mamba):
+        if cache is not None and (conv or latent or sliding or mamba or kda):
             raise NotImplementedError(
                 "only plain full attention takes a decode cache: latent "
-                "attention, a sliding window, the gated short convolution "
-                "and the Mamba-2 mixer do not")
-        if segment_ids is not None and (conv or mamba or sliding
+                "attention, a sliding window, the gated short convolution, "
+                "the Mamba-2 mixer and Kimi Delta Attention (whose rule "
+                "hands on no state and whose convolutions keep no last "
+                "taps) do not")
+        if segment_ids is not None and (conv or mamba or kda or sliding
                                          or cache is not None):
             raise NotImplementedError(
                 f"segment_ids reached a {kind!r} block"
                 + (" with a decode cache" if cache is not None else "")
-                + ": a packed row needs the gated short convolution's taps "
-                "and the Mamba-2 scan's state reset at every boundary, a "
-                "window's kernels masked by document and a cache written "
-                "by document; only full attention (plain or latent) keeps "
-                "documents apart")
+                + ": a packed row needs the gated short convolution's taps, "
+                "the Mamba-2 scan's state and the delta rule's state and "
+                "taps reset at every boundary, a window's kernels masked "
+                "by document and a cache written by document; only full "
+                "attention (plain or latent) keeps documents apart")
         if conv:
             x = join(ShortConv(cfg, name="conv")(y))
             return ffn(x, eps)
         if mamba:
             x = join(Mamba2Mixer(cfg, name="mamba")(y))
+            return ffn(x, eps)
+        if kda:
+            x = join(KimiDeltaMixer(cfg, name="kda")(y))
             return ffn(x, eps)
         if latent:
             x = join(LatentAttention(cfg, self.attn_impl, name="mla")(
@@ -1283,6 +1442,11 @@ class TransformerLM(nn.Module):
                     "KV-cache decoding through a Mamba-2 layer is not "
                     "supported: the scan hands on no state and the "
                     "convolution keeps no last taps")
+            if "kda" in mixers:
+                raise NotImplementedError(
+                    "KV-cache decoding through a Kimi Delta Attention "
+                    "layer is not supported: the chunked rule hands on no "
+                    "state and the convolutions keep no last taps")
             if "ffn" in mixers:
                 raise NotImplementedError(
                     "KV-cache decoding through a block without a mixer is "

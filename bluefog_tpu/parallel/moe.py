@@ -60,7 +60,10 @@ the dropless layer above.  **Sigmoid scoring with a bias**
 (``route_topk(..., scoring="sigmoid", bias=b)``, DeepSeek-V3's ``noaux_tc``):
 the experts are chosen on ``sigmoid(logits) + b`` and weighted by the scores
 alone; ``b`` is state that no gradient reaches and ``update_router_bias``
-moves toward an even load.
+moves toward an even load.  With ``n_group`` > 1 the choice is
+**group-limited**: the experts lie in consecutive groups, a token keeps its
+``topk_group`` best groups (by the sum of a group's two largest ``score +
+b``) and chooses among their experts alone.
 """
 
 from __future__ import annotations
@@ -234,7 +237,8 @@ class Routing(NamedTuple):
 
 def route_topk(router_logits, k: int, *, renormalize: bool = False,
                scoring: str = "softmax", bias=None, scale: float = 1.0,
-               renorm_eps: float = 1e-20) -> Routing:
+               renorm_eps: float = 1e-20, n_group: int = 1,
+               topk_group: int = 1) -> Routing:
     """Top-``k`` routing of (T, E) logits with no capacity: softmax in
     float32, the ``k`` largest probabilities of each token (left as they
     are, or ``renormalize``d to sum to one), the ``T * k`` assignments
@@ -247,15 +251,44 @@ def route_topk(router_logits, k: int, *, renormalize: bool = False,
     LFM2 takes 1e-6).  Under either scoring the weights are multiplied by
     ``scale`` last (a routed scaling factor).
     ``balance_loss`` then takes the scores normalised over the experts as
-    its probabilities."""
+    its probabilities.
+
+    ``n_group`` > 1 (sigmoid scoring; DeepSeek-V3's group-limited choice):
+    the ``E`` experts lie in ``n_group`` consecutive groups of ``E /
+    n_group``; a group's score is the sum of its two largest ``scores +
+    bias``, a token's ``topk_group`` best groups stay (ties to the lower
+    group) and the ``k`` experts are chosen among theirs alone: no expert of
+    another group can be chosen, whatever its score.  The weights are the
+    chosen experts' scores as above.  With one group the staged program is
+    the one without the argument.  ``bf_moe_route_groups_total{kept}``
+    counts the groups a traced route scores, at trace time."""
     if scoring not in ("softmax", "sigmoid"):
         raise ValueError(f"scoring {scoring!r} not in ('softmax', 'sigmoid')")
     T, E = router_logits.shape
+    if n_group > 1 and (scoring != "sigmoid" or E % n_group
+                        or E // n_group < 2
+                        or not 1 <= topk_group <= n_group
+                        or topk_group * (E // n_group) < k):
+        raise ValueError(
+            f"route_topk: n_group {n_group} with topk_group {topk_group} "
+            f"needs scoring='sigmoid' (got {scoring!r}), {E} experts in "
+            f"whole groups of two or more, and top-{k} to fit the groups "
+            "that stay")
     logits = router_logits.astype(jnp.float32)
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
         chosen_on = scores if bias is None else scores + lax.stop_gradient(
             bias.astype(jnp.float32))
+        if n_group > 1:
+            telemetry.inc("bf_moe_route_groups_total", n_group,
+                          kept=str(topk_group))
+            by_group = chosen_on.reshape(T, n_group, E // n_group)
+            _, kept = lax.top_k(lax.top_k(by_group, 2)[0].sum(axis=-1),
+                                topk_group)
+            stays = jax.nn.one_hot(kept, n_group, dtype=jnp.bool_).any(
+                axis=1)
+            chosen_on = jnp.where(stays[..., None], by_group,
+                                  -jnp.inf).reshape(T, E)
         _, experts = lax.top_k(chosen_on, k)
         weights = jnp.take_along_axis(scores, experts, axis=-1)
         if renormalize:
@@ -454,7 +487,8 @@ def update_router_bias(bias, load, rate: float):
 def dropless_moe(x, router_logits, gate, up, down, *, k: int,
                  renormalize: bool = False, held: tuple = None,
                  scoring: str = "softmax", bias=None, scale: float = 1.0,
-                 renorm_eps: float = 1e-20):
+                 renorm_eps: float = 1e-20, n_group: int = 1,
+                 topk_group: int = 1):
     """A dropless top-``k`` mixture of experts on this rank.
 
     ``x``: (T, d) tokens in the compute dtype; ``router_logits``: (T, E);
@@ -492,8 +526,9 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
     float32 terms, and nothing is as long as the run.  With a window
     as large as ``T * k`` (``count * 2 >= E``) the layer is the one it is
     with ``held=None`` over the held matrices.  ``None``: all ``E`` are
-    held.  ``scoring``, ``bias``, ``scale`` and ``renorm_eps`` go to
-    ``route_topk``.
+    held.  ``scoring``, ``bias``, ``scale``, ``renorm_eps``, ``n_group``
+    and ``topk_group`` go to ``route_topk`` (the group-limited choice is
+    made over all ``E``, before the held share's window).
 
     Device scopes: ``bf.moe.route``, ``bf.moe.dispatch``, ``bf.moe.experts``
     and ``bf.moe.combine``; the caller wraps the layer (the router matmul
@@ -515,7 +550,8 @@ def dropless_moe(x, router_logits, gate, up, down, *, k: int,
     with timeline.device_scope("bf.moe.route"):
         plan = route_topk(router_logits, k, renormalize=renormalize,
                           scoring=scoring, bias=bias, scale=scale,
-                          renorm_eps=renorm_eps)
+                          renorm_eps=renorm_eps, n_group=n_group,
+                          topk_group=topk_group)
     if held is not None and held_window(T * k, count, E) < T * k:
         return _held_share(x, plan.weights, gate, up, down, plan.order,
                            plan.load, k, first), plan

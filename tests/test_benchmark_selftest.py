@@ -10,13 +10,14 @@ change moves one of them: the ledger's per-layer metrics would read ``null``.
 Their tests are collected here under their own names behind the file's;
 ``test_cells_cpu.py``, ``test_moe_cell_cpu.py`` (three minutes) and the
 ``test_twin_*`` cases of ``test_xing_cell_cpu.py``, ``test_lfm2_cell_cpu.py``,
-``test_laguna_cell_cpu.py``, ``test_twotower_cell_cpu.py`` and
-``test_kanana_cell_cpu.py`` (whose other cases, the cell's declaration, its
-published widths, its cost functions and its roofline readers, run here; of
-``test_laguna_cell_cpu.py``, ``test_twotower_cell_cpu.py`` and
-``test_kanana_cell_cpu.py`` the traced twin too, a minute and a half and under
-a minute twice: the cell's checks and every new reader on a CPU trace) stay by
-hand.
+``test_laguna_cell_cpu.py``, ``test_twotower_cell_cpu.py``,
+``test_kanana_cell_cpu.py`` and ``test_ling_cell_cpu.py`` (whose other cases,
+the cell's declaration, its published widths, its cost functions and its
+roofline readers, run here; of ``test_laguna_cell_cpu.py``,
+``test_twotower_cell_cpu.py``, ``test_kanana_cell_cpu.py`` and
+``test_ling_cell_cpu.py`` the traced twin too, a minute and a half and about
+a minute three times: the cell's checks and every new reader on a CPU trace)
+stay by hand.
 
 Two cases are collected through ``test_setup_readers.py`` and not directly:
 ``test_the_cell_is_declared_with_its_five_metrics`` / ``..._six_metrics``
@@ -32,8 +33,8 @@ runs here unchanged on ``BENCHMARK.json`` less everything appended after the
 four, cut by position and not by a list of names: the metrics behind the four,
 the cells behind the last one ``kernel_stagings`` lists, the configurations
 behind the last one such a cell names.  PR 40's cell and PR 42's are behind
-that line, and PR 47's cell, and the next appends without touching this
-file but for its tuple of files.  One more case runs on a cut file:
+that line, and PR 47's cell and PR 50's, and the next appends without
+touching this file but for its tuple of files.  One more case runs on a cut file:
 ``test_twotower_cell_cpu.py`` holds PR 42's entries to be the last of their
 lists, so it runs here on ``BENCHMARK.json`` as that PR left it
 (``_WHEN_LAST``); PR 47's own declaration case counts by position instead.
@@ -57,7 +58,7 @@ _WHEN_LAST = ("twotower_cell_cpu",
 
 for _file in ("trace_reduce", "program_readers", "setup_readers", "dropin",
               "xing_cell_cpu", "lfm2_cell_cpu", "laguna_cell_cpu",
-              "twotower_cell_cpu", "kanana_cell_cpu"):
+              "twotower_cell_cpu", "kanana_cell_cpu", "ling_cell_cpu"):
     _module = importlib.import_module(f"benchmark.selftest.test_{_file}")
     for _name, _obj in vars(_module).items():
         if _name.startswith(("test_twin_", "test_the_cell_is_declared_")) \

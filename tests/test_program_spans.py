@@ -350,6 +350,56 @@ def test_rank_map_names_a_callable_without_a_name():
     assert text.startswith("HloModule jit_bf_rank_map_fn,")
 
 
+class _Pending(np.ndarray):
+    """An argument the launch did not find ready."""
+    def is_ready(self):
+        return False
+
+
+@pytest.mark.parametrize("case,ready,waits", [
+    ("every launch held until its arguments were ready", True, 2),
+    ("held launches that returned before their arguments", False, 0),
+])
+def test_rank_map_waits_first_once_the_runtime_held_its_launches(
+        monkeypatch, tmp_path, case, ready, waits):
+    """``_HELD_LAUNCHES`` launches in a row that took ``_HELD_SECONDS`` and
+    returned with every argument ready: the next calls wait for their
+    arguments before they launch.  A launch that returned ahead of an
+    argument counts for nothing."""
+    from bluefog_tpu import basics
+    bf.init(devices=jax.devices()[:4])
+    monkeypatch.setattr(basics, "_HELD_SECONDS", 0.0)
+    mapped = bf.rank_map(lambda a, rest: a * 2)
+    x = np.ones((4, 2), np.float32)
+    rest = () if ready else (np.zeros((4, 1), np.float32).view(_Pending),)
+    out = []
+
+    def calls():
+        for _ in range(basics._HELD_LAUNCHES + 2):
+            out.append(mapped(x, rest))
+    names = [s[0] for s in _trace(tmp_path, calls)
+             if s[0].startswith("bf.rank_map.")]
+    np.testing.assert_array_equal(out[-1], 2 * x)
+    assert names.count("bf.rank_map.launch") == basics._HELD_LAUNCHES + 2
+    assert names.count("bf.rank_map.wait") == waits
+    assert telemetry.snapshot().get("bf_rank_map_waits_total", 0) == waits
+    if waits:
+        # the wait comes before its launch, from the fourth call on
+        assert names[-4:] == ["bf.rank_map.wait", "bf.rank_map.launch"] * 2
+
+
+def test_rank_map_a_quick_launch_starts_the_count_again(monkeypatch):
+    from bluefog_tpu import basics
+    bf.init(devices=jax.devices()[:4])
+    mapped = bf.rank_map(lambda a: a * 2)
+    x = jnp.ones((4, 2), np.float32)
+    for _ in range(3):
+        for held in (0.0,) * (basics._HELD_LAUNCHES - 1) + (3600.0,):
+            monkeypatch.setattr(basics, "_HELD_SECONDS", held)
+            x = mapped(x)   # two held, then one that no clock calls held
+    assert "bf_rank_map_waits_total" not in telemetry.snapshot()
+
+
 def _stripped(text):
     """A compiled module's text less what a name may change: the module's
     name, metadata, and the tables of source locations."""

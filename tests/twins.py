@@ -72,7 +72,8 @@ def _without_bias(ref, y, params, bias, cfg):
 # has / lacks: leaves the float32 case looks for, with their shapes.
 # load: (expert layers, router width) of the statistics.
 # f8: of the float8 case, the share of leaves over the bound and how many
-#   of 50 samples of 8 leaves may pass.
+#   of 50 samples of 8 leaves may pass (no case where the toy cannot set
+#   the precisions apart: ``tiny-ling3``, its ``model_check.why``).
 # traffic: the twin's own optimizer (``benchmark/selftest/traffic``).
 # held, experts: the key that says how many experts are held, and the
 #   reference's expert layer.
@@ -146,6 +147,22 @@ TWINS = {
         held="n_routed_experts", experts=_with_bias,
         documents=lambda seq: [seq - seq // 3 - seq // 5 - seq // 9,
                                seq // 3, seq // 5, seq // 9]),
+    "tiny-ling3": dict(
+        seed=51, f32=dict(seq=200, loss=1e-5, worst=1e-4, median=2e-5),
+        has={"block_0/kda/qkv/kernel": (64, 3 * 64),
+             "block_0/kda/conv_w": (3 * 64, 4),
+             "block_0/kda/f/kernel": (64, 64),
+             "block_0/kda/A_log": (4,), "block_0/kda/norm_scale": (16,),
+             "block_0/gate": None, "block_4/kda": None,
+             "block_5/mla/q/kernel": (64, 4 * 24),
+             "block_5/mla/attn_gate/kernel": (64, 4),
+             "block_1/moe/gate": (2, 64, 32),
+             "block_1/moe/shared_gate/kernel": (64, 32),
+             "block_1/moe/router/kernel": (64, 16)},
+        lacks=("block_0/moe", "block_0/mla", "block_5/kda",
+               "block_5/mla/q_a", "wpe"),
+        load=(5, 16), leaves=104, traffic="tiny-tokens-1row-adamw",
+        held="num_experts", experts=_with_bias),
 }
 
 
