@@ -107,6 +107,28 @@ def test_decays_at_the_bound_stay_finite_and_equal(bound):
             assert rel(a, b) < 2e-5, name
 
 
+@pytest.mark.parametrize("apart,chunk", [(0.3, 64), (0.1, 64), (0.1, 32)])
+def test_keys_that_nearly_repeat_leave_the_inverse_exact(apart, chunk):
+    """A trained layer's keys lie close together (``k_i . k_j`` 0.93 and
+    0.99 here) with ``beta`` near one and little decay: the system's
+    powers reach 1e8 before they cancel, which float32 does not survive,
+    so the inverse is made without them.  Value and gradients as for keys
+    that lie anywhere."""
+    key = jax.random.PRNGKey(11)
+    shape = (1, 200, 2, 32)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa
+    common = normal(key, 10, (1, 1, 2, 32))
+    k = unit(common + apart * normal(key, 1, shape))
+    q = unit(common + apart * normal(key, 0, shape)) * 32 ** -0.5
+    g = -0.05 * jax.nn.sigmoid(normal(key, 3, shape))
+    beta = jax.nn.sigmoid(2.5 + normal(key, 4, shape[:3]))
+    (got, grads), (want, ref_grads) = both(
+        (q, k, normal(key, 2, shape), g, beta), chunk)
+    assert rel(got, want) < 1e-5
+    for name, a, b in zip("q k v g beta".split(), grads, ref_grads):
+        assert rel(a, b) < 2e-5, name
+
+
 def test_the_rule_is_causal_and_corrects_what_the_state_predicts():
     args = rule_inputs(2, 1, 96, 2, 16, 8)
     with HIGHEST():
@@ -155,20 +177,76 @@ def test_the_rule_counts_its_chunks_and_checks_its_shapes():
         kda.kda_recurrent(*args[:4], args[4][..., None])
 
 
-def test_the_inverse_of_a_chunks_system_and_its_transpose():
-    key = jax.random.PRNGKey(5)
-    lower = jnp.tril(normal(key, 0, (3, 64, 64), 0.3), -1)
-    eye = jnp.eye(64)
-    with HIGHEST():
-        inverse = kda._unit_lower_inverse(lower)
-        np.testing.assert_allclose(
-            jnp.einsum("nij,njk->nik", eye + lower, inverse),
-            jnp.broadcast_to(eye, lower.shape), atol=2e-5)
-        w = normal(key, 1, lower.shape)
-        got = jax.grad(lambda a: (kda._unit_lower_inverse(a) * w).sum())(
-            lower)
-        want = jax.grad(lambda a: (jnp.linalg.inv(eye + a) * w).sum())(lower)
-    assert rel(got, want) < 1e-4
+STAGED = 'bf_kernel_stagings_total{kernel="bf_kda_%s"}'
+
+
+@pytest.mark.parametrize("b,seq,step", [(1, 230, 8), (2, 230, 8),
+                                        (2, 230, 1), (1, 384, 4)])
+def test_the_rule_kernels(monkeypatch, b, seq, step):
+    """The published head of 128 in bfloat16, chunks of 64 with and without
+    a tail, one and two sequences, a grid step of all of a sequence's
+    chunks, of some and of one: value and all five gradients against the
+    rule token by token in float32 on the same operands, and each kernel
+    staged once a shape."""
+    monkeypatch.setattr(kda, "_STEP_CHUNKS", step)
+    n = -(-seq // 64)
+    assert kda._step_chunks(n) == {8: 4, 1: 1, 4: 3}[step]
+    q, k, v, g, beta = rule_inputs(6, b, seq, 2, 128, 128)
+    args = tuple(x.astype(jnp.bfloat16) for x in (q, k, v)) + (g, beta)
+    count = lambda: [telemetry.snapshot().get(STAGED % kind, 0.0)  # noqa
+                     for kind in ("fwd", "bwd")]
+    before = count()
+    (got, grads), (want, ref_grads) = both(args, 64)
+    both(args, 64)
+    assert [x - y for x, y in zip(count(), before)] == [1, 1]
+    assert got.shape == want.shape
+    assert rel(got, want) < 0.01
+    for name, a, r in zip("q k v g beta".split(), grads, ref_grads):
+        assert a.dtype == r.dtype and a.shape == r.shape, name
+        assert rel(a, r) < 0.015, name
+
+
+def test_the_backward_keeps_the_inputs_and_the_entering_states_alone():
+    """The residuals of the rule's ``custom_vjp``: ``q``, ``k``, ``v``,
+    ``g``, ``beta`` as they came and a ``(V, K)`` float32 state a chunk and
+    head; no chunk matrix, nothing of ``(S, S)``."""
+    b, seq, H, D, C = 2, 256, 3, 16, 64
+    n = seq // C
+    wide = jax.ShapeDtypeStruct((b, seq, H * D), jnp.bfloat16)
+    operands = (wide, wide, wide,
+                jax.ShapeDtypeStruct(wide.shape, jnp.float32),
+                jax.ShapeDtypeStruct((b, H, n, 1, C), jnp.float32))
+    o, res = jax.eval_shape(lambda *a: kda._rule_fwd(
+        *a, (n, kda._step_chunks(n), C, D, D), True, frozenset()), *operands)
+    assert (o.shape, o.dtype) == (wide.shape, wide.dtype)
+    assert [(r.shape, r.dtype) for r in res[:5]] == [
+        (x.shape, x.dtype) for x in operands]
+    assert len(res) == 6
+    assert (res[5].shape, res[5].dtype) == ((b, H, n, D, D), jnp.float32)
+    # and the plain call writes no states at all
+    assert kda._fwd_call(*(jnp.zeros(x.shape, x.dtype) for x in operands),
+                         dims=(n, 1, C, D, D), save=False, interpret=True,
+                         vma=frozenset())[1] is None
+
+
+def test_shapes_a_tpu_cannot_tile_raise_and_the_interpreter_takes_them(
+        monkeypatch):
+    """Heads of 16 and 8 and chunks of 8 run in the interpreter; where the
+    kernels would be compiled, the door names the shape and stages
+    nothing."""
+    narrow = rule_inputs(7, 1, 40, 2, 16, 8)
+    short = rule_inputs(7, 1, 40, 1, 128, 128)
+    assert kda.kda_chunked(*narrow, chunk=16).shape == (1, 40, 2, 8)
+    assert kda.kda_chunked(*short, chunk=8).shape == (1, 40, 1, 128)
+    monkeypatch.setattr(kda, "platform_in_use", lambda *_: "tpu")
+    before = telemetry.snapshot().get(STAGED % "fwd", 0.0)
+    with pytest.raises(ValueError, match="a head of 16 keys and 8 values in "
+                       "chunks of 16 cannot be tiled on a TPU"):
+        kda.kda_chunked(*narrow, chunk=16)
+    with pytest.raises(ValueError, match="a head of 128 keys and 128 values "
+                       "in chunks of 8 cannot be tiled"):
+        kda.kda_chunked(*short, chunk=8)
+    assert telemetry.snapshot().get(STAGED % "fwd", 0.0) == before
 
 
 # --- the mixer ----------------------------------------------------------------------------
