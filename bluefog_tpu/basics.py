@@ -54,6 +54,8 @@ class _Context:
         self.initialized = False
         self.suspended = False
         self.devices: list = []
+        self.memory_device = None
+        self.launch_headroom_min: Optional[int] = None
         # Enumeration-order device list (the BLUEFOG_TPU_PLACEMENT=0 view);
         # ``devices``/``mesh`` hold the physically-placed permutation of it.
         self.base_devices: list = []
@@ -200,6 +202,12 @@ def init(topology_fn=None, is_weighted: bool = False, *,
         n = len(devs)
         _ctx.devices = devs
         _ctx.base_devices = list(devs)
+        # whose allocator a launch reads (_launch_memory), and the least
+        # room any launch has found since this call
+        me = jax.process_index()
+        _ctx.memory_device = next(
+            (d for d in devs if d.process_index == me), devs[0])
+        _ctx.launch_headroom_min = None
         _ctx.mesh = Mesh(np.asarray(devs), (RANK_AXIS,))
         if local_size is None:
             local_size = (jax.local_device_count()
@@ -480,11 +488,23 @@ def rank_map(fn):
     took 405, groups of five steps 1.917 to 1.961 s; waiting first, launches
     of 8 ms and groups of 1.922 to 1.931 s).  A launch that is quick, or
     returns before an argument is ready, starts the count again: the host
-    is ahead there and stays ahead."""
+    is ahead there and stays ahead.
+
+    **What a launch records** beside that rule: always, its seconds and the
+    wait's as histograms (``bf_rank_map_launch_seconds``,
+    ``bf_rank_map_wait_seconds``) and ``bf_rank_map_launches_total``; and
+    while someone listens (``timeline.listening()``: a profiler trace, a
+    timeline file, a step profile), without a threshold, the allocator's
+    state as it begins (span arguments ``in_use``, ``reserved``,
+    ``largest_free``, ``limit``; ``bf_launch_memory_bytes``,
+    ``bf_launch_headroom_min_bytes``) and whether it outlasted its
+    arguments (``held=1``: one was still being computed when the call
+    began and all were ready when it returned;
+    ``bf_rank_map_held_launches_total``)."""
     import time
 
     from bluefog_tpu.utils import telemetry
-    from bluefog_tpu.utils.timeline import op_span
+    from bluefog_tpu.utils.timeline import listening, op_span, timed_span
 
     def run(*args):
         out = fn(*jax.tree.map(lambda x: x[0], args))
@@ -508,13 +528,27 @@ def rank_map(fn):
 
     def mapped(*args):
         call = program()
-        if held[call] >= _HELD_LAUNCHES:
+        waited = held[call] >= _HELD_LAUNCHES
+        if waited:
             telemetry.inc("bf_rank_map_waits_total")
-            with op_span("rank_map", "wait"):
+            with timed_span("rank_map", "wait"):
                 jax.block_until_ready(args)
+        # What the launch finds (observation only, docs/timeline.md): the
+        # allocator's state, and whether an argument is still being computed.
+        # Asking costs 80 to 105 us a launch on a v5e host (PR 52), a span
+        # 6: so only while someone listens.
+        watched = listening()
+        pending = _pending(args) if watched and not waited else ()
         t0 = time.perf_counter()
-        with op_span("rank_map", "launch"):
+        with timed_span("rank_map", "launch",
+                        **(_launch_memory() if watched else {})) as span:
             out = call(*args)
+            outlasted = bool(pending) and not _pending(pending)
+            if watched:
+                span.set(held=int(outlasted))
+        telemetry.inc("bf_rank_map_launches_total")
+        if outlasted:
+            telemetry.inc("bf_rank_map_held_launches_total")
         if held[call] < _HELD_LAUNCHES:
             was_held = (time.perf_counter() - t0 >= _HELD_SECONDS
                         and all(getattr(x, "is_ready", lambda: True)()
@@ -523,6 +557,69 @@ def rank_map(fn):
         return out
     mapped.lower = lambda *args: program().lower(*args)
     return mapped
+
+
+def _first_leaf(tree):
+    """A leaf of a pytree, found from the root down without flattening it:
+    the first child's first child (None for a tree without leaves).  The
+    built-in containers are walked as they are, a dict in its own order:
+    the registry would sort every dict's keys on the way, and any leaf
+    stands for its tree here."""
+    if tree is None:
+        return None
+    if type(tree) is dict:
+        children = tree.values()
+    elif type(tree) in (tuple, list):
+        children = tree
+    else:
+        node = jax.tree_util.default_registry.flatten_one_level(tree)
+        if node is None:
+            return tree
+        children = node[0]
+    for child in children:
+        leaf = _first_leaf(child)
+        if leaf is not None:
+            return leaf
+    return None
+
+
+def _pending(args) -> tuple:
+    """One leaf of each argument that is still being computed.  A leaf
+    stands for its argument: a tree handed to a launch is one program's
+    result (the parameters are the optimizer step's) or the host's (a
+    batch), and a program's results are ready together; asking 322 leaves
+    would cost the launch more than it takes to enqueue."""
+    leaves = (_first_leaf(a) for a in args)
+    return tuple(x for x in leaves
+                 if not getattr(x, "is_ready", lambda: True)())
+
+
+def _launch_memory() -> dict:
+    """The allocator's state on this process's first device as a launch
+    begins, in bytes (the arguments of ``bf.rank_map.launch``), also
+    published as ``bf_launch_memory_bytes{kind}`` and, for ``limit - in_use
+    - reserved`` (what the allocator can still hand a program's results and
+    temporaries), as the smallest of the launches sampled since
+    ``bf.init()``: ``bf_launch_headroom_min_bytes``.  ``{}`` where the
+    platform keeps no such account (a CPU mesh)."""
+    from bluefog_tpu.utils import telemetry
+    ctx = _require_init()
+    stats = ctx.memory_device.memory_stats()
+    if not stats:
+        return {}
+    memory = {kind: int(stats.get(key, 0)) for kind, key in _MEMORY_KINDS}
+    for kind, value in memory.items():
+        telemetry.set_gauge("bf_launch_memory_bytes", value, kind=kind)
+    headroom = memory["limit"] - memory["in_use"] - memory["reserved"]
+    if ctx.launch_headroom_min is None or headroom < ctx.launch_headroom_min:
+        ctx.launch_headroom_min = headroom
+        telemetry.set_gauge("bf_launch_headroom_min_bytes", headroom)
+    return memory
+
+
+_MEMORY_KINDS = (("in_use", "bytes_in_use"), ("reserved", "bytes_reserved"),
+                 ("largest_free", "largest_free_block_bytes"),
+                 ("limit", "bytes_limit"))
 
 
 # A launch that took this long was held by the runtime, and this many in a

@@ -61,7 +61,7 @@ from bluefog_tpu.ops import schedule as S
 from bluefog_tpu.optim import functional as F
 from bluefog_tpu.optim.functional import CommunicationType, DistOptState
 from bluefog_tpu.utils import telemetry
-from bluefog_tpu.utils.timeline import op_span, startup_span
+from bluefog_tpu.utils.timeline import op_span, startup_span, timed_span
 
 __all__ = [
     "CommunicationType",
@@ -606,7 +606,7 @@ class DistributedOptimizer:
             jax.tree_util.tree_leaves(out[0]), key=lambda x: x.size,
             default=None)
         if before is not None:
-            with op_span("optim", "wait", step=self._steps_seen - 2):
+            with timed_span("optim", "wait", step=self._steps_seen - 2):
                 jax.block_until_ready(before)
         pe = profiler.profile_period(self.profile_every)
         if pe and self._steps_seen % pe == 0 and t0 is not None:

@@ -919,14 +919,32 @@ def _overflow_bwd(x, weights, cast, like, order, load, k, first, dy):
         return (d_x.astype(dy.dtype),) + tuple(rest)
 
 
+# What every operation of a branch carries in its ``op_name``, forward,
+# recompute and transpose: a device trace says by them which path a step's
+# held share took, without the loads on the host.  Not of the shape
+# ``bf.<layer>.<name>``, so an operation's scope stays the one it had.
+HELD_WINDOW = "bf_moe_held_window"
+HELD_OVERFLOW = "bf_moe_held_overflow"
+
+
+def _marked(marker: str, branch):
+    def run():
+        with timeline.device_scope(marker):
+            return branch()
+    return run
+
+
 def _branch(load, first, count, size, window, overflow):
     """One ``lax.cond`` on whether the held run fits the window.  The
     barrier keeps the two branches' ends apart: XLA moves a tail that both
     share out of the conditional, and a branch then hands over the tail's
     operands where it hands over its result."""
     with timeline.device_scope("bf.moe.dispatch"):
-        return lax.cond(_held_rows(load, first, count) <= size, window,
-                        lambda: lax.optimization_barrier(overflow()))
+        return lax.cond(
+            _held_rows(load, first, count) <= size,
+            _marked(HELD_WINDOW, window),
+            _marked(HELD_OVERFLOW,
+                    lambda: lax.optimization_barrier(overflow())))
 
 
 def _held_args(x, weights, gate, up, down, order, load, k, first):

@@ -47,7 +47,9 @@ __all__ = [
     "probe_span",
     "thread_name",
     "set_op_span_hook",
+    "listening",
     "op_span",
+    "timed_span",
     "startup_span",
     "watch_builds",
     "unwatch_builds",
@@ -250,9 +252,14 @@ def _begin(annotation: str, name: str, cat: str, args: dict):
     return ann
 
 
-def _end(ann, name: str, cat: str) -> None:
+def _end(ann, name: str, cat: str, late: dict = None) -> None:
+    """Close a span; ``late`` are the arguments known only now (whether a
+    launch was held): the annotation takes them as it takes the others, the
+    file's closing edge carries them (a viewer merges both edges' args)."""
+    if late:
+        ann.set_metadata(**late)
     if _writer is not None:
-        _emit_edge("E", name, cat, None)
+        _emit_edge("E", name, cat, late)
     ann.__exit__(None, None, None)
 
 
@@ -378,6 +385,15 @@ def set_op_span_hook(hook) -> None:
     _span_hook = hook
 
 
+def listening() -> bool:
+    """Is anyone there to take a span: a running ``jax.profiler`` trace, an
+    open timeline file or the ``StepProfiler`` hook.  A span costs a few
+    microseconds either way; what costs more to record (the allocator's
+    state at a launch: ``basics.rank_map``) is sampled only then."""
+    return (_writer is not None or _span_hook is not None
+            or jax.profiler.TraceAnnotation.is_enabled())
+
+
 class op_span:
     """Framework-internal span ``bf.<op_name>.<phase>``: the eager ops'
     ENQUEUE/COMMUNICATE/UPDATE phases (the automatic analogue of the
@@ -388,14 +404,21 @@ class op_span:
     three listeners: a running ``jax.profiler`` trace, the chrome-JSON
     timeline, and the ``StepProfiler`` hook.  ``args`` (``step=``,
     ``batch=``) are the identifiers that join a span to its counterpart on
-    another thread or on the device.  A class and not a generator: with
-    nobody listening a span costs one idle annotation and three
-    module-global checks (no autostart probe, no registry mutation)."""
+    another thread or on the device; :meth:`set` adds those that only the
+    span's end knows.  A class and not a generator: with nobody listening a
+    span costs one idle annotation and three module-global checks (no
+    autostart probe, no registry mutation)."""
 
-    __slots__ = ("_op", "_phase", "_args", "_ann", "_t0")
+    __slots__ = ("_op", "_phase", "_args", "_late", "_ann", "_t0")
 
     def __init__(self, op_name: str, phase: str, **args):
         self._op, self._phase, self._args = op_name, phase, args
+        self._late = None
+
+    def set(self, **args) -> None:
+        """Arguments of the open span that were not known when it began
+        (``held=1``); they are written when it closes."""
+        self._late = args if self._late is None else {**self._late, **args}
 
     def __enter__(self):
         if _writer is None and os.environ.get("BLUEFOG_TIMELINE"):
@@ -409,12 +432,43 @@ class op_span:
         return self
 
     def __exit__(self, *exc):
-        _end(self._ann, self._phase, self._op)
+        _end(self._ann, self._phase, self._op, self._late)
         if self._t0 is not None:
             _span_depth.d -= 1
             if _span_depth.d == 0 and _span_hook is not None:
                 _span_hook(self._op, self._phase,
                            time.perf_counter() - self._t0)
+        return False
+
+
+# The spans in which the host can stand still, and the histogram each also
+# feeds (``tools/metrics_lint`` reads the table).
+_STANDSTILL_METRICS = {
+    "bf.rank_map.launch": "bf_rank_map_launch_seconds",
+    "bf.rank_map.wait": "bf_rank_map_wait_seconds",
+    "bf.optim.wait": "bf_optim_wait_seconds",
+}
+
+
+class timed_span(op_span):
+    """An :class:`op_span` of ``_STANDSTILL_METRICS`` whose seconds also land
+    in its histogram of the registry: where the host stood still is read off
+    an untraced run too.  One ``with`` feeds both; with telemetry off the
+    histogram is not touched."""
+
+    __slots__ = ("_series", "_s0")
+
+    def __init__(self, op_name: str, phase: str, **args):
+        super().__init__(op_name, phase, **args)
+        self._series = _STANDSTILL_METRICS[f"bf.{op_name}.{phase}"]
+
+    def __enter__(self):
+        self._s0 = telemetry.start_timer()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        telemetry.observe_since(self._s0, self._series)
         return False
 
 

@@ -1,8 +1,8 @@
 """The benchmark's CPU self-tests as cases of tier-1 (ROADMAP I6).
 
 ``benchmark/selftest/test_trace_reduce.py``, ``test_program_readers.py``,
-``test_setup_readers.py`` and ``test_dropin.py`` hold the per-layer readers to
-a trace recorded on the chip, to hand-made counters and to the tiny twins of
+``test_setup_readers.py``, ``test_regime_readers.py`` and ``test_dropin.py``
+hold the per-layer readers to a trace recorded on the chip, to hand-made counters and to the tiny twins of
 the cells, traced here on the CPU in child processes.  The readers find the step program's operations by its scopes
 (``bf.optim.fuse`` / ``combine`` / ``unfuse`` / ``update``, ``bf.loss.chunked``,
 ``bf.moe*``), its spans and its counters, so these cases fail when a library
@@ -38,11 +38,24 @@ touching this file but for its tuple of files.  One more case runs on a cut file
 ``test_twotower_cell_cpu.py`` holds PR 42's entries to be the last of their
 lists, so it runs here on ``BENCHMARK.json`` as that PR left it
 (``_WHEN_LAST``); PR 47's own declaration case counts by position instead.
+
+PR 52 appends five metrics that every cell (three of them) or every
+held-share cell (two) reports, behind everything that was there, so they are
+behind every one of those lines and list cells before them.  The cuts below
+therefore take them off first, by position (``_regime_cut``: the last five,
+held to be ``test_regime_readers.NEW``), and the cases that look at a
+cell's list from its end (PR 36's four as the last of ``xing4`` and
+``lfm2``, ``12 + 5`` and ``12 + 6`` of them) run through
+``test_setup_readers_a_held_share_cell_is_declared...`` here on
+``BENCHMARK.json`` less the five;
+``test_regime_readers_the_five_entries_are_the_last...`` looks for the five.
 """
 
 import importlib
 import os
 import sys
+
+import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
@@ -56,13 +69,19 @@ _BEHIND_THE_FOUR = "test_the_entries_say_what_the_readers_are"
 _WHEN_LAST = ("twotower_cell_cpu",
               "test_declared_with_its_five_metrics_and_no_other_cells")
 
-for _file in ("trace_reduce", "program_readers", "setup_readers", "dropin",
+# PR 36's case that runs the two literal counts (12 + 5, 12 + 6) on the file
+# less its four: run below on the file less PR 52's five as well
+_FROM_THE_END = "test_a_held_share_cell_is_declared_as_it_was_and_with_the_four"
+
+for _file in ("trace_reduce", "program_readers", "setup_readers",
+              "regime_readers", "dropin",
               "xing_cell_cpu", "lfm2_cell_cpu", "laguna_cell_cpu",
               "twotower_cell_cpu", "kanana_cell_cpu", "ling_cell_cpu"):
     _module = importlib.import_module(f"benchmark.selftest.test_{_file}")
     for _name, _obj in vars(_module).items():
         if _name.startswith(("test_twin_", "test_the_cell_is_declared_")) \
-                or _name == _BEHIND_THE_FOUR or (_file, _name) == _WHEN_LAST:
+                or _name in (_BEHIND_THE_FOUR, _FROM_THE_END) \
+                or (_file, _name) == _WHEN_LAST:
             continue    # two minutes, by hand; through test_setup_readers
         if _name.startswith("test_"):
             globals()[f"test_{_file}_{_name[len('test_'):]}"] = _obj
@@ -70,9 +89,40 @@ for _file in ("trace_reduce", "program_readers", "setup_readers", "dropin",
             globals()[_name] = _obj     # a fixture its tests ask for by name
 
 
+def _regime_cut(spec):
+    """``spec.read_json`` that gives ``BENCHMARK.json`` less PR 52's five
+    entries, cut by position: they are the last five."""
+    regime = importlib.import_module("benchmark.selftest.test_regime_readers")
+    read_json = spec.read_json
+    whole = read_json(os.path.join(ROOT, "BENCHMARK.json"))
+    five = len(regime.NEW)
+    assert [m["name"] for m in whole["per_layer"][-five:]] == regime.NEW
+
+    def less_the_five(path):
+        data = read_json(path)
+        if os.path.basename(path) == "BENCHMARK.json":
+            data["per_layer"] = data["per_layer"][:-five]
+        return data
+    return less_the_five
+
+
+@pytest.mark.parametrize("module, test", [
+    ("xing_cell_cpu", "test_the_cell_is_declared_with_its_five_metrics"),
+    ("lfm2_cell_cpu", "test_the_cell_is_declared_with_its_six_metrics")])
+def test_setup_readers_a_held_share_cell_is_declared_as_it_was_and_with_the_four(
+        module, test, monkeypatch):
+    """PR 36's case unchanged (it takes its own four off and runs the
+    cell's literal count), on ``BENCHMARK.json`` less PR 52's five."""
+    from benchmark import spec
+    setup = importlib.import_module("benchmark.selftest.test_setup_readers")
+    monkeypatch.setattr(spec, "read_json", _regime_cut(spec))
+    getattr(setup, _FROM_THE_END)(module, test, monkeypatch)
+
+
 def test_setup_readers_the_entries_say_what_the_readers_are(monkeypatch):
     from benchmark import spec
     setup = importlib.import_module("benchmark.selftest.test_setup_readers")
+    monkeypatch.setattr(spec, "read_json", _regime_cut(spec))
     read_json = spec.read_json
     whole = read_json(os.path.join(ROOT, "BENCHMARK.json"))
     names = [m["name"] for m in whole["per_layer"]]
@@ -111,6 +161,7 @@ def test_twotower_cell_cpu_declared_with_its_five_metrics_and_no_other_cells(
     from benchmark import spec
     module = importlib.import_module(
         f"benchmark.selftest.test_{_WHEN_LAST[0]}")
+    monkeypatch.setattr(spec, "read_json", _regime_cut(spec))
     read_json = spec.read_json
     whole = read_json(os.path.join(ROOT, "BENCHMARK.json"))
     cell = [w["name"] for w in whole["workloads"]].index(
